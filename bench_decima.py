@@ -217,7 +217,7 @@ def _flat_knobs() -> dict:
         "bulk_events": int(os.environ.get("DEC_BENCH_FLAT_EVENTS", 8)),
         # on by default: FULFILL micro-steps only advance in full
         # micro-steps, so with a burst every un-bulked fulfillment costs
-        # a whole burst-sized group (PERF.md round-6 calibration)
+        # a whole burst-sized group (PERF_ROUNDS.md round-6 calibration)
         "fulfill_bulk": bool(int(
             os.environ.get("DEC_BENCH_FLAT_FULFILL", 1)
         )),
@@ -435,7 +435,7 @@ def bench_inference(
 
 
 def _latency_block(samples_ms: list[float], reps: int) -> dict:
-    """The `latency` row's percentile block (PERF.md round 13 schema):
+    """The `latency` row's percentile block (PERF_ROUNDS.md round 13):
     per-decision wall-time percentiles over `reps` timed calls. Since
     round 14 this is the shared `obs.metrics.percentile_block` helper
     (exact numpy percentiles, identical keys/values to the r10 rows —
@@ -446,22 +446,13 @@ def _latency_block(samples_ms: list[float], reps: int) -> dict:
 
 
 def _on_chip_block() -> dict:
-    """On-chip-only latency-row fields, guarded with the established
-    UNAVAILABLE marker so CPU rows are complete and self-describing
-    (the MULTICHIP_r*.json `real_mesh` pattern): allocator stats exist
-    only on the real backend; chip-session stage 14 fills them."""
+    """On-chip-only latency-row fields: allocator stats exist only on
+    an accelerator backend, and the field is absent where they do not
+    (a CPU row)."""
     from sparksched_tpu.obs.memory import device_memory_stats
 
     stats = device_memory_stats()
-    if stats is None:
-        return {
-            "device_memory": (
-                "UNAVAILABLE: no allocator stats on this backend "
-                "(CPU run); chip-session stage 14 records the "
-                "on-chip values"
-            ),
-        }
-    return {"device_memory": stats}
+    return {} if stats is None else {"device_memory": stats}
 
 
 # the serving benches' Decima architecture — ONE definition shared by
@@ -1359,7 +1350,7 @@ def bench_serve_scale(
         # host drains one batched transfer per cadence, so the online
         # loop's record cost is the ring drain, not a per-decision
         # sync. SERVE_SCALE_RING=0 restores the r16 per-decision path
-        # (the before arm of the PERF.md round-20 table).
+        # (the before arm of the PERF_ROUNDS.md round-20 table).
         ring_size = int(os.environ.get(
             "SERVE_SCALE_RING", 8 * max_batch
         ))
@@ -1736,21 +1727,22 @@ def bench_serve_scale(
         # import `bench_decima` fresh; the __main__ bench gates keep
         # re-import side-effect-free), so every replica compiles the
         # SAME net at the SAME seed — bit-identical params fleet-wide.
-        # On a chip host the replicas default to host cores: one
-        # device client per chip means N spawned processes cannot all
-        # claim the parent's accelerator (SERVE_SCALE_FLEET_PLATFORM
-        # overrides, e.g. for per-process device slices).
-        fleet_platform = os.environ.get(
-            "SERVE_SCALE_FLEET_PLATFORM",
-            "" if jax.default_backend() == "cpu" else "cpu",
-        )
+        # This process has already used its device, and a chip belongs
+        # to one process: spawned replicas cannot claim it too. How
+        # replicas are laid out on chips is the benchmark's to decide.
+        if replica_counts and jax.default_backend() != "cpu":
+            raise RuntimeError(
+                "bench_serve_scale's replica sweep spawns replica "
+                "processes from a parent that holds the "
+                f"{jax.default_backend()} device; it runs on a CPU "
+                "backend only (SERVE_SCALE_REPLICAS= skips the sweep)"
+            )
         spec = ReplicaSpec(
             builder="bench_decima:_serve_setup",
             serve_cfg={
                 "capacity": fleet_capacity, "max_batch": fleet_batch,
                 "deterministic": True, "seed": 0,
             },
-            platform=fleet_platform,
         )
         sweep: dict[str, dict] = {}
         for n_rep in replica_counts:
@@ -1830,7 +1822,6 @@ def bench_serve_scale(
                 "capacity_per_replica": fleet_capacity,
                 "max_batch": fleet_batch,
                 "compile_cache": True,
-                "platform": fleet_platform or "inherit",
             },
             "cpu_count": cores,
             # replica scaling is CORE-bound: N serve processes need N
@@ -2048,12 +2039,9 @@ def bench_ppo(
 if __name__ == "__main__":
     from sparksched_tpu.config import (
         enable_compilation_cache,
-        honor_jax_platforms_env,
+        use_fast_prng,
     )
 
-    from sparksched_tpu.config import use_fast_prng
-
-    honor_jax_platforms_env()
     enable_compilation_cache()
     if os.environ.get("BENCH_PRNG", "rbg") == "rbg":
         use_fast_prng()
@@ -2108,14 +2096,12 @@ if __name__ == "__main__":
         )
     # ISSUE 10: decision-serving latency rows (p50/p99, batch=1 vs
     # batch=K, cold start + linger sweep) through the AOT session
-    # store; SERVE_BENCH=0 skips (the rows also run standalone from
-    # chip-session stage 14 at the 1024-session scale)
+    # store; SERVE_BENCH=0 skips
     if os.environ.get("SERVE_BENCH", "1") == "1":
         bench_serve_latency()
     # ISSUE 11: open-loop goodput@SLO rows (offered-load sweep through
     # the seeded load generator + instrumented micro-batching front);
-    # SERVE_SCALE_BENCH=0 skips (the rows also run standalone from
-    # chip-session stage 15 at chip scale)
+    # SERVE_SCALE_BENCH=0 skips
     if os.environ.get("SERVE_SCALE_BENCH", "1") == "1":
         bench_serve_scale()
     # ISSUE 17: the round's top-level summary artifact (the headline

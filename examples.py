@@ -111,9 +111,9 @@ def run_episode(scheduler, seed: int = 0, render: bool = True,
 
 
 if __name__ == "__main__":
-    from sparksched_tpu.config import honor_jax_platforms_env
+    from sparksched_tpu.config import enable_compilation_cache
 
-    honor_jax_platforms_env()
+    enable_compilation_cache()
     p = ArgumentParser()
     p.add_argument("--sched", default="fair",
                    choices=["fair", "fifo", "random", "decima"])

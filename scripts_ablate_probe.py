@@ -2,8 +2,7 @@ import time
 from functools import partial
 import jax
 from jax import lax
-from sparksched_tpu.config import EnvParams, enable_compilation_cache, honor_jax_platforms_env
-honor_jax_platforms_env()
+from sparksched_tpu.config import EnvParams, enable_compilation_cache
 from sparksched_tpu.env import core
 
 # ablation: cheap deterministic sampler (one gather, no rng)

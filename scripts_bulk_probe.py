@@ -87,11 +87,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    from sparksched_tpu.config import (
-        enable_compilation_cache,
-        honor_jax_platforms_env,
-    )
+    from sparksched_tpu.config import enable_compilation_cache
 
-    honor_jax_platforms_env()
     enable_compilation_cache()
     main()

@@ -86,14 +86,10 @@ def main(bursts=(1, 4, 8, 16)):
 
 
 if __name__ == "__main__":
-    from sparksched_tpu.config import (
-        enable_compilation_cache,
-        honor_jax_platforms_env,
-    )
+    from sparksched_tpu.config import enable_compilation_cache
 
     import sys
 
-    honor_jax_platforms_env()
     enable_compilation_cache()
     if len(sys.argv) > 1:
         main(tuple(int(b) for b in sys.argv[1:]))

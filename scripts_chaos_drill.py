@@ -43,10 +43,6 @@ import subprocess
 import sys
 import tempfile
 
-from sparksched_tpu.config import honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 

@@ -215,7 +215,4 @@ def main():
 
 
 if __name__ == "__main__":
-    from sparksched_tpu.config import honor_jax_platforms_env
-
-    honor_jax_platforms_env()
     main()

@@ -33,12 +33,8 @@ import os
 import sys
 
 sys.path.insert(0, "/root/repo")
-from sparksched_tpu.config import (  # noqa: E402
-    enable_compilation_cache,
-    honor_jax_platforms_env,
-)
+from sparksched_tpu.config import enable_compilation_cache  # noqa: E402
 
-honor_jax_platforms_env()
 enable_compilation_cache()
 
 # round-5 bake-off at the 50-exec/50-job eval setting (12 held-out

@@ -19,12 +19,8 @@ models/decima/model_ft_plateau.msgpack.
 import sys
 
 sys.path.insert(0, "/root/repo")
-from sparksched_tpu.config import (  # noqa: E402
-    enable_compilation_cache,
-    honor_jax_platforms_env,
-)
+from sparksched_tpu.config import enable_compilation_cache  # noqa: E402
 
-honor_jax_platforms_env()
 enable_compilation_cache()
 
 FT_CKPT = "/root/repo/models/decima/model_ft.msgpack"

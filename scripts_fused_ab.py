@@ -3,7 +3,7 @@
 knobs, `BENCH_BULK_FUSED` flipped — and write the four rows plus the
 computed speedups to `artifacts/fused_ab_r07.json`.
 
-Configs are the two CPU rows PERF.md has tracked across rounds:
+Configs are the two CPU rows PERF_ROUNDS.md has tracked across rounds:
 
 - 8 lanes,   be=8 fb=1 bc=1  (the round-4 fused-pop A/B config)
 - 256 lanes, be=8 fb=1 bc=1  (the round-4/5 contended-box config)

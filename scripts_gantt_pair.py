@@ -11,10 +11,6 @@ import sys
 
 sys.path.insert(0, "/root/repo")
 
-from sparksched_tpu.config import honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 import examples  # noqa: E402
 
 if __name__ == "__main__":

@@ -19,7 +19,7 @@ params replicated — parallel.py) and records, per program:
   test, so the script and the CI pin cannot drift on what counts as a
   collective.
 
-Writes the table to stdout and appends a dated section to PERF.md when
+Writes the table to stdout and appends a dated section to PERF_ROUNDS.md when
 run with --record (`--engine core|flat` restricts the sweep). CPU-only;
 never touches the chip (force_virtual_cpu_devices before any jax call).
 """
@@ -111,7 +111,7 @@ def main() -> None:
             bad = set(engines) - {"core", "flat"}
             if bad:
                 # an unknown string would silently run the core engine
-                # under the typo'd label and append it to PERF.md as a
+                # under the typo'd label and append it to PERF_ROUNDS.md as a
                 # distinct measured engine
                 sys.exit(f"unknown --engine value(s) {sorted(bad)}; "
                          "valid: core, flat")
@@ -158,9 +158,9 @@ def main() -> None:
     out = "\n".join(lines) + "\n"
     print(out)
     if "--record" in sys.argv:
-        with open("PERF.md", "a") as fp:
+        with open("PERF_ROUNDS.md", "a") as fp:
             fp.write(out)
-        print("appended to PERF.md")
+        print("appended to PERF_ROUNDS.md")
 
 
 if __name__ == "__main__":

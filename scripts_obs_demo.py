@@ -34,18 +34,14 @@ writes `artifacts/runlog/obs_demo.jsonl`:
 
 The task-duration sampler is pinned to a deterministic table lookup for
 the parity section (the two engines draw from legitimately different
-rng STREAMS on stochastic banks — PERF.md operational rules — so only a
-deterministic sampler makes trajectories, and therefore counts,
+rng STREAMS on stochastic banks — PERF_ROUNDS.md operational rules — so
+only a deterministic sampler makes trajectories, and therefore counts,
 comparable). The overhead section runs the stock sampler.
 """
 
 from __future__ import annotations
 
 import time
-
-from sparksched_tpu.config import honor_jax_platforms_env
-
-honor_jax_platforms_env()
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402

@@ -29,7 +29,7 @@ Artifact: artifacts/online_loop_r20.json — swap/rollback counts and
 the zero-recompile pin, learner steps with losses and the per-update
 reward trend, trajectory-buffer accounting (drops are counted, never
 silent), the ring drain accounting, and the record-overhead A/B
-block. PERF.md rounds 16/20 document the row schema.
+block. PERF_ROUNDS.md rounds 16/20 document the row schema.
 
 Env knobs: ONLINE_LOOP_REQUESTS (default 240), ONLINE_LOOP_RATE_RPS
 (25), ONLINE_LOOP_TENANTS (4), ONLINE_LOOP_AB_REPS (5),
@@ -44,34 +44,26 @@ import os
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import jax
 
-import jax  # noqa: E402
-
-from sparksched_tpu.config import (  # noqa: E402
-    EnvParams,
-    honor_jax_platforms_env,
-)
-
-honor_jax_platforms_env()
-
-from sparksched_tpu.obs import runlog as runlog_mod  # noqa: E402
-from sparksched_tpu.obs.metrics import (  # noqa: E402
+from sparksched_tpu.config import EnvParams
+from sparksched_tpu.obs import runlog as runlog_mod
+from sparksched_tpu.obs.metrics import (
     MetricsRegistry,
     interleaved_ab,
     paired_ab_pct,
     percentile_block,
 )
-from sparksched_tpu.obs.runlog import RunLog, emit  # noqa: E402
-from sparksched_tpu.online import online_from_config  # noqa: E402
-from sparksched_tpu.schedulers import DecimaScheduler  # noqa: E402
-from sparksched_tpu.serve import (  # noqa: E402
+from sparksched_tpu.obs.runlog import RunLog, emit
+from sparksched_tpu.online import online_from_config
+from sparksched_tpu.schedulers import DecimaScheduler
+from sparksched_tpu.serve import (
     ContinuousBatcher,
     SessionStore,
     generate_arrivals,
     run_open_loop,
 )
-from sparksched_tpu.workload import make_workload_bank  # noqa: E402
+from sparksched_tpu.workload import make_workload_bank
 
 ARTIFACT = "artifacts/online_loop_r20.json"
 

@@ -21,12 +21,8 @@ import os.path as osp
 import sys
 
 sys.path.insert(0, "/root/repo")
-from sparksched_tpu.config import (  # noqa: E402
-    enable_compilation_cache,
-    honor_jax_platforms_env,
-)
+from sparksched_tpu.config import enable_compilation_cache  # noqa: E402
 
-honor_jax_platforms_env()
 enable_compilation_cache()
 
 from flax import serialization  # noqa: E402

@@ -22,7 +22,7 @@ Flags:
 Exit code 0 == analysis-clean tree.
 
 JAX_PLATFORMS defaults to cpu (tracing is backend-independent, and the
-audit must never claim an accelerator a bench session holds — PERF.md
+audit must never claim an accelerator a bench session holds — PERF_ROUNDS.md
 operational rules); an explicit JAX_PLATFORMS in the environment wins.
 """
 

@@ -118,14 +118,14 @@ MICRO_REC_SCHEMA: dict[str, tuple[str, tuple]] = {
 }
 
 STORED_OBS_SCHEMA: dict[str, tuple[str, tuple]] = {
-    "remaining": ("int32", ("J", "S")),
+    "remaining": ("int32", ("F",)),
     # the audited layout; `env: {obs_dtype: bfloat16}` configs narrow
     # this leaf to bf16 (ISSUE 7) — the audit always runs the default
     # f32 params, so the pin holds for CI while the low-precision
     # layout stays an explicit per-config opt-in
-    "duration": ("float32", ("J", "S")),
-    "schedulable": ("bool", ("J", "S")),
-    "node_mask": ("bool", ("J", "S")),
+    "duration": ("float32", ("F",)),
+    "schedulable": ("bool", ("F",)),
+    "node_mask": ("bool", ("F",)),
     "job_mask": ("bool", ("J",)),
     "job_template": ("int32", ("J",)),
     "exec_supplies": ("int32", ("J",)),
@@ -142,6 +142,9 @@ def dims_from_params(params) -> dict[str, int]:
         "J": params.max_jobs,
         "S": params.max_stages,
         "N": params.num_executors,
+        # a stored step's flat [J,S] node grid, padded to whole
+        # 128-wide rows (trainers/rollout.py:_flat_grid)
+        "F": -(-params.max_jobs * params.max_stages // 128) * 128,
     }
 
 

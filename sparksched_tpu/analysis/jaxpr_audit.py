@@ -2,7 +2,7 @@
 rule-by-rule.
 
 The decision row's cost on op-count-bound backends tracks jaxpr
-equation counts (PERF.md round-4 census), host callbacks serialize the
+equation counts (PERF_ROUNDS.md round-4 census), host callbacks serialize the
 dispatch pipeline, f64/i64 leaves double memory traffic and poison
 compile keys, and a data-dependent while-loop reappearing in a
 pinned-loop-free program re-introduces the straggler tax the flat
@@ -80,7 +80,7 @@ class Budget:
 # gather / scatter counts. A deliberate change that moves a count gets a
 # new cap of ~1.35x the measured value (gather/scatter: measured + max(2,
 # 35%)) IN THE SAME PR, with a bench row justifying the growth
-# (PERF.md "Static analysis"). Bands are deliberately loose: counts
+# (PERF_ROUNDS.md "Static analysis"). Bands are deliberately loose: counts
 # drift a few percent across jax versions; a band breach means
 # structural growth, not noise.
 #
@@ -100,7 +100,7 @@ class Budget:
 # decide_micro_step unchanged at 2729/28/1 (its bulk phase is the
 # mode-exclusive fulfill pass, deliberately left unfused). Caps below
 # tightened to ~1.35x the new measurements per the band policy; the
-# fusion A/B bench rows live in PERF.md round 11.
+# fusion A/B bench rows live in PERF_ROUNDS.md round 11.
 # ---------------------------------------------------------------------------
 
 BUDGETS: dict[str, Budget] = {
@@ -298,6 +298,16 @@ def wide_dtype_avals(jaxpr) -> list[str]:
     return found
 
 
+def is_host_callback_prim(prim: str) -> bool:
+    """Primitives that call back into the host: the `*callback*`
+    family (`pure_callback`, `io_callback`, `debug_callback`), the
+    legacy host_callback pair, and `debug_print`, which is what
+    `jax.debug.print` lowers to since jax 0.9."""
+    return "callback" in prim or prim in (
+        "outside_call", "host_callback", "debug_print",
+    )
+
+
 def audit_closed_jaxpr(name: str, closed, budget: Budget
                        ) -> tuple[list[Violation], dict[str, Any]]:
     """Apply every jaxpr rule to one traced program. Returns the
@@ -315,10 +325,7 @@ def audit_closed_jaxpr(name: str, closed, budget: Budget
     }
     found: list[Violation] = []
 
-    callbacks = {
-        p for p in prims
-        if "callback" in p or p in ("outside_call", "host_callback")
-    }
+    callbacks = {p for p in prims if is_host_callback_prim(p)}
     bad_cb = callbacks - set(budget.callback_allow)
     if bad_cb:
         found.append(Violation(

@@ -4,7 +4,7 @@ lane-fit advisor over the registered hot programs.
 The round-5 flagship bench died in XLA allocation analysis with a
 19.4 GB temp — a per-lane broadcast of the workload bank's duration
 table (`f32[512,154,20,3,8,16]`) that XLA:CPU folds away, so no CPU
-test, bench or calibration run could see it (PERF.md "Round-3 on-chip
+test, bench or calibration run could see it (PERF_ROUNDS.md "Round-3 on-chip
 session 1"; fixed by commit 81e77fb). This pass makes that class of
 failure a CPU-checkable CI failure, with the same shape as the eqn
 budgets in `jaxpr_audit`:
@@ -31,7 +31,7 @@ byte accounting (`obs.memory.jaxpr_memory_estimate` — args / outputs /
 consts / temp-total / peak lower bound and a top-K largest-buffer
 attribution naming shape + producing op), and for the lane programs a
 lane-fit table (max lanes under the `TPU_HBM_BUDGET_BYTES` budget,
-default 17.2 GB = the v5-lite part in PERF.md).
+default 17.2 GB = the v5-lite part in PERF_ROUNDS.md).
 
 Backend-true accounting (`compiled.memory_analysis()` after a real AOT
 compile) is NOT part of the default pass — it is backend-dependent
@@ -45,7 +45,7 @@ Re-pin procedure (same contract as jaxpr_audit.BUDGETS): run
 `passes.memory.measured` block prints every program's measured
 temp-total bytes. A deliberate change that moves a program's bytes
 gets a new cap of ~1.35x the measured value IN THE SAME PR, with a
-bench row justifying the growth (PERF.md "Memory"). Bands are loose:
+bench row justifying the growth (PERF_ROUNDS.md "Memory"). Bands are loose:
 byte totals drift a few percent across jax versions as fusion
 boundaries move; a band breach means structural allocation growth
 (a new lane-batched table, a widened buffer), not noise.

@@ -11,10 +11,11 @@ compile of anything.
 
 Fault classes (block keys; each an iteration list except `sigkill`):
 
-- ``nan_grad: [i, ...]`` — poison one recorded reward with NaN. The
-  returns/advantages go NaN, every minibatch loss/grad goes NaN, and
-  the PPO in-JIT sentinel (trainers/ppo.py) must skip the update while
-  the trainer rolls back and retries.
+- ``nan_grad: [i, ...]`` — poison one recorded reward with NaN. That
+  lane's returns up to the poisoned step go NaN, every minibatch that
+  holds one of those samples gets a NaN loss/grad, and the PPO in-JIT
+  sentinel (trainers/ppo.py) must keep each such minibatch from the
+  optimizer while the trainer rolls the whole update back and retries.
 - ``bank_row: [i, ...]`` — poison a recorded observation's duration
   row with NaN (what a corrupted workload-bank row read produces
   downstream). Detected by the update sentinels via NaN features; the
@@ -105,10 +106,11 @@ class ChaosMonkey:
             injected.append("nan_grad")
         if iteration in self.bank_row:
             b, t = int(rng.integers(B)), int(rng.integers(T))
-            j = int(rng.integers(ro.obs.duration.shape[2]))
-            dur = ro.obs.duration
+            j_cap, s_cap = ro.final_state.stage_remaining.shape[1:]
+            j = int(rng.integers(j_cap))
+            dur = ro.obs.duration  # [B, T, F], job j's stages in a row
             ro = ro.replace(obs=ro.obs.replace(
-                duration=dur.at[b, t, j].set(
+                duration=dur.at[b, t, j * s_cap:(j + 1) * s_cap].set(
                     jnp.asarray(jnp.nan, dur.dtype)
                 )
             ))

@@ -13,7 +13,15 @@ flattens the whole simulation into identical micro-steps —
 — so every lane advances by one unit of work on every iteration and no
 lane ever idles waiting for a straggler. Semantics are identical to the
 `core.step` loop (same phase-split helpers, same ordering); the flat-vs-
-step equivalence is asserted by tests/test_flat_loop.py.
+step equivalence is asserted by tests/test_flat_loop.py. One difference
+is by design: `core.step` looks at the episode's time limit where the
+reference's StochasticTimeLimit wrapper does, back at a decision, so a
+truncated episode's last step runs on to the first decision past the
+limit; this engine looks after every event (`_lane_done`, the bulk
+passes' `stop_at_limit`) and ends the episode on the first event at or
+past the limit. Every decision, its time and every reward but a
+truncated episode's last agree; that last span is a prefix of the core
+path's (test_flat_collection_at_the_time_limit_ends_on_the_crossing_event).
 
 Used by bench/eval paths where only final states and decision counts
 matter, and — since round 6 — by the trainers' fast rollout collectors

@@ -24,12 +24,18 @@ _LIB = None
 
 
 def _build_lib() -> str:
+    """The library is a build output (git-ignored): built from the
+    committed source on first use in a fresh checkout, and again when
+    the source is newer. Written under a temporary name and renamed, so
+    concurrent first users never load a half-written file."""
     out = osp.join(osp.dirname(_SRC), "libsparksched.so")
     if not osp.isfile(out) or os.path.getmtime(out) < os.path.getmtime(_SRC):
+        tmp = f"{out}.{os.getpid()}.tmp"
         subprocess.run(
-            ["g++", "-O2", "-shared", "-fPIC", "-o", out, _SRC],
+            ["g++", "-O2", "-shared", "-fPIC", "-o", tmp, _SRC],
             check=True,
         )
+        os.replace(tmp, out)
     return out
 
 

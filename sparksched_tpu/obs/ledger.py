@@ -29,7 +29,7 @@ diffs JSON by hand. The ledger closes that loop:
   the unit (rates are higher-better, latencies lower-better; unknown
   units are trend-only). REGRESSION only when the bands are DISJOINT in
   the bad direction (latest's most favorable edge worse than previous'
-  least favorable edge) — i.e. outside the noise band, the PERF.md
+  least favorable edge) — i.e. outside the noise band, the PERF_ROUNDS.md
   operational-rule standard. IMPROVEMENT is the mirror; else STABLE.
 
 CLI (`python -m sparksched_tpu.obs.ledger`): prints the trend report,

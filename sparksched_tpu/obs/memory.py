@@ -1,7 +1,7 @@
 """HBM memory observability: per-program byte accounting, tiled-layout
 size estimation, and the lane-fit advisor.
 
-Motivation (PERF.md "Round-3 on-chip session 1"): the round-5 flagship
+Motivation (PERF_ROUNDS.md "Round-3 on-chip session 1"): the round-5 flagship
 bench died in XLA allocation analysis with a 19.4 GB temp
 (`f32[512,154,20,3,8,16]`, a per-lane broadcast of the workload bank's
 duration table) that no CPU run could see — XLA:CPU folds the
@@ -38,7 +38,7 @@ observable on three layers:
   rows and trainer iterations (None on backends without allocator
   stats, e.g. CPU — callers must treat the fields as optional).
 
-`TPU_HBM_BUDGET_BYTES` defaults to the v5-lite number in PERF.md
+`TPU_HBM_BUDGET_BYTES` defaults to the v5-lite number in PERF_ROUNDS.md
 (17.2 GB decimal); override per call for other parts.
 """
 
@@ -46,7 +46,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, Iterator
 
-# the v5-lite HBM the round-5 OOM ran into (PERF.md: 19.4 GB > 17.2 GB)
+# the v5-lite HBM the round-5 OOM ran into (PERF_ROUNDS.md: 19.4 GB > 17.2 GB)
 TPU_HBM_BUDGET_BYTES = int(17.2e9)
 
 # TPU tiled layout: minor dim padded to the 128-wide lane, second-minor
@@ -578,7 +578,7 @@ def _tree_leaves(tree):
 
 
 def gb(n: int | float) -> float:
-    """Decimal GB, the unit PERF.md and the budget table speak."""
+    """Decimal GB, the unit PERF_ROUNDS.md and the budget table speak."""
     return round(float(n) / 1e9, 2)
 
 

@@ -32,11 +32,11 @@ that load, and a snapshot iterating while a handler bumped could see
 a dict mutated mid-iteration. One registry-wide `threading.Lock`
 guards the three tables; an uncontended CPython lock acquire is
 ~0.1us against ms-scale decides, so the <=5% instrumentation bar
-holds (measured: PERF.md round 21).
+holds (measured: PERF_ROUNDS.md round 21).
 
 `percentile_block` / `hist_summary` are the shared quantile helpers
 the benches use: `percentile_block` computes the EXACT sample
-percentiles (numpy) with the PERF.md round-13 latency-row keys — the
+percentiles (numpy) with the PERF_ROUNDS.md round-13 latency-row keys — the
 r10 artifact schema, unchanged — while `hist_summary` is the
 O(buckets) companion block (`hist`) new rows stamp alongside it.
 """
@@ -397,7 +397,7 @@ class MetricsRegistry:
 def interleaved_ab(arm_off, arm_on, warmups: int = 2, reps: int = 5
                    ) -> tuple[float, float, float]:
     """The interleaved-median A/B protocol (scripts_obs_demo.py,
-    PERF.md operational rules): warm both arms, then alternate timed
+    PERF_ROUNDS.md operational rules): warm both arms, then alternate timed
     reps so box-level drift hits both equally, and compare medians.
     `arm_off`/`arm_on` are zero-arg callables returning one rep's
     seconds. Returns (median_off, median_on, overhead_pct). ONE
@@ -442,7 +442,7 @@ def paired_ab_pct(offs: list[float], ons: list[float]) -> float:
 
 def percentile_block(samples: Iterable[float], reps: int | None = None,
                      suffix: str = "_ms") -> dict[str, Any]:
-    """Exact percentile block over retained samples (the PERF.md
+    """Exact percentile block over retained samples (the PERF_ROUNDS.md
     round-13 latency-row schema: p50/p90/p99/mean/max + reps)."""
     import numpy as np
 

@@ -71,7 +71,7 @@ record was flushed when written) and the crash-safety guarantees are
 unchanged: teardown stamps `run_end` into the ACTIVE file and never
 rotates (the signal path must not rename/reopen mid-kill).
 
-Readers: `PERF.md` "Reading a run" documents the schema; a runlog is
+Readers: `PERF_ROUNDS.md` "Reading a run" documents the schema; a runlog is
 greppable (`grep '"ev": "telemetry"' run.jsonl | tail -1`) and loads
 with one `json.loads` per line.
 """
@@ -507,7 +507,7 @@ def _install_teardown_hooks() -> None:
 
     def _on_sigterm(signum, frame):
         # restore the default disposition FIRST: if teardown ever
-        # wedges, a second SIGTERM must still kill the process
+        # blocks, a second SIGTERM must still kill the process
         signal.signal(signum, signal.SIG_DFL)
         _close_open_runlogs("sigterm", from_signal=True)
         os.kill(os.getpid(), signum)

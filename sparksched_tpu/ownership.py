@@ -28,7 +28,7 @@ whole stack in single-threaded benches), so `assert_owner` no-ops on
 
 Cost: the env-var gate is read once at import; with
 `SPARKSCHED_DEBUG_OWNERSHIP` unset every call is one module-global
-load + compare + return (measured ~53ns — see PERF.md round 21,
+load + compare + return (measured ~53ns — see PERF_ROUNDS.md round 21,
 <0.01% of a serve decide). No locks are taken on the fast path.
 """
 

@@ -98,12 +98,6 @@ class ReplicaSpec:
     serve_cfg: dict[str, Any] = field(default_factory=dict)
     compile_cache: bool = True
     trace: bool = False
-    # jax platform FOR THE REPLICAS ("" = inherit the parent's env).
-    # The chip case: one device client per chip means N replica
-    # processes cannot all claim the parent's accelerator — a fleet on
-    # a chip host runs its replicas on host cores (platform="cpu")
-    # unless each process is given its own device slice via env.
-    platform: str = ""
 
 
 def resolve_builder(path: str):
@@ -141,16 +135,10 @@ def _replica_main(conn, idx: int, spec: ReplicaSpec) -> None:
     then loop — drain pipe commands, pump the front, ship resolved
     tickets back. Runs until a `stop` command or pipe EOF."""
     try:
-        from ..config import (
-            enable_compilation_cache,
-            honor_jax_platforms_env,
-        )
+        from ..config import enable_compilation_cache
         from ..obs.metrics import MetricsRegistry
         from .session import front_from_config, store_from_config
 
-        if spec.platform:
-            os.environ["JAX_PLATFORMS"] = spec.platform
-        honor_jax_platforms_env()
         if spec.compile_cache:
             enable_compilation_cache()
         params, bank, scheduler = resolve_builder(spec.builder)(
@@ -306,7 +294,14 @@ class Router:
     """The session-affinity fleet front. See the module docstring for
     the protocol; construction SPAWNS `replicas` worker processes and
     blocks until every one handshakes ready (raising, and reaping the
-    fleet, if any replica fails to boot)."""
+    fleet, if any replica fails to boot).
+
+    A replica uses the device its process is given. A chip belongs to
+    one process, so on a chip host a fleet is either in-process
+    one-device stores (no Router), or one replica process per chip
+    with this parent off jax: nothing on the router side makes a jax
+    device call (test-pinned), so the parent never claims a chip its
+    replicas need."""
 
     def __init__(self, spec: ReplicaSpec, replicas: int = 2, *,
                  metrics=None, runlog=None, collector=None,
@@ -580,9 +575,9 @@ class Router:
         on every member. Returns the applied version (identical across
         the fleet: the explicit `version` stamp, or each store's
         increment from a common history)."""
-        import jax
+        from jax.tree_util import tree_map
 
-        host_params = jax.device_get(model_params)
+        host_params = tree_map(np.asarray, model_params)
         applied = None
         for r in self._alive():
             try:

@@ -38,6 +38,14 @@ from .rollout import Rollout, stored_to_observation
 from .trainer import CfgType, Trainer, TrainState
 
 EPS = 1e-8
+# Samples per gradient chunk of the update (see `_update`). The flagship
+# network keeps ~27 MB of activations alive per sample through the
+# backward pass (exec head over [200 jobs, 50 executors, 64]), so a
+# 16 GB chip holds a few hundred samples and the committed config's
+# minibatch is 16 lanes x 960 steps. 96 is also the size at which the
+# update was seen to reproduce the collector's log-probs on the v5e
+# (PERF.md, PR 25).
+CHUNK_SAMPLES = 96
 
 
 def _masked_mean(x, w, n):
@@ -128,7 +136,23 @@ class PPO(Trainer):
             """a: [B, T, ...], idx: i32[B, m] -> [B, m, ...]."""
             return jax.vmap(lambda row, ii: row[ii])(a, idx)
 
-        def loss_fn(params, idx, ok):
+        # A minibatch of more than CHUNK_SAMPLES samples is evaluated in
+        # chunks of `cs` of its time slots (all B lanes each, so the
+        # lane axis stays shard-aligned) and the chunks' gradients are
+        # summed: the minibatch's loss is a masked sum over samples
+        # divided by one count, so this is the same update up to the
+        # order of the sums. What needs the whole minibatch and not the
+        # network (the count, the advantage standardization) is done
+        # once, outside the chunks.
+        cs = max(
+            d for d in range(1, mbs + 1)
+            if mbs % d == 0 and (d == 1 or B * d <= CHUNK_SAMPLES)
+        )
+        nc = mbs // cs
+
+        def chunk_loss(params, idx, w, adv, n):
+            """One chunk's share of its minibatch's loss. idx, w, adv:
+            [B, cs]; n: the minibatch's valid-sample count."""
             so = jax.tree_util.tree_map(
                 lambda a: gather_t(a, idx).reshape(
                     B * idx.shape[1], *a.shape[2:]
@@ -142,17 +166,7 @@ class PPO(Trainer):
             lgprobs, entropies = self.scheduler.evaluate_actions(
                 params, feats, acts
             )
-            w = (
-                gather_t(valid, idx).reshape(-1)
-                & jnp.tile(ok, (B,))
-            ).astype(jnp.float32)
-            n = jnp.maximum(w.sum(), 1.0)
-
-            adv = gather_t(advantages, idx).reshape(-1)
-            mean = _masked_mean(adv, w, n)
-            var = ((adv - mean) ** 2 * w).sum() / jnp.maximum(n - 1, 1.0)
-            adv = (adv - mean) / (jnp.sqrt(var) + EPS)
-
+            w, adv = w.reshape(-1), adv.reshape(-1)
             log_ratio = lgprobs - gather_t(old_lgprobs, idx).reshape(-1)
             ratio = jnp.exp(log_ratio)
             pl1 = adv * ratio
@@ -169,7 +183,36 @@ class PPO(Trainer):
                 "kl": jax.lax.stop_gradient(kl),
             }
 
-        grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
+        chunk_grad = jax.value_and_grad(chunk_loss, has_aux=True)
+
+        def grad_fn(params, idx, ok):
+            """((loss, aux), grads) of one minibatch. idx: [B, mbs]."""
+            w = (gather_t(valid, idx) & ok[None, :]).astype(jnp.float32)
+            n = jnp.maximum(w.sum(), 1.0)
+            adv = gather_t(advantages, idx)
+            mean = _masked_mean(adv, w, n)
+            var = ((adv - mean) ** 2 * w).sum() / jnp.maximum(n - 1, 1.0)
+            adv = (adv - mean) / (jnp.sqrt(var) + EPS)
+            if nc == 1:
+                return chunk_grad(params, idx, w, adv, n)
+            chunks = jax.tree_util.tree_map(
+                lambda a: a.reshape(B, nc, cs).swapaxes(0, 1),
+                (idx, w, adv),
+            )  # [nc, B, cs]
+
+            def add_chunk(total, chunk):
+                return jax.tree_util.tree_map(
+                    jnp.add, total, chunk_grad(params, *chunk, n)
+                ), None
+
+            zero = jax.tree_util.tree_map(
+                lambda a: jnp.zeros(a.shape, a.dtype),
+                jax.eval_shape(
+                    chunk_grad, params, idx[:, :cs], w[:, :cs],
+                    adv[:, :cs], n,
+                ),
+            )
+            return jax.lax.scan(add_chunk, zero, chunks)[0]
 
         # in-JIT health sentinel (ISSUE 9, opt-in via the `health:`
         # block): a minibatch whose loss or gradients go non-finite is
@@ -207,6 +250,14 @@ class PPO(Trainer):
                 + computed * aux["entropy_loss"],
                 "kl": sums["kl"] + computed * aux["kl"],
                 "count": sums["count"] + computed,
+                # the first minibatch is evaluated at the collector's
+                # own parameters: its KL is how far the update's
+                # recomputed log-probs sit from the recorded ones
+                "kl_first": jnp.where(
+                    sums["count"] == 0, aux["kl"], sums["kl_first"]
+                ),
+                "applied": sums["applied"]
+                + do_update.astype(jnp.float32),
             }
             if health:
                 new_sums["health"] = sums["health"] | mb_mask
@@ -214,7 +265,7 @@ class PPO(Trainer):
 
         zero = jnp.float32(0.0)
         sums0 = {"policy_loss": zero, "entropy_loss": zero, "kl": zero,
-                 "count": zero}
+                 "count": zero, "kl_first": zero, "applied": zero}
         if health:
             sums0["health"] = jnp.int32(0)
         with annotate("train/ppo_update"):
@@ -228,6 +279,8 @@ class PPO(Trainer):
             "policy_loss": jnp.abs(sums["policy_loss"] / n),
             "entropy": jnp.abs(sums["entropy_loss"] / n),
             "approx_kl_div": jnp.abs(sums["kl"] / n),
+            "approx_kl_first": jnp.abs(sums["kl_first"]),
+            "minibatches_applied": sums["applied"],
             "avg_num_jobs_est": avg_num_jobs,
         }
         if health:

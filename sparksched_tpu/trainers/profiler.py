@@ -5,7 +5,7 @@ top-N cumulative stats. Host-side Python profiling is meaningless for a
 jitted program, so `Profiler` keeps the same context-manager interface but
 reports wall time and, when a trace directory is given, captures a
 `jax.profiler` device trace viewable in TensorBoard / Perfetto (phases
-are labeled via `obs.tracing.annotate` scopes — see PERF.md "Reading a
+are labeled via `obs.tracing.annotate` scopes — see PERF_ROUNDS.md "Reading a
 run")."""
 
 from __future__ import annotations

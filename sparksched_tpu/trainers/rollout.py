@@ -48,17 +48,36 @@ from ..workload.bank import WorkloadBank
 _i32 = jnp.int32
 
 
+# The [J,S] node grids of a stored step are kept flat, padded to a
+# multiple of the TPU's 128-wide lane tile. The TPU compiler lays an
+# array out so that padding is least: a rollout leaf [B,T,200,20] or
+# [B,T,4000] gets the TIME axis minor-most, and both the collector's
+# per-step row scatter and the update's minibatch gather along T then
+# go through whole-rollout relayout copies (at the flagship's 16 lanes x
+# 9600 steps: 9.4 GB of temporaries in each program beside the 6.1 GB
+# rollout, more than a 16 GB chip has). A last axis that is a multiple
+# of 128 stays minor-most, rows are contiguous, and the copies are gone
+# (0.4 GB and 3.5 GB of temporaries; tests/test_tpu_compile.py).
+_ROW = 128
+
+
+def _flat_grid(a: jnp.ndarray) -> jnp.ndarray:
+    a = a.reshape(-1)
+    return jnp.pad(a, (0, -a.shape[0] % _ROW))
+
+
 class StoredObs(struct.PyTreeNode):
     """Minimal per-step observation record from which `Observation` (and so
     Decima features) can be rebuilt — the padded equivalent of the obs dicts
     the reference keeps in RolloutBuffer.obsns (rollout_worker.py:27-39).
     The [S,S] adjacency is *not* stored: it is reconstructed from the job's
-    template id, which shrinks the rollout memory footprint by ~10x."""
+    template id, which shrinks the rollout memory footprint by ~10x.
+    F below is J*S rounded up to a multiple of 128 (`_flat_grid`)."""
 
-    remaining: jnp.ndarray  # i32[J,S]
-    duration: jnp.ndarray  # f32[J,S]
-    schedulable: jnp.ndarray  # bool[J,S]
-    node_mask: jnp.ndarray  # bool[J,S]
+    remaining: jnp.ndarray  # i32[F]
+    duration: jnp.ndarray  # f32[F]
+    schedulable: jnp.ndarray  # bool[F]
+    node_mask: jnp.ndarray  # bool[F]
     job_mask: jnp.ndarray  # bool[J]
     job_template: jnp.ndarray  # i32[J]
     exec_supplies: jnp.ndarray  # i32[J]
@@ -73,10 +92,12 @@ def store_obs(obs: Observation, state: EnvState) -> StoredObs:
     # `duration` deliberately inherits the bank's (possibly narrow)
     # dtype — it is the lane-scaled buffer the layout exists to halve
     return StoredObs(
-        remaining=jnp.where(obs.node_mask, state.stage_remaining, 0),
-        duration=obs.nodes[..., 1],
-        schedulable=obs.schedulable,
-        node_mask=obs.node_mask,
+        remaining=_flat_grid(
+            jnp.where(obs.node_mask, state.stage_remaining, 0)
+        ),
+        duration=_flat_grid(obs.nodes[..., 1]),
+        schedulable=_flat_grid(obs.schedulable),
+        node_mask=_flat_grid(obs.node_mask),
         job_mask=obs.job_mask,
         job_template=state.job_template,
         exec_supplies=obs.exec_supplies,
@@ -92,6 +113,11 @@ def stored_to_observation(bank: WorkloadBank, so: StoredObs) -> Observation:
     adjacency rather than stored: an i32[J,S] per step was ~30% of the
     rollout buffer at the flagship 200-job scale, and the S-deep level
     recursion is a small fraction of the GNN work the observation feeds."""
+    j, s = so.job_mask.shape[0], bank.adj.shape[-1]
+    so = so.replace(**{
+        name: getattr(so, name)[: j * s].reshape(j, s)
+        for name in ("remaining", "duration", "schedulable", "node_mask")
+    })
     adj = (
         bank.adj[so.job_template]
         & so.node_mask[:, :, None]
@@ -430,11 +456,12 @@ def _zero_stored(params: EnvParams) -> StoredObs:
     dur_dt = (
         jnp.bfloat16 if params.obs_dtype == "bfloat16" else jnp.float32
     )
+    f = _flat_grid(jnp.zeros((j, s), bool)).shape
     return StoredObs(
-        remaining=jnp.zeros((j, s), _i32),
-        duration=jnp.zeros((j, s), dur_dt),
-        schedulable=jnp.zeros((j, s), bool),
-        node_mask=jnp.zeros((j, s), bool),
+        remaining=jnp.zeros(f, _i32),
+        duration=jnp.zeros(f, dur_dt),
+        schedulable=jnp.zeros(f, bool),
+        node_mask=jnp.zeros(f, bool),
         job_mask=jnp.zeros((j,), bool),
         job_template=jnp.zeros((j,), _i32),
         exec_supplies=jnp.zeros((j,), _i32),

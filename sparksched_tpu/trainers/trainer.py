@@ -350,7 +350,7 @@ class Trainer(abc.ABC):
             train_cfg.get("flat_single_eval", True)
         )
         # micro-step-group budget per decision: the scan runs
-        # rollout_steps * this many groups (PERF.md mode census: ~3
+        # rollout_steps * this many groups (PERF_ROUNDS.md mode census: ~3
         # micro-steps per decision in steady state; 4 adds headroom)
         self.flat_micro_per_decision: float = float(
             train_cfg.get("flat_micro_per_decision", 4.0)
@@ -780,6 +780,10 @@ class Trainer(abc.ABC):
             state = state.replace(iteration=state.iteration + 1)
 
             roll_stats = self._rollout_stats(ro)
+            # the rollout is the largest thing on the device (6 GB at
+            # the flagship's 16 lanes x 9600 steps): do not hold it
+            # through the next iteration's collection
+            del ro
             avg_num_jobs = float(
                 stats.get("avg_num_jobs_est") or roll_stats["avg_num_jobs"]
             )

@@ -613,6 +613,77 @@ def _assert_rollouts_match(ro_core, ro_flat, lane=None):
         )
 
 
+def test_flat_collection_at_the_time_limit_ends_on_the_crossing_event(
+    monkeypatch
+):
+    """The one place the flat engine's rollout leaves the `core.step`
+    path's, pinned as a rule. `core.step` looks at the episode's time
+    limit where the reference's StochasticTimeLimit wrapper does, back
+    at a decision, so a truncated episode's last step runs on to the
+    first decision past the limit. The flat engine looks after every
+    event and freezes at the first one at or past the limit. Every
+    decision, its time and every reward but the last agree; the last
+    span of the flat engine is a prefix of the core path's."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparksched_tpu.env import core
+    from sparksched_tpu.schedulers import round_robin_policy
+    from sparksched_tpu.trainers.rollout import (
+        collect_flat_sync,
+        collect_sync,
+    )
+
+    params, bank, _ = _decima_parity_fixture(monkeypatch)
+
+    def fair(rng, obs):
+        si, ne = round_robin_policy(obs, params.num_executors, True)
+        return si, ne, {}
+
+    T = 80
+    free = core.reset(params, bank, jax.random.PRNGKey(3))
+    times = np.asarray(collect_sync(
+        params, bank, fair, jax.random.PRNGKey(0), T, free
+    ).wall_times)
+    k = 40
+    assert times[k] < times[k + 1], "fixture: pick a strict time step"
+    limit = float(times[k] + times[k + 1]) / 2
+    s0 = free.replace(time_limit=jnp.float32(limit))
+    ro_core = collect_sync(
+        params, bank, fair, jax.random.PRNGKey(0), T, s0
+    )
+    ro_flat = collect_flat_sync(
+        params, bank, fair, jax.random.PRNGKey(1), T, s0,
+        micro_groups=12 * T, fulfill_bulk=True,
+    )
+    nv = int(ro_core.valid.sum())
+    assert nv == k + 1 and bool(ro_core.final_state.truncated)
+    np.testing.assert_array_equal(
+        np.asarray(ro_core.valid), np.asarray(ro_flat.valid)
+    )
+    for name in ("stage_idx", "job_idx", "num_exec_k"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(ro_core, name))[:nv],
+            np.asarray(getattr(ro_flat, name))[:nv], err_msg=name,
+        )
+    np.testing.assert_allclose(
+        np.asarray(ro_core.wall_times)[:nv],
+        np.asarray(ro_flat.wall_times)[:nv], rtol=1e-6,
+    )
+    np.testing.assert_allclose(
+        np.asarray(ro_core.reward)[: nv - 1],
+        np.asarray(ro_flat.reward)[: nv - 1], rtol=1e-4, atol=1e-4,
+    )
+    end_core = float(ro_core.wall_times[nv])
+    end_flat = float(ro_flat.wall_times[nv])
+    assert end_core == pytest.approx(float(times[k + 1]), rel=1e-6)
+    assert limit <= end_flat <= end_core * (1 + 1e-6)
+    assert (
+        float(ro_core.reward[nv - 1]) - 1e-3
+        <= float(ro_flat.reward[nv - 1]) <= 0.0
+    )
+
+
 @pytest.mark.parametrize("job_bucket", [0, 3])
 def test_single_eval_flat_collection_matches_core_step_path(
     monkeypatch, job_bucket
@@ -668,7 +739,7 @@ def test_single_eval_flat_collection_one_policy_eval_per_decide(
     bumps a host counter via io_callback on every actual execution of
     the policy program; with B lanes and T decisions per lane the batch
     collector must evaluate T times total (one batched eval per row) —
-    the per-lane group collector measured ~2 per decision (PERF.md
+    the per-lane group collector measured ~2 per decision (PERF_ROUNDS.md
     round 6)."""
     import jax
 

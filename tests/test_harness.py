@@ -60,6 +60,35 @@ def test_config_loader(tmp_path):
     assert load(cfg_path) == cfg
 
 
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_is_placeable_from_outside(
+        tmp_path, monkeypatch, from_env):
+    """One cache for every entry point: `<checkout>/.jax_cache` by
+    default; where JAX_COMPILATION_CACHE_DIR is set, jax reads it
+    itself and the helper sets no directory in code (it would override
+    the variable)."""
+    import jax
+
+    from sparksched_tpu.config import enable_compilation_cache
+
+    updates: dict = {}
+    monkeypatch.setattr(jax.config, "update", updates.__setitem__)
+    if from_env:
+        monkeypatch.setenv(
+            "JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outside")
+        )
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    enable_compilation_cache()
+    assert updates.pop("jax_persistent_cache_min_compile_time_secs") == 1.0
+    if from_env:
+        assert updates == {}  # no directory was set in code
+    else:
+        assert updates == {"jax_compilation_cache_dir": osp.join(
+            osp.dirname(osp.dirname(osp.abspath(__file__))), ".jax_cache"
+        )}
+
+
 @pytest.mark.slow
 def test_graft_entry_compiles():
     import jax

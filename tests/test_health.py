@@ -210,17 +210,41 @@ def test_ppo_update_skips_poisoned_minibatches_in_jit(tmp_path):
     ro, _, _ = t._collect_jit(
         state.params, state.iteration, state.rng, None
     )
+    # The gate is per minibatch, so the poison has to be in every one
+    # for an all-skipped update. A NaN reward at a lane's LAST step
+    # makes that lane's whole return curve NaN (the returns scan runs
+    # in reverse), and every minibatch takes steps from every lane.
     poisoned = ro.replace(
-        reward=ro.reward.at[0, 0].set(jnp.float32(jnp.nan))
+        reward=ro.reward.at[0, -1].set(jnp.float32(jnp.nan))
     )
     new_state, stats = t._update_jit(state, poisoned)
     assert int(stats["health_mask"]) & H_NONFINITE_GRAD
     # every minibatch skipped on-device: params and opt state unmoved
     assert _tree_equal(new_state.params, state.params)
+    assert _tree_equal(new_state.opt_state, state.opt_state)
+    assert int(stats["minibatches_applied"]) == 0
     # and a clean rollout at the same params DOES move them
     moved, stats2 = t._update_jit(state, ro)
     assert int(stats2["health_mask"]) == 0
     assert not _tree_equal(moved.params, state.params)
+    # A NaN reward at a lane's FIRST step poisons one sample (its
+    # return alone), hence one minibatch of the two: that one is
+    # skipped, the clean one is applied (what jax 0.9's random streams
+    # turned the [0, 0] poison of this test into), the bit still trips
+    # and the host loop rolls the whole update back.
+    one = ro.replace(
+        reward=ro.reward.at[0, 0].set(jnp.float32(jnp.nan))
+    )
+    part, stats3 = t._update_jit(state, one)
+    assert int(stats3["health_mask"]) & H_NONFINITE_GRAD
+    assert (
+        int(stats3["minibatches_applied"])
+        == int(stats2["minibatches_applied"]) - 1
+    )
+    assert all(
+        bool(jnp.isfinite(leaf).all())
+        for leaf in jax.tree_util.tree_leaves(part.params)
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -52,7 +52,7 @@ def test_aval_bytes_int8_minor_dim_padding():
     under the tile model — while tile-aligned shapes keep the full
     width win. The lane-count headroom of the low-precision layout
     therefore comes from the lane-scaled bf16 observation buffers, not
-    the resident bank (PERF.md round 11)."""
+    the resident bank (PERF_ROUNDS.md round 11)."""
     import jax
     import jax.numpy as jnp
 
@@ -198,7 +198,7 @@ def test_lane_fit_quantized_layout_strictly_more_lanes():
     low-precision layout (int16 dur bank + bf16 observation features,
     `obs_dtype`) must fit STRICTLY more recording-collector lanes than
     the f32 layout. The win comes from the lane-scaled rollout-obs
-    buffers (`StoredObs.duration` bf16 halves its tile-padded bytes);
+    buffers (`StoredObs.duration` bf16 halves its bytes);
     the resident bank's tile-padded bytes are dtype-invariant at its
     [...,8,16] tail (see test_aval_bytes_int8_minor_dim_padding)."""
     import jax
@@ -214,10 +214,12 @@ def test_lane_fit_quantized_layout_strictly_more_lanes():
     params16 = params32.replace(obs_dtype="bfloat16")
     bank16 = quantize_bank(bank32, "int16")
 
-    T = 192  # recorded decision rows: the [T,...] obs buffers are the
+    T = 768  # recorded decision rows: the [T,...] obs buffers are the
     # lane-scaled bytes the layout halves, so T sets the per-lane
     # slope — sized so the 17.2 GB crossing lands mid-candidate-range
-    # (~900 f32 lanes at audit shapes)
+    # (~1100 f32 lanes at audit shapes; the stored node grids are flat
+    # [T,F] rows, which tile without padding, so a row costs a fifth
+    # of the [T,J,S] grid this test was first sized for)
 
     def make_fit(params, bank):
         def pol(rng, obs):

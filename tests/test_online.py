@@ -89,8 +89,9 @@ def test_record_results_carry_obs_and_version(rstore):
     r = rstore.decide(sids[0])
     assert r.decided and r.obs is not None
     assert r.params_version == rstore.params_version
-    # StoredObs shape sanity: [J, S] node grid of the serve env
-    assert np.asarray(r.obs.node_mask).shape == (6, 20)
+    # StoredObs shape sanity: the [J, S] = [6, 20] node grid of the
+    # serve env, flat and padded to whole 128-wide rows
+    assert np.asarray(r.obs.node_mask).shape == (128,)
     rs = rstore.decide_batch(sids)
     assert len({x.params_version for x in rs}) == 1
     for s in sids:

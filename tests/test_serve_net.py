@@ -16,6 +16,9 @@ replica of the shared fleet on purpose.
 from __future__ import annotations
 
 import json
+import os
+import subprocess
+import sys
 
 import jax
 import pytest
@@ -349,6 +352,73 @@ def test_open_loop_reconcile_counters_distinct(setup):
 # each one AOT-boots a full store — run with `-m slow` (or no marker
 # filter) to exercise them; tier-1 keeps the in-process HTTP tests.
 # --------------------------------------------------------------------------
+
+
+def _stub_replica_main(conn, idx, spec):
+    """A replica that owns no store: it answers the pipe protocol from
+    host state alone, so the only jax a Router built over it could
+    touch is the router's own."""
+    conn.send(("ready", idx, {"capacity": 4, "pid": os.getpid(),
+                              "front": "stub"}))
+    created = 0
+    while True:
+        msg = conn.recv()
+        op, rid = msg[0], msg[1]
+        if op == "create":
+            conn.send(("reply", rid, {"sid": created}))
+            created += 1
+        elif op == "submit":
+            conn.send(("result", rid, {
+                "session_id": msg[2], "decided": True, "replica": idx,
+            }, None))
+        elif op == "set_params":
+            conn.send(("reply", rid, {"version": msg[3]}))
+        elif op == "stop":
+            conn.send(("reply", rid, {"stopped": idx}))
+            conn.close()
+            return
+        else:
+            conn.send(("reply", rid, {}))
+
+
+def _router_parent_off_jax():
+    """Body of the child interpreter in the test below."""
+    import numpy as np
+    from jax._src import xla_bridge
+
+    import sparksched_tpu.serve.router as router_mod
+
+    router_mod._replica_main = _stub_replica_main
+    router = Router(ReplicaSpec(builder="unused:unused"), replicas=2)
+    try:
+        sids = [router.create(seed=i) for i in range(4)]
+        assert sorted(router.replica_of(s) for s in sids) == [0, 0, 1, 1]
+        tickets = [router.submit(s) for s in sids]
+        router.flush(timeout_s=30.0)
+        assert all(t.result is not None for t in tickets)
+        w = {"w": np.ones(3, np.float32)}
+        assert router.set_params(w, version=7) == 7
+        for s in sids:
+            router.close(s)
+    finally:
+        router.stop()
+    assert not xla_bridge.backends_are_initialized()
+    print("ROUTER_PARENT_OFF_JAX")
+
+
+def test_router_parent_makes_no_jax_device_call():
+    """A chip belongs to one process, so a Router whose replicas hold
+    the chips must never claim one itself: a fresh interpreter that
+    builds a Router over stub replicas and drives create / submit /
+    set_params / close / stop ends with no jax backend initialised."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import tests.test_serve_net as t; t._router_parent_off_jax()"],
+        cwd=repo, capture_output=True, text=True, timeout=300,
+    )
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "ROUTER_PARENT_OFF_JAX" in r.stdout
 
 
 @pytest.mark.slow

@@ -123,26 +123,40 @@ def test_rule_host_callback_fires_and_allowlist_clears():
     import jax
     import jax.numpy as jnp
 
-    def bad(x):
-        jax.debug.print("x={x}", x=x)  # lowers to a callback primitive
+    from sparksched_tpu.analysis import jaxpr_audit
+
+    def printed(x):
+        jax.debug.print("x={x}", x=x)  # `debug_print` since jax 0.9
         return x + 1
 
-    vs, measured = _audit_one(bad, jnp.float32(1.0))
-    assert "host-callback" in _rules(vs)
-    # the explicit allowlist (the telemetry-io_callback escape hatch)
-    # clears exactly that rule
-    vs2, _ = _audit_one(
-        bad, jnp.float32(1.0),
-        callback_allow=frozenset({"debug_callback"}),
-    )
-    assert "host-callback" not in _rules(vs2)
+    def called(x):
+        jax.debug.callback(lambda v: None, x)  # `debug_callback`
+        return x + 1
+
+    for bad, prim in ((printed, "debug_print"),
+                      (called, "debug_callback")):
+        jx = jax.make_jaxpr(bad)(jnp.float32(1.0))
+        assert prim in jaxpr_audit.primitive_counts(jx.jaxpr), prim
+        vs, measured = _audit_one(bad, jnp.float32(1.0))
+        assert "host-callback" in _rules(vs), prim
+        # the explicit allowlist (the telemetry-io_callback escape
+        # hatch) clears exactly that rule, for exactly that primitive
+        vs2, _ = _audit_one(
+            bad, jnp.float32(1.0), callback_allow=frozenset({prim}),
+        )
+        assert "host-callback" not in _rules(vs2), prim
+        vs3, _ = _audit_one(
+            bad, jnp.float32(1.0),
+            callback_allow=frozenset({"io_callback"}),
+        )
+        assert "host-callback" in _rules(vs3), prim
 
 
 def test_rule_wide_dtype_fires():
     import jax
     import jax.numpy as jnp
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         vs, _ = _audit_one(
             lambda x: x.astype(jnp.float64) * 2.0, jnp.float32(1.0)
         )

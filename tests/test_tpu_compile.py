@@ -1,0 +1,227 @@
+"""The main path's large jitted programs compile for the TPU v5e at the
+real widths, without a chip: the installed TPU compiler compiles for a
+described `v5e:2x2` topology and raises what the chip's compiler would
+raise (a program that does not fit the 16 GB of HBM, an op it cannot
+lower). Nothing runs, so this says nothing about results or times —
+`chip_smoke.py` is the run.
+
+The programs are the ones `chip_smoke.py` drives, built from its own
+configuration: the flagship cluster and model (50 executors, job cap
+200, Decima 16 / [32,16] / [64,64], job_bucket 32, 16 lanes, health
+on, rbg keys), the serve phase's store shape, and the headline bench's
+flat micro-step chunk at 1024 lanes unsplit and in sub-batches of 512.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and every xdist worker imports every
+test file. Keep these tests in this one file for the same reason.
+"""
+
+from __future__ import annotations
+
+import os
+
+import pytest
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def flagship(tmp_path_factory):
+    """The smoke run's trainer (its config, uncut), with the
+    process-wide settings it and these compiles touch put back
+    afterwards: the flagship config switches jax to rbg keys, and
+    a compile for a described chip must not go through the persistent
+    cache (it could be written but never read back)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    import chip_smoke
+    from sparksched_tpu.trainers import make_trainer
+
+    prng = jax.config.jax_default_prng_impl
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield make_trainer(chip_smoke.load_cfg(
+            chip_smoke.TRAIN_CONFIG,
+            str(tmp_path_factory.mktemp("tpu_compile")),
+        ))
+    finally:
+        jax.config.update("jax_default_prng_impl", prng)
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    """Shapes of `tree` (arrays or ShapeDtypeStructs) on the chip."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            jnp.shape(a), jnp.result_type(a), sharding=sharding
+        ),
+        tree,
+    )
+
+
+def _fits(compiled, temp_gib: float = 16.0) -> None:
+    ma = compiled.memory_analysis()
+    need = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+            + ma.output_size_in_bytes)
+    assert 0 < need < 16 * 2**30, ma
+    assert ma.temp_size_in_bytes < temp_gib * 2**30, ma
+
+
+def _train_state(trainer, one_chip):
+    import jax
+
+    return _on(one_chip, jax.eval_shape(trainer.init_state))
+
+
+def test_flagship_widths(flagship):
+    """What is compiled below is the flagship, not a cut of it."""
+    import jax
+
+    p = flagship.params_env
+    assert (p.num_executors, p.max_jobs, flagship.num_envs) == (50, 200, 16)
+    assert flagship.scheduler.job_bucket == 32
+    assert flagship.health_enabled and flagship.flat_single_eval
+    assert jax.config.jax_default_prng_impl == "rbg"
+
+
+def test_batch_policy_compiles(flagship, one_chip):
+    """`DecimaScheduler.batch_policy` at K=32 over 16 lanes."""
+    import jax
+
+    from sparksched_tpu.env import core
+    from sparksched_tpu.env.observe import observe
+
+    p, bank = flagship.params_env, flagship.bank
+    state = _train_state(flagship, one_chip)
+    obs = jax.eval_shape(
+        lambda k: jax.vmap(
+            lambda kk: observe(p, core.reset(p, bank, kk))
+        )(jax.random.split(k, flagship.num_envs)),
+        jax.random.PRNGKey(0),
+    )
+    _fits(jax.jit(flagship.scheduler.batch_policy).lower(
+        state.rng, _on(one_chip, obs), state.params
+    ).compile())
+
+
+def test_collect_and_ppo_update_compile(flagship, one_chip):
+    """The trainer's own two programs: the single-eval flat collector
+    (`collect_flat_sync_batch`, 16 lanes, fused bulk pass, health on)
+    and `ppo_update` with the health gate, on the collector's rollout,
+    at the committed 16 lanes x 9600 steps (a 6.1 GiB rollout). The
+    bounds on temporaries hold the two repairs that made that fit:
+    the stored node grids as flat rows of whole 128-lane tiles (with
+    [J,S] grids the compiler puts the time axis minor-most and both
+    programs copy the whole rollout: 9.2 GiB of temporaries each), and
+    the update's gradient in chunks of `ppo.CHUNK_SAMPLES` samples
+    (a 15,360-sample minibatch whole needs some 400 GB)."""
+    import jax
+
+    assert flagship.rollout_steps == 9600
+    state = _train_state(flagship, one_chip)
+    args = (state.params, state.iteration, state.rng, None)
+    _fits(flagship._collect_jit.lower(*args).compile(), temp_gib=1.0)
+    ro = jax.eval_shape(flagship._collect, *args)[0]
+    _fits(flagship._update_jit.lower(state, _on(one_chip, ro)).compile(),
+          temp_gib=5.0)
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_serve_programs_compile(flagship, one_chip, batched):
+    """`serve_decide` and `serve_decide_batch` as `SessionStore` builds
+    them, at the smoke run's store shape (hot set 128, K=8, donated)."""
+    import jax
+    import jax.numpy as jnp
+
+    import chip_smoke
+    from sparksched_tpu.env import core
+    from sparksched_tpu.env.flat_loop import init_loop_state
+    from sparksched_tpu.serve.aot import (
+        SERVE_KNOBS,
+        serve_decide_batch_fn,
+        serve_decide_fn,
+    )
+
+    p, bank = flagship.params_env, flagship.bank
+    hot = chip_smoke.SERVE_CFG["hot_capacity"]
+    k = chip_smoke.SERVE_CFG["max_batch"]
+    pol, bpol = flagship.scheduler.serve_param_policies(deterministic=True)
+    state = _train_state(flagship, one_chip)
+    slot = jax.eval_shape(
+        lambda key: init_loop_state(core.reset(p, bank, key)),
+        jax.random.PRNGKey(0),
+    )
+    store = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(
+            (hot,) + tuple(l.shape), l.dtype, sharding=one_chip
+        ),
+        slot,
+    )
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    flag = jax.ShapeDtypeStruct((), jnp.bool_, sharding=one_chip)
+    slots = jax.ShapeDtypeStruct((k,), jnp.int32, sharding=one_chip)
+    if batched:
+        fn = serve_decide_batch_fn(p, bank, bpol, k, SERVE_KNOBS)
+        args = (store, state.params, slots, state.rng)
+    else:
+        fn = serve_decide_fn(p, bank, pol, SERVE_KNOBS)
+        args = (store, state.params, i32, state.rng, i32, i32, flag)
+    _fits(jax.jit(fn, donate_argnums=(0,)).lower(*args).compile())
+
+
+@pytest.mark.parametrize("sub_batch", [1024, 512])
+def test_flat_chunk_1024_lanes_compiles(flagship, one_chip, sub_batch):
+    """bench.py's flat micro-step chunk at the headline 1024 lanes,
+    unsplit and in the 512-lane sub-batches an old fault made the
+    default: the installed compiler takes both."""
+    import jax
+
+    import bench
+    from sparksched_tpu.config import EnvParams
+    from sparksched_tpu.env import core
+    from sparksched_tpu.env.flat_loop import init_loop_state
+    from sparksched_tpu.obs.telemetry import telemetry_zeros_like
+    from sparksched_tpu.workload import make_workload_bank
+
+    p = EnvParams(
+        num_executors=10, max_jobs=50, max_stages=20, max_levels=20,
+        moving_delay=2000.0, warmup_delay=1000.0,
+        job_arrival_rate=4e-5, mean_time_limit=None,
+    )
+    bank = make_workload_bank(p.num_executors, p.max_stages)
+    p = p.replace(max_stages=bank.max_stages, max_levels=bank.max_stages)
+    n = 1024
+    keys = jax.eval_shape(
+        lambda k: jax.random.split(k, n), jax.random.PRNGKey(0)
+    )
+    lanes = jax.eval_shape(
+        lambda ks: jax.vmap(
+            lambda k: init_loop_state(core.reset(p, bank, k))
+        )(ks),
+        keys,
+    )
+    telem = jax.eval_shape(lambda: telemetry_zeros_like((n,)))
+    _fits(bench.bench_chunk.lower(
+        p, _on(one_chip, bank), _on(one_chip, lanes),
+        _on(one_chip, keys), 8, True, 1, _on(one_chip, telem),
+        sub_batch=sub_batch,
+    ).compile())

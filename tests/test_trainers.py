@@ -609,3 +609,50 @@ def test_stored_observation_roundtrip_is_exact():
         if bool(term) or bool(trunc):
             break
     assert checked > 30
+
+
+@pytest.mark.parametrize("chunk_samples", [24, 8])
+def test_ppo_update_in_chunks_matches_whole_minibatch(
+    tmp_path, monkeypatch, chunk_samples
+):
+    """A minibatch evaluated in chunks of its time slots, with the
+    chunks' gradients summed, is the update on the whole minibatch up
+    to the order of the sums: the same minibatches reach the optimizer,
+    the same losses, and (under SGD, whose step is linear in the
+    gradient) the same parameters. The first minibatch, evaluated at
+    the collector's own parameters, reproduces its log-probs."""
+    import jax
+
+    from sparksched_tpu.trainers import make_trainer, ppo
+
+    cfg = _mini_cfg({
+        "artifacts_dir": str(tmp_path), "num_epochs": 2,
+        "num_batches": 2, "opt_cls": "SGD", "rollout_steps": 48,
+    })
+
+    def update(limit):
+        monkeypatch.setattr(ppo, "CHUNK_SAMPLES", limit)
+        t = make_trainer(cfg)
+        state = t.init_state()
+        state = state.replace(rng=jax.random.fold_in(state.rng, 0))
+        ro, _, _ = t._collect_jit(
+            state.params, state.iteration, state.rng, None
+        )
+        # 24 slots a minibatch: one chunk, 2 chunks of 12, 6 of 4
+        assert ro.reward.shape == (2, 48)
+        new, stats = t._update_jit(state, ro)
+        return (
+            np.concatenate([
+                np.asarray(x).ravel()
+                for x in jax.tree_util.tree_leaves(new.params)
+            ]),
+            {k: float(v) for k, v in stats.items() if v is not None},
+        )
+
+    whole, want = update(10**9)
+    parts, got = update(chunk_samples)
+    assert got["minibatches_applied"] == want["minibatches_applied"] == 4
+    assert want["approx_kl_first"] < 1e-9 and got["approx_kl_first"] < 1e-9
+    for k in ("policy_loss", "entropy", "approx_kl_div"):
+        np.testing.assert_allclose(got[k], want[k], rtol=1e-4, atol=1e-8)
+    np.testing.assert_allclose(parts, whole, rtol=0, atol=1e-7)

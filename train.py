@@ -2,11 +2,11 @@
     python train.py -f config/decima_tpch.yaml
 """
 
-from sparksched_tpu.config import honor_jax_platforms_env, load
+from sparksched_tpu.config import enable_compilation_cache, load
 from sparksched_tpu.trainers import make_trainer
 
 if __name__ == "__main__":
-    honor_jax_platforms_env()
+    enable_compilation_cache()
     cfg = load()
     trainer = make_trainer(cfg)
     trainer.train()
