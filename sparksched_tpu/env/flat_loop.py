@@ -891,42 +891,43 @@ def decide_micro_step(
     `(ls, (decided, reward, dt, reset)[, telemetry])`; `decided` marks
     lanes that recorded a decision (live and in DECIDE mode at entry)."""
     track = telemetry is not None
-    is_dec = ls.mode == M_DECIDE
-    _, k_reset = jax.random.split(rng)
-    # force the tail's mode-keyed logic to the DECIDE shape for every
-    # lane (the event_micro_step pattern): non-decide lanes' branch
-    # results are discarded by the final select below
-    ls0 = ls.replace(mode=_i32(M_DECIDE))
-    ls2 = _apply_decision(params, ls0, stage_idx, num_exec, fulfill_bulk)
-    mode2 = ls2.mode  # pre-tail mode: DECIDE -> non-DECIDE == round done
-    out = _finish_micro_step(
-        params, bank, ls0, ls2, _i32(RQ_NONE), _i32(-1), _i32(-1),
-        _i32(0), ls2.env.source_job_id(), k_reset, auto_reset,
-        fulfill_bulk=fulfill_bulk, record=True, reset_fn=reset_fn,
-        t_ref=t_ref, telem=telemetry,
-    )
-    if track:
-        out_ls, (rw, dt, rs_), telemetry = out
-    else:
-        out_ls, (rw, dt, rs_) = out
-    was_done = _lane_done(ls.env)
-    decided = is_dec & ~was_done
-    if track:
-        telemetry = _tm_add(
-            telemetry,
-            decide_steps=decided,
-            commit_rounds=decided & (mode2 != M_DECIDE),
+    with annotate("env/micro_step/decide"):
+        is_dec = ls.mode == M_DECIDE
+        _, k_reset = jax.random.split(rng)
+        # force the tail's mode-keyed logic to the DECIDE shape for every
+        # lane (the event_micro_step pattern): non-decide lanes' branch
+        # results are discarded by the final select below
+        ls0 = ls.replace(mode=_i32(M_DECIDE))
+        ls2 = _apply_decision(params, ls0, stage_idx, num_exec, fulfill_bulk)
+        mode2 = ls2.mode  # pre-tail mode: DECIDE -> non-DECIDE == round done
+        out = _finish_micro_step(
+            params, bank, ls0, ls2, _i32(RQ_NONE), _i32(-1), _i32(-1),
+            _i32(0), ls2.env.source_job_id(), k_reset, auto_reset,
+            fulfill_bulk=fulfill_bulk, record=True, reset_fn=reset_fn,
+            t_ref=t_ref, telem=telemetry,
         )
-    final = jax.tree_util.tree_map(
-        lambda a, b: jnp.where(is_dec, a, b), out_ls, ls
-    )
-    zero = jnp.float32(0.0)
-    rec = (
-        decided,
-        jnp.where(is_dec, rw, zero),
-        jnp.where(is_dec, dt, zero),
-        is_dec & rs_,
-    )
+        if track:
+            out_ls, (rw, dt, rs_), telemetry = out
+        else:
+            out_ls, (rw, dt, rs_) = out
+        was_done = _lane_done(ls.env)
+        decided = is_dec & ~was_done
+        if track:
+            telemetry = _tm_add(
+                telemetry,
+                decide_steps=decided,
+                commit_rounds=decided & (mode2 != M_DECIDE),
+            )
+        final = jax.tree_util.tree_map(
+            lambda a, b: jnp.where(is_dec, a, b), out_ls, ls
+        )
+        zero = jnp.float32(0.0)
+        rec = (
+            decided,
+            jnp.where(is_dec, rw, zero),
+            jnp.where(is_dec, dt, zero),
+            is_dec & rs_,
+        )
     return (final, rec, telemetry) if track else (final, rec)
 
 
@@ -1046,9 +1047,12 @@ def drain_to_decision(
 
     The batch collectors vmap this; under vmap the while-loop costs the
     batch-max drain length per decision row — but every iteration is
-    pure env machinery (bulk passes + single pops), so the straggler tax
-    lands on the cheap slice while the GNN, the decision row's measured
-    70-90% share, runs exactly once per decision outside this loop.
+    pure env machinery (bulk passes + single pops), and the GNN runs
+    exactly once per decision outside this loop. Which slice is the
+    cheap one depends on the device: on the TPU v5e this loop is over
+    half of a decision row of 128 lanes and the GNN under a third
+    (PERF.md section 5), so there the straggler tax is the larger
+    bill. The device time is under the scope `env/micro_step/drain`.
     The ISSUE-7 restructure keeps that slice cheap two ways: the cond
     reduces to the existence bit of the next event (`_has_pending_event`
     — no argmin/kind chain), and the body runs `drain_micro_step` with
@@ -1091,7 +1095,8 @@ def drain_to_decision(
     c0 = (ls, rng, zero, zero, jnp.bool_(False))
     if track:
         c0 = c0 + (telemetry,)
-    c = lax.while_loop(cond, body, c0)
+    with annotate("env/micro_step/drain"):
+        c = lax.while_loop(cond, body, c0)
     ls, rw, dt, rs = c[0], c[2], c[3], c[4]
     if track:
         return ls, (rw, dt, rs), c[5]

@@ -31,7 +31,7 @@ Each scrape: (1) per-replica windows -> scoreboard (`fleet_status()`),
 (2) fleet-aggregate window -> `SLOMonitor.ingest` (alerts + optional
 rollback), (3) a periodic `fleet` runlog record (every `log_every`
 scrapes) so the scoreboard lands in the same JSONL stream the ledger
-and `scripts_phase_rank.py` read.
+reads.
 
 CLI: `python -m sparksched_tpu.obs.fleet --url http://host:port`
 scrapes a live server's `/fleet` endpoint; `--runlog FILE` renders the

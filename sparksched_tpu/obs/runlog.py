@@ -53,8 +53,6 @@ kind) and `t` (unix seconds); the kinds the trainer/bench write:
 - `alert`: an SLO burn-rate breach (ISSUE 17) — the spec name, both
   window burn rates, the rule that fired, and the action taken
   (`none` | `rollback`); written by `obs.slo.SLOMonitor`
-- `phase_rank`: a ranked on-device phase split (`scripts_phase_rank.py`
-  as data — per-phase device-time shares per bench row)
 
 Crash-safety: every record is flushed at write time, and open runlogs
 are closed (a final `run_end` with a `teardown` reason) from an
@@ -367,17 +365,6 @@ class RunLog:
         (samples, share, estimated self-ms, top innermost sites per
         role). Written once at profiler `stop()`."""
         self.write("hostprof", **tables)
-
-    def phase_rank(self, rows: list[dict[str, Any]],
-                   source: str | None = None, **fields: Any) -> None:
-        """A ranked on-device phase split (ISSUE 17 satellite): the
-        `scripts_phase_rank.py` table as data — per-phase share of
-        device time for each telemetry-stamped bench row — so chip-
-        session phase splits land in the same stream the ledger and
-        the fleet CLI read."""
-        if source is not None:
-            fields["source"] = source
-        self.write("phase_rank", rows=rows, **fields)
 
     # -- JIT recompile hooks ----------------------------------------------
 

@@ -25,6 +25,17 @@ Counter semantics per engine:
   (the micro-step composition), `loop_iters` the events consumed per
   lane (pops + bulk passes) — the lane-imbalance quantity the flat
   engine absorbs without stalling.
+- the single-eval batch collectors (`trainers/rollout.py`:
+  `collect_flat_sync_batch`, `collect_flat_async_batch`) also set the
+  four ROW counters, once per decision row of the scan and never inside
+  the drain's `while`: `rows` (scan iterations), `rows_live` (rows in
+  which some lane decided), `rows_full_width` (rows whose policy took
+  its full-width branch, `DecimaScheduler.full_width`; 0 for a policy
+  that reports none) and `drain_batch_iters` (the maximum over lanes of
+  the row's `drain_iters` increment: the bodies the vmapped `while`
+  really ran, which every lane pays for). They are facts of the batch,
+  not of a lane, so every lane holds the same value; no other engine or
+  collector touches them.
 
 Cross-engine invariant (the parity test): on a deterministic workload
 the two engines process the same trajectory, so `decide_steps`, the
@@ -70,6 +81,12 @@ class Telemetry(struct.PyTreeNode):
     # (flat single-eval path) / `_resume_simulation` (core). Max/mean
     # over lanes IS the measured batch-max drain tax.
     drain_iters: jnp.ndarray
+    # --- decision-row counters of the single-eval batch collectors:
+    # batch-level, the same value in every lane (module docstring) ---
+    rows: jnp.ndarray  # scan iterations (decision rows)
+    rows_live: jnp.ndarray  # rows in which some lane decided
+    rows_full_width: jnp.ndarray  # rows scored at the full job width
+    drain_batch_iters: jnp.ndarray  # sum over rows of max-lane drain iters
     # --- health sentinels (ISSUE 9) ---
     # i32 violation BITMASK (env/health.py bit table), OR-accumulated
     # via `orr` — not a counter. Stays 0 unless a collector runs with
@@ -171,6 +188,15 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
     di = np.asarray(t.drain_iters).ravel().astype(np.float64)
     mean_di = float(di.mean()) if lanes else 0.0
     drain_straggler = float(di.max() / mean_di) if mean_di > 0 else 1.0
+
+    def batch(x) -> int:
+        """A batch-level counter: every lane holds the same value, and
+        the maximum is right for a window in which lanes differ."""
+        x = np.asarray(x)
+        return int(x.max()) if x.size else 0
+
+    rows = batch(t.rows)
+    drain_batch = batch(t.drain_batch_iters)
     hm = np.asarray(t.health_mask).ravel()
     health_mask = (
         int(np.bitwise_or.reduce(hm)) if hm.size else 0
@@ -199,7 +225,7 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
         "fulfillments": fulfill + tot(t.bulk_fulfill_hits),
         # per-phase while-iteration split (ISSUE 7): the engine's
         # iteration budget attributed to decide / fulfill / event /
-        # bulk phases — scripts_phase_rank.py ranks these per decision
+        # bulk phases
         "phase_iters": {
             "decide": decide,
             "fulfill": fulfill,
@@ -209,6 +235,19 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
         "drain_iters_mean": round(mean_di, 2),
         "drain_iters_max": int(di.max()) if lanes else 0,
         "drain_straggler_ratio": round(drain_straggler, 3),
+        # the decision rows of the single-eval batch collectors (all
+        # zero from any other engine): what the device ran, whole rows
+        # and whole `while` bodies over every lane, beside what the
+        # lanes needed (`decisions`, `drain_iters_total`)
+        "row": {
+            "rows": rows,
+            "rows_live": batch(t.rows_live),
+            "rows_full_width": batch(t.rows_full_width),
+            "drain_batch_iters": drain_batch,
+            "lane_rows": rows * lanes,
+            "drain_lane_iters_executed": drain_batch * lanes,
+            "drain_iters_total": tot(t.drain_iters),
+        },
         # health sentinels (ISSUE 9): the pooled violation bitmask, its
         # decoded bit names, and how many lanes tripped anything —
         # all zero/empty unless a collector ran with health=True

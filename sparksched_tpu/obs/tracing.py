@@ -13,9 +13,28 @@ program needs for one Perfetto-readable label:
 Entering both is cheap and safe in either context (a TraceAnnotation
 with no profiler running is a no-op; a named_scope outside tracing only
 touches a thread-local name stack), so call sites don't have to care
-which side of the jit boundary they are on. The phases the codebase
-labels: `decima/gnn` (GNN eval), `env/micro_step` (flat engine),
-`collect/scatter` (decision-buffer scatter), `train/ppo_update`.
+which side of the jit boundary they are on.
+
+The phases the codebase labels. A decision row of the single-eval
+collectors (`trainers/rollout.py`) is covered whole: `collect/observe`
+(the vmapped `observe`), `decima/features`, `decima/gnn` (the net, with
+`decima/gnn/levels`, `decima/gnn/stage_head` and `decima/gnn/exec_head`
+inside it), `decima/sample`, `env/micro_step/decide` and
+`env/micro_step/drain` (the engine's two functions of that row, so the
+serve programs carry them too), `collect/health`, `collect/freeze`,
+`collect/scatter`. Elsewhere: `env/micro_step` (the mode switch and tail
+of `flat_loop.micro_step`), `train/ppo_update`, `serve/decide`,
+`serve/decide_batch`, `serve/dispatch`, `serve/flush`.
+
+A nested phase is ONE scope whose name holds its parent's
+(`annotate("env/micro_step/drain")`, not two nested `annotate`s): a
+device operation's `op_name` is the path of scopes and transforms it
+was traced under, a scope entered inside `jax.vmap` reads
+`vmap(env/micro_step)/drain` there, and flax puts its module names
+between a caller's scope and a module's own. The trace reducer matches
+a scope as a substring of `op_name`, so only a whole name is found under
+its own name and under its parent's. For the same reason a new
+top-level name must not contain an existing one.
 
 Exception safety: a raise inside the annotated block (or inside one of
 the two underlying exits) must still pop the named-scope stack — a

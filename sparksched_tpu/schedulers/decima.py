@@ -30,6 +30,7 @@ from flax import linen as nn
 from flax import struct
 
 from ..env.observe import Observation
+from ..obs.tracing import annotate
 from .base import TrainableScheduler
 
 NUM_NODE_FEATURES = 5  # reference env_wrapper.py:9
@@ -306,11 +307,12 @@ class DecimaNet(nn.Module):
 
         nl = min(self.num_levels, s_cap) if self.num_levels else s_cap
         levels = jnp.arange(nl - 1, -1, -1, dtype=_i32)
-        h_node, _ = nn.scan(
-            level_step,
-            variable_broadcast="params",
-            split_rngs={"params": False},
-        )(self, h0, levels)
+        with annotate("decima/gnn/levels"):
+            h_node, _ = nn.scan(
+                level_step,
+                variable_broadcast="params",
+                split_rngs={"params": False},
+            )(self, h0, levels)
         # reference fast path for an observation with no edges
         # (scheduler.py:205-207,236-241): plain prep(x), no update().
         # Reduced per ITEM (last 3 axes), not over leading batch dims:
@@ -335,44 +337,50 @@ class DecimaNet(nn.Module):
 
         # --- StagePolicyNetwork (reference scheduler.py:279-320) ---
         j_cap = x.shape[-3]
-        h_dag_rpt = jnp.broadcast_to(
-            h_dag[..., :, None, :], (*x.shape[:-1], d)
-        )
-        h_glob_rpt = jnp.broadcast_to(
-            h_glob[..., None, None, :], (*x.shape[:-1], d)
-        )
-        stage_in = jnp.concatenate(
-            [x, h_node, h_dag_rpt, h_glob_rpt], axis=-1
-        )
-        stage_scores = self.mlp_stage(stage_in)[..., 0].astype(jnp.float32)
+        with annotate("decima/gnn/stage_head"):
+            h_dag_rpt = jnp.broadcast_to(
+                h_dag[..., :, None, :], (*x.shape[:-1], d)
+            )
+            h_glob_rpt = jnp.broadcast_to(
+                h_glob[..., None, None, :], (*x.shape[:-1], d)
+            )
+            stage_in = jnp.concatenate(
+                [x, h_node, h_dag_rpt, h_glob_rpt], axis=-1
+            )
+            stage_scores = self.mlp_stage(stage_in)[..., 0].astype(
+                jnp.float32
+            )
 
         # --- ExecPolicyNetwork (reference scheduler.py:323-385) ---
         # x_dag = first NUM_DAG_FEATURES features of each dag's first node;
         # features 0..2 are per-job constants so any active node works.
-        first = jnp.argmax(f.node_mask, axis=-1)
-        x_dag = jnp.take_along_axis(
-            x, first[..., None, None], axis=-2
-        )[..., 0, :NUM_DAG_FEATURES]
-        n = self.num_executors
-        k_frac = (jnp.arange(n, dtype=_i32) / n).astype(x.dtype)
-        per_job = jnp.concatenate([x_dag, h_dag], axis=-1)
-        exec_in = jnp.concatenate(
-            [
-                jnp.broadcast_to(
-                    per_job[..., :, None, :],
-                    (*per_job.shape[:-1], n, per_job.shape[-1]),
-                ),
-                jnp.broadcast_to(
-                    h_glob[..., None, None, :],
-                    (*per_job.shape[:-1], n, d),
-                ),
-                jnp.broadcast_to(
-                    k_frac[:, None], (*per_job.shape[:-1], n, 1)
-                ),
-            ],
-            axis=-1,
-        )
-        exec_scores = self.mlp_exec(exec_in)[..., 0].astype(jnp.float32)
+        with annotate("decima/gnn/exec_head"):
+            first = jnp.argmax(f.node_mask, axis=-1)
+            x_dag = jnp.take_along_axis(
+                x, first[..., None, None], axis=-2
+            )[..., 0, :NUM_DAG_FEATURES]
+            n = self.num_executors
+            k_frac = (jnp.arange(n, dtype=_i32) / n).astype(x.dtype)
+            per_job = jnp.concatenate([x_dag, h_dag], axis=-1)
+            exec_in = jnp.concatenate(
+                [
+                    jnp.broadcast_to(
+                        per_job[..., :, None, :],
+                        (*per_job.shape[:-1], n, per_job.shape[-1]),
+                    ),
+                    jnp.broadcast_to(
+                        h_glob[..., None, None, :],
+                        (*per_job.shape[:-1], n, d),
+                    ),
+                    jnp.broadcast_to(
+                        k_frac[:, None], (*per_job.shape[:-1], n, 1)
+                    ),
+                ],
+                axis=-1,
+            )
+            exec_scores = self.mlp_exec(exec_in)[..., 0].astype(
+                jnp.float32
+            )
 
         return stage_scores, exec_scores
 
@@ -556,6 +564,18 @@ class DecimaScheduler(TrainableScheduler):
         )
 
     # -- scoring (compaction-aware) ----------------------------------------
+    def full_width(self, f: DecimaFeatures):
+        """The scalar predicate of `score`'s full-width fallback: some
+        item of `f` (over all leading axes) holds more than `job_bucket`
+        active jobs. None where the net has one width only (no bucket,
+        or a bucket that covers the job cap). `batch_policy` reports it
+        as `aux["full_width"]`, which the single-eval collectors count
+        per decision row (`Telemetry.rows_full_width`)."""
+        k = self.job_bucket
+        if not k or k >= f.job_mask.shape[-1]:
+            return None
+        return (f.job_mask.sum(-1) > k).any()
+
     def score(self, params, f: DecimaFeatures):
         """Stage/exec scores for padded features `f` — unbatched [J,...]
         or with any number of leading batch axes. With `job_bucket` K > 0
@@ -568,11 +588,11 @@ class DecimaScheduler(TrainableScheduler):
         collectors, bench) execute exactly one branch at runtime —
         unlike a per-lane cond, which jax's batching rule lowers to
         executing both branches for every lane."""
+        overflow = self.full_width(f)
+        if overflow is None:
+            return self.net.apply(params, f)
         k = self.job_bucket
         j_cap = f.job_mask.shape[-1]
-        if not k or k >= j_cap:
-            return self.net.apply(params, f)
-        overflow = (f.job_mask.sum(-1) > k).any()
 
         def full(f):
             return self.net.apply(params, f)
@@ -591,17 +611,19 @@ class DecimaScheduler(TrainableScheduler):
     # -- pure policy (vmap/scan-safe) -------------------------------------
     def policy(self, rng: jax.Array, obs: Observation, params=None,
                deterministic: bool = False):
-        from ..obs.tracing import annotate
-
         params = self.params if params is None else params
-        f = self.features(obs)
+        with annotate("decima/features"):
+            f = self.features(obs)
         with annotate("decima/gnn"):
             stage_scores, exec_scores = self.score(params, f)
-        action, lgprob = sample_action(
-            rng, stage_scores, exec_scores, f, deterministic
-        )
-        # env takes a 1-based executor count (reference env_wrapper.py:33-34)
-        return action.stage_idx, action.num_exec + 1, {
+        with annotate("decima/sample"):
+            action, lgprob = sample_action(
+                rng, stage_scores, exec_scores, f, deterministic
+            )
+            # env takes a 1-based executor count (reference
+            # env_wrapper.py:33-34)
+            num_exec = action.num_exec + 1
+        return action.stage_idx, num_exec, {
             "lgprob": lgprob,
             "job_idx": action.job_idx,
             "num_exec_k": action.num_exec,
@@ -614,24 +636,31 @@ class DecimaScheduler(TrainableScheduler):
         evaluation, with the compaction cond at batch level (scalar
         predicate — one branch executes at runtime). `rng` is a single
         key, split per lane internally. Returns per-lane
-        (stage_idx[B], num_exec_1based[B], aux-of-[B])."""
-        from ..obs.tracing import annotate
-
+        (stage_idx[B], num_exec_1based[B], aux-of-[B]); where the net
+        has two widths, aux also holds the row's scalar `full_width`
+        (see `full_width`)."""
         params = self.params if params is None else params
-        f = jax.vmap(self.features)(obs)
+        with annotate("decima/features"):
+            f = jax.vmap(self.features)(obs)
         with annotate("decima/gnn"):
             stage_scores, exec_scores = self.score(params, f)
-        keys = jax.random.split(rng, f.job_mask.shape[0])
-        action, lgprob = jax.vmap(
-            lambda r, ss, es, ff: sample_action(
-                r, ss, es, ff, deterministic
-            )
-        )(keys, stage_scores, exec_scores, f)
-        return action.stage_idx, action.num_exec + 1, {
+            wide = self.full_width(f)
+        with annotate("decima/sample"):
+            keys = jax.random.split(rng, f.job_mask.shape[0])
+            action, lgprob = jax.vmap(
+                lambda r, ss, es, ff: sample_action(
+                    r, ss, es, ff, deterministic
+                )
+            )(keys, stage_scores, exec_scores, f)
+            num_exec = action.num_exec + 1
+        aux = {
             "lgprob": lgprob,
             "job_idx": action.job_idx,
             "num_exec_k": action.num_exec,
         }
+        if wide is not None:
+            aux["full_width"] = wide
+        return action.stage_idx, num_exec, aux
 
     # -- flat micro-step engine adapter ------------------------------------
     def flat_policy(self, params=None, deterministic: bool = False):
@@ -713,8 +742,6 @@ class DecimaScheduler(TrainableScheduler):
         for the backward pass across the whole minibatch — the memory
         wall at the flagship 200-job/20-stage scale. Remat trades one
         recomputed forward for ~S x less live activation memory."""
-
-        from ..obs.tracing import annotate
 
         def one(f, a):
             with annotate("decima/gnn"):

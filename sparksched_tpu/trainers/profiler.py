@@ -5,8 +5,9 @@ top-N cumulative stats. Host-side Python profiling is meaningless for a
 jitted program, so `Profiler` keeps the same context-manager interface but
 reports wall time and, when a trace directory is given, captures a
 `jax.profiler` device trace viewable in TensorBoard / Perfetto (phases
-are labeled via `obs.tracing.annotate` scopes — see PERF_ROUNDS.md "Reading a
-run")."""
+are labeled via `obs.tracing.annotate` scopes; the module docstring of
+`obs/tracing.py` lists them, and `benchmarks/trace_reduce.py` turns
+such a trace into device seconds per scope)."""
 
 from __future__ import annotations
 

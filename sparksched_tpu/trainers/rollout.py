@@ -41,6 +41,7 @@ from ..env.flat_loop import (
 from ..env.health import reward_health, state_health
 from ..env.observe import Observation, observe
 from ..env.state import EnvState
+from ..obs.telemetry import add as _tm_add
 from ..obs.telemetry import orr as _tm_orr
 from ..obs.tracing import annotate
 from ..workload.bank import WorkloadBank
@@ -739,12 +740,20 @@ def collect_flat_sync(
 #     vmap(drain_to_decision) (non-policy micro-steps until every lane
 #     is at its next decision)
 #
-# The drain reintroduces a batch-max while-loop between decisions — but
-# only over the cheap env machinery (bulk passes + pops); the GNN, the
-# measured 70-90% of the Decima decision row, runs exactly once per
-# decision (test-pinned by a counting-policy test in
-# tests/test_flat_loop.py). Collected quantities remain step-exact vs
-# the `core.step` path.
+# The drain reintroduces a batch-max while-loop between decisions, over
+# the env machinery alone (bulk passes + pops); the GNN runs exactly
+# once per decision (test-pinned by a counting-policy test in
+# tests/test_flat_loop.py). On the TPU v5e that loop, not the GNN, is
+# most of a decision row (PERF.md section 5). Collected quantities
+# remain step-exact vs the `core.step` path.
+#
+# Every operation of the scan body runs under one of the trace scopes
+# `collect/observe`, `decima/features`, `decima/gnn`, `decima/sample`,
+# `env/micro_step` (`decide`, `drain`), `collect/health`,
+# `collect/freeze` and `collect/scatter` (key splits apart;
+# tests/test_obs.py pins it), and with a telemetry carry the body
+# counts its rows (`obs/telemetry.py`: `rows`, `rows_live`,
+# `rows_full_width`, `drain_batch_iters`).
 # ---------------------------------------------------------------------------
 
 
@@ -786,6 +795,12 @@ def _flat_collect_single_eval(
     with the lane axis sharded end-to-end instead of leaving the carry
     layout to the partitioner's fallback (which can silently replicate
     the largest resident buffers of the program).
+
+    With `telemetry`, each decision row also advances the four
+    batch-level row counters (`rows`, `rows_live`, `rows_full_width`,
+    `drain_batch_iters`: `obs/telemetry.py`), once per row and outside
+    the drain's `while`; `rows_full_width` reads the policy's
+    `aux["full_width"]` and stays 0 for a policy that gives none.
 
     With `health` (static; requires telemetry), each decision row ORs
     the per-lane `env/health.py` sentinel mask over the post-drain
@@ -849,23 +864,12 @@ def _flat_collect_single_eval(
         k, k_pol, k_dec, k_drain = jax.random.split(k, 4)
         env0 = ls.env
         wall0 = env0.wall_time  # [B]
-        if rollout_duration is not None:
-            over = elapsed >= rollout_duration
-        else:
-            over = jnp.zeros((B,), bool)
 
         # THE policy evaluation of this decision row (batch-level: one
         # net application, compaction cond on a scalar predicate)
-        obs = jax.vmap(lambda e: observe(params, e))(env0)
+        with annotate("collect/observe"):
+            obs = jax.vmap(lambda e: observe(params, e))(env0)
         stage_idx, num_exec, aux = batch_policy_fn(k_pol, obs)
-        lgprob, job, kk = aux_action_fields(
-            aux, stage_idx, num_exec, s_cap
-        )
-        # heuristic batch policies may omit lgprob (scalar default);
-        # the per-lane buffer scatters need a [B] leading axis
-        lgprob = jnp.broadcast_to(
-            jnp.asarray(lgprob, jnp.float32), stage_idx.shape
-        )
 
         out = v_decide(
             ls, stage_idx, num_exec, jax.random.split(k_dec, B),
@@ -875,9 +879,14 @@ def _flat_collect_single_eval(
             ls2, (decided, rw1, dt1, rs1), tm = out
         else:
             ls2, (decided, rw1, dt1, rs1) = out
-        # discount reference for the span this decision opens (the
-        # decide micro-step itself never advances the wall clock)
-        t_ref2 = jnp.where(decided & ~over, wall0, t_ref)
+        with annotate("collect/freeze"):
+            if rollout_duration is not None:
+                over = elapsed >= rollout_duration
+            else:
+                over = jnp.zeros((B,), bool)
+            # discount reference for the span this decision opens (the
+            # decide micro-step itself never advances the wall clock)
+            t_ref2 = jnp.where(decided & ~over, wall0, t_ref)
 
         out = v_drain(
             ls2, jax.random.split(k_drain, B), lane_idx, t_ref2, tm
@@ -886,35 +895,57 @@ def _flat_collect_single_eval(
             ls3, (rw2, dt2, rs2), tm = out
         else:
             ls3, (rw2, dt2, rs2) = out
-        reward = rw1 + rw2
-        dt = dt1 + dt2
-        reset = rs1 | rs2
+        with annotate("collect/freeze"):
+            reward = rw1 + rw2
+            dt = dt1 + dt2
+            reset = rs1 | rs2
         if health:
-            hm = jax.vmap(state_health)(
-                ls3.env, env0, reset
-            ) | reward_health(reward)
+            with annotate("collect/health"):
+                hm = jax.vmap(state_health)(
+                    ls3.env, env0, reset
+                ) | reward_health(reward)
 
-        # frozen lanes (async budget exhausted): state untouched,
-        # nothing recorded
-        ls3 = jax.tree_util.tree_map(
-            lambda a, b: jnp.where(
-                over.reshape(over.shape + (1,) * (a.ndim - 1)), a, b
-            ),
-            ls, ls3,
-        )
-        if track:
-            tm = jax.tree_util.tree_map(
-                lambda a, b: jnp.where(over, a, b), tm_frozen, tm
+        with annotate("collect/freeze"):
+            # frozen lanes (async budget exhausted): state untouched,
+            # nothing recorded
+            ls3 = jax.tree_util.tree_map(
+                lambda a, b: jnp.where(
+                    over.reshape(over.shape + (1,) * (a.ndim - 1)), a, b
+                ),
+                ls, ls3,
             )
-        if health:
-            tm = _tm_orr(tm, health_mask=jnp.where(over, 0, hm))
-        zero = jnp.float32(0.0)
-        reward = jnp.where(over, zero, reward)
-        dt = jnp.where(over, zero, dt)
-        reset = reset & ~over
-        dec = decided & ~over
+            dec = decided & ~over
+            if track:
+                # the bodies the vmapped drain `while` ran this row:
+                # every lane, frozen ones too, waits for the slowest
+                drained = (tm.drain_iters - tm_frozen.drain_iters).max()
+                tm = jax.tree_util.tree_map(
+                    lambda a, b: jnp.where(over, a, b), tm_frozen, tm
+                )
+                # the row counters are facts of the batch: added after
+                # the freeze, so every lane holds the same value
+                tm = _tm_add(
+                    tm, rows=1, rows_live=dec.any(),
+                    rows_full_width=aux.get("full_width", False),
+                    drain_batch_iters=drained,
+                )
+            if health:
+                tm = _tm_orr(tm, health_mask=jnp.where(over, 0, hm))
+            zero = jnp.float32(0.0)
+            reward = jnp.where(over, zero, reward)
+            dt = jnp.where(over, zero, dt)
+            reset = reset & ~over
+            elapsed2 = elapsed + dt
 
         with annotate("collect/scatter"):
+            lgprob, job, kk = aux_action_fields(
+                aux, stage_idx, num_exec, s_cap
+            )
+            # heuristic batch policies may omit lgprob (scalar default);
+            # the per-lane buffer scatters need a [B] leading axis
+            lgprob = jnp.broadcast_to(
+                jnp.asarray(lgprob, jnp.float32), stage_idx.shape
+            )
             slot = jnp.where(dec & (ndec < T), ndec, T)
             stored = jax.vmap(store_obs)(obs, env0)
             set_at = lambda b, s, v: b.at[s].set(v, mode="drop")  # noqa: E731
@@ -943,7 +974,7 @@ def _flat_collect_single_eval(
                     lambda b, s, v: b.at[s].max(v, mode="drop")
                 )(buf.resets, rslot, reset.astype(_i32)),
             )
-        carry = (ls3, k, t_ref2, elapsed + dt, ndec2, buf)
+        carry = (ls3, k, t_ref2, elapsed2, ndec2, buf)
         return (carry + (tm,) if track else carry), None
 
     carry0 = (

@@ -5,7 +5,7 @@ backends, multi-window burn-rate SLO alerting (cooldown, rollback
 drive, fail-loud config), the online-loop depth probe, the
 perf-regression ledger (full-coverage CLI gate over the repo's real
 artifacts with the round-pinned headline rows, seeded-regression
-rc 4), the `phase_rank` runlog record, and — slow-marked — the real
+rc 4), and — slow-marked — the real
 spawned 2-replica fleet: per-replica scoreboard labels, seeded
 quarantine regression tripping a burn-rate `alert` record that drives
 a fleet-wide params rollback, and the server's `/fleet` + labeled
@@ -591,44 +591,6 @@ def test_ledger_units_and_round_parsing():
     assert round_of("artifacts/bench_tpu_r05_headline.json") == 5
     assert round_of("BENCH_r19.json") == 19
     assert round_of("artifacts/no_round_stamp.json") == -1
-
-
-# --------------------------------------------------------------------------
-# phase_rank runlog records (scripts_phase_rank --runlog satellite)
-# --------------------------------------------------------------------------
-
-
-def test_phase_rank_runlog_record(tmp_path, capsys):
-    sys.path.insert(0, REPO)
-    try:
-        from scripts_phase_rank import main as pr_main
-    finally:
-        sys.path.pop(0)
-    row = {
-        "metric": "decima_infer", "value": 120.0, "unit": "steps/s",
-        "config": {"backend": "cpu"},
-        "telemetry": {
-            "decisions": 100,
-            "phase_iters": {"decide": 100, "event": 300, "bulk": 50,
-                            "fulfill": 0},
-            "bulk": {"relaunch_events": 90, "ready_events": 10},
-            "drain_iters_mean": 4.0, "drain_iters_max": 8,
-            "drain_straggler_ratio": 2.0, "straggler_ratio": 1.5,
-        },
-    }
-    src = tmp_path / "rows.jsonl"
-    src.write_text(json.dumps(row) + "\n")
-    log = tmp_path / "pr.jsonl"
-    assert pr_main([str(src), "--runlog", str(log)]) == 0
-    assert "| 1 | event |" in capsys.readouterr().out
-    recs = [r for r in _records(log) if r.get("ev") == "phase_rank"]
-    assert len(recs) == 1
-    (payload,) = recs[0]["rows"]
-    assert payload["metric"] == "decima_infer"
-    assert payload["phases"][0]["phase"] == "event"
-    assert payload["phases"][0]["share"] == pytest.approx(
-        300 / 450, abs=1e-3)
-    assert recs[0]["source"] == "decima_infer"
 
 
 # --------------------------------------------------------------------------

@@ -665,3 +665,241 @@ def test_training_iteration_writes_runlog(tmp_path):
         assert key in sc, f"scalars record missing {key}"
 
 
+
+
+# ---------------------------------------------------------------------------
+# decision rows of the single-eval batch collectors: the four row
+# counters and the trace scopes of the scan body
+# ---------------------------------------------------------------------------
+
+# the scopes of the collector's scan body; a nested name holds its
+# parent's, so the device trace's substring match reads both
+ROW_SCOPES = (
+    "collect/observe", "decima/features", "decima/gnn", "decima/sample",
+    "env/micro_step/decide", "env/micro_step/drain", "collect/health",
+    "collect/freeze", "collect/scatter",
+)
+
+
+def _tiny_decima_rows(monkeypatch, job_bucket: int, lanes: int = 3):
+    """The 5-executor, 6-job cluster of the collection-parity tests, a
+    small Decima with the given compaction bucket and `lanes` freshly
+    reset lanes."""
+    import jax
+
+    from sparksched_tpu.env import core
+
+    from .test_flat_loop import _decima_parity_fixture
+
+    params, bank, make_sched = _decima_parity_fixture(monkeypatch)
+    states = jax.vmap(lambda k: core.reset(params, bank, k))(
+        jax.random.split(jax.random.PRNGKey(3), lanes)
+    )
+    return params, bank, make_sched(job_bucket=job_bucket), states
+
+
+def _check_row_counters(tm, steps: int, frozen: bool = False) -> dict:
+    """The identities every collection holds; returns the `row` block
+    of the summary. A `frozen` lane's drain runs on the device and is
+    then rolled back with the lane's own counts, so only a collection
+    without frozen lanes bounds the batch's iterations by the lanes'
+    sum."""
+    from sparksched_tpu.analysis.contracts import check_telemetry
+    from sparksched_tpu.obs.telemetry import summarize
+
+    assert check_telemetry(tm, batch_ndim=1) == []
+    rows, live = np.asarray(tm.rows), np.asarray(tm.rows_live)
+    drained = np.asarray(tm.drain_iters)
+    batch = np.asarray(tm.drain_batch_iters)
+    assert rows.tolist() == [steps] * rows.size  # every lane, frozen too
+    assert (live == live[0]).all() and live[0] <= steps
+    assert (batch == batch[0]).all()
+    assert drained.max() <= batch[0]
+    assert frozen or batch[0] <= drained.sum()
+    row = summarize(tm)["row"]
+    assert row == {
+        "rows": steps, "rows_live": int(live[0]),
+        "rows_full_width": int(np.asarray(tm.rows_full_width).max()),
+        "drain_batch_iters": int(batch[0]),
+        "lane_rows": steps * rows.size,
+        "drain_lane_iters_executed": int(batch[0]) * rows.size,
+        "drain_iters_total": int(drained.sum()),
+    }
+    return row
+
+
+@pytest.mark.parametrize("job_bucket", [1, 6])
+def test_row_counters_of_the_sync_batch_collector(monkeypatch, job_bucket):
+    """`rows` is the scan length in every lane, the drain's batch
+    iterations lie between the slowest lane's total and the sum of all
+    lanes', `rows_full_width` counts exactly the rows in which some lane
+    holds more active jobs than the bucket (none when the bucket covers
+    the job cap: the net then has one width), and the telemetry carry
+    leaves the rollout as it is."""
+    import jax
+
+    from sparksched_tpu.obs.telemetry import telemetry_zeros_like
+    from sparksched_tpu.trainers.rollout import collect_flat_sync_batch
+
+    params, bank, sched, states = _tiny_decima_rows(monkeypatch, job_bucket)
+    lanes, steps = 3, 24
+    bpol = sched.flat_batch_policy()
+    key = jax.random.PRNGKey(1)
+    plain = collect_flat_sync_batch(
+        params, bank, bpol, key, steps, states, fulfill_bulk=True)
+    ro, tm = collect_flat_sync_batch(
+        params, bank, bpol, key, steps, states,
+        telemetry_zeros_like((lanes,)), fulfill_bulk=True, health=True)
+    for a, b in zip(jax.tree_util.tree_leaves(plain),
+                    jax.tree_util.tree_leaves(ro)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    row = _check_row_counters(tm, steps)
+    valid = np.asarray(ro.valid)
+    assert valid.all(), "the fixture's lanes decide in every row"
+    assert row["rows_live"] == steps
+    # with every lane deciding in every row, stored step t of a lane is
+    # what the lane observed in row t
+    jobs = np.asarray(ro.obs.job_mask).sum(axis=-1)  # [lanes, steps]
+    over = int((jobs > job_bucket).any(axis=0).sum())
+    if job_bucket >= params.max_jobs:
+        assert sched.full_width(ro.obs) is None
+        assert row["rows_full_width"] == 0
+    else:
+        assert 0 < over and row["rows_full_width"] == over
+
+
+def test_row_counters_of_the_async_batch_collector_with_frozen_lanes():
+    """A lane that has used up its budget is frozen and keeps its own
+    counts; the row counters are the batch's, so they go on in every
+    lane. A policy that reports no width counts no full-width row."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparksched_tpu.config import EnvParams
+    from sparksched_tpu.env import core
+    from sparksched_tpu.env.flat_loop import init_loop_state
+    from sparksched_tpu.obs.telemetry import telemetry_zeros_like
+    from sparksched_tpu.schedulers.heuristics import round_robin_policy
+    from sparksched_tpu.trainers.rollout import collect_flat_async_batch
+    from sparksched_tpu.workload import make_workload_bank
+
+    params = EnvParams(
+        num_executors=4, max_jobs=3, max_stages=20, max_levels=20,
+        moving_delay=500.0, warmup_delay=200.0,
+    )
+    bank = make_workload_bank(params.num_executors, params.max_stages)
+    params = params.replace(
+        max_stages=bank.max_stages, max_levels=bank.max_stages
+    )
+
+    def bpol(rng, obs):
+        si, ne = jax.vmap(
+            lambda o: round_robin_policy(o, params.num_executors, True)
+        )(obs)
+        return si, ne, {}
+
+    lanes, steps = 2, 120
+    states = jax.vmap(lambda k: core.reset(params, bank, k))(
+        jax.random.split(jax.random.PRNGKey(7), lanes)
+    )
+    ro, _, tm = collect_flat_async_batch(
+        params, bank, bpol, jax.random.PRNGKey(9), steps,
+        jax.vmap(init_loop_state)(states), jnp.float32(2.0e6),
+        telemetry=telemetry_zeros_like((lanes,)),
+    )
+    row = _check_row_counters(tm, steps, frozen=True)
+    decided = np.asarray(ro.valid).sum(axis=1)
+    assert (decided < steps).all(), "the budget froze no lane"
+    assert np.asarray(tm.decide_steps).tolist() == decided.tolist()
+    assert decided.max() <= row["rows_live"] < steps
+    assert row["rows_full_width"] == 0
+
+
+def test_summarize_reads_batch_counters_as_the_lane_maximum():
+    """Every lane holds the same row counts; where a window's lanes
+    differ, the maximum is the batch's."""
+    from sparksched_tpu.obs.telemetry import summarize, telemetry_zeros_like
+
+    tm = telemetry_zeros_like((4,))
+    assert set(summarize(tm)["row"].values()) == {0}
+    tm = tm.replace(
+        rows=np.asarray([5, 7, 7, 6], np.int32),
+        rows_live=np.asarray([4, 4, 5, 4], np.int32),
+        rows_full_width=np.asarray([0, 2, 2, 2], np.int32),
+        drain_batch_iters=np.asarray([30, 31, 31, 20], np.int32),
+        drain_iters=np.asarray([10, 20, 25, 5], np.int32),
+    )
+    assert summarize(tm)["row"] == {
+        "rows": 7, "rows_live": 5, "rows_full_width": 2,
+        "drain_batch_iters": 31, "lane_rows": 28,
+        "drain_lane_iters_executed": 124, "drain_iters_total": 60,
+    }
+
+
+def test_full_width_predicate_is_one_scalar_over_the_batch(monkeypatch):
+    import types
+
+    _, _, sched, _ = _tiny_decima_rows(monkeypatch, job_bucket=1, lanes=1)
+    two = types.SimpleNamespace(job_mask=np.asarray(
+        [[True, False, False, False, False, False],
+         [True, True, False, False, False, False]]))
+    one = types.SimpleNamespace(job_mask=two.job_mask[:1])
+    assert bool(sched.full_width(two)) and not bool(sched.full_width(one))
+    assert sched.full_width(two).shape == ()
+    sched.job_bucket = 6  # covers the job cap: one width, no predicate
+    assert sched.full_width(two) is None
+    sched.job_bucket = 0
+    assert sched.full_width(two) is None
+
+
+def test_every_equation_of_the_collector_scan_body_is_under_a_scope(
+    monkeypatch
+):
+    """The device trace names an operation by the scopes in its
+    `op_name`. Code added to the scan body of the single-eval collector
+    must not fall outside them silently: every equation of the body
+    (health sentinels and telemetry on, as the trainer runs it) carries
+    one of `ROW_SCOPES` in its name stack, the handling of the row's
+    PRNG keys apart."""
+    import jax
+
+    from sparksched_tpu.obs.telemetry import telemetry_zeros_like
+    from sparksched_tpu.trainers.rollout import collect_flat_sync_batch
+
+    params, bank, sched, states = _tiny_decima_rows(monkeypatch, job_bucket=3)
+    steps = 5
+    bpol = sched.flat_batch_policy()
+
+    def collect(key, states, tm):
+        return collect_flat_sync_batch(
+            params, bank, bpol, key, steps, states, tm,
+            fulfill_bulk=True, health=True)
+
+    jaxpr = jax.make_jaxpr(collect)(
+        jax.random.PRNGKey(1), states, telemetry_zeros_like((3,)))
+
+    def scans(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "scan":
+                yield eqn
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield from scans(inner)
+
+    (body,) = [e.params["jaxpr"].jaxpr for e in scans(jaxpr.jaxpr)
+               if e.params["length"] == steps
+               and not str(e.source_info.name_stack)]
+    stacks = [(e.primitive.name, str(e.source_info.name_stack))
+              for e in body.eqns]
+    assert len(stacks) > 1000  # the whole decision row is in this body
+    seen = {s for s in ROW_SCOPES if any(s in st for _, st in stacks)}
+    assert seen == set(ROW_SCOPES)
+    bare = [p for p, st in stacks if not any(s in st for s in ROW_SCOPES)]
+    key_handling = {"random_split", "random_wrap", "random_unwrap",
+                    "random_bits", "slice", "squeeze"}
+    assert set(bare) <= key_handling and len(bare) <= 24, bare
+    # the GNN's sub-scopes are inside the net, below `decima/gnn`
+    text = jaxpr.pretty_print(name_stack=True)
+    for sub in ("levels", "stage_head", "exec_head"):
+        assert f"decima/gnn/{sub}" in text
