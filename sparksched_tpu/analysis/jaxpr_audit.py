@@ -101,6 +101,17 @@ class Budget:
 # mode-exclusive fulfill pass, deliberately left unfused). Caps below
 # tightened to ~1.35x the new measurements per the band policy; the
 # fusion A/B bench rows live in PERF_ROUNDS.md round 11.
+#
+# Re-measured 2026-09-28 (jax 0.9.0, PR 28: the fused pass's fixed scan
+# became an early-exit `while` whose body holds two unrolled steps, so
+# every program that runs the pass counts one more step's equations
+# and batched gathers; the loop's trip count, which is what the change
+# cut, is not in a jaxpr): micro_step 3993/29/1 -> 4338/29/1,
+# drain_to_decision 2500/5/1 -> 2845/5/1, flat_collect_batch
+# 14437/190/18 -> 14866/206/18, flat_collect_batch_health 14675/190/20
+# -> 15111/206/20, serve_decide 6265/33/65 -> 6610/33/65,
+# serve_decide_batch 14756/251/65 -> 15185/267/65. Every count is
+# inside its band, so no band moved; the chip rows are PERF.md, PR 28.
 # ---------------------------------------------------------------------------
 
 BUDGETS: dict[str, Budget] = {
@@ -112,7 +123,8 @@ BUDGETS: dict[str, Budget] = {
     ),
     # one flat micro-step at the shipped bulk config (be=8,
     # fulfill_bulk, cycles=1, fused bulk kernel) — the engine's unit
-    # of work (the scan is the fused event run, not a decision loop)
+    # of work (the while is the fused event run's early-exit loop, not
+    # a decision loop)
     "micro_step": Budget(
         eqn_lo=2000, eqn_hi=5500, gather_hi=40, scatter_hi=3,
     ),

@@ -1475,16 +1475,68 @@ def _bulk_ready(
     return state, k
 
 
+# Steps of the fused bulk pass between two tests of "is any lane still
+# active". An iteration of the `while` costs its predicate (a reduce
+# over the lanes and a scalar read, about 3 us on the TPU v5e) on top
+# of the steps it runs (33 us each at 128 lanes), and a granule of g
+# steps pays that once per g and rounds the batch's need up to a
+# multiple of g. Measured on whole collections of 128 lanes x 800
+# decisions: 15.20 s at 1, 15.10 s at 2, 15.35 s at 4, 15.63 s at 8,
+# against 20.43 s for the fixed scan (PERF.md, PR 28).
+_BULK_STEP_GRANULE = 2
+
+
+def _steps_while_active(step_fn, carry0, us, lane_axis=None):
+    """Run `step_fn(carry, u_row, in_budget)` over the rows of `us` in
+    order, from `carry0`, for as long as the pass is active
+    (`carry[-1]`) and rows are left: the early-exit form of
+    `lax.scan(step_fn, carry0, us)`. A step on an inactive pass changes
+    nothing, so the result is the scan's; the steps not run are the
+    ones that could not have done anything.
+
+    Under `jax.vmap` the loop runs until NO lane is active: the
+    batch's largest need, not `len(us)`. With `lane_axis` (the name the
+    caller's `vmap` gave its lane axis) the predicate is reduced over
+    that axis, so it is the same for every lane and the loop selects
+    nothing; without it each lane keeps its own predicate, and the
+    batching rule of `while_loop` selects the carry against it after
+    every iteration (redundant here, and harmless).
+
+    Steps run in granules of `_BULK_STEP_GRANULE`; a granule's steps
+    past the last row are gated off through `in_budget` (the row index
+    is clamped by the slice, and nothing reads the row)."""
+    length = us.shape[0]
+
+    def cond(c):
+        i, carry = c
+        active = carry[-1]
+        if lane_axis is not None:
+            active = lax.pmax(active, lane_axis)
+        return active & (i < length)
+
+    def body(c):
+        i, carry = c
+        for j in range(_BULK_STEP_GRANULE):
+            u_row = lax.dynamic_index_in_dim(us, i + j, keepdims=False)
+            carry = step_fn(carry, u_row, i + j < length)
+        return i + _BULK_STEP_GRANULE, carry
+
+    return lax.while_loop(cond, body, (_i32(0), carry0))[1]
+
+
 def _bulk_events_fused(
     params: EnvParams, bank: WorkloadBank, state: EnvState,
     enabled: jnp.ndarray, stop_at_limit: bool = False,
-    max_events: int = 8,
+    max_events: int = 8, lane_axis: str | None = None,
 ):
     """Consume one maximal run of *simple* events — task relaunches AND
     executor arrivals, interleaved in exact (time, seq) order — in a
-    SINGLE bounded scan. Returns (state, k_rel, k_rdy): events consumed
-    by kind (both 0 when the next event is not simple, the queue is
-    drained, or `enabled` is False).
+    SINGLE bounded early-exit loop. Returns (state, k_rel, k_rdy,
+    steps): events consumed by kind (both 0 when the next event is not
+    simple, the queue is drained, or `enabled` is False) and the steps
+    of the loop this lane needed (those it entered active: one per
+    event taken, plus the step that saw the run end unless a joining
+    arrival or the budget ended it; 0 when not `enabled`).
 
     This fuses `_bulk_relaunch` + `_bulk_ready` into one kernel (ISSUE
     7): instead of a fixed relaunch-pass / arrival-pass order — which
@@ -1531,12 +1583,21 @@ def _bulk_events_fused(
     and each executor's CURRENT finish-event stage (`fj`/`fs` — an
     arrival start re-targets the executor's next finish to its
     destination stage, and that finish may itself relaunch within the
-    same pass). The scan length is `max_events + N`: the budget of one
-    full relaunch cascade plus a worst-case arrival burst, so a fused
-    pass can always consume at least what the unfused pass pair could.
+    same pass). The loop's budget is `max_events + N` steps: one full
+    relaunch cascade plus a worst-case arrival burst, so a fused pass
+    can always consume at least what the unfused pass pair could. It
+    stops stepping as soon as the run is over (`_steps_while_active`):
+    once `active` is false every update of a step is gated off, so the
+    steps left out are no-ops and the result is that of all
+    `max_events + N`. Under `jax.vmap` the device pays the largest need
+    over the lanes of the batch (17 of the 58 steps a pass at 128 lanes
+    of the flagship cluster, PERF.md section 5), not the budget;
+    `lane_axis` names the caller's lane axis, if it has one.
 
     Matches the sequential path bit-exactly except the rng stream
-    (one batched uniform table, as in the unfused passes)."""
+    (one batched uniform table, as in the unfused passes; drawn whole
+    before the loop at `[max_events + N, N, 2]`, whatever the loop
+    reads of it)."""
     n = state.exec_job.shape[0]
     j_cap, s_cap = state.stage_remaining.shape
     pos = jnp.arange(n, dtype=_i32)
@@ -1580,9 +1641,11 @@ def _bulk_events_fused(
     def pick_i(oh, x):
         return jnp.where(oh, x, 0).sum().astype(x.dtype)
 
-    def step_fn(carry, u_row):
+    def step_fn(carry, u_row, in_budget):
         (t_f, sq_f, t_a, fj, fs, rem, jcnt, launch_t, dur_js, relc,
-         arr_done, started, counter, wall, active, crossed) = carry
+         arr_done, started, counter, wall, crossed, steps, active) = carry
+        active = active & in_budget
+        steps = steps + active.astype(_i32)
 
         # lexicographic (time, seq) minimum over finishes and arrivals
         ftmin = t_f.min()
@@ -1645,8 +1708,8 @@ def _bulk_events_fused(
         active = active & ok & ~joins
         return (
             t_f, sq_f, t_a, fj, fs, rem, jcnt, launch_t, dur_js, relc,
-            arr_done, started, counter, wall, active, crossed,
-        ), None
+            arr_done, started, counter, wall, crossed, steps, active,
+        )
 
     jc = jnp.clip(state.exec_job, 0, j_cap - 1)
     sc = jnp.clip(state.exec_task_stage, 0, s_cap - 1)
@@ -1665,11 +1728,14 @@ def _bulk_events_fused(
         jnp.zeros(n, bool),
         state.seq_counter,
         state.wall_time,
-        jnp.asarray(enabled, bool),
         jnp.bool_(False),
+        _i32(0),
+        jnp.asarray(enabled, bool),
     )
     (t_f, sq_f, t_a, _, _, rem, _, launch_t, dur_js, relc, arr_done,
-     started, counter, wall, _, _), _ = lax.scan(step_fn, carry0, us)
+     started, counter, wall, _, steps, _) = _steps_while_active(
+        step_fn, carry0, us, lane_axis
+    )
 
     k_rel = relc.sum()
     k_rdy = arr_done.sum().astype(_i32)
@@ -1735,7 +1801,7 @@ def _bulk_events_fused(
         stage_sat=jnp.where(touched, sat_new, state.stage_sat),
         unsat_parent_count=unsat,
     )
-    return state, k_rel, k_rdy
+    return state, k_rel, k_rdy, steps
 
 
 def _resume_simulation(

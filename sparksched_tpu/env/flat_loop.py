@@ -253,24 +253,30 @@ def _bulk_cycle_chain(
     bulk_events: int,
     bulk_cycles: int,
     bulk_fused: bool = True,
+    lane_axis: str | None = None,
 ):
     """`bulk_cycles` chained bulk passes. With `bulk_fused` (the ISSUE-7
     default) each cycle is ONE `core._bulk_events_fused` kernel that
     consumes a mixed relaunch/arrival run in exact (time, seq) order —
-    one scan, one rng split, one merged state update per cycle; without
-    it, each cycle is the round-3/4 (relaunch cascade + arrival burst)
-    pass pair. The first cycle runs whenever the lane is in EVENT mode;
-    each further cycle runs only while the sequential between-event
+    one early-exit loop (as many steps as the run is long; under vmap,
+    as the longest run of the batch), one rng split, one merged state
+    update per cycle; without it, each cycle is the round-3/4 (relaunch
+    cascade + arrival burst) pass pair. The first cycle runs whenever
+    the lane is in EVENT mode; each further cycle runs only while the
+    sequential between-event
     tail would be a no-op — `num_committable() == 0` (round-ready flip
     and move_and_clear are gated on committable > 0) and the wall clock
     inside the episode limit (the freeze point) — so chaining is
     exactly the next micro-step's bulk phase minus its provably-no-op
-    tail. Returns (env, events_consumed, relaunch_events, ready_events)
-    — the last two split the count by event kind for the telemetry
-    counters."""
+    tail. Returns (env, events_consumed, relaunch_events, ready_events,
+    scan_steps) — relaunch and ready split the count by event kind and
+    `scan_steps` is the steps the fused passes' loops needed for this
+    lane (0 from the unfused pair), all for the telemetry counters.
+    `lane_axis` is handed to the fused pass."""
     nb = _i32(0)
     nb_rel = _i32(0)
     nb_rdy = _i32(0)
+    steps = _i32(0)
     for i in range(bulk_cycles):
         on = is_event if i == 0 else (
             is_event
@@ -278,10 +284,12 @@ def _bulk_cycle_chain(
             & (env.wall_time < env.time_limit)
         )
         if bulk_fused:
-            env, nbi1, nbi2 = _bulk_events_fused(
+            env, nbi1, nbi2, si = _bulk_events_fused(
                 params, bank, env, on,
                 stop_at_limit=True, max_events=bulk_events,
+                lane_axis=lane_axis,
             )
+            steps = steps + si
         else:
             env, nbi1 = _bulk_relaunch(
                 params, bank, env, on,
@@ -297,7 +305,7 @@ def _bulk_cycle_chain(
         nb = nb + nbi1 + nbi2
         nb_rel = nb_rel + nbi1
         nb_rdy = nb_rdy + nbi2
-    return env, nb, nb_rel, nb_rdy
+    return env, nb, nb_rel, nb_rdy, steps
 
 
 def _lane_done(env: EnvState) -> jnp.ndarray:
@@ -532,14 +540,14 @@ def micro_step(
     k_pol, k_reset = jax.random.split(rng)
     ls0 = ls  # pre-bulk state: the freeze path must restore exactly this
     if event_bulk:
-        env_b, nb, nb_rel, nb_rdy = _bulk_cycle_chain(
+        env_b, nb, nb_rel, nb_rdy, nsteps = _bulk_cycle_chain(
             params, bank, ls.env, ls.mode == M_EVENT, bulk_events,
             bulk_cycles, bulk_fused,
         )
         ls = ls.replace(env=env_b, bulked=ls.bulked + nb)
     else:
         nb = _i32(0)
-        nb_rel = nb_rdy = nb
+        nb_rel = nb_rdy = nsteps = nb
     st = ls.env
     s_cap = params.max_stages
 
@@ -606,6 +614,7 @@ def micro_step(
             bulk_relaunch_events=jnp.where(live, nb_rel, 0),
             bulk_ready_events=jnp.where(live, nb_rdy, 0),
             bulk_passes=(nb > 0) & live,
+            bulk_scan_steps=jnp.where(live, nsteps, 0),
             ev_job_arrival=pop_live & (ev_kind == EV_JOB_ARRIVAL),
             ev_task_finished=pop_live & (ev_kind == EV_TASK_FINISHED),
             ev_exec_ready=pop_live & (ev_kind == EV_EXECUTOR_READY),
@@ -816,7 +825,7 @@ def event_micro_step(
 
     ls0 = ls.replace(mode=_i32(M_EVENT))  # pre-bulk state for the tail
     if event_bulk:
-        env_b, nb, nb_rel, nb_rdy = _bulk_cycle_chain(
+        env_b, nb, nb_rel, nb_rdy, nsteps = _bulk_cycle_chain(
             params, bank, ls.env, is_event, bulk_events, bulk_cycles,
             bulk_fused,
         )
@@ -824,7 +833,7 @@ def event_micro_step(
         pop_on = is_event & _fused_pop_gate(env_b, nb)
     else:
         nb = _i32(0)
-        nb_rel = nb_rdy = nb
+        nb_rel = nb_rdy = nsteps = nb
         pop_on = is_event
     st, rk, rj, rs, arg, quirk, popped, ev_kind = _pop_event(
         params, ls.env, pop_on
@@ -848,6 +857,7 @@ def event_micro_step(
             bulk_relaunch_events=jnp.where(gate, nb_rel, 0),
             bulk_ready_events=jnp.where(gate, nb_rdy, 0),
             bulk_passes=(nb > 0) & gate,
+            bulk_scan_steps=jnp.where(gate, nsteps, 0),
             ev_job_arrival=pop_live & (ev_kind == EV_JOB_ARRIVAL),
             ev_task_finished=pop_live & (ev_kind == EV_TASK_FINISHED),
             ev_exec_ready=pop_live & (ev_kind == EV_EXECUTOR_READY),
@@ -945,6 +955,7 @@ def drain_micro_step(
     telemetry=None,
     bulk_fused: bool = True,
     masked: bool = True,
+    lane_axis: str | None = None,
 ) -> tuple:
     """One NON-POLICY micro-step: FULFILL and EVENT lanes advance exactly
     as `micro_step`'s branches (bulk passes + fused pop included); DECIDE
@@ -960,20 +971,21 @@ def drain_micro_step(
     while-loop's batching rule selects the whole carry against each
     lane's own cond, so the per-iteration ~50-leaf select here (adj is
     [J,S,S] per lane) was pure duplicated bandwidth on the drain's hot
-    path (ISSUE 7 drain restructure)."""
+    path (ISSUE 7 drain restructure). `lane_axis`: see
+    `drain_to_decision`."""
     track = telemetry is not None
     active = ls.mode != M_DECIDE
     _, k_reset = jax.random.split(rng)
     ls0 = ls
     if event_bulk:
-        env_b, nb, nb_rel, nb_rdy = _bulk_cycle_chain(
+        env_b, nb, nb_rel, nb_rdy, nsteps = _bulk_cycle_chain(
             params, bank, ls.env, ls.mode == M_EVENT, bulk_events,
-            bulk_cycles, bulk_fused,
+            bulk_cycles, bulk_fused, lane_axis,
         )
         ls = ls.replace(env=env_b, bulked=ls.bulked + nb)
     else:
         nb = _i32(0)
-        nb_rel = nb_rdy = nb
+        nb_rel = nb_rdy = nsteps = nb
 
     def noop(ls: LoopState):
         return ls, _i32(RQ_NONE), _i32(-1), _i32(-1), _i32(0), \
@@ -1005,6 +1017,7 @@ def drain_micro_step(
             bulk_relaunch_events=jnp.where(gate, nb_rel, 0),
             bulk_ready_events=jnp.where(gate, nb_rdy, 0),
             bulk_passes=(nb > 0) & gate,
+            bulk_scan_steps=jnp.where(gate, nsteps, 0),
             ev_job_arrival=pop_live & (ev_kind == EV_JOB_ARRIVAL),
             ev_task_finished=pop_live & (ev_kind == EV_TASK_FINISHED),
             ev_exec_ready=pop_live & (ev_kind == EV_EXECUTOR_READY),
@@ -1039,6 +1052,7 @@ def drain_to_decision(
     t_ref: jnp.ndarray | None = None,
     telemetry=None,
     bulk_fused: bool = True,
+    lane_axis: str | None = None,
 ) -> tuple:
     """Drain one lane's non-decision work — FULFILL leftovers and the
     whole inter-decision event run — until it is ready to DECIDE again
@@ -1049,10 +1063,17 @@ def drain_to_decision(
     batch-max drain length per decision row — but every iteration is
     pure env machinery (bulk passes + single pops), and the GNN runs
     exactly once per decision outside this loop. Which slice is the
-    cheap one depends on the device: on the TPU v5e this loop is over
-    half of a decision row of 128 lanes and the GNN under a third
-    (PERF.md section 5), so there the straggler tax is the larger
-    bill. The device time is under the scope `env/micro_step/drain`.
+    cheap one depends on the device: on the TPU v5e this loop and the
+    GNN are each about two fifths of a decision row of 128 lanes
+    (PERF.md section 5). The device time is under the scope
+    `env/micro_step/drain`. A body of 128 lanes costs 1.16 ms there:
+    0.73 ms whatever the lanes hold (the pass's set-up and merged state
+    update, the pop, the shared tail) and 24 us for each step of the
+    fused bulk pass's early-exit loop, which runs as many steps as the
+    longest run among the lanes (17 on average, of a budget of 58).
+    `lane_axis`, the name the caller's `vmap` gave its lane axis, lets
+    that loop end on one predicate for the whole batch; a caller
+    without one (a single lane) leaves it None.
     The ISSUE-7 restructure keeps that slice cheap two ways: the cond
     reduces to the existence bit of the next event (`_has_pending_event`
     — no argmin/kind chain), and the body runs `drain_micro_step` with
@@ -1083,7 +1104,7 @@ def drain_to_decision(
         out = drain_micro_step(
             params, bank, ls, sub, auto_reset, event_bulk, bulk_events,
             bulk_cycles, reset_fn, t_ref, telemetry=tm,
-            bulk_fused=bulk_fused, masked=False,
+            bulk_fused=bulk_fused, masked=False, lane_axis=lane_axis,
         )
         if track:
             ls, (r, d, re), tm = out

@@ -24,7 +24,13 @@ Counter semantics per engine:
   `fulfill_steps` / `event_steps` count live micro-steps by entry mode
   (the micro-step composition), `loop_iters` the events consumed per
   lane (pops + bulk passes) — the lane-imbalance quantity the flat
-  engine absorbs without stalling.
+  engine absorbs without stalling. `bulk_scan_steps` counts the steps
+  the fused bulk pass's early-exit loop needed for the lane
+  (`core._bulk_events_fused`: one per event taken, plus the step that
+  saw the run end; 0 for a pass that was not enabled), of a budget of
+  `bulk_events + num_executors` a pass: how much of that budget the
+  traffic uses. Under vmap the device runs the largest need over the
+  lanes of each pass, not each lane's own.
 - the single-eval batch collectors (`trainers/rollout.py`:
   `collect_flat_sync_batch`, `collect_flat_async_batch`) also set the
   four ROW counters, once per decision row of the scan and never inside
@@ -77,6 +83,10 @@ class Telemetry(struct.PyTreeNode):
     # decide/fulfill/event phases' iteration counts are decide_steps /
     # fulfill_steps / event_steps; this completes the per-phase split
     bulk_passes: jnp.ndarray
+    # steps the fused bulk passes' early-exit loops needed for this
+    # lane (`core._bulk_events_fused`): of a budget of
+    # `bulk_events + num_executors` a pass (flat engine only)
+    bulk_scan_steps: jnp.ndarray
     # inter-decision while-loop body iterations: `drain_to_decision`
     # (flat single-eval path) / `_resume_simulation` (core). Max/mean
     # over lanes IS the measured batch-max drain tax.
@@ -196,6 +206,8 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
         return int(x.max()) if x.size else 0
 
     rows = batch(t.rows)
+    scan_steps = tot(t.bulk_scan_steps)
+    bulk_passes = tot(t.bulk_passes)
     drain_batch = batch(t.drain_batch_iters)
     hm = np.asarray(t.health_mask).ravel()
     health_mask = (
@@ -230,8 +242,15 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
             "decide": decide,
             "fulfill": fulfill,
             "event": event,
-            "bulk": tot(t.bulk_passes),
+            "bulk": bulk_passes,
         },
+        # how much of its step budget the fused bulk pass uses: the
+        # steps the lanes' passes needed, and the mean over the passes
+        # that took an event
+        "bulk_scan_steps_total": scan_steps,
+        "bulk_scan_steps_per_pass": (
+            round(scan_steps / bulk_passes, 3) if bulk_passes else 0.0
+        ),
         "drain_iters_mean": round(mean_di, 2),
         "drain_iters_max": int(di.max()) if lanes else 0,
         "drain_straggler_ratio": round(drain_straggler, 3),

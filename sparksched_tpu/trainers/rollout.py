@@ -850,10 +850,12 @@ def _flat_collect_single_eval(
             return drain_to_decision(
                 params, bank, l, k_, auto_reset, event_bulk,
                 bulk_events, bulk_cycles, reset_fn=rf, t_ref=tr,
-                telemetry=t_, bulk_fused=bulk_fused,
+                telemetry=t_, bulk_fused=bulk_fused, lane_axis="lanes",
             )
 
-        return jax.vmap(one)(ls, keys, li, t_ref, tm)
+        # the lane axis has a name so that the fused bulk pass can end
+        # its loop on one predicate for the whole batch
+        return jax.vmap(one, axis_name="lanes")(ls, keys, li, t_ref, tm)
 
     def body(carry, _):
         if track:

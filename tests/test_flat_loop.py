@@ -1071,3 +1071,335 @@ def test_bulk_paths_match_sequential_on_synthetic_bank(
                     "completion times"
                 ),
             )
+
+
+# ---------------------------------------------------------------------------
+# PR 28: the fused bulk pass's early-exit loop against the fixed scan
+# ---------------------------------------------------------------------------
+
+
+def _fixed_scan(step_fn, carry0, us, lane_axis=None):
+    """The oracle: the fixed-length scan the fused pass ran before its
+    loop ended early, over the same `step_fn` (every row in budget)."""
+    import jax
+
+    return jax.lax.scan(
+        lambda c, u: (step_fn(c, u, True), None), carry0, us
+    )[0]
+
+
+@pytest.fixture(scope="module")
+def bulk_pass_trail():
+    """Mid-episode engine states for the pass to start from: every
+    `LoopState` along 700 micro-steps of one dense episode (6 executors,
+    short moving delay, the REAL duration sampler, so the uniform table
+    is read and the rng stream compared), stacked on a leading axis;
+    and `need`, the events the fixed scan takes from each under a
+    budget no run reaches (0 where the lane is not in EVENT mode)."""
+    import jax
+
+    from sparksched_tpu.config import EnvParams
+    from sparksched_tpu.env import core
+    from sparksched_tpu.env.flat_loop import (
+        M_EVENT,
+        init_loop_state,
+        micro_step,
+    )
+    from sparksched_tpu.schedulers import round_robin_policy
+    from sparksched_tpu.workload import make_workload_bank
+
+    params = EnvParams(
+        num_executors=6, max_jobs=12, max_stages=20, max_levels=20,
+        moving_delay=700.0, warmup_delay=1000.0,
+        job_arrival_rate=4e-5, mean_time_limit=None,
+    )
+    bank = make_workload_bank(params.num_executors, params.max_stages)
+    params = params.replace(
+        max_stages=bank.max_stages, max_levels=bank.max_stages
+    )
+
+    def pol(rng, obs):
+        si, ne = round_robin_policy(obs, params.num_executors, True)
+        return si, ne, {}
+
+    @jax.jit
+    def trail(s0, key):
+        def body(ls, k):
+            ls2 = micro_step(
+                params, bank, pol, ls, k, auto_reset=False,
+                fulfill_bulk=True,
+            )
+            return ls2, ls
+
+        return jax.lax.scan(
+            body, init_loop_state(s0), jax.random.split(key, 700)
+        )[1]
+
+    lss = trail(
+        core.reset(params, bank, jax.random.PRNGKey(3)),
+        jax.random.PRNGKey(0),
+    )
+    on = np.asarray(lss.mode) == M_EVENT
+    _, k_rel, k_rdy, _ = _run_pass(
+        params, bank, _fixed_scan, lss.env, on, max_events=40,
+        how="vmap",
+    )
+    need = np.asarray(k_rel) + np.asarray(k_rdy)
+    assert need.max() >= 9 and (need == 0).sum() > 50, np.bincount(need)
+    return params, bank, lss, on, need
+
+
+def _run_pass(params, bank, runner, envs, on, *, max_events, how):
+    """`core._bulk_events_fused` over stacked states with `runner` as
+    its step loop: `how` is "one" (a lane at a time, no vmap), "vmap"
+    (per-lane predicate) or "vmap_named" (the lane axis named, so one
+    predicate for the batch)."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparksched_tpu.env import core
+
+    axis = "lanes" if how == "vmap_named" else None
+
+    def one(env, enabled):
+        return core._bulk_events_fused(
+            params, bank, env, enabled, stop_at_limit=True,
+            max_events=max_events, lane_axis=axis,
+        )
+
+    saved = core._steps_while_active
+    core._steps_while_active = runner
+    try:
+        on = jnp.asarray(on)
+        if how == "one":
+            fn = jax.jit(one)
+            outs = [
+                fn(jax.tree_util.tree_map(lambda a: a[i], envs), on[i])
+                for i in range(on.shape[0])
+            ]
+            return jax.tree_util.tree_map(
+                lambda *a: jnp.stack(a), *outs
+            )
+        return jax.jit(jax.vmap(one, axis_name=axis))(envs, on)
+    finally:
+        core._steps_while_active = saved
+
+
+def _assert_same_pass(got, want, msg):
+    import jax
+
+    for (path, a), b in zip(
+        jax.tree_util.tree_leaves_with_path(got),
+        jax.tree_util.tree_leaves(want),
+    ):
+        np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b),
+            err_msg=f"{msg}: {jax.tree_util.keystr(path)}",
+        )
+
+
+def _pick_lanes(on, need, wanted):
+    """One lane index for each wanted need (the first that has it)."""
+    return np.asarray(
+        [int(np.flatnonzero(on & (need == k))[0]) for k in wanted]
+    )
+
+
+@pytest.mark.parametrize("how", ["one", "vmap", "vmap_named"])
+def test_early_exit_pass_matches_fixed_scan(bulk_pass_trail, how):
+    """The early-exit loop returns what the fixed scan over the same
+    `step_fn` returns: every leaf of the state (rng included: the table
+    is drawn whole before the loop and read at the step's index),
+    `k_rel`, `k_rdy` and the steps needed; for one lane and under
+    `vmap`, with lanes whose runs end at different steps, lanes not
+    enabled, and lanes at their episode's time limit (which stop after
+    the event that crosses it: at most two steps)."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparksched_tpu.env import core
+
+    params, bank, lss, on, need = bulk_pass_trail
+    # runs of every length the trail has, then lanes that are not in
+    # EVENT mode; for the vmapped cases, every tenth lane of the trail
+    # as well, so one batch holds a mix of needs
+    lengths = sorted(set(need[on].tolist()))
+    idx = np.concatenate([
+        _pick_lanes(on, need, lengths),
+        np.flatnonzero(~on)[:3],
+        np.arange(0, on.shape[0], 10) if how != "one" else [],
+    ]).astype(int)
+    envs = jax.tree_util.tree_map(lambda a: a[idx], lss.env)
+    enabled = on[idx]
+    # the last four enabled lanes with something to take stand at their
+    # limit: the first event they take crosses it
+    at_limit = np.flatnonzero(enabled & (need[idx] >= 2))[-4:]
+    tl = np.asarray(envs.time_limit).copy()
+    tl[at_limit] = np.asarray(envs.wall_time)[at_limit]
+    envs = envs.replace(time_limit=jnp.asarray(tl))
+
+    want = _run_pass(
+        params, bank, _fixed_scan, envs, enabled, max_events=8, how=how
+    )
+    got = _run_pass(
+        params, bank, core._steps_while_active, envs, enabled,
+        max_events=8, how=how,
+    )
+    _assert_same_pass(got, want, how)
+
+    _, k_rel, k_rdy, steps = got
+    took = np.asarray(k_rel) + np.asarray(k_rdy)
+    steps = np.asarray(steps)
+    assert (took[~enabled] == 0).all() and (steps[~enabled] == 0).all()
+    assert (took[at_limit] == 1).all() and (steps[at_limit] == 2).all()
+    assert len(set(took[enabled].tolist())) >= 6, took
+    # a step per event taken, and the one that saw the run end (not
+    # there when a joining arrival or the budget ended the run)
+    extra = steps - took
+    budget = 8 + params.num_executors
+    assert ((extra == 0) | (extra == 1))[enabled].all()
+    assert (extra[enabled & (took == budget)] == 0).all()
+    ended = enabled & (took < budget)
+    assert (extra[ended] == 1).sum() > ended.sum() // 2
+
+
+@pytest.mark.parametrize("max_events", [1, 2, 3])
+def test_early_exit_pass_keeps_the_budget(bulk_pass_trail, max_events):
+    """`max_events + N` is still the budget, whatever the loop's
+    granule: a run longer than it is cut where the scan cut it (at
+    budgets of 7, 8 and 9 steps: 7 and 9 are no multiple of the
+    granule, so the last granule's step past the budget must take
+    nothing), and a run longer than `max_events` but inside the budget
+    ends where it ends."""
+    import jax
+
+    from sparksched_tpu.env import core
+
+    params, bank, lss, on, need = bulk_pass_trail
+    budget = max_events + params.num_executors
+    over = on & (need > budget)
+    inside = on & (need > max_events) & (need < budget)
+    assert over.sum() >= 1 and inside.sum() >= 2, (budget, need.max())
+    idx = np.concatenate([
+        np.flatnonzero(over)[:4], np.flatnonzero(on & (need == budget))[:2],
+        np.flatnonzero(inside)[:4], np.flatnonzero(~on)[:2],
+    ])
+    envs = jax.tree_util.tree_map(lambda a: a[idx], lss.env)
+    for how in ("vmap_named", "one"):
+        want = _run_pass(
+            params, bank, _fixed_scan, envs, on[idx],
+            max_events=max_events, how=how,
+        )
+        got = _run_pass(
+            params, bank, core._steps_while_active, envs, on[idx],
+            max_events=max_events, how=how,
+        )
+        _assert_same_pass(got, want, f"budget {budget}, {how}")
+        took = np.asarray(got[1]) + np.asarray(got[2])
+        np.testing.assert_array_equal(
+            took, np.minimum(need[idx], budget)
+        )
+        # a run cut by the budget has no step that saw it end
+        np.testing.assert_array_equal(
+            np.asarray(got[3])[need[idx] >= budget], budget
+        )
+
+
+def test_bulk_scan_steps_counter(bulk_pass_trail):
+    """`Telemetry.bulk_scan_steps` is what the pass reports for a live
+    lane that is not in DECIDE mode and 0 otherwise, through all three
+    micro-steps that run the bulk chain; `summarize` gives its total
+    and its mean over the passes that took an event."""
+    import jax
+
+    from sparksched_tpu.env import core
+    from sparksched_tpu.env.flat_loop import (
+        _lane_done,
+        drain_micro_step,
+        event_micro_step,
+        micro_step,
+    )
+    from sparksched_tpu.obs import summarize
+    from sparksched_tpu.obs.telemetry import telemetry_zeros_like
+    from sparksched_tpu.schedulers import round_robin_policy
+
+    params, bank, lss, on, need = bulk_pass_trail
+    idx = np.arange(0, on.shape[0], 7)
+    ls = jax.tree_util.tree_map(lambda a: a[idx], lss)
+    keys = jax.random.split(jax.random.PRNGKey(5), len(idx))
+    tm0 = telemetry_zeros_like((len(idx),))
+    want = np.asarray(_run_pass(
+        params, bank, core._steps_while_active, ls.env, on[idx],
+        max_events=8, how="vmap",
+    )[3])
+    assert (want[on[idx]] >= 1).all() and (want[~on[idx]] == 0).all()
+    # the trail's last lanes are frozen after the episode's end: the
+    # pass still looks at them, no counter moves
+    done = np.asarray(jax.vmap(_lane_done)(ls.env))
+    assert 0 < done.sum() < len(idx) // 2
+    want = np.where(done, 0, want)
+    took_any = (need[idx] > 0) & ~done
+
+    def pol(rng, obs):
+        si, ne = round_robin_policy(obs, params.num_executors, True)
+        return si, ne, {}
+
+    steps = {
+        "drain": lambda l, k, t: drain_micro_step(
+            params, bank, l, k, auto_reset=False, telemetry=t),
+        "event": lambda l, k, t: event_micro_step(
+            params, bank, l, k, auto_reset=False, telemetry=t),
+        "micro": lambda l, k, t: micro_step(
+            params, bank, pol, l, k, auto_reset=False, telemetry=t),
+    }
+    for name, fn in steps.items():
+        tm = jax.jit(jax.vmap(fn))(ls, keys, tm0)[-1]
+        np.testing.assert_array_equal(
+            np.asarray(tm.bulk_scan_steps), want, err_msg=name
+        )
+        s = summarize(tm)
+        assert s["bulk_scan_steps_total"] == int(want.sum()), name
+        assert s["phase_iters"]["bulk"] == int(took_any.sum()), name
+        assert s["bulk_scan_steps_per_pass"] == round(
+            want.sum() / took_any.sum(), 3
+        ), name
+
+
+def test_collection_is_the_same_with_and_without_telemetry(monkeypatch):
+    """The counters are pure adds beside the engine: a single-eval
+    collection with `telemetry=None` is leaf-equal to one that carries
+    them, `bulk_scan_steps` included, and the collection's counter is
+    consistent with the events its bulk passes took."""
+    import jax
+
+    from sparksched_tpu.env import core
+    from sparksched_tpu.obs import summarize
+    from sparksched_tpu.obs.telemetry import telemetry_zeros_like
+    from sparksched_tpu.trainers.rollout import collect_flat_sync_batch
+
+    params, bank, make_sched = _decima_parity_fixture(monkeypatch)
+    bpol = make_sched().flat_batch_policy(deterministic=True)
+    keys = [jax.random.PRNGKey(3), jax.random.PRNGKey(5)]
+    states = jax.tree_util.tree_map(
+        lambda *a: jax.numpy.stack(a),
+        *[core.reset(params, bank, k) for k in keys],
+    )
+    plain = collect_flat_sync_batch(
+        params, bank, bpol, jax.random.PRNGKey(1), 60, states,
+        fulfill_bulk=True,
+    )
+    counted, tm = collect_flat_sync_batch(
+        params, bank, bpol, jax.random.PRNGKey(1), 60, states,
+        telemetry_zeros_like((2,)), fulfill_bulk=True,
+    )
+    _assert_same_pass(plain, counted, "telemetry on against off")
+    s = summarize(tm)
+    bulk_events = s["bulk"]["relaunch_events"] + s["bulk"]["ready_events"]
+    passes = s["phase_iters"]["bulk"]
+    assert passes > 10
+    # every pass that took events needed a step for each, and at most
+    # one more; passes that took nothing needed one step each
+    assert s["bulk_scan_steps_total"] >= bulk_events
+    assert s["bulk_scan_steps_total"] <= bulk_events + s["phase_iters"][
+        "event"]
+    assert s["bulk_scan_steps_per_pass"] >= bulk_events / passes
