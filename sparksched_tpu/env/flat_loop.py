@@ -756,16 +756,24 @@ def _finish_micro_step(
     if auto_reset:
         from . import core as _core
 
-        if reset_fn is None:
-            fresh = _core.reset(params, bank, k_reset)
-        else:
-            # ls2.episodes is the pre-increment completed-episode count:
-            # the async collector's group-shared reset-ordinal hook
-            fresh = reset_fn(k_reset, ls2.episodes)
-        st = jax.tree_util.tree_map(
-            lambda a, b: jnp.where(done, a, b), fresh, st
-        )
-        mode = jnp.where(done, M_DECIDE, mode).astype(_i32)
+        # one whole name (obs/tracing.py): the reset program and the
+        # select of the whole state, paid by every micro-step
+        with annotate("env/micro_step/reset"):
+            if reset_fn is None:
+                fresh = _core.reset(params, bank, k_reset)
+            else:
+                # ls2.episodes is the pre-increment completed-episode
+                # count: the async collector's group-shared
+                # reset-ordinal hook
+                fresh = reset_fn(k_reset, ls2.episodes)
+            st = jax.tree_util.tree_map(
+                lambda a, b: jnp.where(done, a, b), fresh, st
+            )
+            mode = jnp.where(done, M_DECIDE, mode).astype(_i32)
+        if telem is not None:
+            telem = _tm_add(
+                telem, reseeds=done & ~was_done, reset_evals=1
+            )
     else:
         st = jax.tree_util.tree_map(
             lambda a, b: jnp.where(was_done, a, b), ls.env, st

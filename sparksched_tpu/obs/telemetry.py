@@ -42,6 +42,18 @@ Counter semantics per engine:
   really ran, which every lane pays for). They are facts of the batch,
   not of a lane, so every lane holds the same value; no other engine or
   collector touches them.
+- streaming (`auto_reset`, PR 30; set by the tail the single-eval
+  collectors run, `flat_loop._finish_micro_step` under
+  `decide_micro_step` and `drain_micro_step`): `reseeds` counts the
+  lane's episodes that ended in a micro-step and were re-seeded there
+  (`done & ~was_done`), `reset_evals` the times the reset program
+  (`reset_fn` / `core.reset` and the select of the whole state that
+  follows it) was evaluated for the lane: one per micro-step the lane
+  took, whether or not an episode ended. `rows_frozen` (the batch
+  collectors, once a row) counts the decision rows the lane sat out
+  because its sim-time budget was spent (`over`); a frozen lane's other
+  counters, these two included, stand still for the row. All three stay
+  0 in sync mode (`auto_reset=False`, no budget).
 
 Cross-engine invariant (the parity test): on a deterministic workload
 the two engines process the same trajectory, so `decide_steps`, the
@@ -97,6 +109,10 @@ class Telemetry(struct.PyTreeNode):
     rows_live: jnp.ndarray  # rows in which some lane decided
     rows_full_width: jnp.ndarray  # rows scored at the full job width
     drain_batch_iters: jnp.ndarray  # sum over rows of max-lane drain iters
+    # --- streaming (auto_reset) collection: per lane, 0 in sync mode ---
+    reseeds: jnp.ndarray  # episodes that ended in the scan, re-seeded
+    reset_evals: jnp.ndarray  # evaluations of the reset program
+    rows_frozen: jnp.ndarray  # rows sat out with the budget spent
     # --- health sentinels (ISSUE 9) ---
     # i32 violation BITMASK (env/health.py bit table), OR-accumulated
     # via `orr` — not a counter. Stays 0 unless a collector runs with
@@ -251,6 +267,11 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
         "bulk_scan_steps_per_pass": (
             round(scan_steps / bulk_passes, 3) if bulk_passes else 0.0
         ),
+        # streaming collection: episodes re-seeded in the scan, and
+        # evaluations of the reset program (one per micro-step of a
+        # lane while the reset is unconditional); 0 in sync mode
+        "reseeds_total": tot(t.reseeds),
+        "reset_evals_total": tot(t.reset_evals),
         "drain_iters_mean": round(mean_di, 2),
         "drain_iters_max": int(di.max()) if lanes else 0,
         "drain_straggler_ratio": round(drain_straggler, 3),
@@ -266,6 +287,8 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
             "lane_rows": rows * lanes,
             "drain_lane_iters_executed": drain_batch * lanes,
             "drain_iters_total": tot(t.drain_iters),
+            # lane-rows a lane sat out with its budget spent
+            "lane_rows_frozen": tot(t.rows_frozen),
         },
         # health sentinels (ISSUE 9): the pooled violation bitmask, its
         # decoded bit names, and how many lanes tripped anything —

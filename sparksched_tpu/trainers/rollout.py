@@ -753,7 +753,10 @@ def collect_flat_sync(
 # `collect/freeze` and `collect/scatter` (key splits apart;
 # tests/test_obs.py pins it), and with a telemetry carry the body
 # counts its rows (`obs/telemetry.py`: `rows`, `rows_live`,
-# `rows_full_width`, `drain_batch_iters`).
+# `rows_full_width`, `drain_batch_iters`, and per lane `rows_frozen`).
+# In streaming mode (`auto_reset`) the engine's tail adds the scope
+# `env/micro_step/reset` inside both engine scopes and the per-lane
+# counters `reseeds` and `reset_evals`.
 # ---------------------------------------------------------------------------
 
 
@@ -801,6 +804,8 @@ def _flat_collect_single_eval(
     `drain_batch_iters`: `obs/telemetry.py`), once per row and outside
     the drain's `while`; `rows_full_width` reads the policy's
     `aux["full_width"]` and stays 0 for a policy that gives none.
+    The per-lane `rows_frozen` counts the rows a lane sat out with its
+    `rollout_duration` spent (0 without a budget).
 
     With `health` (static; requires telemetry), each decision row ORs
     the per-lane `env/health.py` sentinel mask over the post-drain
@@ -889,6 +894,15 @@ def _flat_collect_single_eval(
             # discount reference for the span this decision opens (the
             # decide micro-step itself never advances the wall clock)
             t_ref2 = jnp.where(decided & ~over, wall0, t_ref)
+            if rollout_duration is not None:
+                # a frozen lane's row is rolled back below whatever it
+                # did, so it sits the drain out (a lane in DECIDE mode
+                # does not enter the `while`): left to run, it repeats
+                # the span after its last decision in every row, and
+                # all 128 lanes wait for it (PERF.md, PR 30)
+                ls2 = ls2.replace(
+                    mode=jnp.where(over, M_DECIDE, ls2.mode)
+                )
 
         out = v_drain(
             ls2, jax.random.split(k_drain, B), lane_idx, t_ref2, tm
@@ -929,7 +943,7 @@ def _flat_collect_single_eval(
                 tm = _tm_add(
                     tm, rows=1, rows_live=dec.any(),
                     rows_full_width=aux.get("full_width", False),
-                    drain_batch_iters=drained,
+                    drain_batch_iters=drained, rows_frozen=over,
                 )
             if health:
                 tm = _tm_orr(tm, health_mask=jnp.where(over, 0, hm))

@@ -698,12 +698,11 @@ def _tiny_decima_rows(monkeypatch, job_bucket: int, lanes: int = 3):
     return params, bank, make_sched(job_bucket=job_bucket), states
 
 
-def _check_row_counters(tm, steps: int, frozen: bool = False) -> dict:
+def _check_row_counters(tm, steps: int) -> dict:
     """The identities every collection holds; returns the `row` block
-    of the summary. A `frozen` lane's drain runs on the device and is
-    then rolled back with the lane's own counts, so only a collection
-    without frozen lanes bounds the batch's iterations by the lanes'
-    sum."""
+    of the summary. A frozen lane sits the drain out (PR 30), so with
+    frozen lanes too the batch's iterations are bounded by the sum of
+    what the lanes counted."""
     from sparksched_tpu.analysis.contracts import check_telemetry
     from sparksched_tpu.obs.telemetry import summarize
 
@@ -715,7 +714,7 @@ def _check_row_counters(tm, steps: int, frozen: bool = False) -> dict:
     assert (live == live[0]).all() and live[0] <= steps
     assert (batch == batch[0]).all()
     assert drained.max() <= batch[0]
-    assert frozen or batch[0] <= drained.sum()
+    assert batch[0] <= drained.sum()
     row = summarize(tm)["row"]
     assert row == {
         "rows": steps, "rows_live": int(live[0]),
@@ -724,6 +723,7 @@ def _check_row_counters(tm, steps: int, frozen: bool = False) -> dict:
         "lane_rows": steps * rows.size,
         "drain_lane_iters_executed": int(batch[0]) * rows.size,
         "drain_iters_total": int(drained.sum()),
+        "lane_rows_frozen": int(np.asarray(tm.rows_frozen).sum()),
     }
     return row
 
@@ -807,7 +807,7 @@ def test_row_counters_of_the_async_batch_collector_with_frozen_lanes():
         jax.vmap(init_loop_state)(states), jnp.float32(2.0e6),
         telemetry=telemetry_zeros_like((lanes,)),
     )
-    row = _check_row_counters(tm, steps, frozen=True)
+    row = _check_row_counters(tm, steps)
     decided = np.asarray(ro.valid).sum(axis=1)
     assert (decided < steps).all(), "the budget froze no lane"
     assert np.asarray(tm.decide_steps).tolist() == decided.tolist()
@@ -833,6 +833,7 @@ def test_summarize_reads_batch_counters_as_the_lane_maximum():
         "rows": 7, "rows_live": 5, "rows_full_width": 2,
         "drain_batch_iters": 31, "lane_rows": 28,
         "drain_lane_iters_executed": 124, "drain_iters_total": 60,
+        "lane_rows_frozen": 0,
     }
 
 
@@ -852,25 +853,39 @@ def test_full_width_predicate_is_one_scalar_over_the_batch(monkeypatch):
     assert sched.full_width(two) is None
 
 
+@pytest.mark.parametrize("mode", ["sync", "stream"])
 def test_every_equation_of_the_collector_scan_body_is_under_a_scope(
-    monkeypatch
+    monkeypatch, mode
 ):
     """The device trace names an operation by the scopes in its
     `op_name`. Code added to the scan body of the single-eval collector
     must not fall outside them silently: every equation of the body
     (health sentinels and telemetry on, as the trainer runs it) carries
     one of `ROW_SCOPES` in its name stack, the handling of the row's
-    PRNG keys apart."""
+    PRNG keys apart. The streaming collector's body is the same scan
+    with the reset program in both engine steps: there the whole-name
+    scope `env/micro_step/reset` appears (inside `decide` and inside
+    `drain`), and it is absent from the sync body."""
     import jax
+    import jax.numpy as jnp
 
+    from sparksched_tpu.env.flat_loop import init_loop_state
     from sparksched_tpu.obs.telemetry import telemetry_zeros_like
-    from sparksched_tpu.trainers.rollout import collect_flat_sync_batch
+    from sparksched_tpu.trainers.rollout import (
+        collect_flat_async_batch,
+        collect_flat_sync_batch,
+    )
 
     params, bank, sched, states = _tiny_decima_rows(monkeypatch, job_bucket=3)
     steps = 5
     bpol = sched.flat_batch_policy()
 
     def collect(key, states, tm):
+        if mode == "stream":
+            return collect_flat_async_batch(
+                params, bank, bpol, key, steps,
+                jax.vmap(init_loop_state)(states), jnp.float32(1.0e6),
+                telemetry=tm, fulfill_bulk=True, health=True)
         return collect_flat_sync_batch(
             params, bank, bpol, key, steps, states, tm,
             fulfill_bulk=True, health=True)
@@ -901,5 +916,25 @@ def test_every_equation_of_the_collector_scan_body_is_under_a_scope(
     assert set(bare) <= key_handling and len(bare) <= 24, bare
     # the GNN's sub-scopes are inside the net, below `decima/gnn`
     text = jaxpr.pretty_print(name_stack=True)
+    # the reset's scope, in the decide step and (inside the drain's
+    # `while`, so below the body's own equations) in the drain
+    def under(jp, scope):
+        for eqn in jp.eqns:
+            if scope in str(eqn.source_info.name_stack):
+                return True
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns") and under(inner, scope):
+                    return True
+        return False
+
+    (drain,) = [e for e in body.eqns if e.primitive.name == "while"
+                and "env/micro_step/drain" in str(e.source_info.name_stack)]
+    in_decide = any("env/micro_step/decide" in st
+                    and "env/micro_step/reset" in st for _, st in stacks)
+    in_drain = under(drain.params["body_jaxpr"].jaxpr,
+                     "env/micro_step/reset")
+    assert in_decide == in_drain == (mode == "stream")
+    assert ("env/micro_step/reset" in text) == (mode == "stream")
     for sub in ("levels", "stage_head", "exec_head"):
         assert f"decima/gnn/{sub}" in text

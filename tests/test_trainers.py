@@ -656,3 +656,120 @@ def test_ppo_update_in_chunks_matches_whole_minibatch(
     for k in ("policy_loss", "entropy", "approx_kl_div"):
         np.testing.assert_allclose(got[k], want[k], rtol=1e-4, atol=1e-8)
     np.testing.assert_allclose(parts, whole, rtol=0, atol=1e-7)
+
+
+# ---------------------------------------------------------------------------
+# streaming counters (PR 30): `reseeds`, `reset_evals`, `rows_frozen`
+# ---------------------------------------------------------------------------
+
+
+def _stream_fixture():
+    """Two persistent lanes of one sequence group on a 4-executor,
+    3-job cluster under a round-robin policy, as the batch test above."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparksched_tpu.config import EnvParams
+    from sparksched_tpu.env import core
+    from sparksched_tpu.schedulers.heuristics import round_robin_policy
+    from sparksched_tpu.workload import make_workload_bank
+
+    params = EnvParams(
+        num_executors=4, max_jobs=3, max_stages=20, max_levels=20,
+        moving_delay=500.0, warmup_delay=200.0,
+    )
+    bank = make_workload_bank(params.num_executors, params.max_stages)
+    params = params.replace(
+        max_stages=bank.max_stages, max_levels=bank.max_stages
+    )
+
+    def bpol(rng, obs):
+        si, ne = jax.vmap(
+            lambda o: round_robin_policy(o, params.num_executors, True)
+        )(obs)
+        return si, ne, {}
+
+    seq_base = jax.random.fold_in(jax.random.PRNGKey(7), 0)
+    seq0 = jax.random.fold_in(seq_base, 0)
+    salts = jnp.asarray([1000, 1001], jnp.int32)
+    states = jax.vmap(
+        lambda salt: core.reset_pair(
+            params, bank, seq0, jax.random.fold_in(seq0, salt)
+        )
+    )(salts)
+    return params, bank, bpol, states, jnp.stack([seq_base] * 2), salts
+
+
+def _assert_leaf_equal(a, b):
+    import jax
+
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("budget", [1e9, 2.0e6], ids=["row_cap", "budget"])
+def test_streaming_counters_against_a_hand_count(budget):
+    """With a telemetry carry the streaming rollout and the carry it
+    hands on are leaf-equal to those without; `reseeds` is the lane's
+    flagged resets, `rows_frozen` the rows it sat out with the budget
+    spent (the scan's rows less the rows it decided in), and
+    `reset_evals` one per micro-step it took: a decide step in every
+    row it was not frozen in, and each body of its drains."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparksched_tpu.env.flat_loop import init_loop_state
+    from sparksched_tpu.obs.telemetry import summarize, telemetry_zeros_like
+    from sparksched_tpu.trainers.rollout import collect_flat_async_batch
+
+    params, bank, bpol, states, bases, salts = _stream_fixture()
+    steps, ones = 120, jnp.ones((2,), jnp.int32)
+    args = (params, bank, bpol, jax.random.PRNGKey(100), steps,
+            jax.vmap(init_loop_state)(states), jnp.float32(budget),
+            bases, salts, ones)
+    ro0, ls0 = collect_flat_async_batch(*args)
+    ro, ls, tm = collect_flat_async_batch(
+        *args, telemetry_zeros_like((2,)))
+    _assert_leaf_equal((ro0, ls0), (ro, ls))
+
+    decided = np.asarray(ro.valid).sum(axis=1)
+    flagged = np.asarray(ro.resets).sum(axis=1)
+    frozen = steps - decided  # a live lane decides in every row
+    assert np.asarray(tm.reseeds).tolist() == flagged.tolist()
+    if budget >= 1e9:
+        assert flagged.min() >= 2, "no episode ended in the scan"
+    assert np.asarray(tm.rows_frozen).tolist() == frozen.tolist()
+    assert (frozen > 0).all() == (budget < 1e9)
+    want = decided + np.asarray(tm.drain_iters)
+    assert np.asarray(tm.reset_evals).tolist() == want.tolist()
+    assert np.asarray(tm.decide_steps).tolist() == decided.tolist()
+    s = summarize(tm)
+    assert s["reseeds_total"] == int(flagged.sum())
+    assert s["reset_evals_total"] == int(want.sum())
+    assert s["reset_evals_total"] == s["micro_steps"]
+    assert s["row"]["lane_rows_frozen"] == int(frozen.sum())
+    assert s["row"]["lane_rows"] == 2 * steps
+
+
+def test_sync_rollout_is_leaf_equal_under_telemetry_and_counts_no_stream():
+    """Sync mode: no reset program in the scan, no budget, so the three
+    streaming counters stay 0 (and `summarize` still prints them), and
+    the telemetry carry leaves the rollout as it is."""
+    import jax
+
+    from sparksched_tpu.obs.telemetry import summarize, telemetry_zeros_like
+    from sparksched_tpu.trainers.rollout import collect_flat_sync_batch
+
+    params, bank, bpol, states, _, _ = _stream_fixture()
+    args = (params, bank, bpol, jax.random.PRNGKey(100), 60, states)
+    ro0 = collect_flat_sync_batch(*args)
+    ro, tm = collect_flat_sync_batch(*args, telemetry_zeros_like((2,)))
+    _assert_leaf_equal(ro0, ro)
+    for name in ("reseeds", "reset_evals", "rows_frozen"):
+        assert not np.asarray(getattr(tm, name)).any(), name
+    s = summarize(tm)
+    assert (s["reseeds_total"], s["reset_evals_total"],
+            s["row"]["lane_rows_frozen"]) == (0, 0, 0)
+    assert s["decisions"] == int(np.asarray(ro.valid).sum())
