@@ -112,6 +112,22 @@ class Budget:
 # -> 15111/206/20, serve_decide 6265/33/65 -> 6610/33/65,
 # serve_decide_batch 14756/251/65 -> 15185/267/65. Every count is
 # inside its band, so no band moved; the chip rows are PERF.md, PR 28.
+#
+# Re-pinned 2026-09-28 (PR 31: the re-seed of a streaming lane left the
+# micro-step tail of the row-structured programs). `decide_micro_step`
+# has no `auto_reset` any more, a decide step cannot end an episode:
+# 2711/28/1 -> 2470/24/0, now loop-free, caps 3700/40/3 -> 3350/33/2.
+# `drain_to_decision(auto_reset=True)` re-seeds once, after its loop:
+# 2845/5/1 -> 2960/5/1 as registered here (one lane and no lane axis,
+# so the re-seed is unconditional; the reset program's equations moved
+# from the loop's body to after it, and the body took the sync tail's
+# freeze select), inside its band. What the change is for, a drain
+# `while` that carries nothing the reset writes, is not a count of
+# equations: tests/test_obs.py holds the streaming loop to the sync
+# one. The decide step's dead key split went with its `rng`: the
+# serve programs 7 and the collectors 10 equations fewer
+# (serve_decide 6610 -> 6603, flat_collect_batch 14866 -> 14856), all
+# inside their bands. The chip rows are PERF.md, PR 31.
 # ---------------------------------------------------------------------------
 
 BUDGETS: dict[str, Budget] = {
@@ -130,7 +146,8 @@ BUDGETS: dict[str, Budget] = {
     ),
     # the single-eval collectors' policy-bearing micro-step
     "decide_micro_step": Budget(
-        eqn_lo=1000, eqn_hi=3700, gather_hi=40, scatter_hi=3,
+        eqn_lo=1000, eqn_hi=3350, gather_hi=33, scatter_hi=2,
+        loop_free=True,
     ),
     # the single-eval collectors' non-policy drain (while-loop by
     # design: it runs until the lane is ready to DECIDE again; the
@@ -551,10 +568,10 @@ def lane_callables() -> dict[str, tuple[Callable, tuple]]:
             (ls, key),
         ),
         "decide_micro_step": (
-            lambda l, si, ne, r: decide_micro_step(
-                params, bank, l, si, ne, r, True, True
+            lambda l, si, ne: decide_micro_step(
+                params, bank, l, si, ne, True
             ),
-            (ls, i32, i32, key),
+            (ls, i32, i32),
         ),
         "drain_to_decision": (
             lambda l, r: drain_to_decision(

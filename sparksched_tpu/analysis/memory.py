@@ -57,7 +57,13 @@ for the ISSUE-7 fused bulk kernel, which SHRANK the engine programs:
 micro_step 22.1 -> 16.1, drain_to_decision 16.2 -> 9.7,
 flat_collect_batch 357.7 -> 329.8; decide_micro_step unchanged at
 9.9 (its bulk phase is the mode-exclusive fulfill pass, deliberately
-unfused). (The decima/ppo programs
+unfused). Re-pinned 2026-09-28 (PR 31): `decide_micro_step` lost its
+`auto_reset` (a decide step cannot end an episode, so the reset
+program it evaluated selected nothing): 9.9 -> 6.0, cap 14 -> 8;
+`drain_to_decision(auto_reset=True)` re-seeds once after its loop and
+no longer in the loop's body: 9.5 -> 10.0 as registered (one lane, no
+lane axis: the re-seed unconditional; the loop's body took the sync
+tail's freeze select), inside its cap. (The decima/ppo programs
 carry a 4-lane batch in their audited shapes, and tile padding
 inflates narrow minor dims — these are model numbers for regression
 detection, not literal HBM footprints; the lane-fit table is the
@@ -120,7 +126,7 @@ MB = 10**6
 MEM_BUDGETS: dict[str, MemBudget] = {
     "observe": MemBudget(temp_hi=4 * MB),
     "micro_step": MemBudget(temp_hi=22 * MB),
-    "decide_micro_step": MemBudget(temp_hi=14 * MB),
+    "decide_micro_step": MemBudget(temp_hi=8 * MB),
     "drain_to_decision": MemBudget(temp_hi=14 * MB),
     "decima_score": MemBudget(temp_hi=210 * MB),
     "decima_batch_policy": MemBudget(temp_hi=230 * MB),

@@ -646,7 +646,7 @@ def _finish_micro_step(
     rs: jnp.ndarray,
     e: jnp.ndarray,
     quirk: jnp.ndarray,
-    k_reset: jax.Array,
+    k_reset: jax.Array | None,  # read under auto_reset only
     auto_reset: bool,
     fulfill_bulk: bool = False,
     record: bool = False,
@@ -736,9 +736,16 @@ def _finish_micro_step(
     st = lax.cond(ready, set_ready, not_ready, st)
     mode = jnp.where(ready, M_DECIDE, ls2.mode).astype(_i32)
 
-    # episode end: auto-reset (unconditional reset + select keeps the
-    # workload bank out of lane-dependent conditionals); with
-    # auto_reset=False finished lanes freeze instead (tests, evals)
+    # episode end. The loops whose unit is the micro-step (`micro_step`,
+    # `event_micro_step`, `drain_micro_step`, so `run_flat` and the
+    # multi-eval collectors) re-seed here with auto_reset: the lane goes
+    # on in its NEXT micro-step, so the reset program runs in every one
+    # and the state is selected against it (unconditional, which keeps
+    # the workload bank out of lane-dependent conditionals). With
+    # auto_reset=False finished lanes freeze instead: tests, evals, the
+    # decide step, and the body of `drain_to_decision`, whose unit is
+    # the decision row and which re-seeds once, after its loop
+    # (`_reseed_ended`)
     done = _lane_done(st)
     was_done = _lane_done(ls.env)
     if record:
@@ -754,18 +761,14 @@ def _finish_micro_step(
             done & ~was_done,
         )
     if auto_reset:
-        from . import core as _core
-
         # one whole name (obs/tracing.py): the reset program and the
-        # select of the whole state, paid by every micro-step
+        # select of the whole state, paid by every micro-step of these
+        # loops
         with annotate("env/micro_step/reset"):
-            if reset_fn is None:
-                fresh = _core.reset(params, bank, k_reset)
-            else:
-                # ls2.episodes is the pre-increment completed-episode
-                # count: the async collector's group-shared
-                # reset-ordinal hook
-                fresh = reset_fn(k_reset, ls2.episodes)
+            # ls2.episodes is the pre-increment completed-episode count
+            fresh = _fresh_episode(
+                params, bank, k_reset, reset_fn, ls2.episodes
+            )
             st = jax.tree_util.tree_map(
                 lambda a, b: jnp.where(done, a, b), fresh, st
             )
@@ -891,27 +894,30 @@ def decide_micro_step(
     ls: LoopState,
     stage_idx: jnp.ndarray,
     num_exec: jnp.ndarray,
-    rng: jax.Array,
-    auto_reset: bool = True,
     fulfill_bulk: bool = False,
-    reset_fn: Callable | None = None,
     t_ref: jnp.ndarray | None = None,
     telemetry=None,
 ) -> tuple:
     """One DECIDE-only micro-step driven by a PRECOMPUTED policy decision:
     lanes in M_DECIDE mode commit (or round-finish) via the shared
     `_apply_decision` + `_finish_micro_step` pair; other lanes no-op
-    bit-exactly (their rng/state must not advance). The single-eval flat
+    bit-exactly (their state must not advance). The single-eval flat
     collectors (`trainers/rollout.py:collect_flat_*_batch`) evaluate the
     policy ONCE per decision row at batch level and feed the outputs
     here, so the GNN appears exactly once per recorded decision instead
     of once per micro-step group. Returns
     `(ls, (decided, reward, dt, reset)[, telemetry])`; `decided` marks
-    lanes that recorded a decision (live and in DECIDE mode at entry)."""
+    lanes that recorded a decision (live and in DECIDE mode at entry).
+
+    The step draws nothing and cannot end an episode: it never advances
+    the wall clock and finishes no task, and `_lane_done` reads those
+    two. So it has no `auto_reset`: there is never an episode to
+    re-seed here, `reset` is False for every lane that was live at
+    entry, and a lane handed in with its episode over is frozen, as in
+    a sync collection."""
     track = telemetry is not None
     with annotate("env/micro_step/decide"):
         is_dec = ls.mode == M_DECIDE
-        _, k_reset = jax.random.split(rng)
         # force the tail's mode-keyed logic to the DECIDE shape for every
         # lane (the event_micro_step pattern): non-decide lanes' branch
         # results are discarded by the final select below
@@ -920,9 +926,9 @@ def decide_micro_step(
         mode2 = ls2.mode  # pre-tail mode: DECIDE -> non-DECIDE == round done
         out = _finish_micro_step(
             params, bank, ls0, ls2, _i32(RQ_NONE), _i32(-1), _i32(-1),
-            _i32(0), ls2.env.source_job_id(), k_reset, auto_reset,
-            fulfill_bulk=fulfill_bulk, record=True, reset_fn=reset_fn,
-            t_ref=t_ref, telem=telemetry,
+            _i32(0), ls2.env.source_job_id(), None, False,
+            fulfill_bulk=fulfill_bulk, record=True, t_ref=t_ref,
+            telem=telemetry,
         )
         if track:
             out_ls, (rw, dt, rs_), telemetry = out
@@ -1089,7 +1095,23 @@ def drain_to_decision(
     carry select instead of re-selecting the ~50-leaf LoopState every
     iteration. The per-lane iteration count is measured directly
     (`drain_iters` — its max/mean over lanes IS the drain's batch-max
-    while tax). Returns `(ls, (reward, dt, reset)[, telemetry])`."""
+    while tax). Returns `(ls, (reward, dt, reset)[, telemetry])`.
+
+    The unit here is the decision row, not the micro-step: a lane whose
+    episode ends leaves the loop at that body (the cond reads
+    `_lane_done`) and nothing runs on it until the next row. So the
+    body's tail never re-seeds, with or without `auto_reset`, and the
+    loop is the same program in both modes: the leaves only a reset
+    writes (the adjacency, the templates, the task counts) stay
+    loop-invariant. With `auto_reset` the lane is re-seeded ONCE, after
+    the loop (`_reseed_ended`): the reset program runs only in a row in
+    which some lane of the batch ended (one predicate over `lane_axis`),
+    at the ordinal the tail would have used. The state, the row's
+    `(reward, dt, reset)` and the episode count are those of
+    `drain_micro_step(auto_reset=True)` repeated until the lane is
+    ready to decide (tests/test_trainers.py holds the two against each
+    other a row at a time); with a `reset_fn` that ignores its key, as
+    the streaming collector's does, bit for bit."""
     track = telemetry is not None
     zero = jnp.float32(0.0)
 
@@ -1110,8 +1132,8 @@ def drain_to_decision(
             (ls, k, rw, dt, rs), tm = c, None
         k, sub = jax.random.split(k)
         out = drain_micro_step(
-            params, bank, ls, sub, auto_reset, event_bulk, bulk_events,
-            bulk_cycles, reset_fn, t_ref, telemetry=tm,
+            params, bank, ls, sub, False, event_bulk, bulk_events,
+            bulk_cycles, t_ref=t_ref, telemetry=tm,
             bulk_fused=bulk_fused, masked=False, lane_axis=lane_axis,
         )
         if track:
@@ -1127,9 +1149,77 @@ def drain_to_decision(
     with annotate("env/micro_step/drain"):
         c = lax.while_loop(cond, body, c0)
     ls, rw, dt, rs = c[0], c[2], c[3], c[4]
-    if track:
-        return ls, (rw, dt, rs), c[5]
-    return ls, (rw, dt, rs)
+    tm = c[5] if track else None
+    if auto_reset:
+        # one whole name (obs/tracing.py), beside `env/micro_step/drain`
+        with annotate("env/micro_step/reset"):
+            ls = _reseed_ended(
+                params, bank, ls, rs, c[1], reset_fn, lane_axis
+            )
+            tm = _tm_add(tm, reseeds=rs)
+    return (ls, (rw, dt, rs), tm) if track else (ls, (rw, dt, rs))
+
+
+def _fresh_episode(
+    params: EnvParams,
+    bank: WorkloadBank,
+    key: jax.Array,
+    reset_fn: Callable | None,
+    ordinal: jnp.ndarray,
+) -> EnvState:
+    """The state a re-seeded lane starts from: `reset_fn(key, ordinal)`
+    where the caller gave one (`ordinal`, the lane's completed-episode
+    count before this episode's end was counted, is the async
+    collectors' group-shared reset-ordinal hook), else `core.reset`
+    under `key`."""
+    if reset_fn is not None:
+        return reset_fn(key, ordinal)
+    from . import core as _core
+
+    return _core.reset(params, bank, key)
+
+
+def _reseed_ended(
+    params: EnvParams,
+    bank: WorkloadBank,
+    ls: LoopState,
+    ended: jnp.ndarray,
+    rng: jax.Array,
+    reset_fn: Callable | None,
+    lane_axis: str | None,
+) -> LoopState:
+    """Start a new episode on a lane whose episode `ended` in the drain
+    just run: the fresh state, in DECIDE mode, everything else as the
+    drain left it. The fresh state (`_fresh_episode`) is asked for at
+    the episode count BEFORE the increment the tail made when the
+    episode ended, as the per-micro-step reset of `_finish_micro_step`
+    asks for it, under `rng`, what is left of the drain's key stream.
+
+    The reset program runs under ONE predicate for the batch: `ended`
+    reduced over `lane_axis`, the name the caller's `vmap` gave its lane
+    axis, so under that `vmap` this stays a conditional and a row in
+    which no lane ended pays nothing. The workload bank is an operand
+    of the conditional, which is sound because the predicate is not
+    lane-dependent. A per-lane predicate would become, under `vmap`, a
+    select over both branches with the bank broadcast to every lane
+    (the memory pass's `bank-broadcast` rule), so a caller without
+    `lane_axis` gets the reset program and the select unconditionally,
+    once a drain."""
+
+    def reseed(ls: LoopState) -> LoopState:
+        fresh = _fresh_episode(
+            params, bank, rng, reset_fn, ls.episodes - 1
+        )
+        env = jax.tree_util.tree_map(
+            lambda a, b: jnp.where(ended, a, b), fresh, ls.env
+        )
+        return ls.replace(
+            env=env, mode=jnp.where(ended, M_DECIDE, ls.mode).astype(_i32)
+        )
+
+    if lane_axis is None:
+        return reseed(ls)
+    return lax.cond(lax.pmax(ended, lane_axis), reseed, lambda ls: ls, ls)
 
 
 def apply_and_drain(
@@ -1164,11 +1254,13 @@ def apply_and_drain(
     `reward`/`dt` accumulate over the decide step and the whole
     drain."""
     track = telemetry is not None
-    k_dec, k_drain = jax.random.split(rng)
+    # the first key is not used (the decide step draws nothing): the
+    # drain keeps the second, so a served lane's stream is what it is
+    _, k_drain = jax.random.split(rng)
     t_ref = ls.env.wall_time
     out = decide_micro_step(
-        params, bank, ls, stage_idx, num_exec, k_dec, auto_reset,
-        fulfill_bulk, t_ref=t_ref, telemetry=telemetry,
+        params, bank, ls, stage_idx, num_exec, fulfill_bulk,
+        t_ref=t_ref, telemetry=telemetry,
     )
     if track:
         ls2, (decided, rw1, dt1, rs1), telemetry = out

@@ -42,18 +42,24 @@ Counter semantics per engine:
   really ran, which every lane pays for). They are facts of the batch,
   not of a lane, so every lane holds the same value; no other engine or
   collector touches them.
-- streaming (`auto_reset`, PR 30; set by the tail the single-eval
-  collectors run, `flat_loop._finish_micro_step` under
-  `decide_micro_step` and `drain_micro_step`): `reseeds` counts the
-  lane's episodes that ended in a micro-step and were re-seeded there
-  (`done & ~was_done`), `reset_evals` the times the reset program
-  (`reset_fn` / `core.reset` and the select of the whole state that
-  follows it) was evaluated for the lane: one per micro-step the lane
-  took, whether or not an episode ended. `rows_frozen` (the batch
-  collectors, once a row) counts the decision rows the lane sat out
-  because its sim-time budget was spent (`over`); a frozen lane's other
-  counters, these two included, stand still for the row. All three stay
-  0 in sync mode (`auto_reset=False`, no budget).
+- streaming (`auto_reset`): `reseeds` counts the lane's episodes that
+  ended in the scan and were re-seeded, `reset_evals` the evaluations
+  of the reset program (`reset_fn` / `core.reset` and the select of the
+  state against it) the lane was part of. In the single-eval batch
+  collector (PR 31) the program runs once a decision row, after the
+  drain, and only in a row in which some unfrozen lane's episode ended
+  (`flat_loop.drain_to_decision`): `reset_evals` is then a fact of the
+  batch, +1 in EVERY lane for such a row, frozen lanes too, so
+  `reset_evals_total / reseeds_total` is at most the number of lanes.
+  In the loops whose unit is the micro-step
+  (`flat_loop._finish_micro_step` under `micro_step`,
+  `event_micro_step` and `drain_micro_step`) the tail evaluates it in
+  every micro-step the lane takes, whether or not an episode ended,
+  and counts one each. `rows_frozen` (the batch collectors, once a
+  row) counts the decision rows the lane sat out because its sim-time
+  budget was spent (`over`); a frozen lane's other counters, `reseeds`
+  included, stand still for the row. All three stay 0 in sync mode
+  (`auto_reset=False`, no budget).
 
 Cross-engine invariant (the parity test): on a deterministic workload
 the two engines process the same trajectory, so `decide_steps`, the
@@ -109,9 +115,11 @@ class Telemetry(struct.PyTreeNode):
     rows_live: jnp.ndarray  # rows in which some lane decided
     rows_full_width: jnp.ndarray  # rows scored at the full job width
     drain_batch_iters: jnp.ndarray  # sum over rows of max-lane drain iters
-    # --- streaming (auto_reset) collection: per lane, 0 in sync mode ---
+    # --- streaming (auto_reset) collection: 0 in sync mode ---
     reseeds: jnp.ndarray  # episodes that ended in the scan, re-seeded
-    reset_evals: jnp.ndarray  # evaluations of the reset program
+    # evaluations of the reset program: rows in which it ran (the batch
+    # collector; every lane the same) or the lane's micro-steps
+    reset_evals: jnp.ndarray
     rows_frozen: jnp.ndarray  # rows sat out with the budget spent
     # --- health sentinels (ISSUE 9) ---
     # i32 violation BITMASK (env/health.py bit table), OR-accumulated

@@ -21,13 +21,16 @@ collectors (`trainers/rollout.py`) is covered whole: `collect/observe`
 `decima/gnn/levels`, `decima/gnn/stage_head` and `decima/gnn/exec_head`
 inside it), `decima/sample`, `env/micro_step/decide` and
 `env/micro_step/drain` (the engine's two functions of that row, so the
-serve programs carry them too; under `auto_reset`, the streaming
-collectors and the serve programs, each holds
-`env/micro_step/reset`: the reset program and the select of the whole
-state that every micro-step pays), `collect/health`, `collect/freeze`,
+serve programs carry them too), `env/micro_step/reset` (streaming
+only: `drain_to_decision(auto_reset=True)` re-seeds a lane whose
+episode ended once, after its loop and beside `drain`, not inside it,
+under one predicate for the batch: the reset program and the select of
+the state against it), `collect/health`, `collect/freeze`,
 `collect/scatter`. Elsewhere: `env/micro_step` (the mode switch and tail
-of `flat_loop.micro_step`), `train/ppo_update`, `serve/decide`,
-`serve/decide_batch`, `serve/dispatch`, `serve/flush`.
+of `flat_loop.micro_step`; with `auto_reset` the tail of the loops whose
+unit is the micro-step holds `env/micro_step/reset` in every step),
+`train/ppo_update`, `serve/decide`, `serve/decide_batch`,
+`serve/dispatch`, `serve/flush`.
 
 A nested phase is ONE scope whose name holds its parent's
 (`annotate("env/micro_step/drain")`, not two nested `annotate`s): a

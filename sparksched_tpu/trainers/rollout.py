@@ -754,9 +754,11 @@ def collect_flat_sync(
 # tests/test_obs.py pins it), and with a telemetry carry the body
 # counts its rows (`obs/telemetry.py`: `rows`, `rows_live`,
 # `rows_full_width`, `drain_batch_iters`, and per lane `rows_frozen`).
-# In streaming mode (`auto_reset`) the engine's tail adds the scope
-# `env/micro_step/reset` inside both engine scopes and the per-lane
-# counters `reseeds` and `reset_evals`.
+# In streaming mode (`auto_reset`) a lane whose episode ended in the
+# row's drain is re-seeded once, after the drain's loop and under one
+# predicate for the batch (`flat_loop._reseed_ended`, scope
+# `env/micro_step/reset`); `reseeds` counts it for the lane and
+# `reset_evals` the rows in which the reset program ran.
 # ---------------------------------------------------------------------------
 
 
@@ -839,15 +841,13 @@ def _flat_collect_single_eval(
             telemetry = constrain_lanes(telemetry, lane_shard)
     lane_idx = jnp.arange(B)
 
-    def v_decide(ls, si, ne, keys, li, tm):
-        def one(l, s_, n_, k_, i_, t_):
-            rf = None if reset_fns is None else reset_fns(i_)
+    def v_decide(ls, si, ne, tm):
+        def one(l, s_, n_, t_):
             return decide_micro_step(
-                params, bank, l, s_, n_, k_, auto_reset, fulfill_bulk,
-                reset_fn=rf, telemetry=t_,
+                params, bank, l, s_, n_, fulfill_bulk, telemetry=t_
             )
 
-        return jax.vmap(one)(ls, si, ne, keys, li, tm)
+        return jax.vmap(one)(ls, si, ne, tm)
 
     def v_drain(ls, keys, li, t_ref, tm):
         def one(l, k_, i_, tr, t_):
@@ -859,7 +859,8 @@ def _flat_collect_single_eval(
             )
 
         # the lane axis has a name so that the fused bulk pass can end
-        # its loop on one predicate for the whole batch
+        # its loop, and the re-seed after the drain be skipped, on one
+        # predicate for the whole batch
         return jax.vmap(one, axis_name="lanes")(ls, keys, li, t_ref, tm)
 
     def body(carry, _):
@@ -868,7 +869,10 @@ def _flat_collect_single_eval(
         else:
             (ls, k, t_ref, elapsed, ndec, buf), tm = carry, None
         tm_frozen = tm
-        k, k_pol, k_dec, k_drain = jax.random.split(k, 4)
+        # the third key is not used (the decide step draws nothing):
+        # the policy and the drain keep the second and the fourth, so
+        # every stream, and with it every rollout, is what it is
+        k, k_pol, _, k_drain = jax.random.split(k, 4)
         env0 = ls.env
         wall0 = env0.wall_time  # [B]
 
@@ -878,10 +882,7 @@ def _flat_collect_single_eval(
             obs = jax.vmap(lambda e: observe(params, e))(env0)
         stage_idx, num_exec, aux = batch_policy_fn(k_pol, obs)
 
-        out = v_decide(
-            ls, stage_idx, num_exec, jax.random.split(k_dec, B),
-            lane_idx, tm,
-        )
+        out = v_decide(ls, stage_idx, num_exec, tm)
         if track:
             ls2, (decided, rw1, dt1, rs1), tm = out
         else:
@@ -945,6 +946,11 @@ def _flat_collect_single_eval(
                     rows_full_width=aux.get("full_width", False),
                     drain_batch_iters=drained, rows_frozen=over,
                 )
+                if auto_reset:
+                    # the drain's re-seed ran iff some lane ended its
+                    # episode there (a frozen lane sits the drain out,
+                    # so `reset` is already without the frozen lanes)
+                    tm = _tm_add(tm, reset_evals=reset.any())
             if health:
                 tm = _tm_orr(tm, health_mask=jnp.where(over, 0, hm))
             zero = jnp.float32(0.0)
@@ -1067,6 +1073,29 @@ def collect_flat_sync_batch(
     return (out[0], out[2]) if telemetry is not None else out[0]
 
 
+def _group_reset_fns(params, bank, seq_bases, reset_counts, lane_salts):
+    """The streaming batch collector's per-lane factory of reset
+    programs: `reset_fns(lane)(key, episodes)` is the lane's episode at
+    the group-shared ordinal `reset_counts[lane] + episodes`. It ignores
+    `key`: the fresh state is a function of the lane's sequence base,
+    its ordinal and its salt alone, so it is the same wherever in a row
+    it is evaluated."""
+
+    def reset_fns(lane_idx):
+        def reset_fn(key, episodes):
+            seq_rng = jax.random.fold_in(
+                seq_bases[lane_idx], reset_counts[lane_idx] + episodes
+            )
+            return core.reset_pair(
+                params, bank, seq_rng,
+                jax.random.fold_in(seq_rng, lane_salts[lane_idx]),
+            )
+
+        return reset_fn
+
+    return reset_fns
+
+
 @partial(
     jax.jit, static_argnums=(0, 2, 4),
     static_argnames=(
@@ -1115,19 +1144,9 @@ def collect_flat_async_batch(
         jnp.asarray(reset_counts, _i32), (B,)
     )
     loop_states = loop_states.replace(episodes=jnp.zeros((B,), _i32))
-
-    def reset_fns(lane_idx):
-        def reset_fn(key, episodes):
-            seq_rng = jax.random.fold_in(
-                seq_bases[lane_idx], reset_counts[lane_idx] + episodes
-            )
-            return core.reset_pair(
-                params, bank, seq_rng,
-                jax.random.fold_in(seq_rng, lane_salts[lane_idx]),
-            )
-
-        return reset_fn
-
+    reset_fns = _group_reset_fns(
+        params, bank, seq_bases, reset_counts, lane_salts
+    )
     out = _flat_collect_single_eval(
         params, bank, batch_policy_fn, rng, num_steps, loop_states,
         auto_reset=True, event_bulk=event_bulk, bulk_events=bulk_events,

@@ -853,19 +853,13 @@ def test_full_width_predicate_is_one_scalar_over_the_batch(monkeypatch):
     assert sched.full_width(two) is None
 
 
-@pytest.mark.parametrize("mode", ["sync", "stream"])
-def test_every_equation_of_the_collector_scan_body_is_under_a_scope(
-    monkeypatch, mode
-):
-    """The device trace names an operation by the scopes in its
-    `op_name`. Code added to the scan body of the single-eval collector
-    must not fall outside them silently: every equation of the body
-    (health sentinels and telemetry on, as the trainer runs it) carries
-    one of `ROW_SCOPES` in its name stack, the handling of the row's
-    PRNG keys apart. The streaming collector's body is the same scan
-    with the reset program in both engine steps: there the whole-name
-    scope `env/micro_step/reset` appears (inside `decide` and inside
-    `drain`), and it is absent from the sync body."""
+_SCAN_BODIES: dict = {}
+
+
+def _collector_scan_body(monkeypatch, mode: str):
+    """The jaxpr of a five-row collection by the sync or the streaming
+    single-eval collector (health sentinels and telemetry on, as the
+    trainer runs it) and its scan body; traced once a mode."""
     import jax
     import jax.numpy as jnp
 
@@ -876,6 +870,8 @@ def test_every_equation_of_the_collector_scan_body_is_under_a_scope(
         collect_flat_sync_batch,
     )
 
+    if mode in _SCAN_BODIES:
+        return _SCAN_BODIES[mode]
     params, bank, sched, states = _tiny_decima_rows(monkeypatch, job_bucket=3)
     steps = 5
     bpol = sched.flat_batch_policy()
@@ -897,44 +893,129 @@ def test_every_equation_of_the_collector_scan_body_is_under_a_scope(
         for eqn in jp.eqns:
             if eqn.primitive.name == "scan":
                 yield eqn
-            for v in eqn.params.values():
-                inner = getattr(v, "jaxpr", v)
-                if hasattr(inner, "eqns"):
-                    yield from scans(inner)
+            for inner in _inner_jaxprs(eqn):
+                yield from scans(inner)
 
     (body,) = [e.params["jaxpr"].jaxpr for e in scans(jaxpr.jaxpr)
                if e.params["length"] == steps
                and not str(e.source_info.name_stack)]
+    _SCAN_BODIES[mode] = jaxpr, body
+    return jaxpr, body
+
+
+def _inner_jaxprs(eqn):
+    """The jaxprs an equation holds: a loop's cond and body, a
+    conditional's branches (a tuple), a call's callee."""
+    for v in eqn.params.values():
+        for x in v if isinstance(v, (tuple, list)) else (v,):
+            inner = getattr(x, "jaxpr", x)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _under(jp, scope: str) -> int:
+    """Equations of `jp`, at any depth, with `scope` in their name."""
+    return sum(
+        (scope in str(eqn.source_info.name_stack))
+        + sum(_under(inner, scope) for inner in _inner_jaxprs(eqn))
+        for eqn in jp.eqns
+    )
+
+
+def _drain_while(body):
+    (drain,) = [e for e in body.eqns if e.primitive.name == "while"
+                and "env/micro_step/drain" in str(e.source_info.name_stack)]
+    return drain
+
+
+RESET_SCOPE = "env/micro_step/reset"
+
+
+@pytest.mark.parametrize("mode", ["sync", "stream"])
+def test_every_equation_of_the_collector_scan_body_is_under_a_scope(
+    monkeypatch, mode
+):
+    """The device trace names an operation by the scopes in its
+    `op_name`. Code added to the scan body of the single-eval collector
+    must not fall outside them silently: every equation of the body
+    (health sentinels and telemetry on, as the trainer runs it) carries
+    one of `ROW_SCOPES` in its name stack, the handling of the row's
+    PRNG keys apart. The streaming collector's body is the same scan
+    with ONE thing more, the re-seed of the lanes whose episode ended
+    in the row (PR 31): the whole-name scope `env/micro_step/reset`
+    appears once, after the drain's `while` and beside it, as one
+    conditional on a predicate reduced over the lanes; it is not inside
+    the `while`, not in the decide step, and absent from the sync
+    body."""
+    jaxpr, body = _collector_scan_body(monkeypatch, mode)
+    scopes = ROW_SCOPES + ((RESET_SCOPE,) if mode == "stream" else ())
     stacks = [(e.primitive.name, str(e.source_info.name_stack))
               for e in body.eqns]
     assert len(stacks) > 1000  # the whole decision row is in this body
-    seen = {s for s in ROW_SCOPES if any(s in st for _, st in stacks)}
-    assert seen == set(ROW_SCOPES)
-    bare = [p for p, st in stacks if not any(s in st for s in ROW_SCOPES)]
+    seen = {s for s in scopes if any(s in st for _, st in stacks)}
+    assert seen == set(scopes)
+    bare = [p for p, st in stacks if not any(s in st for s in scopes)]
     key_handling = {"random_split", "random_wrap", "random_unwrap",
                     "random_bits", "slice", "squeeze"}
     assert set(bare) <= key_handling and len(bare) <= 24, bare
     # the GNN's sub-scopes are inside the net, below `decima/gnn`
     text = jaxpr.pretty_print(name_stack=True)
-    # the reset's scope, in the decide step and (inside the drain's
-    # `while`, so below the body's own equations) in the drain
-    def under(jp, scope):
-        for eqn in jp.eqns:
-            if scope in str(eqn.source_info.name_stack):
-                return True
-            for v in eqn.params.values():
-                inner = getattr(v, "jaxpr", v)
-                if hasattr(inner, "eqns") and under(inner, scope):
-                    return True
-        return False
-
-    (drain,) = [e for e in body.eqns if e.primitive.name == "while"
-                and "env/micro_step/drain" in str(e.source_info.name_stack)]
-    in_decide = any("env/micro_step/decide" in st
-                    and "env/micro_step/reset" in st for _, st in stacks)
-    in_drain = under(drain.params["body_jaxpr"].jaxpr,
-                     "env/micro_step/reset")
-    assert in_decide == in_drain == (mode == "stream")
-    assert ("env/micro_step/reset" in text) == (mode == "stream")
+    assert (RESET_SCOPE in text) == (mode == "stream")
     for sub in ("levels", "stage_head", "exec_head"):
         assert f"decima/gnn/{sub}" in text
+
+    drain = _drain_while(body)
+    assert _under(drain.params["body_jaxpr"].jaxpr, RESET_SCOPE) == 0
+    assert _under(drain.params["cond_jaxpr"].jaxpr, RESET_SCOPE) == 0
+    at_top = [(i, e) for i, e in enumerate(body.eqns)
+              if RESET_SCOPE in str(e.source_info.name_stack)]
+    if mode == "sync":
+        assert at_top == []
+        return
+    # in no other engine scope: the decide step holds none of it
+    assert not any(s in str(e.source_info.name_stack)
+                   for _, e in at_top for s in ROW_SCOPES)
+    # one conditional (the lanes' flags reduced to one predicate, so a
+    # `cond` and not a select over both branches), after the `while`,
+    # and the add of the `reseeds` counter
+    assert sorted(e.primitive.name for _, e in at_top) == [
+        "add", "cond", "convert_element_type", "convert_element_type",
+        "pmax"]
+    (where, cond), = [(i, e) for i, e in at_top
+                      if e.primitive.name == "cond"]
+    assert where > body.eqns.index(drain)
+    assert cond.invars[0].aval.shape == ()  # one predicate, not per lane
+    assert _under(body, RESET_SCOPE) == len(at_top) + sum(
+        _under(b, RESET_SCOPE) for b in _inner_jaxprs(cond))
+    # the reset program is in one branch only; the other hands back
+    # what it was given
+    sizes = sorted(len(b.eqns) for b in _inner_jaxprs(cond))
+    assert sizes[0] == 0 and sizes[1] > 50, sizes
+
+
+def test_the_streaming_drain_while_is_the_sync_one(monkeypatch):
+    """What PR 31 is for, held on a CPU: the drain `while` of the
+    streaming collector carries, closes over and computes no more than
+    the sync collector's. A reset in the body's tail (the shape until
+    PR 31) makes the leaves only a reset writes, the adjacency, the
+    templates, the task counts, part of the carry the loop selects and
+    copies in every iteration: 31 constants and 7,828 equations where
+    the sync loop has 16 and 7,505 at this size."""
+    def size(jp) -> int:
+        return len(jp.eqns) + sum(
+            size(inner) for e in jp.eqns for inner in _inner_jaxprs(e))
+
+    facts = {}
+    for mode in ("sync", "stream"):
+        drain = _drain_while(_collector_scan_body(monkeypatch, mode)[1])
+        loop = drain.params["body_jaxpr"].jaxpr
+        facts[mode] = {
+            "carried": len(loop.outvars),
+            "closed over": drain.params["body_nconsts"]
+            + drain.params["cond_nconsts"],
+            "equations": size(loop)
+            + size(drain.params["cond_jaxpr"].jaxpr),
+        }
+    assert facts["sync"]["equations"] > 5000
+    for what, n in facts["stream"].items():
+        assert n <= facts["sync"][what], (what, facts)

@@ -715,8 +715,10 @@ def test_streaming_counters_against_a_hand_count(budget):
     hands on are leaf-equal to those without; `reseeds` is the lane's
     flagged resets, `rows_frozen` the rows it sat out with the budget
     spent (the scan's rows less the rows it decided in), and
-    `reset_evals` one per micro-step it took: a decide step in every
-    row it was not frozen in, and each body of its drains."""
+    `reset_evals` a fact of the batch, the same in every lane: the rows
+    in which the re-seed after the drain ran, which are the rows in
+    which an unfrozen lane flagged a reset (PR 31; until then one per
+    micro-step the lane took)."""
     import jax
     import jax.numpy as jnp
 
@@ -742,15 +744,129 @@ def test_streaming_counters_against_a_hand_count(budget):
         assert flagged.min() >= 2, "no episode ended in the scan"
     assert np.asarray(tm.rows_frozen).tolist() == frozen.tolist()
     assert (frozen > 0).all() == (budget < 1e9)
-    want = decided + np.asarray(tm.drain_iters)
-    assert np.asarray(tm.reset_evals).tolist() == want.tolist()
+    # a lane's resets are stored at the slot of the decision whose span
+    # ended the episode: its k-th row, since a live lane decides in
+    # every row. Frozen lanes store nothing, so this is the unfrozen
+    # lanes' flags by row
+    by_row = np.zeros((steps,), bool)
+    for lane in np.asarray(ro.resets):
+        by_row |= lane
+    want = int(by_row.sum())
+    assert np.asarray(tm.reset_evals).tolist() == [want, want]
+    assert want <= flagged.sum() < np.asarray(tm.drain_iters).min()
+    assert (want > 0) == (flagged.sum() > 0)
     assert np.asarray(tm.decide_steps).tolist() == decided.tolist()
     s = summarize(tm)
     assert s["reseeds_total"] == int(flagged.sum())
-    assert s["reset_evals_total"] == int(want.sum())
-    assert s["reset_evals_total"] == s["micro_steps"]
+    assert s["reset_evals_total"] == 2 * want
     assert s["row"]["lane_rows_frozen"] == int(frozen.sum())
     assert s["row"]["lane_rows"] == 2 * steps
+
+
+def test_reseed_after_the_drain_equals_the_reset_in_every_micro_step():
+    """The oracle of PR 31's deferral. Three lanes of the streaming
+    fixture, a decision row at a time, two ways. The micro-step way,
+    which stays for the loops whose unit is the micro-step: the decide
+    step, then `drain_micro_step(auto_reset=True, masked=True)` with the
+    collector's reset programs until every lane is ready to decide, the
+    tail re-seeding in the body. And the row as the streaming collector
+    runs it: `drain_to_decision(auto_reset=True)`, whose loop never
+    re-seeds and which re-seeds once, after it, under one predicate for
+    the batch. The `LoopState` and the row's `(reward, dt, reset)` are
+    leaf-equal after every row, a lane frozen as the collector freezes
+    it included, over at least two episode ends a lane."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparksched_tpu.env import core
+    from sparksched_tpu.env.flat_loop import (
+        M_DECIDE,
+        decide_micro_step,
+        drain_micro_step,
+        drain_to_decision,
+        init_loop_state,
+    )
+    from sparksched_tpu.env.observe import observe
+    from sparksched_tpu.trainers.rollout import _group_reset_fns
+
+    params, bank, bpol, _, bases, _ = _stream_fixture()
+    lanes, rows, freeze_from = 3, 150, 110
+    bases = jnp.stack([bases[0]] * lanes)
+    salts = jnp.asarray([1000, 1001, 1002], jnp.int32)
+    counts = jnp.asarray([1, 1, 5], jnp.int32)
+    seq0 = jax.random.fold_in(bases[0], 0)
+    ls = jax.vmap(init_loop_state)(jax.vmap(
+        lambda salt: core.reset_pair(
+            params, bank, seq0, jax.random.fold_in(seq0, salt))
+    )(salts))
+    reset_fns = _group_reset_fns(params, bank, bases, counts, salts)
+    lane_idx = jnp.arange(lanes)
+
+    @jax.jit
+    def decide(ls, over):
+        obs = jax.vmap(lambda e: observe(params, e))(ls.env)
+        si, ne, _ = bpol(None, obs)
+        ls2, rec = jax.vmap(
+            lambda l, s_, n_: decide_micro_step(
+                params, bank, l, s_, n_, True, t_ref=l.env.wall_time)
+        )(ls, si, ne)
+        # the collector's mask: a frozen lane sits the drain out
+        return ls2.replace(mode=jnp.where(over, M_DECIDE, ls2.mode)), rec
+
+    @jax.jit
+    def drain_step(ls, keys, t_ref):
+        return jax.vmap(
+            lambda l, k, i, t: drain_micro_step(
+                params, bank, l, k, True, reset_fn=reset_fns(i), t_ref=t,
+                masked=True)
+        )(ls, keys, lane_idx, t_ref)
+
+    @jax.jit
+    def drain_row(ls, keys, t_ref):
+        return jax.vmap(
+            lambda l, k, i, t: drain_to_decision(
+                params, bank, l, k, True, reset_fn=reset_fns(i), t_ref=t,
+                lane_axis="lanes"),
+            axis_name="lanes",
+        )(ls, keys, lane_idx, t_ref)
+
+    def freeze(over, old, new):
+        return jax.tree_util.tree_map(
+            lambda a, b: jnp.where(
+                over.reshape(over.shape + (1,) * (a.ndim - 1)), a, b),
+            old, new)
+
+    ends = np.zeros((lanes,), int)
+    bodies = 0
+    for row in range(rows):
+        over = jnp.asarray([False, False, row >= freeze_from])
+        keys = jax.random.split(jax.random.PRNGKey(row), lanes)
+        t_ref = ls.env.wall_time
+        ls2, (decided, rw1, dt1, rs1) = decide(ls, over)
+        assert not np.asarray(rs1).any()  # a decide step ends no episode
+        assert np.asarray(decided).all()
+
+        new_ls, (rw, dt, rs) = drain_row(ls2, keys, t_ref)
+        new = (freeze(over, ls, new_ls), rw1 + rw, dt1 + dt, rs1 | rs)
+
+        old_ls = ls2
+        rw, dt = jnp.zeros((lanes,)), jnp.zeros((lanes,))
+        rs = jnp.zeros((lanes,), bool)
+        while (np.asarray(old_ls.mode) != M_DECIDE).any():
+            old_ls, (r, d, re) = drain_step(old_ls, keys, t_ref)
+            rw, dt, rs = rw + r, dt + d, rs | re
+            bodies += 1
+            assert bodies < 40 * rows, "a lane is stuck"
+        old = (freeze(over, ls, old_ls), rw1 + rw, dt1 + dt, rs1 | rs)
+
+        _assert_leaf_equal(old, new)
+        ls = new[0]
+        assert (np.asarray(ls.mode) == M_DECIDE).all()
+        ends += np.asarray(new[3] & ~over)
+    assert ends.min() >= 2, ends
+    assert bodies > 3 * rows
+    # the frozen lane's state stood still from the row it froze in
+    assert np.asarray(ls.episodes).tolist() == ends.tolist()
 
 
 def test_sync_rollout_is_leaf_equal_under_telemetry_and_counts_no_stream():
