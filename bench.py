@@ -14,8 +14,7 @@ Engine: the flat micro-step loop (env/flat_loop.py) — every lane advances
 by one unit of work (decide / fulfill / event) per iteration, so no lane
 pays the batch-max event count of the per-decision `core.step` while_loop
 (the ~6x straggler tax measured in flat_loop.py's docstring). Two further
-measured optimizations (scripts_tail_probe.py / scripts_burst_sweep.py on
-the v5e, 2026-07-30):
+measured optimizations (probes on the v5e, 2026-07-30):
 
 - bulk relaunch (`core._bulk_relaunch`): one EVENT micro-step consumes a
   whole run of task-relaunch events — the dominant event kind — instead
@@ -26,11 +25,6 @@ the v5e, 2026-07-30):
   auto_reset=False (done lanes freeze, episodes last thousands of
   micro-steps so the idle tail is <~2%) and done lanes are re-seeded
   between timed chunks by `reset_done_lanes`.
-
-`BURST - 1` event-only sub-steps per group are still supported but
-default to off: with bulk relaunches the event/decide imbalance the burst
-amortized is mostly gone, and the sweep showed lanes stalled in
-non-EVENT modes during bursts cost more than the amortization saved.
 """
 
 from __future__ import annotations
@@ -90,8 +84,6 @@ MESH_DP = _parse_mesh_dp()
 # records which was used in the row.
 _SB_ENV = os.environ.get("BENCH_SUB_BATCH")
 SUB_BATCH = min(int(_SB_ENV) if _SB_ENV is not None else 512, NUM_ENVS)
-# keep each timed program short and accumulate across calls
-BURST = int(os.environ.get("BENCH_BURST", 1))  # event sub-steps per group
 # cascade length of the bulk-relaunch scan (core._bulk_relaunch); unset
 # -> self-calibrate between the cascade (8) and the single-event path
 # (0) with one short chunk each before the timed run, since the
@@ -119,12 +111,10 @@ BULK_FUSED = os.environ.get("BENCH_BULK_FUSED", "1") == "1"
 # every row stamps config.dtype with the bank's actual dur dtype so
 # the A/B is recorded, never inferred
 BANK_DTYPE = os.environ.get("BENCH_BANK_DTYPE") or None
-MICRO_CHUNK = 256  # micro-steps per timed scan (BURST per scan group)
+# keep each timed program short and accumulate across calls
+MICRO_CHUNK = 256  # micro-steps per timed scan
 assert NUM_ENVS % SUB_BATCH == 0, (
     f"BENCH_SUB_BATCH={SUB_BATCH} must divide {NUM_ENVS}"
-)
-assert 1 <= BURST <= MICRO_CHUNK and MICRO_CHUNK % BURST == 0, (
-    f"BENCH_BURST={BURST} must be a divisor of {MICRO_CHUNK}"
 )
 # timed chunks; BENCH_NUM_CHUNKS raises it for small-lane A/Bs whose
 # default window is seconds long (machine noise swamps a short window
@@ -180,8 +170,8 @@ def _fit_lane_callable(params, bank, bulk_events, fulfill_bulk,
 
     def lane(ls, rng):
         return run_flat(
-            params, bank, pol, rng, MICRO_CHUNK // BURST,
-            auto_reset=False, compute_levels=False, event_burst=BURST,
+            params, bank, pol, rng, MICRO_CHUNK,
+            auto_reset=False, compute_levels=False,
             event_bulk=bulk_events > 0,
             bulk_events=max(bulk_events, 1),
             fulfill_bulk=fulfill_bulk, bulk_cycles=bulk_cycles,
@@ -273,8 +263,8 @@ def bench_chunk(params: EnvParams, bank, loop_states, rngs, bulk_events,
 
     def lane(ls, rng, tm=None):
         return run_flat(
-            params, bank, pol, rng, MICRO_CHUNK // BURST,
-            auto_reset=False, compute_levels=False, event_burst=BURST,
+            params, bank, pol, rng, MICRO_CHUNK,
+            auto_reset=False, compute_levels=False,
             event_bulk=bulk_events > 0,
             bulk_events=max(bulk_events, 1),
             fulfill_bulk=fulfill_bulk, bulk_cycles=bulk_cycles,
@@ -581,7 +571,6 @@ def main() -> None:
             # None: pinned by env var / CPU / lane count not applicable;
             # "ok"/"failed: ...": the 1024-lane single-pass retry outcome
             "sub_batch_retry_1024": sub_batch_retry,
-            "burst": BURST,
             "bulk_events": int(bulk_events),
             "fulfill_bulk": bool(fulfill_bulk),
             "bulk_cycles": int(bulk_cycles),

@@ -379,9 +379,8 @@ def parity_phase(trainer) -> None:
     from sparksched_tpu.env import core
     from sparksched_tpu.schedulers.heuristics import round_robin_policy
     from sparksched_tpu.trainers.rollout import (
-        collect_flat_sync,
+        collect_flat_sync_batch,
         collect_sync,
-        flat_micro_group_budget,
     )
 
     params, bank = trainer.params_env, trainer.bank
@@ -397,6 +396,10 @@ def parity_phase(trainer) -> None:
         si, ne = round_robin_policy(obs, params.num_executors, True)
         return si, ne, {}
 
+    def fair_batch(rng, obs):
+        si, ne, _ = jax.vmap(lambda o: fair(rng, o))(obs)
+        return si, ne, {}
+
     with phase("parity") as out:
         sampler = core.sample_task_duration
         core.sample_task_duration = det_sampler
@@ -406,11 +409,14 @@ def parity_phase(trainer) -> None:
                 params, bank, fair, k, T, s))(
                     state0, jax.random.PRNGKey(0))
             # another collector key on purpose: nothing compared may
-            # depend on it
-            ro_flat = jax.jit(lambda s, k: collect_flat_sync(
-                params, bank, fair, k, T, s,
-                micro_groups=flat_micro_group_budget(T, 4.0, 1),
-                **trainer.flat_knobs))(state0, jax.random.PRNGKey(1))
+            # depend on it. The trainer's collector over a batch of one
+            # lane, unstacked again for the comparison
+            ro_flat = jax.jit(lambda s, k: collect_flat_sync_batch(
+                params, bank, fair_batch, k, T, s,
+                **trainer.flat_knobs))(
+                    jax.tree_util.tree_map(lambda a: a[None], state0),
+                    jax.random.PRNGKey(1))
+            ro_flat = jax.tree_util.tree_map(lambda a: a[0], ro_flat)
             ro_core, ro_flat = jax.device_get((ro_core, ro_flat))
         finally:
             core.sample_task_duration = sampler

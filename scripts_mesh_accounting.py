@@ -2,10 +2,9 @@
 
 One real chip is available, so wall-clock scaling cannot be measured;
 what CAN be measured without hardware is how the compiled SPMD programs
-partition work. For each engine (`core` = per-decision scan, `flat` =
-the single-eval micro-step collector — the production path ISSUE 6
-ships sharded) and dp in {1, 2, 4, 8} this script compiles the PPO
-collect and update at fixed GLOBAL batch (lanes sharded over the mesh,
+partition work. For dp in {1, 2, 4, 8} this script compiles the PPO
+collect (the trainer's collector, which ISSUE 6 ships sharded) and
+update at fixed GLOBAL batch (lanes sharded over the mesh,
 params replicated — parallel.py) and records, per program:
 
 - the per-device shard shape of the rollout buffer's largest field
@@ -20,7 +19,7 @@ params replicated — parallel.py) and records, per program:
   collective.
 
 Writes the table to stdout and appends a dated section to PERF_ROUNDS.md when
-run with --record (`--engine core|flat` restricts the sweep). CPU-only;
+run with --record. CPU-only;
 never touches the chip (force_virtual_cpu_devices before any jax call).
 """
 
@@ -59,15 +58,10 @@ TRAIN = {
     "max_grad_norm": 0.5, "rollout_steps": 48,
 }
 
-def sweep(engine: str) -> list[dict]:
+def sweep() -> list[dict]:
     rows = []
     for dp in (1, 2, 4, 8):
-        mesh = make_mesh(dp)
-        train = TRAIN | {
-            "rollout_engine": engine,
-            "artifacts_dir": f"/tmp/mesh_acct_{engine}",
-        }
-        t = PPO(AGENT, ENV, train, mesh=mesh)
+        t = PPO(AGENT, ENV, TRAIN, mesh=make_mesh(dp))
         state = t.init_state()
 
         lowered_c = t._collect_jit.lower(
@@ -85,9 +79,6 @@ def sweep(engine: str) -> list[dict]:
         comp_u = lowered_u.compile()
 
         rows.append({
-            "engine": engine
-            + ("+single_eval" if engine == "flat"
-               and t.flat_single_eval else ""),
             "dp": dp,
             "global_lanes": t.num_envs,
             "lane_shard": shard_shape[0],
@@ -101,54 +92,33 @@ def sweep(engine: str) -> list[dict]:
 
 
 def main() -> None:
-    engines = ("core", "flat")
-    for i, a in enumerate(sys.argv):
-        if a == "--engine":
-            if i + 1 >= len(sys.argv):
-                sys.exit("--engine needs a value: core, flat, or "
-                         "core,flat")
-            engines = tuple(sys.argv[i + 1].split(","))
-            bad = set(engines) - {"core", "flat"}
-            if bad:
-                # an unknown string would silently run the core engine
-                # under the typo'd label and append it to PERF_ROUNDS.md as a
-                # distinct measured engine
-                sys.exit(f"unknown --engine value(s) {sorted(bad)}; "
-                         "valid: core, flat")
-    rows = [r for e in engines for r in sweep(e)]
-
-    base = {
-        r["engine"]: (r["collect_gflops"], r["update_gflops"])
-        for r in rows if r["dp"] == 1
-    }
+    rows = sweep()
+    base_c, base_u = rows[0]["collect_gflops"], rows[0]["update_gflops"]
     lines = [
         "",
         "## Mesh scaling accounting (virtual CPU mesh, "
         "scripts_mesh_accounting.py)",
         "",
         "Fixed global batch (16 lanes x 48 steps, 8-job envs), lanes "
-        "sharded over a 1-D dp mesh, params replicated, for BOTH "
-        "rollout engines — `core` (per-decision scan) and "
-        "`flat+single_eval` (the single-eval micro-step collector, the "
-        "production path ISSUE 6 ships sharded). XLA `cost_analysis` "
+        "sharded over a 1-D dp mesh, params replicated, the trainer's "
+        "collector and update. XLA `cost_analysis` "
         "FLOPs are per-device for SPMD programs; the table shows "
         "per-device work dropping ~1/dp while the update pays only the "
         "reduction-family collectives (gradient psum + advantage "
         "normalization; the shard-aligned fold_in minibatch keys keep "
         "resharding families out — tests/test_parallel.py pins this).",
         "",
-        "| engine | dp | lanes/device | obs shard [B,T,J,S] | collect "
+        "| dp | lanes/device | obs shard [B,T,F] | collect "
         "GFLOP/dev (x of dp=1) | update GFLOP/dev (x of dp=1) | update "
         "collectives |",
-        "|---|---|---|---|---|---|---|",
+        "|---|---|---|---|---|---|",
     ]
     for r in rows:
         colls = ", ".join(
             f"{k}:{v}" for k, v in sorted(r["update_collectives"].items())
         ) or "none"
-        base_c, base_u = base[r["engine"]]
         lines.append(
-            f"| {r['engine']} | {r['dp']} | {r['lane_shard']} "
+            f"| {r['dp']} | {r['lane_shard']} "
             f"| {r['obs_shard_shape']} "
             f"| {r['collect_gflops']:.2f} "
             f"({r['collect_gflops'] / base_c:.2f}x) "

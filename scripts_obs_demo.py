@@ -17,17 +17,14 @@ writes `artifacts/runlog/obs_demo.jsonl`:
    and reports the overhead (acceptance bar: < 5%), then A/B-times the
    per-chunk device-memory sampling (the `mem_peak_bytes` stamp the
    trainer and bench rows carry — ISSUE 5) against the same bar;
-5. A/B-times the SERVING instrumentation (ISSUE 11): warm micro-batch
-   flush windows through a tiny AOT session store with the metrics
-   registry + per-request span tracing + runlog `trace` records on vs
-   the bare round-13 front, same interleaved-median protocol, same
-   <5% bar (OBS_DEMO_SERVE=0 skips the store compile);
-6. A/B-times the FLEET plane (ISSUE 17): the same instrumented flush
-   windows with a `FleetCollector` + burn-rate `SLOMonitor` scraping
-   on EVERY window (`period_s=0` — the worst case; production scrapes
-   once per second) vs no collector, isolating the collector/SLO cost
-   from the serve instrumentation cost measured in 5, same bar;
-7. A/B-times the TAIL-ATTRIBUTION plane (ISSUE 20): the same traced
+5. A/B-times the FLEET plane (ISSUE 17): warm instrumented micro-batch
+   flush windows through an AOT session store with a `FleetCollector`
+   + burn-rate `SLOMonitor` scraping on EVERY window (`period_s=0` —
+   the worst case; production scrapes once per second) vs no
+   collector, isolating the collector/SLO cost from the serve
+   instrumentation cost, same interleaved-median protocol, same bar
+   (OBS_DEMO_SERVE=0 skips the store compile);
+6. A/B-times the TAIL-ATTRIBUTION plane (ISSUE 20): the same traced
    flush windows with a `CritPathAnalyzer` consuming every ticket and
    a `HostProfiler` sampling in the background vs traced-but-bare,
    isolating the attribution cost from the tracing cost, same bar.
@@ -271,47 +268,49 @@ def overhead_section(log: RunLog) -> float:
     return max(pct, mem_pct)
 
 
-def serve_overhead_section(log: RunLog) -> tuple[float, object]:
-    """ISSUE 11: the serving-path instrumentation A/B — ONE harness,
-    shared with the `serve_scale` artifact's recorded number
-    (`bench_decima._serve_obs_overhead`: uninstrumented vs fully
-    instrumented full-batch flush windows, `obs.metrics.interleaved_ab`
-    medians); returns overhead %. Runs at the PRODUCTION serve config
-    (the shipped Decima agent, width-8 batch program): the
-    instrumentation cost is a fixed ~100s of microseconds of host work
-    per request, so a toy-sized flush window would inflate the
-    percentage against a denominator no deployment has — the bar is
-    about the serve path users run. The AOT compile this costs is one
-    persistent-cache hit (~12 s warm)."""
-    from bench_decima import _serve_obs_overhead, _serve_setup
+def serve_store():
+    """The warm AOT session store the two serving sections share, at the
+    PRODUCTION serve config (the shipped Decima agent, width-8 batch
+    program): the instrumentation cost is a fixed ~100s of microseconds
+    of host work per request, so a toy-sized flush window would inflate
+    the percentage against a denominator no deployment has. The AOT
+    compile this costs is one persistent-cache hit (~12 s warm)."""
+    from sparksched_tpu.schedulers import DecimaScheduler
     from sparksched_tpu.serve import SessionStore
+    from sparksched_tpu.workload import make_workload_bank
 
-    params, bank, sched = _serve_setup()
-    store = SessionStore(
+    params = EnvParams(
+        num_executors=10, max_jobs=50, max_stages=20, max_levels=20,
+        moving_delay=2000.0, warmup_delay=1000.0, job_arrival_rate=4e-5,
+        mean_time_limit=None,
+    )
+    bank = make_workload_bank(params.num_executors, params.max_stages)
+    params = params.replace(
+        max_stages=bank.max_stages, max_levels=bank.max_stages
+    )
+    sched = DecimaScheduler(
+        num_executors=params.num_executors, embed_dim=16,
+        gnn_mlp_kwargs={"hid_dims": [32, 16], "act_cls": "LeakyReLU",
+                        "act_kwargs": {"negative_slope": 0.2}},
+        policy_mlp_kwargs={"hid_dims": [64, 64], "act_cls": "Tanh"},
+        job_bucket=16,
+    )
+    return SessionStore(
         params, bank, sched, capacity=16, max_batch=8, seed=0
     )
-    ab = _serve_obs_overhead(store, reps=40)
-    pct = ab["overhead_pct"]
-    emit(f"serve flush window ({store.max_batch}-wide, warm AOT "
-         f"store): instrumentation off {ab['off_ms']:.2f} ms, on "
-         f"{ab['on_ms']:.2f} ms -> overhead {pct:+.2f}% "
-         f"({'PASS' if ab['passed'] else 'FAIL'}, bar: <5%)")
-    log.write("serve_overhead", off_ms=ab["off_ms"], on_ms=ab["on_ms"],
-              overhead_pct=pct, passed=ab["passed"])
-    return pct, store
 
 
 def fleet_overhead_section(log: RunLog, store) -> float:
     """ISSUE 17: the fleet-plane A/B. Both arms run the SAME fully
     instrumented flush windows (metrics registry on the store, so the
-    serve instrumentation cost — already measured above — cancels);
+    serve instrumentation cost cancels);
     the `on` arm additionally scrapes a `FleetCollector` with a
     burn-rate `SLOMonitor` after EVERY window (`period_s=0`). That is
     the worst case by construction: the production server pump scrapes
     once per `collect_period_s` (default 1 s), i.e. once per ~100
     windows at the width-8 store's throughput, so a <5% per-window
-    verdict here bounds the deployed cost at ~0.05%. Reuses the warm
-    AOT store from the serve section (no second compile)."""
+    verdict here bounds the deployed cost at ~0.05%. Shares the warm
+    AOT store (`serve_store`) with the attribution section."""
     import os
     import tempfile
 
@@ -394,8 +393,8 @@ def fleet_overhead_section(log: RunLog, store) -> float:
 
 def attribution_overhead_section(log: RunLog, store) -> float:
     """ISSUE 20: the tail-attribution A/B. Both arms run fully TRACED
-    flush windows (per-request span stamps on, so the tracing cost —
-    already measured by the serve section — cancels); the `on` arm
+    flush windows (per-request span stamps on, so the tracing cost
+    cancels); the `on` arm
     additionally feeds every finished ticket through a
     `CritPathAnalyzer` (critical-path decomposition + windowed segment
     histograms + slowest-N exemplar reservoir) while a `HostProfiler`
@@ -485,8 +484,8 @@ def main() -> int:
     ok = parity_section(log)
     pct = overhead_section(log)
     if os.environ.get("OBS_DEMO_SERVE", "1") == "1":
-        serve_pct, store = serve_overhead_section(log)
-        pct = max(pct, serve_pct, fleet_overhead_section(log, store),
+        store = serve_store()
+        pct = max(pct, fleet_overhead_section(log, store),
                   attribution_overhead_section(log, store))
     log.close(parity_ok=ok, overhead_pct=round(pct, 2))
     emit(f"runlog written: {log.path}")

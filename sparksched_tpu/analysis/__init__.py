@@ -189,7 +189,7 @@ _STAMP_CACHE: list = []
 
 def analysis_clean_stamp() -> bool | None:
     """The bench-row `analysis_clean` value, memoized per process
-    (bench_decima emits several rows per run; the tree cannot change
+    (a bench may emit several rows per run; the tree cannot change
     between them). `BENCH_ANALYSIS=0` skips the run and stamps null —
     an explicit opt-out, distinct from False which means the analyzer
     found violations, crashed, or timed out."""

@@ -24,9 +24,8 @@ Rules reported by `check_all` (all under pass "contracts"):
 - ``telemetry-schema``: every `Telemetry` counter is an i32 scalar
   (vmapped engines prepend lane axes; the schema checks the trailing
   shape).
-- ``trajectory-schema``: the flat engine's `MicroRec` action/reward
-  leaves and the collectors' `StoredObs` record match their declared
-  dtypes/shapes — an f64 smuggled into the rollout buffer doubles its
+- ``trajectory-schema``: the collectors' `StoredObs` record matches
+  its declared dtypes/shapes — an f64 smuggled into the rollout buffer doubles its
   footprint and poisons the update's compile key.
 - ``step-invariance``: `core.step` and flat `micro_step` return an
   `EnvState` with the *identical* spec as their input (via eval_shape;
@@ -40,7 +39,7 @@ from typing import Any
 from . import Violation
 
 SCHEMA_NAMES = (
-    "EnvState", "Telemetry", "MicroRec", "StoredObs",
+    "EnvState", "Telemetry", "StoredObs",
 )
 
 # --- schemas (declarative data) -------------------------------------------
@@ -103,19 +102,6 @@ ENV_STATE_SCHEMA: dict[str, tuple[str, tuple]] = {
 
 # every engine counter is an i32 scalar per lane (telemetry.py)
 TELEMETRY_SCHEMA_DTYPE = "int32"
-
-# MicroRec's non-obs leaves (obs is checked against the Observation the
-# engine builds — its shapes follow EnvParams and need no extra pins)
-MICRO_REC_SCHEMA: dict[str, tuple[str, tuple]] = {
-    "stage_idx": ("int32", ()),
-    "job_idx": ("int32", ()),
-    "num_exec_k": ("int32", ()),
-    "lgprob": ("float32", ()),
-    "decide": ("bool", ()),
-    "reward": ("float32", ()),
-    "dt": ("float32", ()),
-    "reset": ("bool", ()),
-}
 
 STORED_OBS_SCHEMA: dict[str, tuple[str, tuple]] = {
     "remaining": ("int32", ("F",)),
@@ -345,7 +331,7 @@ def check_all() -> list[Violation]:
         spec_of(tm0), spec_of(out_tm), "core.step(Telemetry)"
     ))
 
-    # step-invariance + trajectory-schema: flat micro_step
+    # step-invariance: flat micro_step
     def pol(rng, obs):
         from ..schedulers.heuristics import round_robin_policy
 
@@ -354,28 +340,15 @@ def check_all() -> list[Violation]:
 
     def run_micro(ls, r):
         return micro_step(
-            params, bank, pol, ls, r, True, True, True, 8, True, 1,
-            record=True,
+            params, bank, pol, ls, r, True, True, True, 8, True, 1
         )
 
     ls0 = jax.eval_shape(init_loop_state, state_sds)
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
-    ls1, rec = jax.eval_shape(run_micro, ls0, key)
+    ls1 = jax.eval_shape(run_micro, ls0, key)
     found.extend(diff_spec(
         spec_of(ls0), spec_of(ls1), "micro_step(LoopState)"
     ))
-    # every MicroRec field except obs goes through check_fields, so a
-    # leaf added without a schema update (the f64-into-the-rollout-
-    # buffer hazard) is reported as unknown, and a renamed/removed
-    # field is reported as missing rather than crashing the pass
-    rec_no_obs = {
-        k: getattr(rec, k)
-        for k in rec.__dataclass_fields__ if k != "obs"
-    }
-    found.extend(check_fields(
-        rec_no_obs, MICRO_REC_SCHEMA, dims, "MicroRec"
-    ))
-
     # trajectory-schema: the collectors' stored-observation record
     from ..env.observe import observe
     from ..trainers.rollout import store_obs

@@ -101,16 +101,13 @@ COVERAGE: dict[tuple[str, str], tuple[str, Any]] = {
     ("trainers/rollout.py", "collect_flat_async_batch"): ("program", (
         "flat_collect_batch",
     )),
-    # legacy/single-lane collectors kept for parity tests; the
-    # audited production program is flat_collect_batch
+    # the reference collectors over core.step: what the tests,
+    # chip_smoke.py and scripts_eval_decima.py hold the production
+    # collectors to; the trainer does not reach them
     ("trainers/rollout.py", "collect_sync"): ("waiver",
-        "legacy per-lane collector, parity-test path"),
+        "reference collector over core.step, parity-test path"),
     ("trainers/rollout.py", "collect_async"): ("waiver",
-        "legacy per-lane collector, parity-test path"),
-    ("trainers/rollout.py", "collect_flat_sync"): ("waiver",
-        "single-lane flat collector, parity-test path"),
-    ("trainers/rollout.py", "collect_flat_async"): ("waiver",
-        "single-lane flat collector, parity-test path"),
+        "reference collector over core.step, parity-test path"),
     # Trainer.__init__ jits the collect/update pair; the update is
     # audited as ppo_update (+_health), the collect as
     # flat_collect_batch through the rollout entries above

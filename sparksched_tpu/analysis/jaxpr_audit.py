@@ -462,7 +462,7 @@ def _batched(tree, b: int):
 
 
 # per-lane programs of the registry: the ones that run under a lane
-# vmap in production (bench.py / the flat collectors), and therefore
+# vmap in production (bench.py / the trainer's collectors), and therefore
 # the ones the memory pass lane-batches for the bank-broadcast rule
 # and the lane-fit advisor
 LANE_PROGRAMS = (

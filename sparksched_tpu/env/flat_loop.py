@@ -23,13 +23,12 @@ past the limit. Every decision, its time and every reward but a
 truncated episode's last agree; that last span is a prefix of the core
 path's (test_flat_collection_at_the_time_limit_ends_on_the_crossing_event).
 
-Used by bench/eval paths where only final states and decision counts
-matter, and — since round 6 — by the trainers' fast rollout collectors
-(`trainers/rollout.py:collect_flat_sync/_async`): with `record=True` a
-micro-step additionally reports the DECIDE branch's observation/action/
-log-prob plus the micro-step's reward and wall-clock advance, which the
-collectors scatter into fixed-offset per-decision buffers (the DECIDE
-mask keeps non-decision micro-steps out of the PPO batch).
+`run_flat` over `micro_step` serves the bench/eval paths, where only
+final states and decision counts matter. The trainers' collectors
+(`trainers/rollout.py:collect_flat_sync_batch/_async_batch`) evaluate
+the policy once per decision row themselves and drive the engine through
+`decide_micro_step` and `drain_to_decision`, which also report the
+row's reward, wall-clock advance and episode end.
 """
 
 from __future__ import annotations
@@ -99,36 +98,15 @@ def aux_action_fields(aux: dict, stage_idx: jnp.ndarray,
     derivation fallbacks for policies that omit keys (heuristics report
     no job_idx; it derives from the flat padded node index
     stage_idx = job * max_stages + stage). Single source of truth for
-    BOTH collection paths — `trainers/rollout.py` (core.step scan) and
-    `micro_step(record=True)` below — so their recorded actions cannot
-    drift apart."""
+    the reference collectors over `core.step` and the flat-engine
+    collectors (both in `trainers/rollout.py`), so their recorded
+    actions cannot drift apart."""
     lgprob = aux.get("lgprob", jnp.float32(0.0))
     job = aux.get(
         "job_idx", jnp.where(stage_idx >= 0, stage_idx // max_stages, 0)
     )
     k = aux.get("num_exec_k", num_exec - 1)
     return lgprob, job, k
-
-
-class MicroRec(struct.PyTreeNode):
-    """One micro-step's trajectory record (`micro_step(record=True)`).
-
-    `obs` and the action fields are meaningful only where `decide` is set
-    (the micro-step ran the DECIDE branch on a live lane); `reward` is the
-    micro-step's negative job-time contribution (discount-referenced to
-    the caller-carried `t_ref`, see `_compute_jobtime`), `dt` its
-    wall-clock advance (pre-reset), and `reset` whether the episode ended
-    during the micro-step."""
-
-    obs: Any  # Observation at the micro-step's start
-    stage_idx: jnp.ndarray  # i32 []; raw policy output
-    job_idx: jnp.ndarray  # i32 []
-    num_exec_k: jnp.ndarray  # i32 []; 0-based exec choice
-    lgprob: jnp.ndarray  # f32 []
-    decide: jnp.ndarray  # bool []
-    reward: jnp.ndarray  # f32 []
-    dt: jnp.ndarray  # f32 []
-    reset: jnp.ndarray  # bool []
 
 
 def take_slot(store, i):
@@ -214,9 +192,7 @@ def init_loop_state(state: EnvState) -> LoopState:
 
 
 def _pop_event(params: EnvParams, st: EnvState, enabled):
-    """Pop + handle one event (core._resume_simulation body). Shared by
-    the full micro-step's EVENT branch and `event_micro_step` so the two
-    can never drift. Returns
+    """Pop + handle one event (core._resume_simulation body). Returns
     (state, req_kind, rj, rs, event_arg, quirk, popped, kind);
     a no-op (RQ_NONE, popped=False) when `enabled` is False or the
     queue is drained. `popped`/`kind` feed the telemetry counters."""
@@ -480,9 +456,7 @@ def micro_step(
     bulk_events: int = 8,
     fulfill_bulk: bool = False,
     bulk_cycles: int = 1,
-    record: bool = False,
     reset_fn: Callable | None = None,
-    t_ref: jnp.ndarray | None = None,
     telemetry=None,
     bulk_fused: bool = True,
 ) -> LoopState | tuple:
@@ -513,17 +487,8 @@ def micro_step(
     branches), so the flag is calibration-gated in bench.py rather than
     assumed to win.
 
-    With `record` (static), returns `(LoopState, MicroRec)` instead of
-    just the state: the DECIDE branch's observation/policy outputs are
-    hoisted above the mode switch (identical cost under vmap, where a
-    batched switch executes every branch anyway) so the trainers' flat
-    collectors can scatter them into per-decision buffers. `reset_fn`,
-    when given, replaces the auto-reset draw: called as
-    `reset_fn(key, episodes)` with the lane's completed-episode count,
-    which the async collector maps to the group-shared reset ordinal.
-    `t_ref` is the discount reference wall time for the recorded reward
-    (the wall time of the round-finishing decision; only read when
-    `params.beta > 0`).
+    `reset_fn`, when given, replaces the auto-reset draw: called as
+    `reset_fn(key, episodes)` with the lane's completed-episode count.
 
     With `bulk_fused` (the ISSUE-7 default), the bulk phase is the
     single fused `core._bulk_events_fused` kernel — mixed
@@ -534,8 +499,8 @@ def micro_step(
     With `telemetry` (an `obs.Telemetry`, static None check), the
     counters are advanced on live lanes — micro-step composition by
     entry mode, events consumed (`loop_iters`), pops by kind, bulk-pass
-    consumption — and the return gains a trailing telemetry element:
-    `(ls[, rec], telemetry)`. The None path threads nothing."""
+    consumption — and the call returns `(ls, telemetry)`. The None
+    path threads nothing."""
     track = telemetry is not None
     k_pol, k_reset = jax.random.split(rng)
     ls0 = ls  # pre-bulk state: the freeze path must restore exactly this
@@ -548,27 +513,12 @@ def micro_step(
     else:
         nb = _i32(0)
         nb_rel = nb_rdy = nsteps = nb
-    st = ls.env
-    s_cap = params.max_stages
-
-    if record:
-        # bulk passes never touch DECIDE-mode lanes, so the post-bulk env
-        # equals the pre-bulk env wherever the decide branch runs and the
-        # hoisted observation is exactly what the branch would compute
-        r_obs = observe(params, st, compute_levels)
-        r_stage, r_nexec, r_aux = policy_fn(k_pol, r_obs)
-        r_lgprob, r_job, r_k = aux_action_fields(
-            r_aux, r_stage, r_nexec, s_cap
-        )
 
     # ---- DECIDE: one commitment from the policy (core.step's front
     # half; the commit/round logic lives in the shared `_apply_decision`)
     def decide(ls: LoopState):
-        if record:
-            stage_idx, num_exec = r_stage, r_nexec
-        else:
-            obs = observe(params, ls.env, compute_levels)
-            stage_idx, num_exec, _ = policy_fn(k_pol, obs)
+        obs = observe(params, ls.env, compute_levels)
+        stage_idx, num_exec, _ = policy_fn(k_pol, obs)
         ls2 = _apply_decision(params, ls, stage_idx, num_exec, fulfill_bulk)
         return ls2, _i32(RQ_NONE), _i32(-1), _i32(-1), _i32(0), \
             ls2.env.source_job_id(), jnp.bool_(False), _i32(0)
@@ -591,17 +541,14 @@ def micro_step(
         )
         out = _finish_micro_step(
             params, bank, ls0, ls2, rk, rj, rs, e, quirk, k_reset,
-            auto_reset, fulfill_bulk=fulfill_bulk, record=record,
-            reset_fn=reset_fn, t_ref=t_ref, telem=telemetry,
+            auto_reset, fulfill_bulk=fulfill_bulk, reset_fn=reset_fn,
+            telem=telemetry,
         )
     if track:
-        *out, telemetry = out
-        out = out[0] if len(out) == 1 else tuple(out)
-    # frozen lanes (auto_reset=False, episode already over at entry) must
-    # not report a decision — the tail rolls their state/counters back
-    was_done = _lane_done(ls0.env)
-    if track:
-        live = ~was_done
+        out, telemetry = out
+        # frozen lanes (auto_reset=False, episode already over at
+        # entry) count nothing — the tail rolls their state back
+        live = ~_lane_done(ls0.env)
         pop_live = popped & live
         telemetry = _tm_add(
             telemetry,
@@ -619,21 +566,7 @@ def micro_step(
             ev_task_finished=pop_live & (ev_kind == EV_TASK_FINISHED),
             ev_exec_ready=pop_live & (ev_kind == EV_EXECUTOR_READY),
         )
-    if not record:
-        return (out, telemetry) if track else out
-    ls_f, (r_reward, r_dt, r_reset) = out
-    rec = MicroRec(
-        obs=r_obs,
-        stage_idx=r_stage,
-        job_idx=r_job,
-        num_exec_k=r_k,
-        lgprob=r_lgprob,
-        decide=(ls0.mode == M_DECIDE) & ~was_done,
-        reward=r_reward,
-        dt=r_dt,
-        reset=r_reset,
-    )
-    return (ls_f, rec, telemetry) if track else (ls_f, rec)
+    return (out, telemetry) if track else out
 
 
 def _finish_micro_step(
@@ -658,7 +591,10 @@ def _finish_micro_step(
     and readiness, episode end. `ls` is the pre-step state, `ls2` the
     state after the mode branch ran. With `record`, also returns the
     micro-step's `(reward, dt, reset)` triple, measured on the pre-reset
-    state and zeroed for frozen lanes (see `MicroRec`). With `telem`,
+    state and zeroed for frozen lanes: `reward` is the negative job-time
+    contribution (discount-referenced to the caller-carried `t_ref`, see
+    `_compute_jobtime`), `dt` the wall-clock advance, and `reset`
+    whether the episode ended during the micro-step. With `telem`,
     the bulk-fulfillment hit count is added (live lanes only) and the
     telemetry is returned as the trailing element.
 
@@ -736,10 +672,10 @@ def _finish_micro_step(
     st = lax.cond(ready, set_ready, not_ready, st)
     mode = jnp.where(ready, M_DECIDE, ls2.mode).astype(_i32)
 
-    # episode end. The loops whose unit is the micro-step (`micro_step`,
-    # `event_micro_step`, `drain_micro_step`, so `run_flat` and the
-    # multi-eval collectors) re-seed here with auto_reset: the lane goes
-    # on in its NEXT micro-step, so the reset program runs in every one
+    # episode end. The loops whose unit is the micro-step (`micro_step`
+    # and so `run_flat`, and `drain_micro_step` called on its own)
+    # re-seed here with auto_reset: the lane goes on in its NEXT
+    # micro-step, so the reset program runs in every one
     # and the state is selected against it (unconditional, which keeps
     # the workload bank out of lane-dependent conditionals). With
     # auto_reset=False finished lanes freeze instead: tests, evals, the
@@ -800,94 +736,6 @@ def _finish_micro_step(
     return ret[0] if len(ret) == 1 else ret
 
 
-def event_micro_step(
-    params: EnvParams,
-    bank: WorkloadBank,
-    ls: LoopState,
-    rng: jax.Array,
-    auto_reset: bool = True,
-    event_bulk: bool = True,
-    bulk_events: int = 8,
-    bulk_cycles: int = 1,
-    record: bool = False,
-    reset_fn: Callable | None = None,
-    t_ref: jnp.ndarray | None = None,
-    telemetry=None,
-    bulk_fused: bool = True,
-) -> LoopState | tuple:
-    """One EVENT-only micro-step: lanes in M_EVENT mode pop + handle one
-    event (with the full shared tail); other lanes no-op. With `record`,
-    also returns the `(reward, dt, reset)` triple (zeroed for non-event
-    lanes, which are untouched). With `telemetry`, counters advance for
-    live event-mode lanes only and the return gains a trailing
-    telemetry element.
-
-    The point is cost amortization under vmap: a full `micro_step` pays
-    for all three mode branches on every lane (batched `lax.switch`
-    executes every branch), but in steady state >90% of micro-steps are
-    events — the policy/observe/argsort work of the DECIDE branch is
-    wasted 10x over. Interleaving K-1 of these between full micro-steps
-    ("event burst") advances event-heavy lanes at a fraction of the cost;
-    per-lane semantics are unchanged because event processing is exactly
-    the M_EVENT path and non-event lanes are untouched."""
-    track = telemetry is not None
-    is_event = ls.mode == M_EVENT
-    _, k_reset = jax.random.split(rng)
-
-    ls0 = ls.replace(mode=_i32(M_EVENT))  # pre-bulk state for the tail
-    if event_bulk:
-        env_b, nb, nb_rel, nb_rdy, nsteps = _bulk_cycle_chain(
-            params, bank, ls.env, is_event, bulk_events, bulk_cycles,
-            bulk_fused,
-        )
-        ls = ls.replace(env=env_b, bulked=ls.bulked + nb)
-        pop_on = is_event & _fused_pop_gate(env_b, nb)
-    else:
-        nb = _i32(0)
-        nb_rel = nb_rdy = nsteps = nb
-        pop_on = is_event
-    st, rk, rj, rs, arg, quirk, popped, ev_kind = _pop_event(
-        params, ls.env, pop_on
-    )
-    ls_ev = ls.replace(mode=_i32(M_EVENT), env=st)
-    out = _finish_micro_step(
-        params, bank, ls0, ls_ev,
-        rk, rj, rs, arg, quirk, k_reset, auto_reset,
-        record=record, reset_fn=reset_fn, t_ref=t_ref,
-    )
-    if record:
-        out, (rw, dt, rs_) = out
-    if track:
-        was_done = _lane_done(ls0.env)
-        gate = is_event & ~was_done
-        pop_live = popped & gate
-        telemetry = _tm_add(
-            telemetry,
-            event_steps=gate,
-            loop_iters=jnp.where(gate, nb + popped.astype(_i32), 0),
-            bulk_relaunch_events=jnp.where(gate, nb_rel, 0),
-            bulk_ready_events=jnp.where(gate, nb_rdy, 0),
-            bulk_passes=(nb > 0) & gate,
-            bulk_scan_steps=jnp.where(gate, nsteps, 0),
-            ev_job_arrival=pop_live & (ev_kind == EV_JOB_ARRIVAL),
-            ev_task_finished=pop_live & (ev_kind == EV_TASK_FINISHED),
-            ev_exec_ready=pop_live & (ev_kind == EV_EXECUTOR_READY),
-        )
-    # non-event lanes are untouched (their rng/state must not advance)
-    final = jax.tree_util.tree_map(
-        lambda a, b: jnp.where(is_event, a, b), out, ls
-    )
-    if record:
-        zero = jnp.float32(0.0)
-        rec_tail = (
-            jnp.where(is_event, rw, zero),
-            jnp.where(is_event, dt, zero),
-            is_event & rs_,
-        )
-        return (final, rec_tail, telemetry) if track else (final, rec_tail)
-    return (final, telemetry) if track else final
-
-
 def decide_micro_step(
     params: EnvParams,
     bank: WorkloadBank,
@@ -919,8 +767,8 @@ def decide_micro_step(
     with annotate("env/micro_step/decide"):
         is_dec = ls.mode == M_DECIDE
         # force the tail's mode-keyed logic to the DECIDE shape for every
-        # lane (the event_micro_step pattern): non-decide lanes' branch
-        # results are discarded by the final select below
+        # lane: non-decide lanes' branch results are discarded by the
+        # final select below
         ls0 = ls.replace(mode=_i32(M_DECIDE))
         ls2 = _apply_decision(params, ls0, stage_idx, num_exec, fulfill_bulk)
         mode2 = ls2.mode  # pre-tail mode: DECIDE -> non-DECIDE == round done
@@ -1288,7 +1136,6 @@ def run_flat(
     state: EnvState | None = None,
     auto_reset: bool = True,
     compute_levels: bool = True,
-    event_burst: int = 1,
     event_bulk: bool = True,
     bulk_events: int = 8,
     fulfill_bulk: bool = False,
@@ -1297,10 +1144,8 @@ def run_flat(
     telemetry=None,
     bulk_fused: bool = True,
 ) -> LoopState | tuple:
-    """Scan `num_groups` micro-step groups for one lane (vmap over
-    lanes). Each group is one full micro-step plus `event_burst - 1`
-    event-only sub-steps (see `event_micro_step`), i.e.
-    `num_groups * event_burst` micro-steps in total. Pass `loop_state`
+    """Scan `num_groups` micro-steps for one lane (vmap over lanes). Pass
+    `loop_state`
     (instead of a freshly-reset `state`) to continue a previous run —
     bench chunks resume this way. With `telemetry` (an
     `obs.Telemetry`), the counters ride the scan carry and the call
@@ -1320,14 +1165,6 @@ def run_flat(
             bulk_cycles, telemetry=tm, bulk_fused=bulk_fused,
         )
         ls, tm = out if track else (out, None)
-        for _ in range(event_burst - 1):
-            k, sub = jax.random.split(k)
-            out = event_micro_step(
-                params, bank, ls, sub, auto_reset, event_bulk,
-                bulk_events, bulk_cycles, telemetry=tm,
-                bulk_fused=bulk_fused,
-            )
-            ls, tm = out if track else (out, None)
         return ((ls, k, tm) if track else (ls, k)), None
 
     if track:

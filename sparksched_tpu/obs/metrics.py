@@ -434,9 +434,9 @@ def paired_ab_pct(offs: list[float], ons: list[float]) -> float:
 
 # ---------------------------------------------------------------------------
 # shared bench quantile helpers (ISSUE 11 satellite): the latency rows'
-# percentile block — EXACT sample percentiles with the round-13 keys,
-# so refactored callers (bench_decima._latency_block) emit byte-equal
-# r10-schema fields — plus the streaming-histogram companion block.
+# percentile block — EXACT sample percentiles with the round-13 keys
+# (byte-equal r10-schema fields) — plus the streaming-histogram
+# companion block.
 # ---------------------------------------------------------------------------
 
 

@@ -52,8 +52,8 @@ Counter semantics per engine:
   batch, +1 in EVERY lane for such a row, frozen lanes too, so
   `reset_evals_total / reseeds_total` is at most the number of lanes.
   In the loops whose unit is the micro-step
-  (`flat_loop._finish_micro_step` under `micro_step`,
-  `event_micro_step` and `drain_micro_step`) the tail evaluates it in
+  (`flat_loop._finish_micro_step` under `micro_step` and
+  `drain_micro_step`) the tail evaluates it in
   every micro-step the lane takes, whether or not an episode ended,
   and counts one each. `rows_frozen` (the batch collectors, once a
   row) counts the decision rows the lane sat out because its sim-time

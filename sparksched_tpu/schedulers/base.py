@@ -49,3 +49,9 @@ class TrainableScheduler(Scheduler):
     def evaluate_actions(self, params: Any, obsns: Any, actions: Any):
         """Log-probs and entropies of `actions` under `params`, batched over
         the rollout. Pure; differentiable wrt `params`."""
+
+    @abc.abstractmethod
+    def batch_policy(self, rng: jax.Array, obs: Any, params: Any = None):
+        """`policy` over a [B]-stacked Observation from ONE evaluation:
+        `(stage_idx[B], num_exec[B], aux)`. The trainer's collectors
+        call it once per decision row (trainers/rollout.py)."""

@@ -7,8 +7,8 @@ issued the next request only after the previous reply, so the measured
 decision looks identical at any demand. Production traffic is OPEN
 loop: arrivals come from the world on their own clock, and when
 offered load exceeds capacity the queue (and the tail) grows without
-bound. The goodput@SLO bench (`bench_decima.bench_serve_scale`) needs
-that behavior on purpose, so this generator:
+bound. A goodput@SLO measurement needs that behavior on purpose, so
+this generator:
 
 - precomputes a SEEDED, deterministic arrival schedule — a list of
   (arrival_time_s, tenant) pairs — from one of two processes:

@@ -12,8 +12,8 @@ from .rollout import (  # noqa: F401
     Rollout,
     StoredObs,
     collect_async,
-    collect_flat_async,
-    collect_flat_sync,
+    collect_flat_async_batch,
+    collect_flat_sync_batch,
     collect_sync,
     store_obs,
     stored_to_observation,

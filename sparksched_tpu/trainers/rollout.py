@@ -14,6 +14,12 @@ Both reference modes exist:
 - async (RolloutWorkerAsync:160-206): fixed sim-time budget per iteration;
   lanes persist across iterations and auto-reset mid-scan, recording reset
   steps.
+
+One collector per mode is what the trainer runs, both over the flat
+engine (env/flat_loop.py): `collect_flat_sync_batch` and
+`collect_flat_async_batch`. `collect_sync` and `collect_async`, over
+`core.step`, are the reference collectors the tests, `chip_smoke.py` and
+`scripts_eval_decima.py` hold them to; the trainer does not reach them.
 """
 
 from __future__ import annotations
@@ -34,9 +40,7 @@ from ..env.flat_loop import (
     aux_action_fields,
     decide_micro_step,
     drain_to_decision,
-    event_micro_step,
     init_loop_state,
-    micro_step,
 )
 from ..env.health import reward_health, state_health
 from ..env.observe import Observation, observe
@@ -201,7 +205,10 @@ def collect_sync(
     telemetry=None,
     health: bool = False,
 ) -> Rollout | tuple:
-    """One episode (from the given freshly-reset state), padded to
+    """The reference collector over `core.step`, for one lane (vmap
+    over lanes); `collect_flat_sync_batch` is what the trainer runs
+    and is held to this one step by step (tests/test_flat_loop.py).
+    One episode (from the given freshly-reset state), padded to
     `num_steps` decisions (reference RolloutWorkerSync.collect_rollout).
     With `telemetry` (an `obs.Telemetry`), engine counters ride the scan
     carry — rolled back on frozen (done) lanes — and the call returns
@@ -292,7 +299,9 @@ def collect_async(
     telemetry=None,
     health: bool = False,
 ) -> Rollout | tuple:
-    """Fixed sim-time budget with persistent envs and auto-reset (reference
+    """The reference streaming collector over `core.step`, for one lane
+    (vmap over lanes); the trainer runs `collect_flat_async_batch`.
+    Fixed sim-time budget with persistent envs and auto-reset (reference
     RolloutWorkerAsync.collect_rollout:171-206). `wall_times` are *elapsed*
     times within the iteration, continuing across resets. Steps after the
     budget is exhausted are masked. With `telemetry`, counters ride the
@@ -407,46 +416,48 @@ def collect_async(
     return (ro, carry[4]) if track else ro
 
 
-def vmap_collect(collect_fn, params, bank, policy_fn, rngs, num_steps,
-                 states, *args):
-    """Collect B rollouts in parallel: `rngs` [B,2] and `states` with a
-    leading [B] axis (the TPU replacement for the reference's B worker
-    processes)."""
-    return jax.vmap(
-        lambda r, s: collect_fn(
-            params, bank, policy_fn, r, num_steps, s, *args
-        )
-    )(rngs, states)
-
-
 # ---------------------------------------------------------------------------
-# flat micro-step collection (env/flat_loop.py engine)
+# flat-engine collection (env/flat_loop.py): the trainer's collectors
 #
 # The per-decision `core.step` scan above pays the straggler tax of a
-# vmapped `lax.while_loop` between decisions (batch-max event count per
-# decision, measured ~6x the mean at 64 lanes). The collectors below drive
-# the flat micro-step engine instead — every lane advances by one unit of
-# work per iteration — and scatter the DECIDE micro-steps' records into
-# the same fixed-shape `Rollout` the trainers already consume, so only
-# decision steps enter the PPO batch. Collected quantities are step-exact
-# vs the `core.step` path (tests/test_flat_loop.py parity test): actions,
-# log-probs, the DECIDE mask, per-decision wall times and rewards (the
-# micro-step reward deltas telescope to `core.step`'s per-decision span
-# quantity — see `core._compute_jobtime`'s `t_ref` note).
+# vmapped `lax.while_loop` that holds observe and the policy too. The
+# collectors below drive the flat micro-step engine instead and scatter
+# one record per decision into the same fixed-shape `Rollout` the
+# trainers consume. The scan is decision-synchronous over the WHOLE lane
+# batch, and ONE policy evaluation is both acted on and recorded per
+# decision row:
+#
+#   scan iteration k == decision k:
+#     observe -> batch_policy (ONE eval over the [B] lane stack, with
+#     the Decima job-compaction cond at batch level) ->
+#     vmap(decide_micro_step) (acts on + records the same outputs) ->
+#     vmap(drain_to_decision) (non-policy micro-steps until every lane
+#     is at its next decision)
+#
+# The drain is a batch-max while-loop between decisions, over the env
+# machinery alone (bulk passes + pops); the GNN runs exactly once per
+# decision (test-pinned by a counting-policy test in
+# tests/test_flat_loop.py). On the TPU v5e that loop, not the GNN, is
+# most of a decision row (PERF.md section 5). Collected quantities are
+# step-exact vs the `core.step` path (tests/test_flat_loop.py parity
+# test): actions, log-probs, the valid mask, per-decision wall times
+# and rewards (the micro-step reward deltas telescope to `core.step`'s
+# per-decision span quantity — see `core._compute_jobtime`'s `t_ref`
+# note).
+#
+# Every operation of the scan body runs under one of the trace scopes
+# `collect/observe`, `decima/features`, `decima/gnn`, `decima/sample`,
+# `env/micro_step` (`decide`, `drain`), `collect/health`,
+# `collect/freeze` and `collect/scatter` (key splits apart;
+# tests/test_obs.py pins it), and with a telemetry carry the body
+# counts its rows (`obs/telemetry.py`: `rows`, `rows_live`,
+# `rows_full_width`, `drain_batch_iters`, and per lane `rows_frozen`).
+# In streaming mode (`auto_reset`) a lane whose episode ended in the
+# row's drain is re-seeded once, after the drain's loop and under one
+# predicate for the batch (`flat_loop._reseed_ended`, scope
+# `env/micro_step/reset`); `reseeds` counts it for the lane and
+# `reset_evals` the rows in which the reset program ran.
 # ---------------------------------------------------------------------------
-
-
-def flat_micro_group_budget(
-    num_steps: int, micro_per_decision: float, event_burst: int
-) -> int:
-    """Scan length (micro-step groups) for the flat collectors:
-    ceil(num_steps * micro_per_decision / event_burst). Shared by the
-    trainer and bench_decima so the two cannot drift on rounding."""
-    import math
-
-    return max(
-        1, math.ceil(num_steps * micro_per_decision / event_burst)
-    )
 
 
 def _zero_stored(params: EnvParams) -> StoredObs:
@@ -472,294 +483,19 @@ def _zero_stored(params: EnvParams) -> StoredObs:
 
 
 class _FlatBuf(struct.PyTreeNode):
-    """Fixed-offset per-decision buffers the micro-step scan scatters
-    into (carried through the scan — per-micro-step stacking would
-    multiply rollout memory by the micro-steps-per-decision factor)."""
+    """Fixed-offset per-decision buffers the collection scan scatters
+    into, carried through the scan: a lane's decision lands in its own
+    slot `ndec`, so a row in which the lane did not decide stores
+    nothing."""
 
-    obs: StoredObs  # [T, ...]
-    stage_idx: jnp.ndarray  # i32[T]
-    job_idx: jnp.ndarray  # i32[T]
-    num_exec_k: jnp.ndarray  # i32[T]
-    lgprob: jnp.ndarray  # f32[T]
-    reward: jnp.ndarray  # f32[T]
-    walls: jnp.ndarray  # f32[T]
-    resets: jnp.ndarray  # i32[T]
-
-
-def _flat_collect(
-    params: EnvParams,
-    bank: WorkloadBank,
-    policy_fn: PolicyFn,
-    rng: jax.Array,
-    num_steps: int,
-    ls: LoopState,
-    micro_groups: int,
-    auto_reset: bool,
-    event_burst: int,
-    event_bulk: bool,
-    bulk_events: int,
-    fulfill_bulk: bool,
-    bulk_cycles: int,
-    reset_fn,
-    rollout_duration,
-    use_elapsed: bool,
-    telemetry=None,
-    bulk_fused: bool = True,
-    health: bool = False,
-):
-    """Shared flat-engine collection scan for one lane (vmap over lanes).
-
-    Scans `micro_groups` micro-step groups (one full micro-step plus
-    `event_burst - 1` event-only sub-steps, the `run_flat` grouping).
-    Each group's DECIDE record lands in per-decision slot `ndec` and its
-    micro-rewards/resets accumulate into the slot of the most recent
-    decision, so decision k's reward is exactly the job-time of the span
-    (decision k, decision k+1]. A lane freezes when its decision buffer
-    is full AND it is about to decide again (so the last slot still
-    receives its full trailing span, matching `collect_sync`'s T-step
-    truncation), or — async — when `rollout_duration` sim-time elapsed.
-    Micro-rewards before a chunk's first decision (async lanes resuming
-    mid-phase) belong to the previous chunk's final decision, which was
-    already consumed; they are dropped together with their `dt`, which
-    keeps the (reward, dt) pairing the returns/average-job estimators
-    rely on consistent.
-
-    With `telemetry`, engine counters ride the scan carry (rolled back
-    on frozen lanes) and the returned tuple gains a trailing
-    Telemetry. With `health` (static; requires telemetry), each live
-    micro-step group ORs the `env/health.py` sentinel mask over the
-    group's post-state + accumulated reward into
-    `telemetry.health_mask` (monotonicity checks are suppressed across
-    in-group auto-resets)."""
-    track = telemetry is not None
-    if health and not track:
-        raise ValueError("health=True requires a telemetry carry")
-    T = num_steps
-    zs = _zero_stored(params)
-    buf0 = _FlatBuf(
-        obs=jax.tree_util.tree_map(
-            lambda a: jnp.zeros((T,) + a.shape, a.dtype), zs
-        ),
-        stage_idx=jnp.zeros(T, _i32),
-        job_idx=jnp.zeros(T, _i32),
-        num_exec_k=jnp.zeros(T, _i32),
-        lgprob=jnp.zeros(T, jnp.float32),
-        reward=jnp.zeros(T, jnp.float32),
-        walls=jnp.zeros(T, jnp.float32),
-        resets=jnp.zeros(T, _i32),
-    )
-
-    def body(carry, _):
-        if track:
-            ls, k, t_ref, elapsed, ndec, buf, tm = carry
-        else:
-            (ls, k, t_ref, elapsed, ndec, buf), tm = carry, None
-        tm_frozen = tm
-        k, sub = jax.random.split(k)
-        env0 = ls.env
-        wall0 = env0.wall_time
-        # pre-step freeze: full decision buffer about to decide again,
-        # or (async) sim-time budget exhausted
-        over = (ls.mode == M_DECIDE) & (ndec >= T)
-        if rollout_duration is not None:
-            over = over | (elapsed >= rollout_duration)
-
-        out = micro_step(
-            params, bank, policy_fn, ls, sub, auto_reset, True,
-            event_bulk, bulk_events, fulfill_bulk, bulk_cycles,
-            record=True, reset_fn=reset_fn, t_ref=t_ref,
-            telemetry=tm, bulk_fused=bulk_fused,
-        )
-        (ls2, rec, tm) = out if track else (out + (None,))
-        # advance the discount reference BEFORE the burst sub-steps: with
-        # fulfill_bulk a round-finishing DECIDE micro-step jumps straight
-        # to M_EVENT, so this group's own sub-steps already advance time
-        # within the NEW decision's span
-        t_ref = jnp.where(rec.decide & ~over, wall0, t_ref)
-        reward, dt, reset = rec.reward, rec.dt, rec.reset
-        for _ in range(event_burst - 1):
-            k, sub = jax.random.split(k)
-            out = event_micro_step(
-                params, bank, ls2, sub, auto_reset, event_bulk,
-                bulk_events, bulk_cycles,
-                record=True, reset_fn=reset_fn, t_ref=t_ref,
-                telemetry=tm, bulk_fused=bulk_fused,
-            )
-            (ls2, (rw, dd, rr), tm) = (
-                out if track else (out + (None,))
-            )
-            reward = reward + rw
-            dt = dt + dd
-            reset = reset | rr
-        if health:
-            hm = state_health(
-                ls2.env, prev=env0, resetting=reset
-            ) | reward_health(reward)
-
-        # frozen lanes: state untouched, nothing recorded
-        ls2 = jax.tree_util.tree_map(
-            lambda a, b: jnp.where(over, a, b), ls, ls2
-        )
-        if track:
-            tm = jax.tree_util.tree_map(
-                lambda a, b: jnp.where(over, a, b), tm_frozen, tm
-            )
-        if health:
-            tm = _tm_orr(tm, health_mask=jnp.where(over, 0, hm))
-        zero = jnp.float32(0.0)
-        reward = jnp.where(over, zero, reward)
-        dt = jnp.where(over, zero, dt)
-        reset = reset & ~over
-        dec = rec.decide & ~over
-
-        # decision-slot scatter (mode="drop" discards non-decide steps
-        # and buffer overflow alike)
-        with annotate("collect/scatter"):
-            slot = jnp.where(dec & (ndec < T), ndec, T)
-            stored = store_obs(rec.obs, env0)
-            buf = buf.replace(
-                obs=jax.tree_util.tree_map(
-                    lambda b, v: b.at[slot].set(v, mode="drop"),
-                    buf.obs, stored,
-                ),
-                stage_idx=buf.stage_idx.at[slot].set(
-                    rec.stage_idx, mode="drop"
-                ),
-                job_idx=buf.job_idx.at[slot].set(
-                    rec.job_idx, mode="drop"
-                ),
-                num_exec_k=buf.num_exec_k.at[slot].set(
-                    rec.num_exec_k, mode="drop"
-                ),
-                lgprob=buf.lgprob.at[slot].set(rec.lgprob, mode="drop"),
-                walls=buf.walls.at[slot].set(
-                    elapsed if use_elapsed else wall0, mode="drop"
-                ),
-            )
-            ndec2 = ndec + dec.astype(_i32)
-            # micro-rewards belong to the most recent decision's span
-            rslot = jnp.where((ndec2 > 0) & (ndec2 <= T), ndec2 - 1, T)
-            buf = buf.replace(
-                reward=buf.reward.at[rslot].add(reward, mode="drop"),
-                resets=buf.resets.at[rslot].max(
-                    reset.astype(_i32), mode="drop"
-                ),
-            )
-        carry = (ls2, k, t_ref, elapsed + dt, ndec2, buf)
-        return (carry + (tm,) if track else carry), None
-
-    carry0 = (
-        ls, rng, ls.env.wall_time, jnp.float32(0.0), _i32(0), buf0
-    )
-    if track:
-        carry0 = carry0 + (telemetry,)
-    carry, _ = lax.scan(body, carry0, None, length=micro_groups)
-    ls, elapsed, ndec, buf = carry[0], carry[3], carry[4], carry[5]
-    if track:
-        telemetry = carry[6]
-
-    valid = jnp.arange(T) < jnp.minimum(ndec, T)
-    final_t = elapsed if use_elapsed else ls.env.wall_time
-    walls = jnp.where(valid, buf.walls, final_t)
-    ro = Rollout(
-        obs=buf.obs,
-        stage_idx=jnp.where(valid, buf.stage_idx, -1),
-        job_idx=buf.job_idx,
-        num_exec_k=buf.num_exec_k,
-        lgprob=buf.lgprob,
-        reward=buf.reward,
-        wall_times=jnp.concatenate([walls, final_t[None]]),
-        valid=valid,
-        resets=buf.resets > 0,
-        final_state=ls.env,
-        final_reset_count=ls.episodes,
-    )
-    return (ro, ls, telemetry) if track else (ro, ls)
-
-
-@partial(
-    jax.jit, static_argnums=(0, 2, 4),
-    static_argnames=(
-        "micro_groups", "event_burst", "event_bulk", "bulk_events",
-        "fulfill_bulk", "bulk_cycles", "bulk_fused", "health",
-    ),
-)
-def collect_flat_sync(
-    params: EnvParams,
-    bank: WorkloadBank,
-    policy_fn: PolicyFn,
-    rng: jax.Array,
-    num_steps: int,
-    state: EnvState,
-    telemetry=None,
-    *,
-    micro_groups: int,
-    event_burst: int = 1,
-    event_bulk: bool = True,
-    bulk_events: int = 8,
-    fulfill_bulk: bool = False,
-    bulk_cycles: int = 1,
-    bulk_fused: bool = True,
-    health: bool = False,
-) -> Rollout | tuple:
-    """Flat-engine equivalent of `collect_sync`: one episode from the
-    given freshly-reset state, micro-stepped with frozen lanes at episode
-    end, padded to `num_steps` decisions. `micro_groups` bounds the scan
-    (size it at ~3-4 micro-step groups per expected decision; a too-small
-    value truncates the episode exactly like a too-small `num_steps`).
-    With `telemetry`, returns `(Rollout, Telemetry)`; `health` (static)
-    additionally ORs the in-JIT sentinel mask into
-    `telemetry.health_mask` per live group."""
-    out = _flat_collect(
-        params, bank, policy_fn, rng, num_steps,
-        init_loop_state(state), micro_groups,
-        auto_reset=False, event_burst=event_burst, event_bulk=event_bulk,
-        bulk_events=bulk_events, fulfill_bulk=fulfill_bulk,
-        bulk_cycles=bulk_cycles, reset_fn=None, rollout_duration=None,
-        use_elapsed=False, telemetry=telemetry, bulk_fused=bulk_fused,
-        health=health,
-    )
-    return (out[0], out[2]) if telemetry is not None else out[0]
-
-
-# ---------------------------------------------------------------------------
-# single-eval flat collection (round 8)
-#
-# The per-lane collectors above run `micro_step(record=True)`, which
-# evaluates observe+policy on EVERY full micro-step group — at the
-# round-6 calibrations that measured ~2 GNN evaluations per recorded
-# decision (the DECIDE group's eval plus the wasted eval of each group
-# that lands on a FULFILL/EVENT lane). The collectors below restructure
-# the scan so ONE policy evaluation is both acted on and recorded per
-# decision row:
-#
-#   scan iteration k == decision k:
-#     observe -> batch_policy (ONE eval over the [B] lane stack, with
-#     the Decima job-compaction cond at batch level) ->
-#     vmap(decide_micro_step) (acts on + records the same outputs) ->
-#     vmap(drain_to_decision) (non-policy micro-steps until every lane
-#     is at its next decision)
-#
-# The drain reintroduces a batch-max while-loop between decisions, over
-# the env machinery alone (bulk passes + pops); the GNN runs exactly
-# once per decision (test-pinned by a counting-policy test in
-# tests/test_flat_loop.py). On the TPU v5e that loop, not the GNN, is
-# most of a decision row (PERF.md section 5). Collected quantities
-# remain step-exact vs the `core.step` path.
-#
-# Every operation of the scan body runs under one of the trace scopes
-# `collect/observe`, `decima/features`, `decima/gnn`, `decima/sample`,
-# `env/micro_step` (`decide`, `drain`), `collect/health`,
-# `collect/freeze` and `collect/scatter` (key splits apart;
-# tests/test_obs.py pins it), and with a telemetry carry the body
-# counts its rows (`obs/telemetry.py`: `rows`, `rows_live`,
-# `rows_full_width`, `drain_batch_iters`, and per lane `rows_frozen`).
-# In streaming mode (`auto_reset`) a lane whose episode ended in the
-# row's drain is re-seeded once, after the drain's loop and under one
-# predicate for the batch (`flat_loop._reseed_ended`, scope
-# `env/micro_step/reset`); `reseeds` counts it for the lane and
-# `reset_evals` the rows in which the reset program ran.
-# ---------------------------------------------------------------------------
+    obs: StoredObs  # [B, T, ...]
+    stage_idx: jnp.ndarray  # i32[B, T]
+    job_idx: jnp.ndarray  # i32[B, T]
+    num_exec_k: jnp.ndarray  # i32[B, T]
+    lgprob: jnp.ndarray  # f32[B, T]
+    reward: jnp.ndarray  # f32[B, T]
+    walls: jnp.ndarray  # f32[B, T]
+    resets: jnp.ndarray  # i32[B, T]
 
 
 # batch policy: policy_fn(rng, obs_with_leading_B_axis) -> per-lane
@@ -1053,10 +789,10 @@ def collect_flat_sync_batch(
     bulk_fused: bool = True,
     health: bool = False,
 ) -> Rollout | tuple:
-    """Single-eval flat equivalent of `vmap(collect_sync)`: one episode
-    per lane from the given freshly-reset [B] states, exactly one policy
-    evaluation per decision row (no `micro_groups` sizing — the scan
-    length IS `num_steps`). With `telemetry` ([B]-leading), returns
+    """The trainer's sync collector, the flat-engine equivalent of
+    `vmap(collect_sync)`: one episode per lane from the given
+    freshly-reset [B] states, exactly one policy evaluation per
+    decision row (the scan length IS `num_steps`). With `telemetry` ([B]-leading), returns
     `(Rollout, Telemetry)`. `lane_shard` (static; a lane-axis
     `NamedSharding`) runs the collection SPMD over a dp mesh — see
     `_flat_collect_single_eval`. `health` (static) ORs the in-JIT
@@ -1124,11 +860,14 @@ def collect_flat_async_batch(
     bulk_fused: bool = True,
     health: bool = False,
 ) -> tuple:
-    """Single-eval flat equivalent of `vmap(collect_flat_async)`:
-    persistent [B] lanes, fixed sim-time budget, group-shared mid-scan
-    reset sequences from `fold_in(seq_bases[i], reset_counts[i] +
-    completed_episodes)`. Budget granularity is the decision row (the
-    same as `collect_async`). Returns `(Rollout, LoopState[,
+    """The trainer's streaming collector, the flat-engine equivalent
+    of `vmap(collect_async)`: persistent [B] lanes, fixed sim-time
+    budget, group-shared mid-scan reset sequences from
+    `fold_in(seq_bases[i], reset_counts[i] + completed_episodes)`.
+    Budget granularity is the decision row (the same as
+    `collect_async`). Takes and returns the full `LoopState` (a
+    budget-frozen lane may be mid-FULFILL/EVENT phase, which `EnvState`
+    alone cannot represent). Returns `(Rollout, LoopState[,
     Telemetry])`. `lane_shard` (static) runs the collection SPMD over
     a dp mesh — see `_flat_collect_single_eval`; the returned
     `LoopState` carry stays lane-sharded, so the next iteration's
@@ -1157,79 +896,6 @@ def collect_flat_async_batch(
     )
     ro, ls = out[0], out[1]
     ro = ro.replace(final_reset_count=reset_counts + ls.episodes)
-    if telemetry is not None:
-        return ro, ls, out[2]
-    return ro, ls
-
-
-@partial(
-    jax.jit, static_argnums=(0, 2, 4),
-    static_argnames=(
-        "micro_groups", "event_burst", "event_bulk", "bulk_events",
-        "fulfill_bulk", "bulk_cycles", "bulk_fused", "health",
-    ),
-)
-def collect_flat_async(
-    params: EnvParams,
-    bank: WorkloadBank,
-    policy_fn: PolicyFn,
-    rng: jax.Array,
-    num_steps: int,
-    loop_state: LoopState,
-    rollout_duration: jnp.ndarray | float = jnp.inf,
-    seq_base: jax.Array | None = None,
-    lane_salt: jnp.ndarray | int = 0,
-    reset_count: jnp.ndarray | int = 0,
-    telemetry=None,
-    *,
-    micro_groups: int,
-    event_burst: int = 1,
-    event_bulk: bool = True,
-    bulk_events: int = 8,
-    fulfill_bulk: bool = False,
-    bulk_cycles: int = 1,
-    bulk_fused: bool = True,
-    health: bool = False,
-) -> tuple:
-    """Flat-engine equivalent of `collect_async`: persistent lanes with a
-    fixed sim-time budget per iteration and mid-scan auto-resets drawn
-    from `fold_in(seq_base, reset_count + completed_episodes)` — the same
-    group-shared job-sequence scheme as `collect_async` (lanes sharing
-    `seq_base` replay identical sequences at equal reset ordinals).
-
-    Takes and returns the full `LoopState` (a budget-frozen lane may be
-    mid-FULFILL/EVENT phase, which `EnvState` alone cannot represent);
-    the returned rollout's `final_reset_count` is the next reset ordinal,
-    as in `collect_async`. The budget check runs at micro-step-group
-    granularity rather than `collect_async`'s decision granularity, and
-    micro-rewards a resumed lane accrues before its first decision of the
-    chunk are dropped (see `_flat_collect`). With `telemetry`, returns
-    `(Rollout, LoopState, Telemetry)`."""
-    rollout_duration = jnp.float32(rollout_duration)
-    if seq_base is None:
-        seq_base = rng
-    lane_salt = jnp.asarray(lane_salt, _i32)
-    reset_count = jnp.asarray(reset_count, _i32)
-    # episodes doubles as the chunk's reset ordinal offset; zero it so
-    # `reset_count + episodes` counts from this chunk's start
-    loop_state = loop_state.replace(episodes=jnp.zeros((), _i32))
-
-    def reset_fn(key, episodes):
-        seq_rng = jax.random.fold_in(seq_base, reset_count + episodes)
-        return core.reset_pair(
-            params, bank, seq_rng, jax.random.fold_in(seq_rng, lane_salt)
-        )
-
-    out = _flat_collect(
-        params, bank, policy_fn, rng, num_steps, loop_state, micro_groups,
-        auto_reset=True, event_burst=event_burst, event_bulk=event_bulk,
-        bulk_events=bulk_events, fulfill_bulk=fulfill_bulk,
-        bulk_cycles=bulk_cycles, reset_fn=reset_fn,
-        rollout_duration=rollout_duration, use_elapsed=True,
-        telemetry=telemetry, bulk_fused=bulk_fused, health=health,
-    )
-    ro, ls = out[0], out[1]
-    ro = ro.replace(final_reset_count=reset_count + ls.episodes)
     if telemetry is not None:
         return ro, ls, out[2]
     return ro, ls

@@ -15,13 +15,12 @@ max_grad_norm, + PPO keys), `agent:`, `env:`. One new required cap:
 `rollout_steps` — the static scan length (the reference's dynamic episode
 lengths become masked fixed-shape rollouts).
 
-Rollout-engine keys (all optional): `rollout_engine: core|flat` selects
-the per-decision `core.step` scan or the flat micro-step engine
-(env/flat_loop.py; see trainers/rollout.py:collect_flat_*), and
-`flat_micro_per_decision` / `flat_event_burst` / `flat_event_bulk` /
-`flat_bulk_events` / `flat_fulfill_bulk` / `flat_bulk_cycles` expose the
-flat engine's calibration surface (bench.py documents the per-backend
-winners).
+Rollouts are collected by one collector per mode, both over the flat
+micro-step engine (env/flat_loop.py): `collect_flat_sync_batch`, and
+`collect_flat_async_batch` when `rollout_duration` is set
+(trainers/rollout.py). The optional keys `flat_event_bulk` /
+`flat_bulk_events` / `flat_fulfill_bulk` / `flat_bulk_cycles` /
+`flat_bulk_fused` are the engine's bulk-pass knobs.
 
 Multi-chip: a top-level `parallel:` YAML block (`dp: auto|N`) builds a
 1-D dp mesh (parallel.py) and runs the whole iteration SPMD — rollout
@@ -80,16 +79,17 @@ from .returns import (
 from ..env.flat_loop import init_loop_state
 from .rollout import (
     Rollout,
-    collect_async,
-    collect_flat_async,
     collect_flat_async_batch,
-    collect_flat_sync,
     collect_flat_sync_batch,
-    collect_sync,
-    flat_micro_group_budget,
 )
 
 CfgType = dict[str, Any]
+
+# trainer keys that chose among collectors until PR 32 (MIGRATION.md)
+REMOVED_TRAINER_KEYS = (
+    "rollout_engine", "flat_single_eval", "flat_micro_per_decision",
+    "flat_event_burst",
+)
 
 
 class TrainState(struct.PyTreeNode):
@@ -139,6 +139,13 @@ class Trainer(abc.ABC):
                  obs_cfg: CfgType | None = None,
                  health_cfg: CfgType | None = None,
                  chaos_cfg: CfgType | None = None) -> None:
+        for key in REMOVED_TRAINER_KEYS:
+            if key in train_cfg:
+                raise ValueError(
+                    f"trainer config key {key!r} was removed: the trainer "
+                    "has one collector per mode, over the flat engine. "
+                    "Delete the key; see MIGRATION.md (PR 32)"
+                )
         # TPU-friendly rbg PRNG for the whole training program (the env
         # hot loop draws several keys per micro-step; see
         # config.use_fast_prng). Must run before any key is created.
@@ -325,40 +332,6 @@ class Trainer(abc.ABC):
             "rollout_steps", 48 * self.params_env.max_jobs
         )
 
-        # rollout engine: "core" drives the per-decision core.step scan
-        # (a vmapped while_loop between decisions — pays the batch-max
-        # straggler tax); "flat" drives the flat micro-step engine
-        # (env/flat_loop.py) and scatters DECIDE micro-steps into the
-        # same Rollout (trainers/rollout.py:collect_flat_*). Knobs
-        # mirror bench.py's calibration surface.
-        self.rollout_engine: str = str(
-            train_cfg.get("rollout_engine", "core")
-        )
-        if self.rollout_engine not in ("core", "flat"):
-            raise ValueError(
-                f"rollout_engine must be 'core' or 'flat', got "
-                f"{self.rollout_engine!r}"
-            )
-        # single-eval flat collection (round 8, default on): the scan is
-        # decision-synchronous — ONE batched policy evaluation per
-        # decision row (vs ~2 per decision measured on the per-lane
-        # micro-step-group collectors), with the Decima job-compaction
-        # cond at batch level. Requires a scheduler exposing
-        # `flat_batch_policy`; set `flat_single_eval: false` to fall
-        # back to the round-6 per-lane group collectors.
-        self.flat_single_eval: bool = bool(
-            train_cfg.get("flat_single_eval", True)
-        )
-        # micro-step-group budget per decision: the scan runs
-        # rollout_steps * this many groups (PERF_ROUNDS.md mode census: ~3
-        # micro-steps per decision in steady state; 4 adds headroom)
-        self.flat_micro_per_decision: float = float(
-            train_cfg.get("flat_micro_per_decision", 4.0)
-        )
-        # the flat knob dicts are built AFTER the scheduler exists: the
-        # single-eval capability check may downgrade flat_single_eval,
-        # and fulfill_bulk's default follows the final mode
-
         # bound the Decima level scan by the bank's true max DAG depth
         # (bit-identical — deeper levels are no-op updates — and the
         # dominant GNN cost scales with it; the synthetic bank is 6 deep
@@ -378,45 +351,23 @@ class Trainer(abc.ABC):
             | agent_cfg
             | {"num_executors": self.params_env.num_executors}
         )
-        assert isinstance(scheduler, TrainableScheduler), (
-            "scheduler must be trainable"
-        )
+        if not isinstance(scheduler, TrainableScheduler):
+            raise ValueError(
+                f"{type(scheduler).__name__} is not a TrainableScheduler"
+            )
         self.scheduler: TrainableScheduler = scheduler
-        # single-eval collection calls scheduler.batch_policy (one
-        # batched evaluation per decision row); schedulers without it
-        # fall back to the per-lane group collectors
-        self.flat_single_eval = self.flat_single_eval and hasattr(
-            scheduler, "batch_policy"
-        )
+        # the flat engine's bulk-pass knobs, passed to both collectors.
+        # fulfill_bulk: leftovers otherwise cost one drain iteration
+        # each, and the pass's op count rides the decision row.
+        # bulk_fused: one fused bulk kernel (mixed relaunch/arrival runs
+        # in one pass) against the pass pair; step-exact either way
         self.flat_knobs = {
-            "event_burst": int(train_cfg.get("flat_event_burst", 1)),
             "event_bulk": bool(train_cfg.get("flat_event_bulk", True)),
             "bulk_events": int(train_cfg.get("flat_bulk_events", 8)),
-            # single-eval mode defaults fulfill bulking ON: leftovers
-            # otherwise cost one drain iteration each, and the pass's
-            # op count rides the already-GNN-dominated decision row.
-            # The default follows the FINAL mode (post capability
-            # check), so a per-lane fallback keeps its round-6 False.
-            "fulfill_bulk": bool(
-                train_cfg.get("flat_fulfill_bulk",
-                              self.flat_single_eval)
-            ),
+            "fulfill_bulk": bool(train_cfg.get("flat_fulfill_bulk", True)),
             "bulk_cycles": int(train_cfg.get("flat_bulk_cycles", 1)),
-            # ISSUE 7: single fused bulk kernel (mixed relaunch/arrival
-            # runs in one pass) vs the round-3/4 pass pair; step-exact
-            # either way, so this is purely a dispatch-count knob
             "bulk_fused": bool(train_cfg.get("flat_bulk_fused", True)),
         }
-        # the batch (single-eval) collectors take no event_burst —
-        # bursts amortized the policy eval the restructure removed
-        self.flat_batch_knobs = {
-            k: v for k, v in self.flat_knobs.items()
-            if k != "event_burst"
-        }
-        self.flat_micro_groups: int = flat_micro_group_budget(
-            self.rollout_steps, self.flat_micro_per_decision,
-            self.flat_knobs["event_burst"],
-        )
         self.tx = make_optimizer(train_cfg)
         self.train_cfg = train_cfg
         self._env_states = None  # async mode: persistent lanes
@@ -488,11 +439,12 @@ class Trainer(abc.ABC):
 
     def _collect(self, model_params, iteration: jnp.ndarray,
                  rng: jax.Array, env_states) -> tuple[Rollout, Any, Any]:
-        """One iteration's rollouts: [B]-vmapped scans. Seed layout mirrors
-        the reference (trainer.py:268-271): lanes in the same sequence
-        group share the job-sequence key, refreshed per reset. Returns
-        `(rollout, env_states, telemetry)` — telemetry is a per-lane
-        `obs.Telemetry` when `obs: telemetry` is on, else None."""
+        """One iteration's rollouts: one scan over the [B] lane batch.
+        Seed layout mirrors the reference (trainer.py:268-271): lanes in
+        the same sequence group share the job-sequence key, refreshed per
+        reset. Returns `(rollout, env_states, telemetry)` — telemetry is
+        a per-lane `obs.Telemetry` when `obs: telemetry` is on, else
+        None."""
         p, bank = self.params_env, self.bank
         G, R = self.num_sequences, self.num_rollouts
         master = jax.random.PRNGKey(self.seed)
@@ -509,116 +461,51 @@ class Trainer(abc.ABC):
 
         g_ids = jnp.repeat(jnp.arange(G), R)
         r_ids = jnp.tile(jnp.arange(R), G)
-        seq_rngs = jax.vmap(lambda g: seq_key(g, iteration))(g_ids)
-        lane_rngs = jax.vmap(
-            lambda s, r: jax.random.fold_in(s, 1000 + r)
-        )(seq_rngs, r_ids)
-        pol_rngs = jax.vmap(
-            lambda r: jax.random.fold_in(jax.random.fold_in(rng, r), 7)
-        )(jnp.arange(G * R))
 
-        def policy_fn(k, obs):
-            return self.scheduler.policy(k, obs, model_params)
-
-        flat = self.rollout_engine == "flat"
-        single = flat and self.flat_single_eval
-        if single:
-            def batch_policy_fn(k, obs):
-                return self.scheduler.batch_policy(k, obs, model_params)
-        if self.rollout_duration:  # async mode
-            if env_states is None:
-                states = jax.vmap(
-                    lambda s, l: core.reset_pair(p, bank, s, l)
-                )(seq_rngs, lane_rngs)
-                if flat:
-                    states = jax.vmap(init_loop_state)(states)
-                # the initial reset consumed ordinal `iteration`; the
-                # next (mid-scan) reset of any lane is ordinal + 1
-                reset_counts = jnp.full(
-                    (G * R,), iteration + 1, jnp.int32
-                )
-            else:
-                states, reset_counts = env_states
-            seq_bases = jax.vmap(
-                lambda g: jax.random.fold_in(master, g)
-            )(g_ids)
-            lane_salts = (1000 + r_ids).astype(jnp.int32)
-            # telem0 is None or a per-lane Telemetry; vmap treats None
-            # as an empty pytree, so ONE vmapped call covers both modes
-            # (the collector's return shape switches on the Python-level
-            # None check at trace time)
-            track = telem0 is not None
-            if single:
-                out = collect_flat_async_batch(
-                    p, bank, batch_policy_fn,
-                    jax.random.fold_in(rng, 7), self.rollout_steps,
-                    states, self.rollout_duration, seq_bases,
-                    lane_salts, reset_counts, telem0,
-                    lane_shard=self._lane_sharding,
-                    health=self.health_enabled,
-                    **self.flat_batch_knobs,
-                )
-                ro, loop_states, telem = (
-                    out if track else (out + (None,))
-                )
-                return ro, (loop_states, ro.final_reset_count), telem
-            if flat:
-                out = jax.vmap(
-                    lambda k, s, sb, salt, rc, tm: collect_flat_async(
-                        p, bank, policy_fn, k, self.rollout_steps, s,
-                        self.rollout_duration, sb, salt, rc, tm,
-                        micro_groups=self.flat_micro_groups,
-                        health=self.health_enabled,
-                        **self.flat_knobs,
-                    )
-                )(pol_rngs, states, seq_bases, lane_salts,
-                  reset_counts, telem0)
-                ro, loop_states, telem = (
-                    out if track else (out + (None,))
-                )
-                return ro, (loop_states, ro.final_reset_count), telem
-            out = jax.vmap(
-                lambda k, s, sb, salt, rc, tm: collect_async(
-                    p, bank, policy_fn, k, self.rollout_steps, s,
-                    self.rollout_duration, sb, salt, rc, tm,
-                    health=self.health_enabled,
-                )
-            )(pol_rngs, states, seq_bases, lane_salts, reset_counts,
-              telem0)
-            ro, telem = out if track else (out, None)
-            return ro, (ro.final_state, ro.final_reset_count), telem
-        else:  # sync: fresh episode per iteration
-            states = jax.vmap(
+        def fresh_states():
+            seq_rngs = jax.vmap(lambda g: seq_key(g, iteration))(g_ids)
+            lane_rngs = jax.vmap(
+                lambda s, r: jax.random.fold_in(s, 1000 + r)
+            )(seq_rngs, r_ids)
+            return jax.vmap(
                 lambda s, l: core.reset_pair(p, bank, s, l)
             )(seq_rngs, lane_rngs)
-            track = telem0 is not None
-            if single:
-                out = collect_flat_sync_batch(
-                    p, bank, batch_policy_fn,
-                    jax.random.fold_in(rng, 7), self.rollout_steps,
-                    states, telem0,
-                    lane_shard=self._lane_sharding,
-                    health=self.health_enabled,
-                    **self.flat_batch_knobs,
-                )
-            elif flat:
-                out = jax.vmap(
-                    lambda k, s, tm: collect_flat_sync(
-                        p, bank, policy_fn, k, self.rollout_steps, s, tm,
-                        micro_groups=self.flat_micro_groups,
-                        health=self.health_enabled,
-                        **self.flat_knobs,
-                    )
-                )(pol_rngs, states, telem0)
-            else:
-                out = jax.vmap(
-                    lambda k, s, tm: collect_sync(
-                        p, bank, policy_fn, k, self.rollout_steps, s, tm,
-                        health=self.health_enabled,
-                    )
-                )(pol_rngs, states, telem0)
+
+        def batch_policy_fn(k, obs):
+            return self.scheduler.batch_policy(k, obs, model_params)
+
+        k_pol = jax.random.fold_in(rng, 7)
+        # the collectors' return shape switches on the Python-level
+        # None check of telem0 at trace time
+        track = telem0 is not None
+        if not self.rollout_duration:  # sync: fresh episode per iteration
+            out = collect_flat_sync_batch(
+                p, bank, batch_policy_fn, k_pol, self.rollout_steps,
+                fresh_states(), telem0,
+                lane_shard=self._lane_sharding,
+                health=self.health_enabled, **self.flat_knobs,
+            )
             ro, telem = out if track else (out, None)
             return ro, None, telem
+        if env_states is None:
+            states = jax.vmap(init_loop_state)(fresh_states())
+            # the initial reset consumed ordinal `iteration`; the
+            # next (mid-scan) reset of any lane is ordinal + 1
+            reset_counts = jnp.full((G * R,), iteration + 1, jnp.int32)
+        else:
+            states, reset_counts = env_states
+        seq_bases = jax.vmap(
+            lambda g: jax.random.fold_in(master, g)
+        )(g_ids)
+        lane_salts = (1000 + r_ids).astype(jnp.int32)
+        out = collect_flat_async_batch(
+            p, bank, batch_policy_fn, k_pol, self.rollout_steps, states,
+            self.rollout_duration, seq_bases, lane_salts, reset_counts,
+            telem0, lane_shard=self._lane_sharding,
+            health=self.health_enabled, **self.flat_knobs,
+        )
+        ro, loop_states, telem = out if track else (out + (None,))
+        return ro, (loop_states, ro.final_reset_count), telem
 
     def _returns_and_baselines(self, state: TrainState, ro: Rollout):
         """Shared preprocessing (reference trainer.py:172-212)."""
@@ -954,7 +841,6 @@ class Trainer(abc.ABC):
                 num_iterations=self.num_iterations,
                 num_envs=self.num_envs,
                 rollout_steps=self.rollout_steps,
-                rollout_engine=self.rollout_engine,
                 telemetry=self.obs_telemetry,
                 memory=self.obs_memory,
                 seed=self.seed,

@@ -32,14 +32,11 @@ def _neq_ignoring_rng(sa, sb):
     return neq
 
 
-# fast tier keeps the diamond fixture at burst 1 under BOTH fulfillment
-# modes (False is the library default every non-bench caller uses; True
-# is one of bench.py's self-calibration candidates); the multi-job and
-# burst sweeps run in the slow tier
+# fast tier keeps the diamond fixture under BOTH fulfillment modes
+# (False is the library default every non-bench caller uses; True is
+# one of bench.py's self-calibration candidates); the multi-job fixture
+# runs in the slow tier
 @pytest.mark.parametrize("fulfill_bulk", [False, True])
-@pytest.mark.parametrize(
-    "burst", [1, pytest.param(4, marks=pytest.mark.slow)]
-)
 @pytest.mark.parametrize(
     "spec_fn,num_exec",
     [
@@ -49,7 +46,7 @@ def _neq_ignoring_rng(sa, sb):
         ),
     ],
 )
-def test_flat_loop_matches_step_loop(spec_fn, num_exec, burst, fulfill_bulk):
+def test_flat_loop_matches_step_loop(spec_fn, num_exec, fulfill_bulk):
     import jax
     import jax.numpy as jnp
 
@@ -94,9 +91,8 @@ def test_flat_loop_matches_step_loop(spec_fn, num_exec, burst, fulfill_bulk):
 
     ls = jax.jit(
         lambda s, r: run_flat(
-            params, bank, pol, r, 40 * decisions // burst, s,
-            auto_reset=False, event_burst=burst,
-            fulfill_bulk=fulfill_bulk,
+            params, bank, pol, r, 40 * decisions, s,
+            auto_reset=False, fulfill_bulk=fulfill_bulk,
         )
     )(state0, jax.random.PRNGKey(0))
 
@@ -351,32 +347,6 @@ def test_bulk_stop_at_limit_matches_single_event_flat_loop():
             )
 
 
-def test_event_micro_step_leaves_non_event_lanes_untouched():
-    """A lane in DECIDE/FULFILL mode must be bit-identical after an
-    event-only sub-step (including its rng chain and counters)."""
-    import jax
-
-    from sparksched_tpu.env.flat_loop import (
-        M_DECIDE,
-        event_micro_step,
-        init_loop_state,
-    )
-
-    spec = spec_diamond()
-    params, bank, state0 = make_tpu_env_state(spec, 4)
-    ls = init_loop_state(state0)
-    assert int(ls.mode) == M_DECIDE
-
-    out = jax.jit(
-        lambda l, r: event_micro_step(params, bank, l, r)
-    )(ls, jax.random.PRNGKey(3))
-
-    for a, b in zip(
-        jax.tree_util.tree_leaves(out), jax.tree_util.tree_leaves(ls)
-    ):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
 @pytest.mark.slow
 def test_run_flat_loop_state_resume_matches_single_run():
     """Chunked runs resuming via `loop_state` (the bench pattern) must
@@ -415,114 +385,6 @@ def test_run_flat_loop_state_resume_matches_single_run():
         jax.tree_util.tree_leaves(whole), jax.tree_util.tree_leaves(chunked)
     ):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_flat_decima_collection_matches_core_step_path(monkeypatch):
-    """The tentpole guarantee of the flat rollout collectors: a Decima
-    rollout collected from the flat micro-step engine
-    (`collect_flat_sync`) must agree step-exactly with the per-decision
-    `core.step` collection path (`collect_sync`) at fixed seeds —
-    actions (stage/job/exec choice), log-probs, per-decision rewards,
-    wall times, the DECIDE/valid mask, and the stored observations the
-    PPO update rebuilds features from. The duration sampler is pinned
-    deterministic (the engines' rng STREAMS legitimately differ) and the
-    policy is greedy Decima (argmax heads), so every compared quantity
-    is rng-independent."""
-    import jax
-    import jax.numpy as jnp
-
-    from sparksched_tpu.config import EnvParams
-    from sparksched_tpu.env import core
-    from sparksched_tpu.schedulers import DecimaScheduler
-    from sparksched_tpu.trainers.rollout import (
-        collect_flat_sync,
-        collect_sync,
-    )
-    from sparksched_tpu.workload import make_workload_bank
-
-    def det_sampler(params, bank, rng, template, stage, num_local,
-                    task_valid, same_stage):
-        base = bank.rough_duration[template, stage]
-        return (
-            base
-            + jnp.where(task_valid & same_stage, 7.0, 131.0)
-            + 17.0 * stage.astype(jnp.float32)
-        )
-
-    monkeypatch.setattr(core, "sample_task_duration", det_sampler)
-
-    params = EnvParams(
-        num_executors=5, max_jobs=6, max_stages=20, max_levels=20,
-        moving_delay=700.0, warmup_delay=500.0, job_arrival_rate=4e-5,
-        mean_time_limit=None, beta=5e-3,
-    )
-    bank = make_workload_bank(params.num_executors, params.max_stages)
-    params = params.replace(
-        max_stages=bank.max_stages, max_levels=bank.max_stages
-    )
-    sched = DecimaScheduler(
-        num_executors=params.num_executors, embed_dim=8,
-        gnn_mlp_kwargs={"hid_dims": [16, 8], "act_cls": "LeakyReLU",
-                        "act_kwargs": {"negative_slope": 0.2}},
-        policy_mlp_kwargs={"hid_dims": [16, 16], "act_cls": "Tanh"},
-        seed=7,
-    )
-    pol = sched.flat_policy(deterministic=True)
-
-    state0 = core.reset(params, bank, jax.random.PRNGKey(3))
-    T = 160
-    ro_core = collect_sync(
-        params, bank, pol, jax.random.PRNGKey(0), T, state0
-    )
-    # different collector rng on purpose: nothing compared may depend
-    # on it. event_burst > 1 exercises the burst sub-step records and
-    # fulfill_bulk the shipped-config path where a round-finishing
-    # DECIDE micro-step jumps straight to M_EVENT, so the same group's
-    # sub-steps must discount-reference the NEW decision's wall time
-    # (the beta > 0 fixture makes a stale reference show up in rewards).
-    ro_flat = collect_flat_sync(
-        params, bank, pol, jax.random.PRNGKey(1), T, state0,
-        micro_groups=500, event_burst=2, fulfill_bulk=True,
-    )
-
-    nv = int(ro_core.valid.sum())
-    assert nv > 30, "fixture episode too short to be meaningful"
-    np.testing.assert_array_equal(
-        np.asarray(ro_core.valid), np.asarray(ro_flat.valid)
-    )
-    np.testing.assert_array_equal(
-        np.asarray(ro_core.stage_idx), np.asarray(ro_flat.stage_idx)
-    )
-    for name in ("job_idx", "num_exec_k"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(ro_core, name))[:nv],
-            np.asarray(getattr(ro_flat, name))[:nv],
-            err_msg=name,
-        )
-    np.testing.assert_allclose(
-        np.asarray(ro_core.lgprob)[:nv],
-        np.asarray(ro_flat.lgprob)[:nv], rtol=1e-5, atol=1e-6,
-    )
-    np.testing.assert_allclose(
-        np.asarray(ro_core.reward), np.asarray(ro_flat.reward),
-        rtol=1e-4, atol=1e-4,
-    )
-    np.testing.assert_allclose(
-        np.asarray(ro_core.wall_times), np.asarray(ro_flat.wall_times),
-        rtol=1e-6,
-    )
-    for name in ("remaining", "duration", "schedulable", "node_mask",
-                 "job_mask", "job_template", "exec_supplies",
-                 "num_committable", "source_job"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(ro_core.obs, name))[:nv],
-            np.asarray(getattr(ro_flat.obs, name))[:nv],
-            err_msg=f"stored obs field {name}",
-        )
-    np.testing.assert_allclose(
-        float(ro_core.final_state.wall_time),
-        float(ro_flat.final_state.wall_time), rtol=1e-6,
-    )
 
 
 def _decima_parity_fixture(monkeypatch):
@@ -630,7 +492,7 @@ def test_flat_collection_at_the_time_limit_ends_on_the_crossing_event(
     from sparksched_tpu.env import core
     from sparksched_tpu.schedulers import round_robin_policy
     from sparksched_tpu.trainers.rollout import (
-        collect_flat_sync,
+        collect_flat_sync_batch,
         collect_sync,
     )
 
@@ -638,6 +500,10 @@ def test_flat_collection_at_the_time_limit_ends_on_the_crossing_event(
 
     def fair(rng, obs):
         si, ne = round_robin_policy(obs, params.num_executors, True)
+        return si, ne, {}
+
+    def fair_batch(rng, obs):
+        si, ne, _ = jax.vmap(lambda o: fair(rng, o))(obs)
         return si, ne, {}
 
     T = 80
@@ -652,9 +518,13 @@ def test_flat_collection_at_the_time_limit_ends_on_the_crossing_event(
     ro_core = collect_sync(
         params, bank, fair, jax.random.PRNGKey(0), T, s0
     )
-    ro_flat = collect_flat_sync(
-        params, bank, fair, jax.random.PRNGKey(1), T, s0,
-        micro_groups=12 * T, fulfill_bulk=True,
+    # a batch of one lane, unstacked again for the comparison
+    ro_flat = jax.tree_util.tree_map(
+        lambda a: a[0],
+        collect_flat_sync_batch(
+            params, bank, fair_batch, jax.random.PRNGKey(1), T,
+            jax.tree_util.tree_map(lambda a: a[None], s0),
+        ),
     )
     nv = int(ro_core.valid.sum())
     assert nv == k + 1 and bool(ro_core.final_state.truncated)
@@ -869,7 +739,7 @@ def test_fused_bulk_pass_matches_unfused_plain(monkeypatch, moving_delay):
 
 
 def test_fused_bulk_pass_matches_unfused_recorded(monkeypatch):
-    """ISSUE 7 fused-kernel parity with `record=True`: the single-eval
+    """ISSUE 7 fused-kernel parity on a recorded rollout: the single-eval
     batch collector (decide micro-step + drain-to-decision — the path
     whose drain now runs the cheap-cond/`masked=False` body) must
     produce an IDENTICAL Rollout under `bulk_fused` on/off at fixed
@@ -1307,7 +1177,7 @@ def test_early_exit_pass_keeps_the_budget(bulk_pass_trail, max_events):
 
 def test_bulk_scan_steps_counter(bulk_pass_trail):
     """`Telemetry.bulk_scan_steps` is what the pass reports for a live
-    lane that is not in DECIDE mode and 0 otherwise, through all three
+    lane that is not in DECIDE mode and 0 otherwise, through both
     micro-steps that run the bulk chain; `summarize` gives its total
     and its mean over the passes that took an event."""
     import jax
@@ -1316,7 +1186,6 @@ def test_bulk_scan_steps_counter(bulk_pass_trail):
     from sparksched_tpu.env.flat_loop import (
         _lane_done,
         drain_micro_step,
-        event_micro_step,
         micro_step,
     )
     from sparksched_tpu.obs import summarize
@@ -1346,8 +1215,6 @@ def test_bulk_scan_steps_counter(bulk_pass_trail):
 
     steps = {
         "drain": lambda l, k, t: drain_micro_step(
-            params, bank, l, k, auto_reset=False, telemetry=t),
-        "event": lambda l, k, t: event_micro_step(
             params, bank, l, k, auto_reset=False, telemetry=t),
         "micro": lambda l, k, t: micro_step(
             params, bank, pol, l, k, auto_reset=False, telemetry=t),

@@ -113,9 +113,9 @@ def test_dryrun_multichip_8_devices():
 
 
 def test_ppo_smoke_trains_on_flat_collector(tmp_path):
-    """End-to-end PPO iteration with `rollout_engine: flat` (the round-6
-    fast path): trajectories come from the flat micro-step engine's
-    DECIDE records and the update must still move the parameters."""
+    """End-to-end PPO iteration at a tiny size: trajectories come from
+    the flat micro-step engine's decision rows and the update must
+    still move the parameters."""
     import jax
     import numpy as np
 
@@ -140,8 +140,6 @@ def test_ppo_smoke_trains_on_flat_collector(tmp_path):
             "opt_kwargs": {"lr": 3.0e-4},
             "max_grad_norm": 0.5,
             "rollout_steps": 40,
-            "rollout_engine": "flat",
-            "flat_micro_per_decision": 4.0,
         },
         "agent": {
             "agent_cls": "DecimaScheduler",
@@ -161,7 +159,6 @@ def test_ppo_smoke_trains_on_flat_collector(tmp_path):
         },
     }
     t = make_trainer(cfg)
-    assert t.rollout_engine == "flat"
     p0 = jax.device_get(t.scheduler.params)
     state = t.train()
     p1 = jax.device_get(state.params)

@@ -203,11 +203,11 @@ def test_lane_fit_quantized_layout_strictly_more_lanes():
     [...,8,16] tail (see test_aval_bytes_int8_minor_dim_padding)."""
     import jax
 
-    from sparksched_tpu.analysis.jaxpr_audit import audit_setup
+    from sparksched_tpu.analysis.jaxpr_audit import _batched, audit_setup
     from sparksched_tpu.env import core
     from sparksched_tpu.obs.memory import TPU_HBM_BUDGET_BYTES, lane_fit
     from sparksched_tpu.schedulers.heuristics import round_robin_policy
-    from sparksched_tpu.trainers.rollout import collect_flat_sync
+    from sparksched_tpu.trainers.rollout import collect_flat_sync_batch
     from sparksched_tpu.workload import quantize_bank
 
     params32, bank32, _ = audit_setup()
@@ -222,22 +222,26 @@ def test_lane_fit_quantized_layout_strictly_more_lanes():
     # of the [T,J,S] grid this test was first sized for)
 
     def make_fit(params, bank):
-        def pol(rng, obs):
-            si, ne = round_robin_policy(obs, params.num_executors, True)
+        def bpol(rng, obs):
+            si, ne = jax.vmap(lambda o: round_robin_policy(
+                o, params.num_executors, True))(obs)
             return si, ne, {}
-
-        def lane(s, r):
-            return collect_flat_sync(
-                params, bank, pol, r, T, s, None, micro_groups=8,
-                fulfill_bulk=True,
-            )
 
         key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
         state = jax.eval_shape(
             lambda k: core.reset(params, bank, k), key
         )
+
+        # the collector takes the lane axis itself: trace it at each
+        # base width in place of a vmap over a per-lane program
+        def tracer(lanes):
+            return jax.make_jaxpr(
+                lambda s, r: collect_flat_sync_batch(
+                    params, bank, bpol, r, T, s)
+            )(_batched(state, lanes), key)
+
         return lane_fit(
-            lane, (state, key),
+            tracer=tracer,
             candidates=tuple(range(256, 2049, 32)),
             budget_bytes=TPU_HBM_BUDGET_BYTES,
         )

@@ -230,7 +230,7 @@ def test_shard_lanes_places_every_leaf():
 
 
 # ---------------------------------------------------------------------------
-# ISSUE 6: the sharded flat single-eval path — step-exact, 1/dp work,
+# ISSUE 6: the sharded flat collector — step-exact, 1/dp work,
 # census-pinned collectives
 # ---------------------------------------------------------------------------
 
@@ -239,20 +239,18 @@ def _make_flat_trainer(num_rollouts: int, mesh=None):
     from sparksched_tpu.trainers.ppo import PPO
 
     agent, env, tr = _tiny_cfg(num_rollouts)
-    tr = tr | {"rollout_steps": 8, "rollout_engine": "flat"}
+    tr = tr | {"rollout_steps": 8}
     return PPO(agent, env, tr, mesh=mesh)
 
 
 @pytest.fixture(scope="module")
 def flat_dp_pair():
-    """dp=1 and dp=8 trainers over the same 16-lane flat single-eval
-    config, with their AOT-compiled collect programs and one executed
+    """dp=1 and dp=8 trainers over the same 16-lane config, with their AOT-compiled collect programs and one executed
     rollout each (shared across the parity / FLOPs / census tests —
     the two collect compiles are the expensive part)."""
     out = {}
     for dp in (1, 8):
         t = _make_flat_trainer(16, mesh=make_mesh(dp))
-        assert t.flat_single_eval, "Decima batch_policy went missing"
         s = t.init_state()
         comp = t._collect_jit.lower(
             s.params, s.iteration, s.rng, None
@@ -262,7 +260,7 @@ def flat_dp_pair():
     return out
 
 
-def test_flat_single_eval_collect_dp8_step_exact(flat_dp_pair):
+def test_flat_collect_dp8_step_exact(flat_dp_pair):
     """The lane-sharded single-eval collector is STEP-EXACT vs dp=1 at
     fixed seeds: collection is embarrassingly parallel along lanes (the
     only cross-lane op is the compaction predicate, an integer max), so
@@ -279,7 +277,7 @@ def test_flat_single_eval_collect_dp8_step_exact(flat_dp_pair):
     assert ro8.valid.any()
 
 
-def test_flat_single_eval_collect_flops_scale_1_over_dp(flat_dp_pair):
+def test_flat_collect_flops_scale_1_over_dp(flat_dp_pair):
     """XLA cost-analysis FLOPs are per-device for an SPMD program: the
     dp=8 collect must do <= 1.1x of (dp=1 FLOPs)/8 per device — the
     quantitative scaling claim (ROADMAP item 1), asserted, not

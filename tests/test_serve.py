@@ -1102,18 +1102,13 @@ def test_store_from_config_rejects_unknown_keys(setup, store):
 
 
 def test_latency_row_blocks():
-    """The `latency` bench row's building blocks: the percentile block
-    schema (PERF_ROUNDS.md round 13), and on-chip-only fields that are absent
-    from a CPU row, never a placeholder string."""
-    import bench_decima
+    """A latency row's building block: the percentile block schema
+    (PERF_ROUNDS.md round 13)."""
+    from sparksched_tpu.obs.metrics import percentile_block
 
-    block = bench_decima._latency_block([1.0, 2.0, 3.0, 100.0], 4)
+    block = percentile_block([1.0, 2.0, 3.0, 100.0], 4)
     assert set(block) == {
         "p50_ms", "p90_ms", "p99_ms", "mean_ms", "max_ms", "reps",
     }
     assert block["p50_ms"] <= block["p90_ms"] <= block["p99_ms"]
-    chip = bench_decima._on_chip_block()
-    if jax.default_backend() == "cpu":
-        assert chip == {}
-    else:
-        assert isinstance(chip["device_memory"], dict)
+    assert block["max_ms"] == 100.0 and block["reps"] == 4

@@ -499,27 +499,28 @@ def test_contract_trajectory_schema_fires():
 
     from sparksched_tpu.analysis import contracts
 
-    # a MicroRec whose lgprob drifted to f64 must fire
+    # a StoredObs whose duration drifted to f64 must fire
+    dims = {"F": 128, "J": 4}
     rec = {
-        k: jax.ShapeDtypeStruct((), dt)
-        for k, (dt, _) in contracts.MICRO_REC_SCHEMA.items()
+        k: jax.ShapeDtypeStruct(tuple(dims[d] for d in shape), dt)
+        for k, (dt, shape) in contracts.STORED_OBS_SCHEMA.items()
     }
     assert contracts.check_fields(
-        rec, contracts.MICRO_REC_SCHEMA, {}, "MicroRec"
+        rec, contracts.STORED_OBS_SCHEMA, dims, "StoredObs"
     ) == []
-    rec["lgprob"] = jax.ShapeDtypeStruct((), "float64")
+    rec["duration"] = jax.ShapeDtypeStruct((128,), "float64")
     vs = contracts.check_fields(
-        rec, contracts.MICRO_REC_SCHEMA, {}, "MicroRec"
+        rec, contracts.STORED_OBS_SCHEMA, dims, "StoredObs"
     )
     assert vs and vs[0].rule == "trajectory-schema"
 
     # a leaf added without a schema update is itself a violation (the
     # f64-smuggled-into-the-rollout-buffer hazard must not hide behind
     # a schema-keyed projection)
-    rec["lgprob"] = jax.ShapeDtypeStruct((), "float32")
+    rec["duration"] = jax.ShapeDtypeStruct((128,), "float32")
     rec["value_est"] = jax.ShapeDtypeStruct((), "float64")
     vs = contracts.check_fields(
-        rec, contracts.MICRO_REC_SCHEMA, {}, "MicroRec"
+        rec, contracts.STORED_OBS_SCHEMA, dims, "StoredObs"
     )
     assert vs and "value_est" in vs[0].where, vs
 

@@ -99,7 +99,7 @@ def test_flagship_widths(flagship):
     p = flagship.params_env
     assert (p.num_executors, p.max_jobs, flagship.num_envs) == (50, 200, 16)
     assert flagship.scheduler.job_bucket == 32
-    assert flagship.health_enabled and flagship.flat_single_eval
+    assert flagship.health_enabled
     assert jax.config.jax_default_prng_impl == "rbg"
 
 

@@ -226,6 +226,23 @@ def _mini_cfg(trainer_overrides=None, env_overrides=None):
     return cfg
 
 
+@pytest.mark.parametrize("key,value", [
+    ("rollout_engine", "core"),
+    ("rollout_engine", "flat"),
+    ("flat_single_eval", False),
+    ("flat_micro_per_decision", 4.0),
+    ("flat_event_burst", 4),
+])
+def test_removed_collector_key_fails_at_construction(key, value):
+    """A config from before PR 32 that still chooses a collector is
+    rejected by name (an old `rollout_engine: core` must not quietly
+    train on the other engine), whatever value the key carries."""
+    from sparksched_tpu.trainers import make_trainer
+
+    with pytest.raises(ValueError, match=f"'{key}'.*MIGRATION.md"):
+        make_trainer(_mini_cfg({key: value}))
+
+
 @pytest.mark.slow
 def test_ppo_trains_and_checkpoints(tmp_path):
     """Mirrors the reference's only test (test/test_train.py): a full
@@ -377,102 +394,15 @@ def test_collect_async_group_shares_sequences_across_resets():
     assert not (same and same_arrivals)
 
 
-def test_collect_flat_async_group_sequences_budget_and_resume():
-    """Flat-engine async collection (the `rollout_engine: flat` +
-    `rollout_duration` path): lanes sharing `seq_base` must replay
+def test_collect_flat_async_batch_group_sequences_budget_and_resume():
+    """The streaming collector, `collect_flat_async_batch` (one policy
+    evaluation per decision row, per-lane reset closures over
+    seq_bases/lane_salts arrays): lanes sharing `seq_base` must replay
     identical job sequences at equal reset ordinals (the group-shared
     `fold_in(seq_base, reset_count + episodes)` scheme the critic-free
     baseline relies on), the sim-time budget must freeze lanes, and a
     second chunk resumed from the returned LoopState must keep
     collecting."""
-    import jax
-    import jax.numpy as jnp
-
-    from sparksched_tpu.config import EnvParams
-    from sparksched_tpu.env import core
-    from sparksched_tpu.env.flat_loop import init_loop_state
-    from sparksched_tpu.schedulers.heuristics import round_robin_policy
-    from sparksched_tpu.trainers.rollout import collect_flat_async
-    from sparksched_tpu.workload import make_workload_bank
-
-    params = EnvParams(
-        num_executors=4, max_jobs=3, max_stages=20, max_levels=20,
-        moving_delay=500.0, warmup_delay=200.0,
-    )
-    bank = make_workload_bank(params.num_executors, params.max_stages)
-    params = params.replace(
-        max_stages=bank.max_stages, max_levels=bank.max_stages
-    )
-
-    def pol(rng, obs):
-        si, ne = round_robin_policy(obs, params.num_executors, True)
-        return si, ne, {}
-
-    master = jax.random.PRNGKey(7)
-    seq_base = jax.random.fold_in(master, 0)
-    seq0 = jax.random.fold_in(seq_base, 0)
-    T = 120
-    ros, lss = [], []
-    for r in range(2):  # two lanes of the same sequence group
-        lane_salt = 1000 + r
-        state = core.reset_pair(
-            params, bank, seq0, jax.random.fold_in(seq0, lane_salt)
-        )
-        ro, ls = collect_flat_async(
-            params, bank, pol, jax.random.fold_in(master, 100 + r),
-            T, init_loop_state(state), 1e9, seq_base, lane_salt, 1,
-            micro_groups=900,
-        )
-        ros.append(ro)
-        lss.append(ls)
-    n_resets = [int(ro.resets.sum()) for ro in ros]
-    assert min(n_resets) >= 2, n_resets
-    for ordinal in range(2):
-        tmpl = []
-        for ro in ros:
-            idx = int(
-                np.flatnonzero(np.asarray(ro.resets))[ordinal]
-            ) + 1
-            assert idx < T
-            tmpl.append(np.asarray(ro.obs.job_template[idx]))
-        np.testing.assert_array_equal(tmpl[0], tmpl[1])
-        # final_reset_count advances by completed episodes
-        assert int(ros[0].final_reset_count) == 1 + n_resets[0]
-
-    # chunk 2 resumes from the returned LoopState and keeps collecting
-    ro2, _ = collect_flat_async(
-        params, bank, pol, jax.random.fold_in(master, 300),
-        T, lss[0], 1e9, seq_base, 1000, ros[0].final_reset_count,
-        micro_groups=300,
-    )
-    assert int(ro2.valid.sum()) > 0
-
-    # sim-time budget freezes the lane near the budget boundary
-    budget = 2.0e6
-    state = core.reset_pair(
-        params, bank, seq0, jax.random.fold_in(seq0, 5)
-    )
-    ro3, _ = collect_flat_async(
-        params, bank, pol, jax.random.fold_in(master, 400),
-        T, init_loop_state(state), jnp.float32(budget), seq_base, 5, 1,
-        micro_groups=900,
-    )
-    total = float(ro3.wall_times[-1])
-    assert total >= budget * 0.5, "budget never approached"
-    # freeze is at micro-step-group granularity: elapsed may overshoot
-    # by at most one group's span, not keep running to the scan's end
-    unbudgeted = float(ros[0].wall_times[-1])
-    assert total < unbudgeted * 0.5, (
-        f"budget freeze ineffective: {total} vs {unbudgeted}"
-    )
-
-
-def test_collect_flat_async_batch_group_sequences_budget_and_resume():
-    """Round-8 single-eval async collector: the same group-shared
-    sequence / budget / resume contract as the per-lane
-    `collect_flat_async` test above, on the batch-level
-    `collect_flat_async_batch` (one policy evaluation per decision
-    row, per-lane reset closures over seq_bases/lane_salts arrays)."""
     import jax
     import jax.numpy as jnp
 

@@ -84,7 +84,7 @@ def test_vmapped_flat_loop_does_not_broadcast_bank(setup):
     def lane(ls, rng):
         return run_flat(
             params, bank, pol, rng, 2, auto_reset=False,
-            compute_levels=False, event_burst=2, event_bulk=True,
+            compute_levels=False, event_bulk=True,
             bulk_events=8, fulfill_bulk=True, loop_state=ls,
         )
 
