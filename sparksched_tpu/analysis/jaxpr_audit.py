@@ -128,6 +128,21 @@ class Budget:
 # serve programs 7 and the collectors 10 equations fewer
 # (serve_decide 6610 -> 6603, flat_collect_batch 14866 -> 14856), all
 # inside their bands. The chip rows are PERF.md, PR 31.
+#
+# Re-measured 2026-09-29 (PR 33: `DecimaNet`'s level scan runs one step
+# fewer, which is a trip count and no equation, and sums the children's
+# messages by a select and a reduce where it had one `dot_general`;
+# LeakyReLU is a `custom_jvp` call around one `max` where it was a
+# compare and a select under a `jit`: one `net.apply` 208 -> 210
+# equations, +2 for each net a program traces). Eqns before -> after,
+# gathers and scatters unmoved, every count inside its band, so no band
+# moved: decima_score 491 -> 495,
+# decima_batch_policy 728 -> 732, ppo_update 2834 -> 2859,
+# ppo_update_health 3183 -> 3208, flat_collect_batch 14856 -> 14860,
+# flat_collect_batch_health 15109 -> 15113, serve_decide 6603 -> 6607,
+# serve_decide_batch 15178 -> 15182 (the record, ring, group and
+# sharded variants +4 each); observe, micro_step, decide_micro_step and
+# drain_to_decision as they were. The chip rows are PERF.md, PR 33.
 # ---------------------------------------------------------------------------
 
 BUDGETS: dict[str, Budget] = {

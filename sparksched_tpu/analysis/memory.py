@@ -63,7 +63,27 @@ program it evaluated selected nothing): 9.9 -> 6.0, cap 14 -> 8;
 `drain_to_decision(auto_reset=True)` re-seeds once after its loop and
 no longer in the loop's body: 9.5 -> 10.0 as registered (one lane, no
 lane axis: the re-seed unconditional; the loop's body took the sync
-tail's freeze select), inside its cap. (The decima/ppo programs
+tail's freeze select), inside its cap. Re-pinned 2026-09-29 (PR 33):
+`DecimaNet` sums a node's children's messages by a select and a reduce
+over the child axis where it had a per-job matrix product, and a jaxpr
+holds the select's [J,S,S,D] operand as a buffer of its own (at audit
+shapes f32[4,20,20,20,16], 19.7 MB tile-padded, once a level step and
+once more in the update's backward pass). The compiler fuses it into
+the reduce, so this is the MODEL's growth, not the chip's: compiled
+for the v5e at 128 lanes x 200 jobs the net's temporaries FELL, 331 ->
+166 MB, and the gradient over 96 samples 3.69 -> 3.22 GB (PERF.md,
+PR 33; tests/test_tpu_compile.py bounds the compiled collector and
+update). Every program that evaluates the net moved, measured MB
+before -> after, caps 1.35x the new value: decima_score 153.6 ->
+361.4, decima_batch_policy 169.2 -> 377.0, ppo_update 270.2 -> 464.1,
+ppo_update_health 270.5 -> 464.3, flat_collect_batch 366.2 -> 574.1,
+flat_collect_batch_health 367.0 -> 574.8, serve_decide 58.9 -> 110.9,
+serve_decide_record 59.2 -> 111.2, serve_decide_record_ring 59.3 ->
+111.3, serve_decide_batch 362.3 -> 570.1, serve_decide_batch_sharded
+366.1 -> 574.0, serve_decide_batch_group 361.4 -> 569.2,
+serve_decide_batch_record 363.7 -> 571.5,
+serve_decide_batch_record_ring 363.9 -> 571.8; the four engine
+programs did not move. (The decima/ppo programs
 carry a 4-lane batch in their audited shapes, and tile padding
 inflates narrow minor dims — these are model numbers for regression
 detection, not literal HBM footprints; the lane-fit table is the
@@ -128,23 +148,23 @@ MEM_BUDGETS: dict[str, MemBudget] = {
     "micro_step": MemBudget(temp_hi=22 * MB),
     "decide_micro_step": MemBudget(temp_hi=8 * MB),
     "drain_to_decision": MemBudget(temp_hi=14 * MB),
-    "decima_score": MemBudget(temp_hi=210 * MB),
-    "decima_batch_policy": MemBudget(temp_hi=230 * MB),
-    "ppo_update": MemBudget(temp_hi=365 * MB),
+    "decima_score": MemBudget(temp_hi=490 * MB),
+    "decima_batch_policy": MemBudget(temp_hi=510 * MB),
+    "ppo_update": MemBudget(temp_hi=627 * MB),
     # ISSUE 6: the single-eval batch collector the dp mesh shards,
     # audited at its native 4-lane batch (audit shapes are per-REPLICA:
     # under a dp mesh each device holds a 1/dp shard of every
     # lane-batched buffer, which is what the lane-fit advisor's `mesh`
     # mode models — these bytes bound the unsharded audit program)
-    "flat_collect_batch": MemBudget(temp_hi=445 * MB),
+    "flat_collect_batch": MemBudget(temp_hi=775 * MB),
     # ISSUE 9 `health:`-on variants (pinned 2026-08-03): the sentinels
     # are scalar reductions, so bytes barely move — ppo_update_health
     # 269.8 MB (vs 269.6 off), flat_collect_batch_health 330.6 MB (vs
     # 329.8). The byte budget pins that the sentinels stay reductions:
     # a health check that starts materializing per-lane tables would
     # breach this long before it OOMs a chip.
-    "ppo_update_health": MemBudget(temp_hi=365 * MB),
-    "flat_collect_batch_health": MemBudget(temp_hi=450 * MB),
+    "ppo_update_health": MemBudget(temp_hi=627 * MB),
+    "flat_collect_batch_health": MemBudget(temp_hi=776 * MB),
     # ISSUE 10 serving programs (pinned 2026-08-04): serve_decide
     # 59.0 MB, serve_decide_batch 325.5 MB at the audit store/batch
     # shapes. The byte budget is the serving-latency analog of the
@@ -152,15 +172,15 @@ MEM_BUDGETS: dict[str, MemBudget] = {
     # materializing store-sized temporaries (the donation exists so
     # steady-state decisions allocate nothing store-shaped) breaches
     # this band long before it shows up as a p99 regression on-chip.
-    "serve_decide": MemBudget(temp_hi=80 * MB),
-    "serve_decide_batch": MemBudget(temp_hi=440 * MB),
+    "serve_decide": MemBudget(temp_hi=150 * MB),
+    "serve_decide_batch": MemBudget(temp_hi=770 * MB),
     # ISSUE 13 sharded-store variant (pinned 2026-08-04): 329.3 MB vs
     # 325.5 unsharded — the sharding constraints add layout ops, not
     # buffers. The band pins that sharding the [C] axis never starts
     # materializing a gathered (unsharded) store copy: that would
     # roughly double the temp bytes and breach here on CPU before a
     # multi-chip window ever compiles it.
-    "serve_decide_batch_sharded": MemBudget(temp_hi=445 * MB),
+    "serve_decide_batch_sharded": MemBudget(temp_hi=775 * MB),
     # ISSUE 14 record-on serve variants (pinned 2026-08-04): 59.3 MB
     # / 326.7 MB vs 59.0 / 325.5 record-off — the StoredObs record is
     # a handful of [J,S] masks/counters per decision, ~0.4% bytes.
@@ -170,15 +190,15 @@ MEM_BUDGETS: dict[str, MemBudget] = {
     # unmasked [J,S,S] adjacency copy) breaches here first. The
     # record-off programs re-measured byte-identical in the same PR
     # (the hot-swap params-as-argument refactor moved no bytes).
-    "serve_decide_record": MemBudget(temp_hi=81 * MB),
-    "serve_decide_batch_record": MemBudget(temp_hi=442 * MB),
+    "serve_decide_record": MemBudget(temp_hi=151 * MB),
+    "serve_decide_batch_record": MemBudget(temp_hi=772 * MB),
     # ISSUE 15 group-shaped store program (pinned 2026-08-04):
     # 324.6 MB vs 325.5 at the full audit store — the temp bytes are
     # batch-axis-dominated (the width-K policy eval), so halving the
     # STORE axis moves almost nothing. The band pins that a grouped
     # lowering never starts materializing cross-group state (a
     # concatenated all-groups view would double here immediately).
-    "serve_decide_batch_group": MemBudget(temp_hi=440 * MB),
+    "serve_decide_batch_group": MemBudget(temp_hi=769 * MB),
     # ISSUE 18 ring-record serve variants (pinned 2026-08-07): 59.9 MB
     # / 327.3 MB vs 59.3 / 326.7 for the per-decision record programs —
     # the trajectory ring rides in the donated ARGS (one [R,...] RingRec
@@ -189,8 +209,8 @@ MEM_BUDGETS: dict[str, MemBudget] = {
     # [R,...] ring to stage the append (instead of scattering in place)
     # would add the full ring bytes here and breach on CPU before a
     # record-on serve deploy ever pages it.
-    "serve_decide_record_ring": MemBudget(temp_hi=82 * MB),
-    "serve_decide_batch_record_ring": MemBudget(temp_hi=443 * MB),
+    "serve_decide_record_ring": MemBudget(temp_hi=151 * MB),
+    "serve_decide_batch_record_ring": MemBudget(temp_hi=773 * MB),
 }
 
 # lane counts the advisor sweeps (the bench's production range; 1024

@@ -8,9 +8,9 @@ summaries and two autoregressive policy heads — but the ragged PyG graphs
 become fixed-shape [max_jobs, max_stages] arrays with masks:
 
 - the per-level masked sparse matmul (reference scheduler.py:219-232)
-  becomes a dense per-job `[S,S] @ [S,D]` einsum inside a `lax.scan` over
-  topological generations — batched matmuls that tile onto the MXU instead
-  of scatter/gather kernels;
+  becomes a dense per-job sum over the child axis (`[S,S] @ [S,D]`,
+  written as a select and a reduce) inside a `lax.scan` over topological
+  generations — fixed-shape array work instead of scatter/gather kernels;
 - the edge-mask batches the reference caches per observation
   (env_wrapper.py:145-162) are replaced by the env-maintained per-node
   `node_level` array, so no host-side graph analysis happens at all;
@@ -121,7 +121,7 @@ def build_features(
 #
 # The reference only ever embeds the arrived, incomplete jobs (its PyG
 # batch is built from live DAGs; scheduler.py:219-232), while the dense
-# padded port pays the full [J,S,S]@[S,D] level einsum over every padded
+# padded port pays the full [J,S,S]@[S,D] level sum over every padded
 # job slot. These helpers gather the <=K active jobs into a width-K view,
 # run the (shape-polymorphic) net at width K, and scatter the per-job
 # scores back to the padded [J] layout before masked softmax — cutting
@@ -182,6 +182,32 @@ def scatter_job_scores(
 # --------------------------------------------------------------------------
 
 
+def leaky_relu(slope: float) -> Callable:
+    """LeakyReLU as one maximum, `max(x, slope * x)`: the values of
+    `where(x >= 0, x, slope * x)` for a slope in [0, 1]. The TPU
+    compiler makes the compare-and-select form's predicate an array of
+    its own beside every Dense layer of the GNN (a fifth of a policy
+    evaluation on a v5e, PERF.md PR 33). The derivative is stated, and
+    is the select form's (1 at x >= 0, else the slope): a maximum's own
+    rule splits a tie between its arguments, which every zero-padded
+    node slot is, and keeps both of them for the backward pass."""
+    if not 0.0 <= slope <= 1.0:
+        raise ValueError(
+            f"LeakyReLU negative_slope {slope!r} is not in [0, 1]"
+        )
+
+    @jax.custom_jvp
+    def act(x):
+        return jnp.maximum(x, slope * x)
+
+    @act.defjvp
+    def _(primals, tangents):
+        (x,), (t,) = primals, tangents
+        return act(x), jnp.where(x >= 0, t, slope * t)
+
+    return act
+
+
 def make_act(name: str, kwargs: Any = None) -> Callable:
     """Activation factory (reference utils.make_mlp's act_cls lookup).
     `kwargs` may be a dict or the hashable tuple-of-pairs form flax module
@@ -191,8 +217,7 @@ def make_act(name: str, kwargs: Any = None) -> Callable:
     kwargs = kwargs or {}
     name = name.lower()
     if name in ("leakyrelu", "leaky_relu"):
-        slope = kwargs.get("negative_slope", 0.01)
-        return lambda x: jnp.where(x >= 0, x, slope * x)
+        return leaky_relu(kwargs.get("negative_slope", 0.01))
     if name == "tanh":
         return jnp.tanh
     if name == "relu":
@@ -246,11 +271,13 @@ class DecimaNet(nn.Module):
     # stay f32. "bfloat16" puts the matmuls on the MXU's native input
     # precision; scores are returned as f32 either way.
     compute_dtype: str | None = None
-    # upper bound on topological depth (0 = all s_cap levels). Levels
-    # >= the deepest active node are exact no-ops (the update mask is
-    # all-false), so bounding the scan by the workload bank's true max
-    # DAG depth (e.g. 6 for the synthetic TPC-H bank vs s_cap = 20) is
-    # bit-identical and cuts the GNN's dominant cost proportionally.
+    # upper bound on topological depth: the number of generations a DAG
+    # can have (0 = all s_cap). Levels past the deepest active node are
+    # exact no-ops (the update mask is all-false), so bounding the scan
+    # by the workload bank's true max DAG depth (5 for the synthetic
+    # TPC-H bank vs s_cap = 20) is bit-identical and cuts the
+    # GNN's dominant cost proportionally. The scan runs one step fewer
+    # than there are generations: the deepest holds no parent.
     # The reference gets this for free from its per-observation edge
     # mask list (scheduler.py:219-232 iterates only realized levels).
     num_levels: int = 0
@@ -285,9 +312,9 @@ class DecimaNet(nn.Module):
         x = f.x.astype(cdt) if cdt is not None else f.x
         s_cap = x.shape[-2]
         h_init = self.mlp_prep(x)
-        adj_f = f.adj.astype(h_init.dtype)
         has_child = f.adj.any(axis=-1)
         h0 = jnp.where(has_child[..., None], 0.0, self.mlp_update(h_init))
+        eye = jnp.eye(d, dtype=h_init.dtype)
 
         # one `nn.scan` step per topological generation, deepest first.
         # Weights are broadcast across levels (the reference reuses the
@@ -295,18 +322,35 @@ class DecimaNet(nn.Module):
         # instead of statically unrolling keeps the compiled program one
         # body regardless of s_cap — at the flagship 200-job scale the
         # unrolled chain dominated XLA compile time.
+        #
+        # The children's messages are summed by a select and a reduce
+        # over the child axis, not by the per-job `[S,S] @ [S,D]`
+        # product the sum is: the TPU compiler lays the Dense chain's
+        # arrays with the batch's lane axis minor-most, and a batched
+        # product of 25,600 tiny matrices wants the stage axis there, so
+        # it copied mlp_msg's output into that layout and the sums back
+        # out of it in every step (three quarters of a step on a v5e,
+        # PERF.md PR 33). The sum keeps a product's operand precision
+        # all the same: `msg @ eye` reads the messages as a product at
+        # the default precision reads an operand (rounded to bfloat16
+        # on a TPU, untouched on a CPU), so the scores differ from the
+        # product's by the order of the sums at most.
         def level_step(mdl, h_node, lvl):
-            agg = jnp.einsum(
-                "...pc,...cd->...pd", adj_f, mdl.mlp_msg(h_node)
-            )
+            msg = mdl.mlp_msg(h_node) @ eye
+            agg = jnp.where(
+                f.adj[..., None], msg[..., None, :, :], 0
+            ).sum(axis=-2, dtype=jnp.float32)
             upd = (f.node_level == lvl) & has_child
             h_node = jnp.where(
                 upd[..., None], h_init + mdl.mlp_update(agg), h_node
             )
             return h_node, None
 
+        # a node of the deepest generation has no child (`node_level` is
+        # the longest path from a source), so the scan starts one
+        # generation above it: nl - 1 steps, none for a bank of sources
         nl = min(self.num_levels, s_cap) if self.num_levels else s_cap
-        levels = jnp.arange(nl - 1, -1, -1, dtype=_i32)
+        levels = jnp.arange(nl - 2, -1, -1, dtype=_i32)
         with annotate("decima/gnn/levels"):
             h_node, _ = nn.scan(
                 level_step,
@@ -665,8 +709,8 @@ class DecimaScheduler(TrainableScheduler):
     # -- flat micro-step engine adapter ------------------------------------
     def flat_policy(self, params=None, deterministic: bool = False):
         """Bind this scheduler into a `policy_fn(rng, obs)` for the flat
-        micro-step engine (`env/flat_loop.py`): the dense per-job einsum
-        GNN runs on the DECIDE branch's padded observation inside the
+        micro-step engine (`env/flat_loop.py`): the dense per-job GNN
+        runs on the DECIDE branch's padded observation inside the
         micro-step scan, and the aux dict carries the log-prob/action
         decomposition the trajectory recorder stores. Pass explicit
         `params` (e.g. the live training parameters) to keep the returned

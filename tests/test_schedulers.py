@@ -280,47 +280,6 @@ def test_decima_forward_matches_numpy_replica():
     )
 
 
-def test_decima_depth_bounded_levels_bit_identical():
-    """A `num_levels` bound at the workload bank's true max DAG depth
-    must be bit-identical to scanning all s_cap levels (the skipped
-    levels' update masks are all-false) — the trainer wires this bound
-    automatically from bank.node_level."""
-    import jax
-    import numpy as np_
-
-    from sparksched_tpu.config import EnvParams
-    from sparksched_tpu.env import core
-    from sparksched_tpu.env.observe import observe
-    from sparksched_tpu.schedulers.decima import (
-        DecimaScheduler,
-        build_features,
-    )
-    from sparksched_tpu.workload import make_workload_bank
-
-    params = EnvParams(num_executors=6, max_jobs=6)
-    bank = make_workload_bank(6, params.max_stages)
-    params = params.replace(
-        max_stages=bank.max_stages, max_levels=bank.max_stages
-    )
-    nl = np_.asarray(bank.node_level)
-    depth = int(np_.max(np_.where(nl < bank.max_stages, nl, -1))) + 1
-    assert 0 < depth < bank.max_stages  # the bound actually bites
-
-    full = DecimaScheduler(num_executors=6)
-    bounded = DecimaScheduler(num_executors=6, num_levels=depth)
-    st = core.reset(params, bank, jax.random.PRNGKey(3))
-    for _ in range(15):
-        obs = observe(params, st)
-        flat = np_.flatnonzero(np_.asarray(obs.schedulable).reshape(-1))
-        si = int(flat[0]) if flat.size else -1
-        st, _, _, _ = core.step(params, bank, st, si, 2)
-    f = build_features(observe(params, st), 6)
-    sa, ea = full.net.apply(full.params, f)
-    sb, eb = bounded.net.apply(bounded.params, f)
-    np_.testing.assert_array_equal(np_.asarray(sa), np_.asarray(sb))
-    np_.testing.assert_array_equal(np_.asarray(ea), np_.asarray(eb))
-
-
 def test_decima_no_edges_fast_path():
     """With zero active edges anywhere, h_node must equal mlp_prep(x)
     (reference scheduler.py:236-241), not mlp_update(mlp_prep(x))."""
@@ -867,3 +826,577 @@ def test_decima_bf16_compute_close_to_f32():
     np.testing.assert_allclose(
         np.asarray(e16), np.asarray(e32), rtol=0.05, atol=0.05
     )
+
+
+# ---------------------------------------------------------------------------
+# PR 33: the level scan at the job widths and batchings its callers use
+# ---------------------------------------------------------------------------
+
+_NET_KW = dict(
+    num_executors=5, embed_dim=8, gnn_hid=(12, 8), policy_hid=(16, 16),
+    gnn_act_kwargs=(("negative_slope", 0.2),),
+)
+
+
+def _random_decima_features(rng, lead, j_cap, s_cap=8, num_exec=5,
+                            depth=4, live=0.8):
+    """Random padded model inputs: per live job a DAG of 2..s_cap nodes
+    in contiguous slots whose longest path from a root is its
+    `node_level` (every node below the roots has a parent one level
+    up, and extra parents further up), at most `depth` generations."""
+    import jax.numpy as jnp
+
+    from sparksched_tpu.schedulers.decima import (
+        NUM_NODE_FEATURES,
+        DecimaFeatures,
+    )
+
+    shape = (*lead, j_cap, s_cap)
+    n_nodes = rng.integers(2, s_cap + 1, size=shape[:-1])
+    job_mask = rng.random(shape[:-1]) < live
+    job_mask[..., 0] = True
+    node_mask = (np.arange(s_cap) < n_nodes[..., None]) & job_mask[..., None]
+    level = np.full(shape, s_cap, np.int32)
+    adj = np.zeros((*shape, s_cap), bool)
+    for idx in np.ndindex(*shape[:-1]):
+        n = int(node_mask[idx].sum())
+        if not n:
+            continue
+        lv = np.sort(rng.integers(0, depth, size=n))
+        lv[0] = 0
+        lv = np.unique(lv, return_inverse=True)[1]  # contiguous from 0
+        level[idx][:n] = lv
+        for c in range(n):
+            if lv[c] == 0:
+                continue
+            adj[idx][rng.choice(np.flatnonzero(lv == lv[c] - 1)), c] = True
+            extra = np.flatnonzero((lv < lv[c]) & (rng.random(n) < 0.25))
+            adj[idx][extra, c] = True
+    x = rng.normal(size=(*shape, NUM_NODE_FEATURES)).astype(np.float32)
+    x[..., :3] = x[..., :1, :3]  # features 0..2 are per-job constants
+    x = np.where(node_mask[..., None], x, 0.0).astype(np.float32)
+    return DecimaFeatures(
+        x=jnp.asarray(x),
+        node_mask=jnp.asarray(node_mask),
+        job_mask=jnp.asarray(job_mask),
+        stage_mask=jnp.asarray(node_mask),
+        exec_mask=jnp.asarray(
+            np.broadcast_to(job_mask[..., None], (*shape[:-1], num_exec))
+        ),
+        adj=jnp.asarray(adj),
+        node_level=jnp.asarray(level),
+    )
+
+
+def _perturbed_params(net, feats, seed=0):
+    """Initial parameters with every leaf moved off its start (biases
+    start at zero, which would hide a bias laid out wrongly)."""
+    import jax
+
+    params = net.init(jax.random.PRNGKey(seed), feats)
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
+    return jax.tree_util.tree_unflatten(
+        tree,
+        [a + 0.1 * jax.random.normal(k, a.shape) for a, k in zip(leaves, keys)],
+    )
+
+
+def _item(feats, idx):
+    import jax
+
+    return jax.tree_util.tree_map(lambda a: np.asarray(a)[idx], feats)
+
+
+def _check_against_references(params, feats, stage, execs, replica_items=1):
+    """`stage`/`execs` (any leading axes) against the benchmark's plain
+    forward pass on every item, and against this file's compact replica
+    on the first `replica_items` of them."""
+    import jax
+
+    from benchmarks.reference import decima_np
+
+    np_params = jax.tree_util.tree_map(np.asarray, params)
+    lead = np.shape(feats.job_mask)[:-1]
+    for n, idx in enumerate(np.ndindex(*lead)):
+        f = _item(feats, idx)
+        nm, jm = f.node_mask, f.job_mask
+        got_s, got_e = np.asarray(stage)[idx], np.asarray(execs)[idx]
+        ref_s, ref_e = decima_np.forward(
+            np_params,
+            {k: getattr(f, k) for k in
+             ("x", "node_mask", "job_mask", "stage_mask", "exec_mask", "adj")},
+            _NET_KW["num_executors"], gnn_slope=0.2,
+        )
+        np.testing.assert_allclose(got_s[nm], ref_s[nm], rtol=1e-4, atol=2e-5)
+        np.testing.assert_allclose(got_e[jm], ref_e[jm], rtol=1e-4, atol=2e-5)
+        if n >= replica_items:
+            continue
+        jobs = np.flatnonzero(jm)
+        counts = [int(nm[j].sum()) for j in jobs]
+        ptr = np.concatenate([[0], np.cumsum(counts)])
+        edges = [
+            (int(ptr[i] + p), int(ptr[i] + c))
+            for i, j in enumerate(jobs)
+            for p, c in zip(*np.nonzero(f.adj[j]))
+        ]
+        rep_s, rep_e = _np_decima_forward(
+            np_params,
+            np.concatenate([f.x[j, :c] for j, c in zip(jobs, counts)]),
+            edges, counts, _NET_KW["num_executors"], _NET_KW["embed_dim"],
+        )
+        np.testing.assert_allclose(
+            np.concatenate([got_s[j, :c] for j, c in zip(jobs, counts)]),
+            rep_s, rtol=1e-4, atol=2e-5,
+        )
+        np.testing.assert_allclose(got_e[jobs], rep_e, rtol=1e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("batching", ["unbatched", "lead1", "lead2", "vmap"])
+@pytest.mark.parametrize("j_cap", [6, 8, 32, 200])
+def test_decima_net_matches_references(j_cap, batching):
+    """Stage and exec scores of the net against both plain references
+    at the job widths the level scan lays out differently, through
+    every way a caller batches it."""
+    import jax
+
+    from sparksched_tpu.schedulers.decima import DecimaNet
+
+    lead = {"unbatched": (), "lead1": (3,), "lead2": (2, 2), "vmap": (3,)}
+    rng = np.random.default_rng(j_cap)
+    feats = _random_decima_features(rng, lead[batching], j_cap)
+    net = DecimaNet(**_NET_KW)
+    params = _perturbed_params(net, _item(feats, (0,) * len(lead[batching])))
+    if batching == "vmap":
+        stage, execs = jax.vmap(lambda f: net.apply(params, f))(feats)
+    else:
+        stage, execs = net.apply(params, feats)
+    assert stage.shape == feats.node_mask.shape
+    assert execs.shape == feats.exec_mask.shape
+    _check_against_references(params, feats, stage, execs)
+
+
+def test_decima_edgeless_item_in_a_batch_of_edged_ones():
+    """An observation without an edge takes upstream's plain-prep path
+    (scheduler.py:236-241) by ITSELF: batched with edged observations
+    it scores as it does alone, and as the references say."""
+    import jax.numpy as jnp
+
+    from sparksched_tpu.schedulers.decima import DecimaNet
+
+    feats = _random_decima_features(np.random.default_rng(5), (3,), 8)
+    feats = feats.replace(
+        adj=feats.adj.at[1].set(False),
+        node_level=feats.node_level.at[1].set(
+            jnp.where(feats.node_mask[1], 0, feats.node_level[1])
+        ),
+    )
+    net = DecimaNet(**_NET_KW)
+    params = _perturbed_params(net, _item(feats, (0,)))
+    stage, execs = net.apply(params, feats)
+    _check_against_references(params, feats, stage, execs, replica_items=3)
+    for i in range(3):
+        s1, e1 = net.apply(params, _item(feats, (i,)))
+        np.testing.assert_allclose(stage[i], s1, rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(execs[i], e1, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("j_cap,bucket", [(12, 4), (16, 8), (200, 32)])
+def test_decima_compact_branch_matches_full(j_cap, bucket):
+    """`score` at the bucket's width (the compact branch: every item
+    holds at most `bucket` live jobs) against the net at the full
+    width, on the live jobs."""
+    from sparksched_tpu.schedulers.decima import DecimaScheduler
+
+    feats = _random_decima_features(
+        np.random.default_rng(bucket), (3,), j_cap, live=0.6 * bucket / j_cap
+    )
+    assert 1 <= int(np.asarray(feats.job_mask).sum(-1).max()) <= bucket
+    sched = DecimaScheduler(num_executors=5, seed=3, job_bucket=bucket)
+    params = _perturbed_params(sched.net, _item(feats, (0,)))
+    assert not bool(sched.full_width(feats))
+    full_s, full_e = sched.net.apply(params, feats)
+    comp_s, comp_e = sched.score(params, feats)
+    nm, jm = np.asarray(feats.node_mask), np.asarray(feats.job_mask)
+    np.testing.assert_allclose(
+        np.asarray(comp_s)[nm], np.asarray(full_s)[nm], rtol=2e-5, atol=2e-6
+    )
+    np.testing.assert_allclose(
+        np.asarray(comp_e)[jm], np.asarray(full_e)[jm], rtol=2e-5, atol=2e-6
+    )
+
+
+def _plain_decima_forward(net, params, f):
+    """The net as the paper states it, on the padded arrays as they
+    come: plain Dense layers over [J,S,D], the children's messages
+    summed by a per-job matrix product, every one of the S levels
+    visited. What the net's own layout must not change."""
+    import jax.numpy as jnp
+
+    slope = dict(net.gnn_act_kwargs)["negative_slope"]
+    assert (net.gnn_act, net.policy_act) == ("LeakyReLU", "Tanh")
+
+    def g_act(v):
+        return jnp.where(v >= 0, v, slope * v)
+
+    p_act = jnp.tanh
+    w = params["params"]
+
+    def mlp(name, v, act):
+        n = len(w[name])
+        for i in range(n):
+            d = w[name][f"dense_{i}"]
+            v = v @ d["kernel"] + d["bias"]
+            if i < n - 1:
+                v = act(v)
+        return v
+
+    x, s_cap = f.x, f.x.shape[-2]
+    h_init = mlp("mlp_prep", x, g_act)
+    has_child = f.adj.any(axis=-1)
+    h = jnp.where(has_child[..., None], 0.0, mlp("mlp_update", h_init, g_act))
+    for lvl in range(s_cap - 1, -1, -1):
+        agg = jnp.einsum(
+            "...pc,...cd->...pd", f.adj.astype(x.dtype),
+            mlp("mlp_msg", h, g_act),
+        )
+        upd = (f.node_level == lvl) & has_child
+        h = jnp.where(
+            upd[..., None], h_init + mlp("mlp_update", agg, g_act), h
+        )
+    edgeless = ~f.adj.any(axis=(-3, -2, -1))
+    h = jnp.where(edgeless[..., None, None, None], h_init, h)
+    h = jnp.where(f.node_mask[..., None], h, 0.0)
+    z = mlp("mlp_dag", jnp.concatenate([x, h], axis=-1), g_act)
+    h_dag = jnp.where(f.node_mask[..., None], z, 0.0).sum(axis=-2)
+    zg = mlp("mlp_glob", h_dag, g_act)
+    h_glob = jnp.where(f.job_mask[..., None], zg, 0.0).sum(axis=-2)
+    d = h_dag.shape[-1]
+    rpt = (*x.shape[:-1], d)
+    stage = mlp("mlp_stage", jnp.concatenate([
+        x, h, jnp.broadcast_to(h_dag[..., :, None, :], rpt),
+        jnp.broadcast_to(h_glob[..., None, None, :], rpt),
+    ], axis=-1), p_act)[..., 0]
+    first = jnp.argmax(f.node_mask, axis=-1)
+    x_dag = jnp.take_along_axis(x, first[..., None, None], axis=-2)[..., 0, :3]
+    n = net.num_executors
+    per_job = jnp.concatenate([x_dag, h_dag], axis=-1)
+    shape = (*per_job.shape[:-1], n)
+    execs = mlp("mlp_exec", jnp.concatenate([
+        jnp.broadcast_to(per_job[..., :, None, :], (*shape, per_job.shape[-1])),
+        jnp.broadcast_to(h_glob[..., None, None, :], (*shape, d)),
+        jnp.broadcast_to((jnp.arange(n) / n)[:, None].astype(x.dtype),
+                         (*shape, 1)),
+    ], axis=-1), p_act)[..., 0]
+    return stage, execs
+
+
+def _scan_lengths(fn, *args):
+    """The `length` of every `scan` in the jaxpr of `fn(*args)`."""
+    import jax
+
+    from sparksched_tpu.analysis.jaxpr_audit import iter_eqns
+
+    return [
+        e.params["length"]
+        for e in iter_eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+        if e.primitive.name == "scan"
+    ]
+
+
+@pytest.mark.parametrize("depth", [0, 1, 2, 3, 4, 5, 6])
+def test_decima_depth_bounded_levels_bit_identical(depth):
+    """A `num_levels` bound at the DAGs' true depth is bit-identical to
+    the unbounded scan, and both to a scan that also visits the deepest
+    generation (whose nodes have no child to hear from): the scan runs
+    `depth - 1` steps, none at all for DAGs of single nodes, and
+    `s_cap - 1` where no bound is given (`depth` 0)."""
+    from sparksched_tpu.schedulers.decima import DecimaNet
+
+    s_cap = 8
+    feats = _random_decima_features(
+        np.random.default_rng(depth), (2,), 8, s_cap=s_cap,
+        depth=depth or 6,
+    )
+    deepest = int(np.asarray(feats.node_level)[
+        np.asarray(feats.node_mask)].max()) + 1
+    assert deepest <= (depth or 6)
+    assert bool(np.asarray(feats.adj).any()) == (deepest > 1)
+    bounded, free = DecimaNet(**_NET_KW, num_levels=depth), DecimaNet(**_NET_KW)
+    params = _perturbed_params(free, _item(feats, (0,)))
+    sa, ea = free.apply(params, feats)
+    sb, eb = bounded.apply(params, feats)
+    np.testing.assert_array_equal(np.asarray(sa), np.asarray(sb))
+    np.testing.assert_array_equal(np.asarray(ea), np.asarray(eb))
+    # every one of the s_cap levels visited, the plain way
+    sp, ep = _plain_decima_forward(free, params, feats)
+    np.testing.assert_allclose(sa, sp, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(ea, ep, rtol=1e-5, atol=1e-6)
+    assert _scan_lengths(
+        lambda p, f: bounded.apply(p, f), params, feats
+    ) == [(depth or s_cap) - 1]
+
+
+def test_decima_depth_bound_from_bank_bit_identical():
+    """The bound the trainer takes from `bank.node_level` (the bank's
+    true max DAG depth) changes no score of a rolled-out episode."""
+    import jax
+    import numpy as np_
+
+    from sparksched_tpu.config import EnvParams
+    from sparksched_tpu.env import core
+    from sparksched_tpu.env.observe import observe
+    from sparksched_tpu.schedulers.decima import (
+        DecimaScheduler,
+        build_features,
+    )
+    from sparksched_tpu.workload import make_workload_bank
+
+    params = EnvParams(num_executors=6, max_jobs=6)
+    bank = make_workload_bank(6, params.max_stages)
+    params = params.replace(
+        max_stages=bank.max_stages, max_levels=bank.max_stages
+    )
+    nl = np_.asarray(bank.node_level)
+    depth = int(np_.max(np_.where(nl < bank.max_stages, nl, -1))) + 1
+    assert 0 < depth < bank.max_stages  # the bound actually bites
+
+    full = DecimaScheduler(num_executors=6)
+    bounded = DecimaScheduler(num_executors=6, num_levels=depth)
+    st = core.reset(params, bank, jax.random.PRNGKey(3))
+    for _ in range(15):
+        obs = observe(params, st)
+        flat = np_.flatnonzero(np_.asarray(obs.schedulable).reshape(-1))
+        si = int(flat[0]) if flat.size else -1
+        st, _, _, _ = core.step(params, bank, st, si, 2)
+    f = build_features(observe(params, st), 6)
+    sa, ea = full.net.apply(full.params, f)
+    sb, eb = bounded.net.apply(bounded.params, f)
+    np_.testing.assert_array_equal(np_.asarray(sa), np_.asarray(sb))
+    np_.testing.assert_array_equal(np_.asarray(ea), np_.asarray(eb))
+    assert _scan_lengths(
+        lambda p, ff: bounded.net.apply(p, ff), bounded.params, f
+    ) == [depth - 1]
+
+
+@pytest.mark.parametrize("j_cap", [6, 8])
+def test_decima_evaluate_actions_grad_matches_plain_forward(j_cap):
+    """The update's gradient: `jax.grad` of a loss over
+    `DecimaScheduler.evaluate_actions` with respect to every parameter,
+    against the same loss through the plain forward pass above."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparksched_tpu.schedulers import decima
+    from sparksched_tpu.schedulers.decima import DecimaScheduler
+
+    rng = np.random.default_rng(j_cap)
+    feats = _random_decima_features(rng, (3,), j_cap, s_cap=5, depth=3)
+    sched = DecimaScheduler(
+        num_executors=5, embed_dim=8,
+        gnn_mlp_kwargs={"hid_dims": [12, 8],
+                        "act_kwargs": {"negative_slope": 0.2}},
+        policy_mlp_kwargs={"hid_dims": [16, 16]},
+    )
+    params = _perturbed_params(sched.net, _item(feats, (0,)))
+    nm = np.asarray(feats.node_mask)
+    s_cap = nm.shape[-1]
+    stage_idx = np.array([
+        rng.choice(np.flatnonzero(nm[i].reshape(-1))) for i in range(3)
+    ])
+    actions = decima.DecimaAction(
+        stage_idx=jnp.asarray(stage_idx, jnp.int32),
+        job_idx=jnp.asarray(stage_idx // s_cap, jnp.int32),
+        num_exec=jnp.asarray(rng.integers(0, 5, size=3), jnp.int32),
+    )
+
+    def loss_of(lgprob, ent):
+        return -(lgprob.sum() + 0.1 * ent.sum())
+
+    def loss_net(p):
+        return loss_of(*sched.evaluate_actions(p, feats, actions))
+
+    def loss_plain(p):
+        def one(f, a):
+            s, e = _plain_decima_forward(sched.net, p, f)
+            return decima.evaluate_actions(s, e, f, a, sched.num_executors)
+
+        return loss_of(*jax.vmap(one)(feats, actions))
+
+    np.testing.assert_allclose(loss_net(params), loss_plain(params), rtol=1e-5)
+    g_net = jax.tree_util.tree_leaves_with_path(jax.grad(loss_net)(params))
+    g_plain = jax.tree_util.tree_leaves(jax.grad(loss_plain)(params))
+    assert len(g_net) == 42
+    for (path, a), b in zip(g_net, g_plain):
+        # every parameter is reached (but the heads' last biases: a
+        # softmax does not see a shift of all its scores)
+        assert float(jnp.abs(b).max()) > 0 or b.shape == (1,), path
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=1e-5, err_msg=jax.tree_util.keystr(path)
+        )
+
+
+# ---------------------------------------------------------------------------
+# PR 33: the parameter tree is what every stored checkpoint holds
+# ---------------------------------------------------------------------------
+
+_GNN = [(16, 32), (32, 16), (16, 16)]
+_DECIMA_PARAM_SHAPES = {
+    f"{mlp}/dense_{i}/{leaf}": shape if leaf == "kernel" else shape[1:]
+    for mlp, layers in {
+        "mlp_prep": [(5, 32)] + _GNN[1:],
+        "mlp_msg": _GNN,
+        "mlp_update": _GNN,
+        "mlp_dag": [(21, 32)] + _GNN[1:],
+        "mlp_glob": _GNN,
+        "mlp_stage": [(53, 64), (64, 64), (64, 1)],
+        "mlp_exec": [(36, 64), (64, 64), (64, 1)],
+    }.items()
+    for i, shape in enumerate(layers)
+    for leaf in ("kernel", "bias")
+}
+
+
+def _param_shapes(params) -> dict:
+    import jax
+
+    return {
+        "/".join(k.key for k in path[1:]): tuple(np.shape(leaf))
+        for path, leaf in jax.tree_util.tree_leaves_with_path(params)
+    }
+
+
+def _repo_file(*parts: str) -> str:
+    import os.path as osp
+
+    return osp.join(osp.dirname(osp.dirname(osp.abspath(__file__))), *parts)
+
+
+def _checkpoint_dirs() -> list[str]:
+    import glob
+    import os.path as osp
+
+    return ["models/decima"] + sorted(
+        osp.relpath(d, _repo_file())
+        for d in glob.glob(_repo_file("artifacts", "decima_*"))
+        if glob.glob(osp.join(d, "checkpoints", "*", "model.msgpack"))
+    )
+
+
+@pytest.fixture(scope="module")
+def flagship_decima():
+    from sparksched_tpu.schedulers.decima import DecimaScheduler
+
+    return DecimaScheduler(
+        num_executors=50,
+        gnn_mlp_kwargs={"act_kwargs": {"negative_slope": 0.2}},
+    )
+
+
+def test_decima_param_tree_is_the_checkpoints(flagship_decima):
+    assert _param_shapes(flagship_decima.params) == _DECIMA_PARAM_SHAPES
+    assert len(_DECIMA_PARAM_SHAPES) == 42
+
+
+@pytest.mark.parametrize("ckpt_dir", _checkpoint_dirs())
+def test_decima_checkpoints_load(ckpt_dir, flagship_decima):
+    """Every stored model under `ckpt_dir` (and the directory's train
+    state) restores into the net's parameter tree with the paths and
+    shapes written above, and the net scores with what it loaded."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from flax import serialization
+
+    from sparksched_tpu.schedulers.decima import DecimaScheduler
+
+    files = sorted(
+        glob.glob(_repo_file(ckpt_dir, "*.msgpack"))
+        + glob.glob(_repo_file(ckpt_dir, "checkpoints", "*", "model.msgpack"))
+    )
+    models = [f for f in files if not f.endswith("train_state.msgpack")]
+    assert models
+    template = flagship_decima.params
+    for path in files:
+        with open(path, "rb") as fp:
+            raw = fp.read()
+        if path.endswith("train_state.msgpack"):
+            loaded = serialization.from_state_dict(
+                template, serialization.msgpack_restore(raw)["params"]
+            )
+        else:
+            loaded = serialization.from_bytes(template, raw)
+        assert _param_shapes(loaded) == _DECIMA_PARAM_SHAPES, path
+    # the constructor's own loading path, and a forward pass with it
+    sched = DecimaScheduler(num_executors=50, state_dict_path=models[-1])
+    with open(models[-1], "rb") as fp:
+        direct = serialization.from_bytes(template, fp.read())
+    assert _param_shapes(sched.params) == _DECIMA_PARAM_SHAPES
+    for a, b in zip(jax.tree_util.tree_leaves(sched.params),
+                    jax.tree_util.tree_leaves(direct)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    feats = _random_decima_features(
+        np.random.default_rng(0), (), 8, num_exec=50
+    )
+    stage, execs = sched.net.apply(sched.params, feats)
+    assert bool(jnp.isfinite(stage).all()) and bool(jnp.isfinite(execs).all())
+
+
+def test_decima_torch_checkpoint_loads(tmp_path):
+    """A `.pt` state dict under the reference's names (Linear layers at
+    the even indices of each `Sequential`) converts into the same
+    tree: `load_torch_state_dict` as it was."""
+    torch = pytest.importorskip("torch")
+
+    from sparksched_tpu.schedulers.decima import (
+        _TORCH_TO_FLAX,
+        DecimaScheduler,
+    )
+
+    rng = np.random.default_rng(0)
+    sd = {}
+    for tname, fname in _TORCH_TO_FLAX.items():
+        for i in range(3):
+            k_in, k_out = _DECIMA_PARAM_SHAPES[f"{fname}/dense_{i}/kernel"]
+            sd[f"{tname}.{2 * i}.weight"] = torch.tensor(
+                rng.normal(size=(k_out, k_in)).astype(np.float32))
+            sd[f"{tname}.{2 * i}.bias"] = torch.tensor(
+                rng.normal(size=(k_out,)).astype(np.float32))
+    path = str(tmp_path / "model.pt")
+    torch.save(sd, path)
+    sched = DecimaScheduler(num_executors=50, state_dict_path=path)
+    assert _param_shapes(sched.params) == _DECIMA_PARAM_SHAPES
+    for tname, fname in _TORCH_TO_FLAX.items():
+        for i in range(3):
+            got = sched.params["params"][fname][f"dense_{i}"]
+            for leaf, want in (
+                ("kernel", sd[f"{tname}.{2 * i}.weight"].numpy().T),
+                ("bias", sd[f"{tname}.{2 * i}.bias"].numpy()),
+            ):
+                np.testing.assert_array_equal(np.asarray(got[leaf]), want)
+
+
+def test_leaky_relu_is_the_select_form():
+    """`leaky_relu`: the values of `where(x >= 0, x, slope * x)`, its
+    derivative (1 at a tie, as every zero-padded slot is), and a slope
+    a maximum cannot express refused."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparksched_tpu.schedulers.decima import leaky_relu
+
+    x = jnp.asarray([-3.0, -1e-30, -0.0, 0.0, 1e-30, 2.5, 3e38, -3e38])
+    for slope in (0.0, 0.01, 0.2, 1.0):
+        act = leaky_relu(slope)
+        np.testing.assert_array_equal(
+            np.asarray(act(x)), np.asarray(jnp.where(x >= 0, x, slope * x))
+        )
+        np.testing.assert_array_equal(
+            np.asarray(jax.vmap(jax.grad(act))(x)),
+            np.asarray(jnp.where(x >= 0, 1.0, slope)),
+        )
+    with pytest.raises(ValueError, match="negative_slope"):
+        leaky_relu(1.5)
+    with pytest.raises(ValueError, match="negative_slope"):
+        leaky_relu(-0.1)

@@ -145,6 +145,55 @@ def test_collect_and_ppo_update_compile(flagship, one_chip):
           temp_gib=5.0)
 
 
+def test_level_scan_body_has_one_layout(flagship, one_chip):
+    """What takes a counter's place for the level scan (PR 33): in the
+    net compiled for the v5e at the benchmark's 128 lanes x 200 jobs,
+    every node-sized array of the scan's body lies with the 128-lane
+    batch axis minor-most (whole tiles, nothing padded), and the body
+    copies none of them into another layout. The per-job
+    `[S,S] @ [S,D]` product the children's sum used to be wanted the
+    stage axis there: a copy in and a copy out, every step."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from sparksched_tpu.schedulers.decima import DecimaFeatures
+
+    lanes, (j, s, n) = 128, (200, 20, 50)
+    net = flagship.scheduler.net
+    assert (net.num_levels, flagship.params_env.max_jobs) == (5, j)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct((lanes, *shape), dtype, sharding=one_chip)
+
+    feats = DecimaFeatures(
+        x=on_chip((j, s, 5), jnp.float32),
+        node_mask=on_chip((j, s), jnp.bool_),
+        job_mask=on_chip((j,), jnp.bool_),
+        stage_mask=on_chip((j, s), jnp.bool_),
+        exec_mask=on_chip((j, n), jnp.bool_),
+        adj=on_chip((j, s, s), jnp.bool_),
+        node_level=on_chip((j, s), jnp.int32),
+    )
+    params = _on(one_chip, jax.eval_shape(flagship.init_state).params)
+    compiled = jax.jit(net.apply).lower(params, feats).compile()
+    # the select feeding the sum is fused into the reduce: alone its
+    # [128,200,20,20,16] operand would be 0.6 GiB (the net's whole
+    # temporaries: 0.16 GiB, where the per-job product's were 0.31)
+    _fits(compiled, temp_gib=0.25)
+    text = compiled.as_text()
+    (body_name,) = set(re.findall(r"body=%([\w.\-]+)", text))
+    body = text[text.index(f"\n%{body_name} ("):]
+    body = body[:body.index("\n}\n")]
+    node_sized = re.findall(
+        rf"= \w+\[{lanes},{j},{s},\d+\](\{{[\d,]*)[^ ]* ([\w\-]+)\(", body
+    )
+    assert len(node_sized) >= 8, body  # six Dense layers, the sum, the select
+    assert {layout for layout, _ in node_sized} == {"{0,3,2,1"}, node_sized
+    assert not {op for _, op in node_sized} & {"copy", "transpose"}, node_sized
+
+
 @pytest.mark.parametrize("batched", [False, True])
 def test_serve_programs_compile(flagship, one_chip, batched):
     """`serve_decide` and `serve_decide_batch` as `SessionStore` builds
