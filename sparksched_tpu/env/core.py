@@ -1504,7 +1504,13 @@ def _steps_while_active(step_fn, carry0, us, lane_axis=None):
 
     Steps run in granules of `_BULK_STEP_GRANULE`; a granule's steps
     past the last row are gated off through `in_budget` (the row index
-    is clamped by the slice, and nothing reads the row)."""
+    is clamped by the slice, and nothing reads the row).
+
+    Returns the carry and the number of times the loop's predicate was
+    evaluated (its iterations and the one that ended it): with
+    `lane_axis` each is one reduction over the lanes, across the chips
+    of a dp mesh an all-reduce (`Telemetry.lane_syncs`); without it
+    the count is the lane's own and counts no reduction."""
     length = us.shape[0]
 
     def cond(c):
@@ -1521,7 +1527,8 @@ def _steps_while_active(step_fn, carry0, us, lane_axis=None):
             carry = step_fn(carry, u_row, i + j < length)
         return i + _BULK_STEP_GRANULE, carry
 
-    return lax.while_loop(cond, body, (_i32(0), carry0))[1]
+    i, carry = lax.while_loop(cond, body, (_i32(0), carry0))
+    return carry, i // _BULK_STEP_GRANULE + 1
 
 
 def _bulk_events_fused(
@@ -1532,11 +1539,14 @@ def _bulk_events_fused(
     """Consume one maximal run of *simple* events — task relaunches AND
     executor arrivals, interleaved in exact (time, seq) order — in a
     SINGLE bounded early-exit loop. Returns (state, k_rel, k_rdy,
-    steps): events consumed by kind (both 0 when the next event is not
-    simple, the queue is drained, or `enabled` is False) and the steps
-    of the loop this lane needed (those it entered active: one per
-    event taken, plus the step that saw the run end unless a joining
-    arrival or the budget ended it; 0 when not `enabled`).
+    steps, syncs): events consumed by kind (both 0 when the next event
+    is not simple, the queue is drained, or `enabled` is False), the
+    steps of the loop this lane needed (those it entered active: one
+    per event taken, plus the step that saw the run end unless a
+    joining arrival or the budget ended it; 0 when not `enabled`) and
+    the reductions over `lane_axis` the loop made for the batch (the
+    evaluations of its predicate, the same in every lane; 0 without
+    `lane_axis`).
 
     This fuses `_bulk_relaunch` + `_bulk_ready` into one kernel (ISSUE
     7): instead of a fixed relaunch-pass / arrival-pass order — which
@@ -1733,9 +1743,12 @@ def _bulk_events_fused(
         jnp.asarray(enabled, bool),
     )
     (t_f, sq_f, t_a, _, _, rem, _, launch_t, dur_js, relc, arr_done,
-     started, counter, wall, _, steps, _) = _steps_while_active(
+     started, counter, wall, _, steps, _), evals = _steps_while_active(
         step_fn, carry0, us, lane_axis
     )
+    # the loop's predicate is a reduction over the lanes only where the
+    # caller named its lane axis
+    syncs = evals if lane_axis is not None else _i32(0)
 
     k_rel = relc.sum()
     k_rdy = arr_done.sum().astype(_i32)
@@ -1801,7 +1814,7 @@ def _bulk_events_fused(
         stage_sat=jnp.where(touched, sat_new, state.stage_sat),
         unsat_parent_count=unsat,
     )
-    return state, k_rel, k_rdy, steps
+    return state, k_rel, k_rdy, steps, syncs
 
 
 def _resume_simulation(

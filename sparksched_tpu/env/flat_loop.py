@@ -245,14 +245,17 @@ def _bulk_cycle_chain(
     inside the episode limit (the freeze point) — so chaining is
     exactly the next micro-step's bulk phase minus its provably-no-op
     tail. Returns (env, events_consumed, relaunch_events, ready_events,
-    scan_steps) — relaunch and ready split the count by event kind and
-    `scan_steps` is the steps the fused passes' loops needed for this
-    lane (0 from the unfused pair), all for the telemetry counters.
-    `lane_axis` is handed to the fused pass."""
+    scan_steps, lane_syncs) — relaunch and ready split the count by
+    event kind, `scan_steps` is the steps the fused passes' loops
+    needed for this lane and `lane_syncs` the reductions over
+    `lane_axis` they made for the batch (both 0 from the unfused pair),
+    all for the telemetry counters. `lane_axis` is handed to the fused
+    pass."""
     nb = _i32(0)
     nb_rel = _i32(0)
     nb_rdy = _i32(0)
     steps = _i32(0)
+    syncs = _i32(0)
     for i in range(bulk_cycles):
         on = is_event if i == 0 else (
             is_event
@@ -260,12 +263,13 @@ def _bulk_cycle_chain(
             & (env.wall_time < env.time_limit)
         )
         if bulk_fused:
-            env, nbi1, nbi2, si = _bulk_events_fused(
+            env, nbi1, nbi2, si, yi = _bulk_events_fused(
                 params, bank, env, on,
                 stop_at_limit=True, max_events=bulk_events,
                 lane_axis=lane_axis,
             )
             steps = steps + si
+            syncs = syncs + yi
         else:
             env, nbi1 = _bulk_relaunch(
                 params, bank, env, on,
@@ -281,7 +285,7 @@ def _bulk_cycle_chain(
         nb = nb + nbi1 + nbi2
         nb_rel = nb_rel + nbi1
         nb_rdy = nb_rdy + nbi2
-    return env, nb, nb_rel, nb_rdy, steps
+    return env, nb, nb_rel, nb_rdy, steps, syncs
 
 
 def _lane_done(env: EnvState) -> jnp.ndarray:
@@ -505,7 +509,7 @@ def micro_step(
     k_pol, k_reset = jax.random.split(rng)
     ls0 = ls  # pre-bulk state: the freeze path must restore exactly this
     if event_bulk:
-        env_b, nb, nb_rel, nb_rdy, nsteps = _bulk_cycle_chain(
+        env_b, nb, nb_rel, nb_rdy, nsteps, _ = _bulk_cycle_chain(
             params, bank, ls.env, ls.mode == M_EVENT, bulk_events,
             bulk_cycles, bulk_fused,
         )
@@ -840,14 +844,14 @@ def drain_micro_step(
     _, k_reset = jax.random.split(rng)
     ls0 = ls
     if event_bulk:
-        env_b, nb, nb_rel, nb_rdy, nsteps = _bulk_cycle_chain(
+        env_b, nb, nb_rel, nb_rdy, nsteps, nsyncs = _bulk_cycle_chain(
             params, bank, ls.env, ls.mode == M_EVENT, bulk_events,
             bulk_cycles, bulk_fused, lane_axis,
         )
         ls = ls.replace(env=env_b, bulked=ls.bulked + nb)
     else:
         nb = _i32(0)
-        nb_rel = nb_rdy = nsteps = nb
+        nb_rel = nb_rdy = nsteps = nsyncs = nb
 
     def noop(ls: LoopState):
         return ls, _i32(RQ_NONE), _i32(-1), _i32(-1), _i32(0), \
@@ -880,6 +884,9 @@ def drain_micro_step(
             bulk_ready_events=jnp.where(gate, nb_rdy, 0),
             bulk_passes=(nb > 0) & gate,
             bulk_scan_steps=jnp.where(gate, nsteps, 0),
+            # a fact of the batch, whatever this lane holds: the
+            # collector's row takes the longest-running lane's total
+            lane_syncs=nsyncs,
             ev_job_arrival=pop_live & (ev_kind == EV_JOB_ARRIVAL),
             ev_task_finished=pop_live & (ev_kind == EV_TASK_FINISHED),
             ev_exec_ready=pop_live & (ev_kind == EV_EXECUTOR_READY),

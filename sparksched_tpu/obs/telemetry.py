@@ -41,7 +41,20 @@ Counter semantics per engine:
   the row's `drain_iters` increment: the bodies the vmapped `while`
   really ran, which every lane pays for). They are facts of the batch,
   not of a lane, so every lane holds the same value; no other engine or
-  collector touches them.
+  collector touches them. A fifth, `lane_syncs`, counts the REDUCTIONS
+  OVER THE LANE AXIS the row executed: every evaluation of the fused
+  bulk pass's loop predicate (`core._steps_while_active` with a named
+  lane axis: its iterations and the one that ended it, in every body
+  of the drain), every evaluation of the drain `while`'s batched
+  predicate (its bodies and one), and the row's own (the policy's
+  full-width predicate, the maximum that gives `drain_batch_iters`,
+  `rows_live`'s `any`; streaming adds `reset_evals`' `any` and the
+  re-seed's predicate). On one chip each is a local reduction; on a
+  dp mesh each is an all-reduce across the chips, on the critical path.
+  Inside the drain's loop the lane that runs longest accumulates the
+  passes' predicates in its own `lane_syncs` (one scalar of the carry);
+  the row takes the maximum over lanes with `drain_batch_iters`', in
+  the one reduction.
 - streaming (`auto_reset`): `reseeds` counts the lane's episodes that
   ended in the scan and were re-seeded, `reset_evals` the evaluations
   of the reset program (`reset_fn` / `core.reset` and the select of the
@@ -115,6 +128,7 @@ class Telemetry(struct.PyTreeNode):
     rows_live: jnp.ndarray  # rows in which some lane decided
     rows_full_width: jnp.ndarray  # rows scored at the full job width
     drain_batch_iters: jnp.ndarray  # sum over rows of max-lane drain iters
+    lane_syncs: jnp.ndarray  # reductions over the lane axis executed
     # --- streaming (auto_reset) collection: 0 in sync mode ---
     reseeds: jnp.ndarray  # episodes that ended in the scan, re-seeded
     # evaluations of the reset program: rows in which it ran (the batch
@@ -230,6 +244,10 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
         return int(x.max()) if x.size else 0
 
     rows = batch(t.rows)
+    # the reductions over the lane axis the rows executed (on a dp mesh,
+    # all-reduces on the critical path); in the summary where rows were
+    # counted (PERF.md section 7 says what keeps it from every summary)
+    lane_syncs = {"lane_syncs": batch(t.lane_syncs)} if rows else {}
     scan_steps = tot(t.bulk_scan_steps)
     bulk_passes = tot(t.bulk_passes)
     drain_batch = batch(t.drain_batch_iters)
@@ -292,6 +310,7 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
             "rows_live": batch(t.rows_live),
             "rows_full_width": batch(t.rows_full_width),
             "drain_batch_iters": drain_batch,
+            **lane_syncs,
             "lane_rows": rows * lanes,
             "drain_lane_iters_executed": drain_batch * lanes,
             "drain_iters_total": tot(t.drain_iters),

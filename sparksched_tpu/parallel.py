@@ -7,16 +7,41 @@ The TPU-native equivalent has two layers:
 - on-chip: `jax.vmap` already runs thousands of env lanes per core — that
   alone replaces the reference's N worker processes;
 - across chips: the lane axis is sharded over a 1-D `dp` mesh axis with
-  `NamedSharding(P("dp"))`. Rollout collection is embarrassingly parallel
-  along lanes; the PPO update's global minibatch permutation, advantage
-  normalization and gradient reduction become XLA collectives (all-gather /
-  psum) over ICI — no NCCL, no parameter scatter, no pickling. Multi-host
-  works the same way: the mesh simply spans hosts and the same collectives
-  ride DCN.
+  `NamedSharding(P("dp"))`. The PPO update's global minibatch
+  permutation, advantage normalization and gradient reduction become XLA
+  collectives (all-gather / psum) over ICI — no NCCL, no parameter
+  scatter, no pickling. Multi-host works the same way: the mesh simply
+  spans hosts and the same collectives ride DCN.
+
+Rollout collection moves no lane's data to another chip, but it is NOT
+free of cross-lane operations: the collector is one program under `jit`
+with sharding constraints (no `shard_map`), so every reduction over the
+lane axis is an all-reduce of a scalar across the chips, on the critical
+path. A decision row makes these (`Telemetry.lane_syncs` counts them,
+`collector_collectives` below holds the compiled program to them):
+
+- the predicate of the fused bulk pass's early-exit loop, `lax.pmax`
+  over the lanes, once an iteration of that loop in every body of the
+  drain (`env/core.py: _steps_while_active`, PR 28);
+- the batched predicate of the drain's vmapped `while`, an `any` over
+  the lanes, once a body (`flat_loop.drain_to_decision`);
+- once a row: the policy's full-width predicate
+  (`DecimaScheduler.full_width`), the maximum over lanes that gives
+  `drain_batch_iters`, `rows_live`'s `any`; streaming adds
+  `reset_evals`' `any` and the re-seed's predicate
+  (`flat_loop._reseed_ended`, PR 31).
+
+Under rbg keys (`fast_prng`) jax draws a vmapped batch of random bits
+from the FIRST lane's key, so each such draw (two in a drain body, one in
+a decide step) is also a broadcast of that key from the chip that holds
+lane 0: the partitioner lowers it to an all-reduce of the key's four
+words. `lane_syncs` does not count those (they reduce nothing); the
+trace does (PERF.md, PR 34).
 """
 
 from __future__ import annotations
 
+import math
 import re
 from typing import Any
 
@@ -47,7 +72,8 @@ def make_host_device_mesh(
     """2-D ("host", "dp") mesh for multi-host runs.
 
     Lanes shard over BOTH axes (`lane_sharding` spans every mesh axis),
-    so rollout collection stays embarrassingly parallel; the update's
+    so rollout collection moves no lane's data (its scalar reductions
+    over the lanes cross both axes: module docstring); the update's
     reductions become hierarchical collectives — XLA reduces along the
     fast "dp" (intra-host ICI) axis before the "host" (DCN) axis, which
     is exactly the hierarchy the reference's per-process workers + one
@@ -135,10 +161,10 @@ def mesh_from_config(cfg: dict[str, Any] | None) -> Mesh | None:
 # collective census: the HLO-level contract of the sharded update
 # ---------------------------------------------------------------------------
 
-COLLECTIVE_RE = re.compile(
-    r"\b(all-reduce|all-gather|reduce-scatter|collective-permute|"
-    r"all-to-all)\b"
+_COLLECTIVE_FAMILIES = (
+    "all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all"
 )
+COLLECTIVE_RE = re.compile(rf"\b({_COLLECTIVE_FAMILIES})\b")
 
 # what the shard-aligned update is ALLOWED to lower to: the gradient /
 # advantage-normalization reductions (all-reduce), their occasional
@@ -155,6 +181,57 @@ EXPECTED_UPDATE_COLLECTIVES = frozenset(
 FORBIDDEN_UPDATE_COLLECTIVES = frozenset(
     {"all-to-all", "collective-permute"}
 )
+
+
+# what the sharded COLLECTOR may lower to: all-reduces, each of a few
+# words (the scalar reductions over the lane axis listed in the module
+# docstring, the compiler's combinations of them, and the rbg key
+# broadcasts). Anything else (all-gather, all-to-all,
+# collective-permute, reduce-scatter), or an all-reduce of an array as
+# long as the lane axis, moves or replicates lane-sized data.
+EXPECTED_COLLECT_COLLECTIVES = frozenset({"all-reduce"})
+# the widest all-reduce the collector may hold, in elements: a combined
+# tuple of rbg keys (three keys of four words, the reset's draws)
+COLLECT_ALL_REDUCE_MAX_ELEMENTS = 16
+
+_COLLECTIVE_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+ = (?P<shape>.*?) "
+    rf"(?P<family>{_COLLECTIVE_FAMILIES})(?:-start)?\(",
+    re.M,
+)
+_SHAPE_DIMS = re.compile(r"\w+\[([\d,]*)\]")
+
+
+def collector_collectives(hlo_text: str) -> list[dict[str, Any]]:
+    """The collective INSTRUCTIONS of an optimized-HLO dump (not the
+    mentions `collective_census` counts: an operand named after its
+    instruction is one): family, the elements of the result (summed
+    over a tuple's parts) and the `op_name`, which holds the
+    `named_scope` path."""
+    out = []
+    for m in _COLLECTIVE_INSTRUCTION.finditer(hlo_text):
+        line = hlo_text[m.start():hlo_text.find("\n", m.start())]
+        name = re.search(r'op_name="([^"]*)"', line)
+        elements = sum(
+            math.prod(int(d) for d in dims.split(",") if d)
+            for dims in _SHAPE_DIMS.findall(m.group("shape"))
+        )
+        out.append({
+            "family": m.group("family"), "elements": elements,
+            "op_name": name.group(1) if name else "",
+        })
+    return out
+
+
+def collector_violations(hlo_text: str) -> list[dict[str, Any]]:
+    """The collectives of a compiled sharded collector that it may not
+    hold: any family but all-reduce, and an all-reduce of more than
+    `COLLECT_ALL_REDUCE_MAX_ELEMENTS` elements."""
+    return [
+        c for c in collector_collectives(hlo_text)
+        if c["family"] not in EXPECTED_COLLECT_COLLECTIVES
+        or c["elements"] > COLLECT_ALL_REDUCE_MAX_ELEMENTS
+    ]
 
 
 def compiled_flops(compiled) -> float:

@@ -451,7 +451,8 @@ def collect_async(
 # `collect/freeze` and `collect/scatter` (key splits apart;
 # tests/test_obs.py pins it), and with a telemetry carry the body
 # counts its rows (`obs/telemetry.py`: `rows`, `rows_live`,
-# `rows_full_width`, `drain_batch_iters`, and per lane `rows_frozen`).
+# `rows_full_width`, `drain_batch_iters`, `lane_syncs`, and per lane
+# `rows_frozen`).
 # In streaming mode (`auto_reset`) a lane whose episode ended in the
 # row's drain is re-seeded once, after the drain's loop and under one
 # predicate for the batch (`flat_loop._reseed_ended`, scope
@@ -537,10 +538,11 @@ def _flat_collect_single_eval(
     layout to the partitioner's fallback (which can silently replicate
     the largest resident buffers of the program).
 
-    With `telemetry`, each decision row also advances the four
+    With `telemetry`, each decision row also advances the five
     batch-level row counters (`rows`, `rows_live`, `rows_full_width`,
-    `drain_batch_iters`: `obs/telemetry.py`), once per row and outside
-    the drain's `while`; `rows_full_width` reads the policy's
+    `drain_batch_iters`, `lane_syncs`: `obs/telemetry.py`), once per
+    row and outside the drain's `while`; `rows_full_width` reads the
+    policy's
     `aux["full_width"]` and stays 0 for a policy that gives none.
     The per-lane `rows_frozen` counts the rows a lane sat out with its
     `rollout_duration` spent (0 without a budget).
@@ -576,6 +578,12 @@ def _flat_collect_single_eval(
         if track:
             telemetry = constrain_lanes(telemetry, lane_shard)
     lane_idx = jnp.arange(B)
+    # the reductions over the lane axis a row makes outside its loops:
+    # the maximum that gives `drain_batch_iters` and `rows_live`'s
+    # `any`; streaming adds `reset_evals`' `any` and the re-seed's
+    # predicate (`flat_loop._reseed_ended`); a policy with two widths
+    # adds its predicate (`aux["full_width"]`), counted in the body
+    row_syncs = 4 if auto_reset else 2
 
     def v_decide(ls, si, ne, tm):
         def one(l, s_, n_, t_):
@@ -669,18 +677,28 @@ def _flat_collect_single_eval(
             )
             dec = decided & ~over
             if track:
-                # the bodies the vmapped drain `while` ran this row:
-                # every lane, frozen ones too, waits for the slowest
-                drained = (tm.drain_iters - tm_frozen.drain_iters).max()
+                # the bodies the vmapped drain `while` ran this row
+                # (every lane, frozen ones too, waits for the slowest)
+                # and the predicates of the fused passes in them: the
+                # lane that ran longest counted every one. One
+                # reduction over the lanes for both.
+                drained, pass_syncs = jnp.stack(
+                    [tm.drain_iters - tm_frozen.drain_iters,
+                     tm.lane_syncs - tm_frozen.lane_syncs], -1
+                ).max(0)
                 tm = jax.tree_util.tree_map(
                     lambda a, b: jnp.where(over, a, b), tm_frozen, tm
-                )
+                ).replace(lane_syncs=tm_frozen.lane_syncs)
                 # the row counters are facts of the batch: added after
                 # the freeze, so every lane holds the same value
                 tm = _tm_add(
                     tm, rows=1, rows_live=dec.any(),
                     rows_full_width=aux.get("full_width", False),
                     drain_batch_iters=drained, rows_frozen=over,
+                    # the passes' predicates, the drain `while`'s (its
+                    # bodies and the one that ended it) and the row's
+                    lane_syncs=pass_syncs + drained + 1 + row_syncs
+                    + ("full_width" in aux),
                 )
                 if auto_reset:
                     # the drain's re-seed ran iff some lane ended its

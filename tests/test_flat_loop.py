@@ -950,12 +950,13 @@ def test_bulk_paths_match_sequential_on_synthetic_bank(
 
 def _fixed_scan(step_fn, carry0, us, lane_axis=None):
     """The oracle: the fixed-length scan the fused pass ran before its
-    loop ended early, over the same `step_fn` (every row in budget)."""
+    loop ended early, over the same `step_fn` (every row in budget);
+    it evaluates no predicate."""
     import jax
 
     return jax.lax.scan(
         lambda c, u: (step_fn(c, u, True), None), carry0, us
-    )[0]
+    )[0], 0
 
 
 @pytest.fixture(scope="module")
@@ -1019,11 +1020,14 @@ def bulk_pass_trail():
     return params, bank, lss, on, need
 
 
-def _run_pass(params, bank, runner, envs, on, *, max_events, how):
+def _run_pass(params, bank, runner, envs, on, *, max_events, how,
+              syncs=False):
     """`core._bulk_events_fused` over stacked states with `runner` as
     its step loop: `how` is "one" (a lane at a time, no vmap), "vmap"
     (per-lane predicate) or "vmap_named" (the lane axis named, so one
-    predicate for the batch)."""
+    predicate for the batch). Returns the pass's state, `k_rel`,
+    `k_rdy` and steps, or with `syncs` its count of reductions over
+    the lanes alone."""
     import jax
     import jax.numpy as jnp
 
@@ -1032,10 +1036,11 @@ def _run_pass(params, bank, runner, envs, on, *, max_events, how):
     axis = "lanes" if how == "vmap_named" else None
 
     def one(env, enabled):
-        return core._bulk_events_fused(
+        out = core._bulk_events_fused(
             params, bank, env, enabled, stop_at_limit=True,
             max_events=max_events, lane_axis=axis,
         )
+        return out[4] if syncs else out[:4]
 
     saved = core._steps_while_active
     core._steps_while_active = runner
@@ -1120,6 +1125,20 @@ def test_early_exit_pass_matches_fixed_scan(bulk_pass_trail, how):
     _, k_rel, k_rdy, steps = got
     took = np.asarray(k_rel) + np.asarray(k_rdy)
     steps = np.asarray(steps)
+    # the reductions over the lanes the loop made: with the lane axis
+    # named, one for each granule the longest run of the batch needed
+    # and the one that ended the loop, the same in every lane; none
+    # for a loop that keeps each lane's own predicate
+    got_syncs = np.asarray(_run_pass(
+        params, bank, core._steps_while_active, envs, enabled,
+        max_events=8, how=how, syncs=True,
+    ))
+    g = core._BULK_STEP_GRANULE
+    if how == "vmap_named":
+        assert (got_syncs == -(-int(steps.max()) // g) + 1).all()
+        assert steps.max() > g
+    else:
+        assert (got_syncs == 0).all()
     assert (took[~enabled] == 0).all() and (steps[~enabled] == 0).all()
     assert (took[at_limit] == 1).all() and (steps[at_limit] == 2).all()
     assert len(set(took[enabled].tolist())) >= 6, took

@@ -715,11 +715,19 @@ def _check_row_counters(tm, steps: int) -> dict:
     assert (batch == batch[0]).all()
     assert drained.max() <= batch[0]
     assert batch[0] <= drained.sum()
+    # the reductions over the lane axis: the same in every lane, and at
+    # least the drain `while`'s predicates (its bodies and one a row),
+    # one predicate of the fused pass's loop a body that ended it at
+    # once, and two of the row's own
+    syncs = np.asarray(tm.lane_syncs)
+    assert (syncs == syncs[0]).all()
+    assert syncs[0] >= 2 * batch[0] + 3 * steps
     row = summarize(tm)["row"]
     assert row == {
         "rows": steps, "rows_live": int(live[0]),
         "rows_full_width": int(np.asarray(tm.rows_full_width).max()),
         "drain_batch_iters": int(batch[0]),
+        "lane_syncs": int(syncs[0]),
         "lane_rows": steps * rows.size,
         "drain_lane_iters_executed": int(batch[0]) * rows.size,
         "drain_iters_total": int(drained.sum()),
@@ -828,10 +836,11 @@ def test_summarize_reads_batch_counters_as_the_lane_maximum():
         rows_full_width=np.asarray([0, 2, 2, 2], np.int32),
         drain_batch_iters=np.asarray([30, 31, 31, 20], np.int32),
         drain_iters=np.asarray([10, 20, 25, 5], np.int32),
+        lane_syncs=np.asarray([200, 260, 260, 240], np.int32),
     )
     assert summarize(tm)["row"] == {
         "rows": 7, "rows_live": 5, "rows_full_width": 2,
-        "drain_batch_iters": 31, "lane_rows": 28,
+        "drain_batch_iters": 31, "lane_syncs": 260, "lane_rows": 28,
         "drain_lane_iters_executed": 124, "drain_iters_total": 60,
         "lane_rows_frozen": 0,
     }

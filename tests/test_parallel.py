@@ -262,10 +262,14 @@ def flat_dp_pair():
 
 def test_flat_collect_dp8_step_exact(flat_dp_pair):
     """The lane-sharded single-eval collector is STEP-EXACT vs dp=1 at
-    fixed seeds: collection is embarrassingly parallel along lanes (the
-    only cross-lane op is the compaction predicate, an integer max), so
-    sharding must not change a single recorded bit — same actions,
-    log-probs, rewards, wall times, valid mask, same final EnvState."""
+    fixed seeds. Collection moves no lane's data, and its cross-lane
+    operations are reductions of one predicate or one count over the
+    lane axis (the fused bulk pass's `pmax` of PR 28, the drain
+    `while`'s batched predicate, the re-seed's predicate of PR 31, the
+    row's full-width predicate and counters: `parallel.py`'s
+    docstring), exact in any order, so sharding must not change a
+    single recorded bit — same actions, log-probs, rewards, wall
+    times, valid mask, same final EnvState."""
     ro1 = jax.device_get(flat_dp_pair[1]["ro"])
     ro8 = jax.device_get(flat_dp_pair[8]["ro"])
     leaves1, treedef1 = jax.tree_util.tree_flatten(ro1)
@@ -349,3 +353,160 @@ def test_lane_fit_mesh_answers_per_device_budget():
     assert f8["candidates"][0]["fits"]
     assert f8["candidates"][0]["lanes_per_device"] == 8
     assert f8["dp"] == 8 and f8["max_lanes_fit"] == 64
+
+
+# ---------------------------------------------------------------------------
+# PR 34: the collector on a dp=4 mesh as the benchmark's cell
+# `decima_rollout_dp4` runs it — telemetry and health on, a policy with
+# two widths — held to what the cell's `correct` rests on
+# ---------------------------------------------------------------------------
+
+MESH_LANES, MESH_ROWS, MESH_PREFIX = 64, 12, 5
+
+
+def _make_mesh_cell_trainer(dp: int, rows: int = MESH_ROWS,
+                            lanes: int = MESH_LANES):
+    from sparksched_tpu.trainers.ppo import PPO
+
+    agent, env, tr = _tiny_cfg(8)
+    tr = tr | {"rollout_steps": rows, "num_sequences": max(lanes // 8, 1),
+               "num_rollouts": min(lanes, 8)}
+    return PPO(agent | {"job_bucket": 2}, env, tr,
+               mesh=make_mesh(dp) if dp > 1 else None,
+               obs_cfg={"telemetry": True}, health_cfg={"enabled": True})
+
+
+def _collect_compiled(trainer):
+    s = trainer.init_state()
+    comp = trainer._collect_jit.lower(
+        s.params, s.iteration, s.rng, None
+    ).compile()
+    ro, _, telem = comp(s.params, s.iteration, s.rng, None)
+    return {"trainer": trainer, "compiled": comp, "ro": ro, "telem": telem}
+
+
+@pytest.fixture(scope="module")
+def mesh_cell_pair():
+    """dp=1 and dp=4 collections of 64 lanes x 12 rows (16 lanes a
+    device), telemetry and health on, and the dp=1 collection of the
+    first 5 rows."""
+    return {
+        1: _collect_compiled(_make_mesh_cell_trainer(1)),
+        4: _collect_compiled(_make_mesh_cell_trainer(4)),
+        "prefix": _collect_compiled(
+            _make_mesh_cell_trainer(1, rows=MESH_PREFIX)),
+    }
+
+
+def _per_decision(ro) -> dict:
+    from benchmarks.drivers.collect_rollout_dp import LEAVES
+
+    return {k: getattr(ro, k) for k in LEAVES + ("valid",)}
+
+
+def test_mesh_cell_dp4_leaf_equal_with_telemetry_and_health(mesh_cell_pair):
+    """The mesh changes no stored bit and no counter: every leaf of the
+    rollout and of the per-lane telemetry equal, `summarize` equal
+    (`row.lane_syncs` included), no sentinel tripped, the rollout and
+    the telemetry lane-sharded over four devices."""
+    from sparksched_tpu.obs.telemetry import summarize
+
+    one, four = mesh_cell_pair[1], mesh_cell_pair[4]
+    for name in ("ro", "telem"):
+        a, b = jax.device_get((one[name], four[name]))
+        assert jax.tree_util.tree_structure(a) == (
+            jax.tree_util.tree_structure(b))
+        for (path, x), y in zip(jax.tree_util.tree_leaves_with_path(a),
+                                jax.tree_util.tree_leaves(b)):
+            np.testing.assert_array_equal(
+                x, y, err_msg=f"{name}{jax.tree_util.keystr(path)}")
+    s1, s4 = summarize(one["telem"]), summarize(four["telem"])
+    assert s1 == s4
+    assert s4["health_mask"] == 0 and s4["decisions"] > MESH_LANES
+    assert s4["row"]["rows"] == MESH_ROWS and s4["row"]["lane_syncs"] > 0
+    for leaf in (four["ro"].reward, four["telem"].lane_syncs):
+        assert len({s.device.id for s in leaf.addressable_shards}) == 4
+        assert {s.data.shape[0] for s in leaf.addressable_shards} == {
+            MESH_LANES // 4}
+
+
+def test_mesh_cell_prefix_rows_equal_the_longer_collection(mesh_cell_pair):
+    """What the cell's mesh check rests on: the collector's scan is
+    causal, so a collection of R rows stores, at every slot it marks
+    valid, what a collection of T > R rows under the same parameters,
+    key and collection number stores there — but `reward` and `resets`
+    at a lane's last valid slot, which later rows still add to. Against
+    the dp=1 collection and against the dp=4 one."""
+    from benchmarks.drivers.collect_rollout_dp import mesh_gap
+
+    short = _per_decision(mesh_cell_pair["prefix"]["ro"])
+    assert short["valid"].shape == (MESH_LANES, MESH_PREFIX)
+    for dp in (1, 4):
+        long = jax.device_get(_per_decision(mesh_cell_pair[dp]["ro"]))
+        found = jax.device_get(jax.jit(mesh_gap)(
+            jax.device_get(short), long))
+        assert found["slots"] > MESH_LANES and found["valid_lost"] == 0
+        assert found["lanes_parted"] == 0, dp
+        assert found["slots_before_parting"] == found["slots"]
+        assert all(n == 0 for n in found["unequal"].values()), found
+        # on the CPU the two programs store the same log-probs too
+        assert found["lgprob_unequal_per_lane"].sum() == 0
+
+
+def test_mesh_cell_collector_holds_scalar_all_reduces_only(mesh_cell_pair):
+    """The compiled dp=4 collector lowers to all-reduces of a few words
+    and nothing else: no all-gather, all-to-all, collective-permute or
+    reduce-scatter, and no all-reduce as long as a device's share of
+    the lanes. All of them sit in the scan body, under the scopes the
+    body has, and there are four: the fused bulk pass's loop predicate,
+    the drain `while`'s predicate, the row's counters' maximum, and one
+    that the compiler combined from the policy's full-width predicate
+    and `rows_live`'s `any`."""
+    from sparksched_tpu.parallel import (
+        COLLECT_ALL_REDUCE_MAX_ELEMENTS,
+        EXPECTED_COLLECT_COLLECTIVES,
+        collector_collectives,
+        collector_violations,
+    )
+
+    hlo = mesh_cell_pair[4]["compiled"].as_text()
+    found = collector_collectives(hlo)
+    assert found and not collector_violations(hlo), found
+    assert {c["family"] for c in found} == EXPECTED_COLLECT_COLLECTIVES
+    assert COLLECT_ALL_REDUCE_MAX_ELEMENTS < MESH_LANES // 4 + 1
+    assert all("/while/body/" in c["op_name"] for c in found)
+    scopes = ("env/micro_step/drain", "decima/gnn", "collect/freeze")
+    assert all(any(s in c["op_name"] for s in scopes) for c in found)
+    by_scope = sorted(next(s for s in scopes if s in c["op_name"])
+                      for c in found)
+    assert by_scope == ["collect/freeze", "decima/gnn",
+                        "env/micro_step/drain", "env/micro_step/drain"]
+    # the dp=1 program has none, and a doctored dump is caught
+    assert not collector_collectives(mesh_cell_pair[1]["compiled"].as_text())
+    bad = hlo + (
+        "\n  %all-gather.9 = s32[64]{0} all-gather(%x), dimensions={0}"
+        "\n  ROOT %all-reduce.9 = (f32[64,12]{1,0}, pred[]) all-reduce(%y)")
+    assert [(c["family"], c["elements"])
+            for c in collector_violations(bad)] == [
+        ("all-gather", 64), ("all-reduce", 769)]
+
+
+def test_lane_syncs_against_a_count_made_by_hand(monkeypatch):
+    """One lane, the fused pass's loop stepping one step at a time: the
+    loop's predicate is evaluated once for each step the lane needed
+    and once more in every body of the drain, the drain `while`'s once
+    a body and once more a row, and a row makes three reductions of its
+    own (the full-width predicate, the counters' maximum, `rows_live`),
+    so the counter is a sum of counters that were there."""
+    from sparksched_tpu.env import core
+    from sparksched_tpu.obs.telemetry import summarize
+
+    monkeypatch.setattr(core, "_BULK_STEP_GRANULE", 1)
+    s = summarize(_collect_compiled(
+        _make_mesh_cell_trainer(1, lanes=1))["telem"])
+    row = s["row"]
+    assert row["drain_batch_iters"] == row["drain_iters_total"] > 10
+    assert s["bulk_scan_steps_total"] > 10
+    assert row["lane_syncs"] == (
+        s["bulk_scan_steps_total"] + row["drain_iters_total"]
+        + row["drain_batch_iters"] + row["rows"] + 3 * row["rows"])
