@@ -86,6 +86,7 @@ ENV_STATE_SCHEMA: dict[str, tuple[str, tuple]] = {
     "stage_sat": ("bool", ("J", "S")),
     "unsat_parent_count": ("int32", ("J", "S")),
     "incomplete_parent_count": ("int32", ("J", "S")),
+    "parent_sets": ("uint32", ("J", "W", "S")),
     "node_level": ("int32", ("J", "S")),
     "commit_count": ("int32", ("J", "S")),
     "moving_count": ("int32", ("J", "S")),
@@ -124,10 +125,14 @@ STORED_OBS_SCHEMA: dict[str, tuple[str, tuple]] = {
 
 
 def dims_from_params(params) -> dict[str, int]:
+    from ..env.state import STAGE_SET_BITS
+
     return {
         "J": params.max_jobs,
         "S": params.max_stages,
         "N": params.num_executors,
+        # words of a packed stage set (`core.pack_parents`)
+        "W": -(-params.max_stages // STAGE_SET_BITS),
         # a stored step's flat [J,S] node grid, padded to whole
         # 128-wide rows (trainers/rollout.py:_flat_grid)
         "F": -(-params.max_jobs * params.max_stages // 128) * 128,

@@ -51,6 +51,17 @@ from . import Violation
 
 WIDE_DTYPES = frozenset({"float64", "int64", "uint64", "complex128"})
 LOOP_PRIMS = frozenset({"while", "scan"})
+# primitives that read an operand by rows: what they cost follows the
+# rows taken, not the operand
+ROW_READ_PRIMS = frozenset({"gather", "dynamic_slice"})
+# a select between two whole copies of a leaf hands it on: where
+# nothing in the loop writes the leaf both sides are the loop's own
+# input, and the compiler drops the select (the tail's freeze select of
+# a one-lane program; tests/test_tpu_compile.py holds the compiled
+# loop to no adjacency-sized result)
+HAND_ON_PRIMS = frozenset({"select_n"})
+# the adjacency [J,S,S] of one lane at the audit config (`audit_setup`)
+AUDIT_ADJ_ELEMS = 20 * 20 * 20
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,7 +72,15 @@ class Budget:
     there hurts more than its eqn share suggests). `loop_free` pins the
     program free of while/scan; `callback_allow` names callback
     primitives the program may legitimately contain (empty everywhere
-    today — the telemetry counters are pure adds, not callbacks)."""
+    today — the telemetry counters are pure adds, not callbacks).
+    `while_whole_read_elems` pins the program's `while` loops (bodies
+    and predicates, nested ones too) free of any equation that reads
+    an operand of at least that many elements whole: only a gather or
+    a dynamic slice may touch one (PR 39: set to the adjacency's size
+    on the one-lane programs that drain, whose loop body contracted
+    the whole [J,S,S] adjacency once an iteration until then; on the
+    TPU that one equation was a sixth of the body). None: not
+    pinned."""
 
     eqn_lo: int
     eqn_hi: int
@@ -69,6 +88,7 @@ class Budget:
     scatter_hi: int
     loop_free: bool = False
     callback_allow: frozenset = frozenset()
+    while_whole_read_elems: int | None = None
 
 
 # ---------------------------------------------------------------------------
@@ -143,6 +163,26 @@ class Budget:
 # serve_decide_batch 15178 -> 15182 (the record, ring, group and
 # sharded variants +4 each); observe, micro_step, decide_micro_step and
 # drain_to_decision as they were. The chip rows are PERF.md, PR 33.
+#
+# Re-measured 2026-09-30 (PR 39: the fused pass refreshes
+# `unsat_parent_count` from the state's packed parent sets, two
+# compares, packs, `and`s and population counts where it had one
+# `dot_general` over the adjacency; `EnvState` has one leaf more,
+# `parent_sets`, packed at reset, so every select, conditional and
+# store write over the state has one operand more). Eqns / gathers /
+# scatters before -> after, every count inside its band, so no band
+# moved: micro_step 4337/29/1 -> 4379/29/1, decide_micro_step
+# 2470/24/0 -> 2474/24/0, drain_to_decision 2970/5/1 -> 3014/5/1,
+# serve_decide 6621/33/65 -> 6666/33/66 (+0.7%; the one more scatter is
+# the leaf's write back to the store, as on every serve program: 65 ->
+# 66, the ring programs 86 -> 87), serve_decide_record 6643 -> 6688,
+# serve_decide_record_ring 6771 -> 6816, serve_decide_batch
+# 15196/267/65 -> 15378/268/66 (+1.2%; its group, record, ring and
+# sharded variants the same +182 and one gather), flat_collect_batch
+# 14874 -> 15050, flat_collect_batch_health 15143 -> 15319; observe,
+# the net's and the update's as they were. New with it: the
+# `while_whole_read_elems` pin on micro_step, drain_to_decision and
+# serve_decide. The chip rows are PERF.md, PR 39.
 # ---------------------------------------------------------------------------
 
 BUDGETS: dict[str, Budget] = {
@@ -158,6 +198,7 @@ BUDGETS: dict[str, Budget] = {
     # a decision loop)
     "micro_step": Budget(
         eqn_lo=2000, eqn_hi=5500, gather_hi=40, scatter_hi=3,
+        while_whole_read_elems=AUDIT_ADJ_ELEMS,
     ),
     # the single-eval collectors' policy-bearing micro-step
     "decide_micro_step": Budget(
@@ -170,6 +211,7 @@ BUDGETS: dict[str, Budget] = {
     # and drops the per-iteration full-pytree rollback select)
     "drain_to_decision": Budget(
         eqn_lo=1200, eqn_hi=3450, gather_hi=8, scatter_hi=3,
+        while_whole_read_elems=AUDIT_ADJ_ELEMS,
     ),
     # Decima stage/exec scores over a [B]-stacked feature set, both
     # compaction branches under the scalar cond (the scan is the
@@ -219,6 +261,7 @@ BUDGETS: dict[str, Budget] = {
     # scan is the GNN level pass + the bulk event kernel.
     "serve_decide": Budget(
         eqn_lo=3000, eqn_hi=8800, gather_hi=45, scatter_hi=88,
+        while_whole_read_elems=AUDIT_ADJ_ELEMS,
     ),
     "serve_decide_batch": Budget(
         eqn_lo=6000, eqn_hi=17400, gather_hi=339, scatter_hi=88,
@@ -295,17 +338,51 @@ BUDGETS: dict[str, Budget] = {
 # ---------------------------------------------------------------------------
 
 
+def _sub_jaxprs(eqn) -> list:
+    """The jaxprs an equation carries in its parameters (the branches
+    of a conditional, a loop's body and predicate, a call's body)."""
+    subs = []
+    for v in eqn.params.values():
+        for sub in v if isinstance(v, (list, tuple)) else [v]:
+            if hasattr(sub, "jaxpr"):
+                subs.append(sub.jaxpr)
+            elif hasattr(sub, "eqns"):
+                subs.append(sub)
+    return subs
+
+
 def iter_eqns(jaxpr):
     """Yield every equation including nested sub-jaxprs (cond/scan/while
     branches, closed calls, custom_* wrappers)."""
     for eqn in jaxpr.eqns:
         yield eqn
-        for v in eqn.params.values():
-            for sub in v if isinstance(v, (list, tuple)) else [v]:
-                if hasattr(sub, "jaxpr"):
-                    yield from iter_eqns(sub.jaxpr)
-                elif hasattr(sub, "eqns"):
-                    yield from iter_eqns(sub)
+        for sub in _sub_jaxprs(eqn):
+            yield from iter_eqns(sub)
+
+
+def whole_reads_in_while(jaxpr, elems: int, _in_loop: bool = False
+                         ) -> list[str]:
+    """The equations inside a `while` (its body or its predicate, at
+    any depth) that read an operand of at least `elems` elements
+    otherwise than by rows (`ROW_READ_PRIMS`) or to hand it on
+    (`HAND_ON_PRIMS`), as `primitive(shape)`. An equation that only
+    hands operands to sub-jaxprs (a conditional, a call, an inner
+    loop) is looked into, not counted."""
+    found = []
+    for eqn in jaxpr.eqns:
+        subs = _sub_jaxprs(eqn)
+        if subs:
+            inside = _in_loop or eqn.primitive.name == "while"
+            for sub in subs:
+                found += whole_reads_in_while(sub, elems, inside)
+        elif _in_loop and eqn.primitive.name not in (
+                ROW_READ_PRIMS | HAND_ON_PRIMS):
+            found += [
+                f"{eqn.primitive.name}{tuple(v.aval.shape)}"
+                for v in eqn.invars
+                if getattr(getattr(v, "aval", None), "size", 0) >= elems
+            ]
+    return found
 
 
 def count_eqns(jaxpr) -> int:
@@ -398,6 +475,18 @@ def audit_closed_jaxpr(name: str, closed, budget: Budget
             "out came back",
         ))
 
+    if budget.while_whole_read_elems is not None:
+        whole = whole_reads_in_while(jaxpr, budget.while_whole_read_elems)
+        if whole:
+            found.append(Violation(
+                "jaxpr", "loop-whole-read", name,
+                f"{len(whole)} equations inside a `while` read an "
+                f"operand of {budget.while_whole_read_elems} elements "
+                f"or more whole (e.g. {whole[:3]}) — once an iteration, "
+                "whatever the iteration does; read rows (a gather), or "
+                "make what the loop needs of it once, outside",
+            ))
+
     if not (budget.eqn_lo <= n_eqns <= budget.eqn_hi):
         found.append(Violation(
             "jaxpr", "budget", name,
@@ -446,6 +535,7 @@ def audit_setup():
     params = params.replace(
         max_stages=bank.max_stages, max_levels=bank.max_stages
     )
+    assert params.max_jobs * params.max_stages**2 == AUDIT_ADJ_ELEMS
     key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
     state = jax.eval_shape(lambda k: core.reset(params, bank, k), key)
     _SETUP_CACHE.append((params, bank, state))

@@ -37,6 +37,7 @@ from .state import (
     EV_JOB_ARRIVAL,
     EV_TASK_FINISHED,
     INF,
+    STAGE_SET_BITS,
     EnvState,
     empty_state,
     topo_levels,  # shared levels reduction (re-exported; observe/tests
@@ -1531,6 +1532,47 @@ def _steps_while_active(step_fn, carry0, us, lane_axis=None):
     return carry, i // _BULK_STEP_GRANULE + 1
 
 
+def _pack_stage_sets(member: jnp.ndarray) -> jnp.ndarray:
+    """bool[J, S, ...] -> uint32[J, W, ...]: the stage axis as bit sets,
+    stage `p` at bit `p % 32` of word `p // 32` (`STAGE_SET_BITS`;
+    W = ceil(S / 32): one word up to 32 stages a job)."""
+    j_cap, s_cap = member.shape[:2]
+    rest = member.shape[2:]
+    words = -(-s_cap // STAGE_SET_BITS)
+    pad = [(0, 0), (0, words * STAGE_SET_BITS - s_cap)] + [(0, 0)] * len(rest)
+    bits = jnp.pad(member, pad).reshape(j_cap, words, STAGE_SET_BITS, *rest)
+    place = jnp.arange(STAGE_SET_BITS, dtype=jnp.uint32).reshape(
+        (STAGE_SET_BITS,) + (1,) * len(rest)
+    )
+    # distinct bits, so the sum is the union
+    return (bits.astype(jnp.uint32) << place).sum(2, dtype=jnp.uint32)
+
+
+def pack_parents(adj: jnp.ndarray) -> jnp.ndarray:
+    """The adjacency `bool[J,S,S]` (`adj[j,p,c]`: edge p -> c) as each
+    stage's PARENT SET: `uint32[J,W,S]`, bit `p % 32` of `[j, p // 32,
+    c]` set iff p is a parent of c. `EnvState.parent_sets` holds it: a
+    function of `adj` alone, written where `adj` is (at reset)."""
+    return _pack_stage_sets(adj)
+
+
+def _flipped_parents(state: EnvState, delta: jnp.ndarray) -> jnp.ndarray:
+    """`sum_p delta[j,p] * adj[j,p,c]` for `delta` in {-1, 0, +1}, from
+    the state's packed parent sets: the parents of (j,c) that turned
+    saturated less those that turned unsaturated, two set
+    intersections counted. Integer arithmetic, so equal to the
+    contraction over `state.adj` for every input; and it reads [J,S]
+    words where that reads [J,S,S] (tests/test_flat_loop.py keeps the
+    contraction as the reference)."""
+    def hits(flipped):  # bool[J,S] -> i32[J,S]
+        of_job = _pack_stage_sets(flipped)[:, :, None]
+        return lax.population_count(
+            state.parent_sets & of_job
+        ).sum(1).astype(_i32)
+
+    return hits(delta > 0) - hits(delta < 0)
+
+
 def _bulk_events_fused(
     params: EnvParams, bank: WorkloadBank, state: EnvState,
     enabled: jnp.ndarray, stop_at_limit: bool = False,
@@ -1554,14 +1596,20 @@ def _bulk_events_fused(
     op chains per micro-step — every scan step picks the lexicographic
     (time, seq) minimum over ALL pending finishes and arrivals,
     classifies it against the live remaining-task view, and applies it.
-    One rng split, one duration-sampling chain per consumed event, one
-    merged `state.replace` at the end. Because events are processed in
-    true queue order, the separate passes' cross-kind stop conditions
-    (`_bulk_ready`'s generated-finish cutoff, `_bulk_relaunch` treating
-    arrivals as competitors) dissolve: a finish event generated by an
-    in-run arrival start simply participates in later steps, and mixed
-    relaunch/arrival runs that previously cost one micro-step per kind
-    switch are consumed in one pass.
+    One rng split, one duration-sampling chain per consumed event, and
+    after the loop one merged state update: the [J,S] counters of the
+    consumed arrivals as one-hot sums over the executors, and the
+    saturation caches (`stage_sat`, `unsat_parent_count`) refreshed at
+    the stages a launch or an arrival touched, the parents' flips
+    counted on `state.parent_sets` (`_flipped_parents`), so that
+    nothing here reads the [J,S,S] adjacency whole. Because events are
+    processed in true queue order, the separate passes' cross-kind stop
+    conditions (`_bulk_ready`'s generated-finish cutoff,
+    `_bulk_relaunch` treating arrivals as competitors) dissolve: a
+    finish event generated by an in-run arrival start simply
+    participates in later steps, and mixed relaunch/arrival runs that
+    previously cost one micro-step per kind switch are consumed in one
+    pass.
 
     An event is *simple* iff its target stage still has unlaunched
     tasks at its turn (`rem > 0` on the live view):
@@ -1771,8 +1819,13 @@ def _bulk_events_fused(
         state.job_saturated_stages + newly_exh.sum(-1).astype(_i32)
     )
 
-    # saturation-cache refresh over every touched stage, full-array
-    # form: demand moved wherever a launch or an arrival landed
+    # saturation-cache refresh over every touched stage: demand moved
+    # wherever a launch or an arrival landed. `delta` is the flip of
+    # each stage's saturation ([J,S], nearly always all zero: a stage
+    # saturates once in its life, and un-saturates only when an
+    # arrival parks); a child's unsaturated-parent count moves by the
+    # flips among its parents, counted on the state's packed parent
+    # sets (`_flipped_parents`) and not by a contraction over `adj`
     touched = launch_t | (cnt_arr > 0)
     demand = rem - moving_count - state.commit_count
     sat_new = demand <= 0
@@ -1781,9 +1834,7 @@ def _bulk_events_fused(
         sat_new.astype(_i32) - state.stage_sat.astype(_i32),
         0,
     )
-    unsat = state.unsat_parent_count - jnp.einsum(
-        "jp,jpc->jc", delta, state.adj.astype(_i32)
-    )
+    unsat = state.unsat_parent_count - _flipped_parents(state, delta)
 
     state = state.replace(
         rng=jnp.where(bulked, rng_next, state.rng),
@@ -2036,6 +2087,7 @@ def reset_from_sequence(
         stage_sat=sat0,
         unsat_parent_count=unsat0,
         incomplete_parent_count=ipc0,
+        parent_sets=pack_parents(adj),
         node_level=topo_levels(exists, adj),
         time_limit=time_limit,
         seq_counter=num_jobs,

@@ -932,14 +932,19 @@ def drain_to_decision(
     batch-max drain length per decision row — but every iteration is
     pure env machinery (bulk passes + single pops), and the GNN runs
     exactly once per decision outside this loop. Which slice is the
-    cheap one depends on the device: on the TPU v5e this loop and the
-    GNN are each about two fifths of a decision row of 128 lanes
-    (PERF.md section 5). The device time is under the scope
-    `env/micro_step/drain`. A body of 128 lanes costs 1.16 ms there:
-    0.73 ms whatever the lanes hold (the pass's set-up and merged state
-    update, the pop, the shared tail) and 24 us for each step of the
+    cheap one depends on the device: on the TPU v5e this loop is
+    nearly three fifths of a decision row of 128 lanes and the GNN a
+    sixth (PERF.md section 5, PR 39). The device time is under the
+    scope `env/micro_step/drain`. A body of 128 lanes costs 1.05 ms
+    there: 0.59 ms whatever the lanes hold (the pass's set-up, a
+    gather of the arrivals' frontier bits 77 us of it, and its merged
+    state update; the pop; the shared tail; sixty-odd relayouts of
+    [lanes,J,S] arrays at 8 to 10 us) and 24 us for each step of the
     fused bulk pass's early-exit loop, which runs as many steps as the
-    longest run among the lanes (17 on average, of a budget of 58).
+    longest run among the lanes (19 on average, of a budget of 58).
+    Nothing in the body reads the [J,S,S] adjacency whole: the pass's
+    refresh of the saturation caches counts on `EnvState.parent_sets`
+    (until PR 39 a contraction over the adjacency, 181 us a body).
     `lane_axis`, the name the caller's `vmap` gave its lane axis, lets
     that loop end on one predicate for the whole batch; a caller
     without one (a single lane) leaves it None.

@@ -43,6 +43,8 @@ EV_JOB_ARRIVAL, EV_TASK_FINISHED, EV_EXECUTOR_READY = 0, 1, 2
 # numpy dtypes carry through jnp ops identically
 INF = np.float32(np.inf)
 BIG_SEQ = np.int32(2**30)
+# stages a word of a packed stage set holds (`EnvState.parent_sets`)
+STAGE_SET_BITS = 32
 
 
 def topo_levels(active: jnp.ndarray, adj_act: jnp.ndarray) -> jnp.ndarray:
@@ -127,6 +129,12 @@ class EnvState(struct.PyTreeNode):
     stage_sat: jnp.ndarray  # bool[J,S]; exec_demand <= 0
     unsat_parent_count: jnp.ndarray  # i32[J,S]; parents with ~sat & exists
     incomplete_parent_count: jnp.ndarray  # i32[J,S]; parents not completed
+    # each stage's parents as a bit set: `adj` packed over its parent
+    # axis (`core.pack_parents`; W = ceil(S / 32) words), written where
+    # `adj` is, at reset, and read by the fused bulk pass, whose refresh
+    # of `unsat_parent_count` counts flipped parents on it instead of
+    # contracting the whole [J,S,S] adjacency in every drain body
+    parent_sets: jnp.ndarray  # u32[J,W,S]; bit p%32 of [j,p//32,c] = adj[j,p,c]
 
     # --- incremental node-level cache [J,S] ---
     # per-job topological generations over the job's existing, incomplete
@@ -353,6 +361,9 @@ def empty_state(params: EnvParams, rng: jax.Array) -> EnvState:
         stage_sat=jnp.ones((j, s), bool),
         unsat_parent_count=jnp.zeros((j, s), i32),
         incomplete_parent_count=jnp.zeros((j, s), i32),
+        parent_sets=jnp.zeros(
+            (j, -(-s // STAGE_SET_BITS), s), jnp.uint32
+        ),
         node_level=jnp.full((j, s), s, i32),
         commit_count=jnp.zeros((j, s), i32),
         moving_count=jnp.zeros((j, s), i32),

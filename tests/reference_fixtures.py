@@ -228,3 +228,15 @@ def make_tpu_env_state(spec: dict[str, Any], num_executors: int,
         jnp.int32(mask.sum()), jnp.asarray(mask),
     )
     return params, bank, state
+
+
+def parent_sets_by_hand(adj: np.ndarray) -> np.ndarray:
+    """`EnvState.parent_sets` recomputed from `adj[..., J, S, S]`, a bit
+    at a time: bit p % 32 of word [j, p // 32, c] is adj[j, p, c]."""
+    *lead, j_cap, s_cap, _ = adj.shape
+    out = np.zeros((*lead, j_cap, -(-s_cap // 32), s_cap), np.uint32)
+    for p in range(s_cap):
+        out[..., p // 32, :] |= (
+            adj[..., p, :].astype(np.uint32) << np.uint32(p % 32)
+        )
+    return out

@@ -1251,6 +1251,238 @@ def test_bulk_scan_steps_counter(bulk_pass_trail):
         ), name
 
 
+# ---------------------------------------------------------------------
+# PR 39: the pass's refresh of `unsat_parent_count` counts flipped
+# parents on the state's packed parent sets (`EnvState.parent_sets`)
+# where it contracted `delta` against the whole [J,S,S] adjacency. The
+# contraction lives on here, as the reference.
+# ---------------------------------------------------------------------
+
+
+def _contracted_refresh(state, delta):
+    """The refresh as it was until PR 39."""
+    import jax.numpy as jnp
+
+    return jnp.einsum("jp,jpc->jc", delta, state.adj.astype(jnp.int32))
+
+
+def _run_pass_contracted(params, bank, envs, on, **kw):
+    """`_run_pass` with the pass reading the adjacency itself, through
+    the contraction."""
+    from sparksched_tpu.env import core
+
+    saved = core._flipped_parents
+    core._flipped_parents = _contracted_refresh
+    try:
+        return _run_pass(
+            params, bank, core._steps_while_active, envs, on, **kw
+        )
+    finally:
+        core._flipped_parents = saved
+
+
+def _golden_unsat(env):
+    sat = np.asarray(env.stage_saturated)
+    ex = np.asarray(env.stage_exists)
+    return (np.asarray(env.adj) & (~sat & ex)[..., None]).sum(-2)
+
+
+@pytest.mark.parametrize("how", ["one", "vmap_named"])
+def test_packed_refresh_matches_contraction_mid_episode(
+    bulk_pass_trail, how
+):
+    """Every leaf the pass writes, from the packed parent sets and from
+    the contraction over the adjacency, on states along an episode: all
+    700 of the trail under `vmap`, and one at a time (no batch axis)
+    the lanes whose pass flips a stage's saturation, with some that
+    flip none."""
+    import jax
+
+    from sparksched_tpu.env import core
+
+    params, bank, lss, on, need = bulk_pass_trail
+    want = _run_pass_contracted(
+        params, bank, lss.env, on, max_events=8, how="vmap"
+    )
+    flips = (
+        np.asarray(want[0].stage_sat) != np.asarray(lss.env.stage_sat)
+    ).any((1, 2))
+    # the trail's passes saturate stages (none un-saturates one: the
+    # made-up states below do), and most flip none
+    assert flips.sum() >= 10 and (~flips & on).sum() > 100
+    if how == "one":
+        idx = np.concatenate([
+            np.flatnonzero(flips)[:12], np.flatnonzero(~flips & on)[:3],
+        ])
+        envs = jax.tree_util.tree_map(lambda a: a[idx], lss.env)
+        want = jax.tree_util.tree_map(lambda a: a[idx], want)
+        enabled = on[idx]
+    else:
+        envs, enabled = lss.env, on
+    got = _run_pass(
+        params, bank, core._steps_while_active, envs, enabled,
+        max_events=8, how=how,
+    )
+    _assert_same_pass(got, want, how)
+    np.testing.assert_array_equal(
+        np.asarray(got[0].unsat_parent_count), _golden_unsat(got[0])
+    )
+
+
+def _made_up_pass_states():
+    """Four hand-made states for the pass, one for each way it moves a
+    stage's saturation. Two jobs of one diamond (0 -> 1, 0 -> 2,
+    1 -> 3, 2 -> 3), four executors, both jobs arrived, the caches set
+    to their recomputation. Returns (params, bank, stacked states,
+    names)."""
+    import jax
+    import jax.numpy as jnp
+
+    adj = np.zeros((4, 4), bool)
+    adj[0, 1] = adj[0, 2] = adj[1, 3] = adj[2, 3] = True
+    job = {"adj": adj, "num_tasks": [6, 6, 6, 6],
+           "fresh": [900.0] * 4, "first": [700.0] * 4, "rest": [500.0] * 4}
+    params, bank, base = make_tpu_env_state(
+        {"arrivals": [0.0, 0.0], "jobs": [job, job]}, 4, moving_delay=700.0
+    )
+    inf = np.float32(np.inf)
+
+    def build(remaining, moving, execs):
+        """`execs`: per executor None (idle in the common pool),
+        ("run", job, stage, t) or ("move", job, stage, t)."""
+        e = {k: [] for k in (
+            "job", "stage", "tstage", "tvalid", "executing", "fin",
+            "moving", "arr", "dj", "ds", "common")}
+        for x in execs:
+            kind = x[0] if x else None
+            e["job"].append(x[1] if kind == "run" else -1)
+            e["stage"].append(x[2] if kind == "run" else -1)
+            e["tstage"].append(x[2] if kind == "run" else -1)
+            e["tvalid"].append(kind == "run")
+            e["executing"].append(kind == "run")
+            e["fin"].append(x[3] if kind == "run" else inf)
+            e["moving"].append(kind == "move")
+            e["arr"].append(x[3] if kind == "move" else inf)
+            e["dj"].append(x[1] if kind == "move" else -1)
+            e["ds"].append(x[2] if kind == "move" else -1)
+            e["common"].append(kind is None)
+        rem = jnp.asarray(remaining, jnp.int32)
+        mov = jnp.asarray(moving, jnp.int32)
+        st = base.replace(
+            job_arrived=jnp.ones(2, bool), round_ready=jnp.bool_(False),
+            stage_remaining=rem, moving_count=mov,
+            stage_executing=jnp.zeros_like(rem).at[
+                jnp.asarray([max(j, 0) for j in e["job"]]),
+                jnp.asarray([max(s_, 0) for s_ in e["stage"]]),
+            ].add(jnp.asarray(e["executing"], jnp.int32)),
+            exec_job=jnp.asarray(e["job"], jnp.int32),
+            exec_stage=jnp.asarray(e["stage"], jnp.int32),
+            exec_task_stage=jnp.asarray(e["tstage"], jnp.int32),
+            exec_task_valid=jnp.asarray(e["tvalid"]),
+            exec_executing=jnp.asarray(e["executing"]),
+            exec_finish_time=jnp.asarray(e["fin"], jnp.float32),
+            exec_finish_seq=jnp.arange(10, 14, dtype=jnp.int32),
+            exec_moving=jnp.asarray(e["moving"]),
+            exec_arrive_time=jnp.asarray(e["arr"], jnp.float32),
+            exec_arrive_seq=jnp.arange(20, 24, dtype=jnp.int32),
+            exec_dst_job=jnp.asarray(e["dj"], jnp.int32),
+            exec_dst_stage=jnp.asarray(e["ds"], jnp.int32),
+            exec_at_common=jnp.asarray(e["common"]),
+            seq_counter=jnp.int32(30),
+        )
+        st = st.replace(stage_sat=st.stage_saturated)
+        return st.replace(
+            unsat_parent_count=jnp.asarray(_golden_unsat(st), jnp.int32)
+        )
+
+    full = [[6, 6, 6, 6], [6, 6, 6, 6]]
+    none = np.zeros((2, 4), int)
+    states = {
+        # two executors finish tasks of (0, 0), which has two left: the
+        # second relaunch saturates it
+        "shared_stage": build(
+            [[2, 6, 6, 6], full[1]], none,
+            [("run", 0, 0, 10.0), ("run", 0, 0, 20.0), None, None],
+        ),
+        # an executor arrives at (1, 0), on the frontier, and starts;
+        # its finish is then (1, 0)'s, and relaunching there saturates
+        # it: the flip lands where the arrival re-targeted the finish
+        "retargeted_finish": build(
+            [full[0], [2, 6, 6, 6]], [[0] * 4, [1, 0, 0, 0]],
+            [("move", 1, 0, 5.0), None, None, None],
+        ),
+        # an executor arrives at (1, 2), whose parent is incomplete: it
+        # parks, (1, 2)'s demand rises to 1 and the stage un-saturates
+        "park_unsaturates": build(
+            [full[0], [6, 6, 1, 6]], [[0] * 4, [0, 0, 1, 0]],
+            [None, ("move", 1, 2, 5.0), None, None],
+        ),
+        # relaunches on stages with more tasks left than the pass has
+        # steps: nothing flips
+        "no_flip": build(
+            [[40, 6, 6, 6], [40, 6, 6, 6]], none,
+            [None, None, ("run", 1, 0, 10.0), ("run", 0, 0, 20.0)],
+        ),
+    }
+    stacked = jax.tree_util.tree_map(
+        lambda *a: jnp.stack(a), *states.values()
+    )
+    return params, bank, stacked, list(states)
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["shared_stage", "retargeted_finish", "park_unsaturates", "no_flip"],
+)
+def test_packed_refresh_matches_contraction_made_up(case):
+    """The four ways a pass moves (or leaves) a stage's saturation, on
+    hand-made states: the pass from the packed parent sets is leaf-equal
+    to the pass through the contraction, the caches equal their
+    recomputation afterwards, and each state does what it was made
+    for."""
+    import jax
+
+    from sparksched_tpu.env import core
+
+    params, bank, stacked, names = _made_up_pass_states()
+    i = names.index(case)
+    envs = jax.tree_util.tree_map(lambda a: a[i:i + 1], stacked)
+    on = np.ones(1, bool)
+    want = _run_pass_contracted(
+        params, bank, envs, on, max_events=8, how="vmap"
+    )
+    got = _run_pass(
+        params, bank, core._steps_while_active, envs, on,
+        max_events=8, how="vmap",
+    )
+    _assert_same_pass(got, want, case)
+    env, k_rel, k_rdy, _ = jax.tree_util.tree_map(
+        lambda a: np.asarray(a)[0], got
+    )
+    before = jax.tree_util.tree_map(lambda a: np.asarray(a)[0], envs)
+    np.testing.assert_array_equal(env.stage_sat, env.stage_saturated)
+    np.testing.assert_array_equal(
+        env.unsat_parent_count, _golden_unsat(env)
+    )
+    sat_moved = env.stage_sat.astype(int) - before.stage_sat.astype(int)
+    unsat_moved = env.unsat_parent_count - before.unsat_parent_count
+    want_sat = np.zeros((2, 4), int)
+    want_unsat = np.zeros((2, 4), int)
+    if case == "shared_stage":
+        assert (k_rel, k_rdy) == (2, 0)
+        want_sat[0, 0], want_unsat[0, 1:3] = 1, -1
+    elif case == "retargeted_finish":
+        assert (k_rel, k_rdy) == (1, 1)
+        want_sat[1, 0], want_unsat[1, 1:3] = 1, -1
+    elif case == "park_unsaturates":
+        assert (k_rel, k_rdy) == (0, 1)
+        want_sat[1, 2], want_unsat[1, 3] = -1, 1
+    else:
+        assert k_rel >= 2 and k_rdy == 0
+    np.testing.assert_array_equal(sat_moved, want_sat)
+    np.testing.assert_array_equal(unsat_moved, want_unsat)
+
+
 def test_collection_is_the_same_with_and_without_telemetry(monkeypatch):
     """The counters are pure adds beside the engine: a single-eval
     collection with `telemetry=None` is leaf-equal to one that carries

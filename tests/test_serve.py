@@ -34,6 +34,8 @@ from sparksched_tpu.serve import (
 from sparksched_tpu.serve.aot import abstract_like
 from sparksched_tpu.workload import make_workload_bank
 
+from .reference_fixtures import parent_sets_by_hand
+
 _i32 = jnp.int32
 
 
@@ -69,6 +71,34 @@ def _tiny_store_state(params, bank, capacity=2):
     return jax.tree_util.tree_map(
         lambda a: jnp.broadcast_to(a, (capacity,) + a.shape).copy(), ls
     )
+
+
+def test_create_writes_the_parent_sets_of_its_adjacency(setup):
+    """A session's slot holds the packed parent sets of ITS adjacency
+    (`EnvState.parent_sets`, what the fused bulk pass of the served
+    drain reads since PR 39): after `create` over whatever the slot
+    held, and still after decisions served from it."""
+    params, bank, sched = setup
+    store = SessionStore(params, bank, sched, capacity=3, max_batch=2,
+                         seed=0)
+
+    def check(sids):
+        env = store._stores[0].env
+        adj = np.asarray(env.adj)
+        np.testing.assert_array_equal(
+            np.asarray(env.parent_sets), parent_sets_by_hand(adj)
+        )
+        return [adj[s].copy() for s in sids]
+
+    first = check([store.create(seed=10 + i) for i in range(3)])
+    for sid in range(3):
+        for _ in range(4):
+            store.decide(sid)
+    check(range(3))
+    store.close(1)
+    assert store.create(seed=99) == 1  # the freed slot, another episode
+    again = check(range(3))
+    assert (again[1] != first[1]).any() and (again[0] == first[0]).all()
 
 
 # ---------------------------------------------------------------------------

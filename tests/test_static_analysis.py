@@ -212,6 +212,94 @@ def test_rule_budget_fires_on_eqn_and_gather_and_scatter():
     assert "budget" in _rules(vs) and measured["scatters"] >= 1
 
 
+def test_rule_loop_whole_read_fires_and_row_reads_clear():
+    """Inside a `while`, an equation that computes with a pinned-size
+    operand whole is a violation; a row read of it (a dynamic slice, a
+    gather), a select that hands it on, and the same equation OUTSIDE
+    the loop are not; an inner call or conditional is looked into."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    big = jnp.zeros((6, 5, 5), bool)
+
+    def loop(step):
+        def fn(x):
+            return lax.while_loop(
+                lambda c: c[0] < 3,
+                lambda c: (c[0] + 1, step(x, c[0], c[1])),
+                (jnp.int32(0), jnp.int32(0)),
+            )[1]
+        return fn
+
+    def whole(x, i, acc):
+        return acc + x.sum()
+
+    def whole_in_a_call(x, i, acc):
+        return acc + jax.jit(lambda a: a.astype(jnp.int32).sum())(x)
+
+    def whole_in_a_branch(x, i, acc):
+        return lax.cond(
+            i > 1, lambda a: acc + a.sum(), lambda a: acc, x
+        )
+
+    def by_rows(x, i, acc):
+        row = lax.dynamic_index_in_dim(x, i, keepdims=False)
+        return acc + row.sum() + x[i, i % 5].sum()
+
+    def handed_on(x, i, acc):
+        return acc + jnp.where(i > 1, x, x)[i, 0, 0]
+
+    def outside(x):
+        return loop(lambda x_, i, acc: acc + i)(x) + x.sum()
+
+    for step in (whole, whole_in_a_call, whole_in_a_branch):
+        vs, _ = _audit_one(loop(step), big, while_whole_read_elems=150)
+        assert "loop-whole-read" in _rules(vs), step.__name__
+        # a smaller operand than the pin is nobody's business
+        vs, _ = _audit_one(loop(step), big, while_whole_read_elems=151)
+        assert "loop-whole-read" not in _rules(vs), step.__name__
+    for fn in (loop(by_rows), loop(handed_on), outside):
+        vs, _ = _audit_one(fn, big, while_whole_read_elems=150)
+        assert "loop-whole-read" not in _rules(vs), vs
+    # not pinned, not looked for
+    vs, _ = _audit_one(loop(whole), big)
+    assert "loop-whole-read" not in _rules(vs)
+
+
+def test_drain_loop_reads_the_adjacency_by_rows_only(monkeypatch):
+    """What takes a counter's place for PR 39: no equation inside the
+    `while` of `drain_to_decision` (the one-lane program the collectors
+    vmap) and of `serve_decide` computes with an operand of the
+    adjacency's size, J*S*S elements; the fused pass refreshes
+    `unsat_parent_count` against the state's packed parent sets. With
+    the refresh as it was, a contraction of the whole adjacency in
+    every body, the rule names both programs."""
+    import jax.numpy as jnp
+
+    from sparksched_tpu.analysis import jaxpr_audit
+    from sparksched_tpu.env import core
+
+    names = ("drain_to_decision", "serve_decide", "micro_step")
+    for name in names:
+        assert (jaxpr_audit.BUDGETS[name].while_whole_read_elems
+                == jaxpr_audit.AUDIT_ADJ_ELEMS)
+    vs, measured = jaxpr_audit.audit_all(names=names)
+    assert set(measured) == set(names) and not vs, vs
+
+    monkeypatch.setattr(
+        core, "_flipped_parents",
+        lambda state, delta: jnp.einsum(
+            "jp,jpc->jc", delta, state.adj.astype(jnp.int32)
+        ),
+    )
+    vs, _ = jaxpr_audit.audit_all(names=names)
+    assert {(v.rule, v.where) for v in vs} == {
+        ("loop-whole-read", "drain_to_decision"),
+        ("loop-whole-read", "serve_decide"),
+    }, vs
+
+
 def test_unknown_program_name_is_an_error():
     from sparksched_tpu.analysis import jaxpr_audit
 

@@ -123,7 +123,17 @@ def test_batch_policy_compiles(flagship, one_chip):
     ).compile())
 
 
-def test_collect_and_ppo_update_compile(flagship, one_chip):
+@pytest.fixture(scope="module")
+def collector(flagship, one_chip):
+    """The trainer's collector compiled for the chip, once for the
+    tests that read it."""
+    state = _train_state(flagship, one_chip)
+    return flagship._collect_jit.lower(
+        state.params, state.iteration, state.rng, None
+    ).compile()
+
+
+def test_collect_and_ppo_update_compile(flagship, one_chip, collector):
     """The trainer's own two programs: the single-eval flat collector
     (`collect_flat_sync_batch`, 16 lanes, fused bulk pass, health on)
     and `ppo_update` with the health gate, on the collector's rollout,
@@ -139,7 +149,7 @@ def test_collect_and_ppo_update_compile(flagship, one_chip):
     assert flagship.rollout_steps == 9600
     state = _train_state(flagship, one_chip)
     args = (state.params, state.iteration, state.rng, None)
-    _fits(flagship._collect_jit.lower(*args).compile(), temp_gib=1.0)
+    _fits(collector, temp_gib=1.0)
     ro = jax.eval_shape(flagship._collect, *args)[0]
     _fits(flagship._update_jit.lower(state, _on(one_chip, ro)).compile(),
           temp_gib=5.0)
@@ -192,6 +202,86 @@ def test_level_scan_body_has_one_layout(flagship, one_chip):
     assert len(node_sized) >= 8, body  # six Dense layers, the sum, the select
     assert {layout for layout, _ in node_sized} == {"{0,3,2,1"}, node_sized
     assert not {op for _, op in node_sized} & {"copy", "transpose"}, node_sized
+
+
+def _called(text: str, root: str) -> dict[str, str]:
+    """The computations of an HLO module reachable from `root`, by
+    name: their bodies."""
+    import re
+
+    comps = {
+        m.group(1): m.group(2)
+        for m in re.finditer(
+            r"\n(?:ENTRY )?%([\w.\-]+) \([^\n]*\{\n(.*?)\n\}\n", text, re.S
+        )
+    }
+    calls = re.compile(
+        r"(?:calls|body|condition|to_apply|true_computation"
+        r"|false_computation)=%([\w.\-]+)|branch_computations=\{([^}]*)\}"
+    )
+    seen, todo = {}, [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen[name] = comps[name]
+        for m in calls.finditer(comps[name]):
+            todo += [m.group(1)] if m.group(1) else [
+                x.strip().lstrip("%") for x in m.group(2).split(",")
+            ]
+    return seen
+
+
+def test_drain_loop_writes_nothing_of_the_adjacency_s_size(
+    flagship, collector
+):
+    """What takes a counter's place for the fused pass's refresh of
+    `unsat_parent_count` (PR 39): in the collector compiled for the
+    v5e, no instruction of the drain `while` (its body and predicate,
+    and every fusion, conditional and inner loop they call) yields an
+    array with the adjacency's dimensions, lanes x 200 x 20 x 20 in
+    any order: no copy of it into another layout, no select or
+    product over it. The loop hands the adjacency on (parameters and
+    tuples) and reads rows of it (gathers, whose results are rows).
+    Until PR 39 the refresh selected and reduced the whole of it, as
+    stored (a job's 20 x 20 block padded to a tile: 105 MB at 128
+    lanes), in every body."""
+    import re
+
+    p = flagship.params_env
+    dims = sorted(
+        [flagship.num_envs, p.max_jobs, p.max_stages, p.max_stages]
+    )
+    text = collector.as_text()
+    loops = [
+        line for line in text.split("\n")
+        if " while(" in line and 'env/micro_step/drain)/while"' in line
+    ]
+    assert len(loops) == 1, len(loops)
+    inside = {}
+    for key in ("body", "condition"):
+        root = re.search(rf"{key}=%([\w.\-]+)", loops[0]).group(1)
+        inside |= _called(text, root)
+    assert len(inside) > 100, len(inside)  # the loop, not a stub of it
+
+    hands_on = {"parameter", "get-tuple-element", "tuple", "bitcast"}
+    found, carried = [], 0
+    for name, body in inside.items():
+        for line in body.split("\n"):
+            m = re.match(
+                r"\s*(?:ROOT )?%[\w.\-]+ = (\(?)(\w+)\[([\d,]*)\]"
+                r"[^ ]* ([\w\-]+)\(", line
+            )
+            if not m or m.group(1):
+                continue  # a tuple is handed on, whatever it holds
+            shape = sorted(int(d) for d in m.group(3).split(",") if d)
+            if shape == dims:
+                if m.group(4) in hands_on:
+                    carried += 1
+                else:
+                    found.append((name, line.strip()[:160]))
+    assert carried >= 2, carried  # the adjacency IS in the loop
+    assert not found, found
 
 
 @pytest.mark.parametrize("batched", [False, True])
