@@ -18,11 +18,11 @@ a thread of its own.
 `verify`, outside the window, holds the window's LAST rollout to the
 engine's sentinels and counts (no tripped health bit, as many valid
 decisions as the telemetry counted, every lane decides, finite rewards,
-times and log-probs), and scores a seeded sample of its stored decisions
-with the benchmark's plain numpy forward pass under the parameters that
-collected them: the recorded log-probability of the recorded action on
-the recorded observation, against the reference in plain float32 and at
-the stated precision.
+times and log-probs: `sentinel_checks`, which the other collector
+drivers hold their cells to as well), and scores a seeded sample of its
+stored decisions with the benchmark's plain numpy forward pass under the
+parameters that collected them (`benchmarks/logprob_check.py`, the one
+copy of that comparison): the mean gap and a high quantile of it.
 """
 
 from __future__ import annotations
@@ -33,8 +33,7 @@ import time
 
 import numpy as np
 
-from benchmarks import harness
-from benchmarks.reference import decima_np
+from benchmarks import harness, logprob_check
 
 HOST_SPANS = ("bench/collect",)
 UNATTRIBUTED = "rollout/host_gap"  # an idle gap under no host span
@@ -144,15 +143,18 @@ def measure(ctx: dict, seconds: float, tracer) -> dict:
     }
 
 
-def verify(ctx: dict, window: dict) -> list[dict]:
+def sentinel_checks(ctx: dict, window: dict) -> list[dict]:
+    """The engine's sentinels and counts on the window and its last
+    rollout: enough collections, no tripped health bit, as many valid
+    decisions as the telemetry counted, every lane decides, finite
+    rewards, times and log-probs."""
     import jax
 
-    conf = ctx["cell"]["config_data"]
-    params, ro = ctx["last"]
+    ro = ctx["last"][1]
     per_lane = np.asarray(jax.device_get(ro.valid)).sum(axis=1)
     finite = all(bool(np.isfinite(np.asarray(jax.device_get(a))).all())
                  for a in (ro.reward, ro.wall_times, ro.lgprob))
-    checks = [
+    return [
         harness.check("collections", len(window["scalars"]), int(
             ctx["cell"]["mix"]["min_collections"]), ">="),
         harness.check("health_mask", max(
@@ -164,67 +166,12 @@ def verify(ctx: dict, window: dict) -> list[dict]:
         harness.check("idle_lanes", int((per_lane == 0).sum()), 0, "=="),
         harness.check("rollout_finite", finite, True, "=="),
     ]
-    return checks + logprob_checks(
-        ctx["trainer"], params, ro, ctx["seed"], conf, conf["limits"])
 
 
-def logprob_checks(trainer, params, ro, seed: int, conf: dict,
-                   limits: dict) -> list[dict]:
-    """A seeded sample of the rollout's valid stored decisions against
-    the plain forward pass: the gap between the collector's recorded
-    log-probability and the reference's, for the recorded action on the
-    recorded observation, under the parameters that collected it; the
-    reference once in plain float32 and once at the stated precision."""
-    import jax
-
-    valid = np.asarray(jax.device_get(ro.valid))
-    lanes_t = np.argwhere(valid)
-    rng = np.random.default_rng(seed)
-    n = min(int(limits["logprob_sample"]), len(lanes_t))
-    pick = lanes_t[rng.choice(len(lanes_t), size=n, replace=False)]
-    bi, ti = pick[:, 0], pick[:, 1]
-    rows = jax.device_get(jax.tree_util.tree_map(
-        lambda a: a[bi, ti],
-        (ro.obs, ro.stage_idx, ro.num_exec_k, ro.lgprob)))
-    so, stage_idx, exec_k, lgprob = rows
-    weights = jax.tree_util.tree_map(np.asarray, jax.device_get(params))
-    adj_bank = np.asarray(trainer.bank.adj)
-    j, s = so.job_mask.shape[1], adj_bank.shape[-1]
-    gaps = {"float32": [], "bf16_operands": []}
-    for i in range(n):
-        obs = {
-            name: np.asarray(getattr(so, name)[i])[: j * s].reshape(j, s)
-            for name in ("remaining", "duration", "schedulable",
-                         "node_mask")}
-        obs |= {"job_mask": so.job_mask[i],
-                "exec_supplies": so.exec_supplies[i],
-                "num_committable": so.num_committable[i],
-                "source_job": so.source_job[i],
-                "adj": adj_bank[np.asarray(so.job_template[i])]}
-        for matmul, out in gaps.items():
-            ref = decima_np.score_action(
-                weights, obs, int(stage_idx[i]), int(exec_k[i]),
-                trainer.params_env.num_executors,
-                gnn_slope=conf["model"]["gnn_negative_slope"],
-                matmul=matmul)
-            out.append(abs(float(lgprob[i]) - ref["lgprob"]))
-    return [harness.check("logprob_sample", n, 1, ">=")] + gap_checks(
-        gaps, limits)
-
-
-def gap_checks(gaps: dict, limits: dict) -> list[dict]:
-    """The log-probability gaps against the plain float32 reference and
-    against the reference at the stated precision (bfloat16 operands),
-    mean and widest, each beside its limit."""
-    out = []
-    for matmul, key in (("float32", "gap"), ("bf16_operands", "stated_gap")):
-        g = np.asarray(gaps[matmul])
-        for how, value in (("mean", g.mean() if g.size else np.nan),
-                           ("max", g.max() if g.size else np.nan)):
-            out.append(harness.check(
-                f"logprob_{key}_{how}", float(value),
-                limits[f"logprob_{key}_{how}"], "<="))
-    return out
+def verify(ctx: dict, window: dict) -> list[dict]:
+    params, ro = ctx["last"]
+    return sentinel_checks(ctx, window) + logprob_check.checks(
+        ctx["trainer"], params, ro, ctx["seed"], ctx["cell"]["config_data"])
 
 
 def close(ctx: dict) -> None:

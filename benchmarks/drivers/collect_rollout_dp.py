@@ -16,7 +16,7 @@ first looks for the program's multi-chip configuration, as
 (a) the sentinels and counts of `collect_rollout`;
 (b) a seeded sample of its stored decisions against the plain forward
     pass at the stated precision, by the mean gap and a high quantile
-    of it, as `collect_stream` takes them (its functions);
+    of it (`benchmarks/logprob_check.py`, as every collector cell);
 (c) the mesh guarantee. A second trainer, built from the same
     configuration with `parallel.dp: 1` and `rollout_steps: R` (the
     configuration's `limits.mesh_check_rows`), collects the same
@@ -72,8 +72,8 @@ import time
 
 import numpy as np
 
-from benchmarks import harness
-from benchmarks.drivers import collect_rollout, collect_stream
+from benchmarks import harness, logprob_check
+from benchmarks.drivers import collect_rollout
 
 HOST_SPANS = collect_rollout.HOST_SPANS
 UNATTRIBUTED = collect_rollout.UNATTRIBUTED
@@ -109,32 +109,12 @@ close = collect_rollout.close
 
 
 def verify(ctx: dict, window: dict) -> list[dict]:
-    import jax
-
     conf = ctx["cell"]["config_data"]
     limits = conf["limits"]
     params, ro = ctx["last"]
-    per_lane = np.asarray(jax.device_get(ro.valid)).sum(axis=1)
-    finite = all(bool(np.isfinite(np.asarray(jax.device_get(a))).all())
-                 for a in (ro.reward, ro.wall_times, ro.lgprob))
-    checks = [
-        harness.check("collections", len(window["scalars"]), int(
-            ctx["cell"]["mix"]["min_collections"]), ">="),
-        harness.check("health_mask", max(
-            (t["health_mask"] for t in window["telemetry"]), default=None),
-            0, "=="),
-        harness.check("telemetry_decisions_gap", sum(
-            t["decisions"] for t in window["telemetry"])
-            - window["samples"]["decisions"], 0, "=="),
-        harness.check("idle_lanes", int((per_lane == 0).sum()), 0, "=="),
-        harness.check("rollout_finite", finite, True, "=="),
-    ]
-    t0 = time.perf_counter()
-    gaps = collect_stream.logprob_gaps(
-        ctx["trainer"], params, ro, ctx["seed"], conf,
-        int(limits["logprob_sample"]))
-    checks += collect_stream.gap_checks(gaps, limits)
-    harness.say(reference_seconds=time.perf_counter() - t0)
+    checks = collect_rollout.sentinel_checks(ctx, window)
+    checks += logprob_check.checks(
+        ctx["trainer"], params, ro, ctx["seed"], conf)
     return checks + mesh_checks(
         ctx, window["scalars"][-1]["collection"],
         int(limits["mesh_check_rows"]), limits)

@@ -23,13 +23,15 @@ times, `resets`, reset counts, job templates: 0.1 GB of the 4.4) and the
 lane's last valid row of `remaining` and `node_mask`.
 
 `verify`, outside the window: the sentinels and counts of
-`collect_rollout`; the four guarantees of streaming on the window's last
-two collections by the plain reference `reference/stream_np.py`
+`collect_rollout` (its `sentinel_checks`); the four guarantees of
+streaming on the window's last two collections by the plain reference
+`reference/stream_np.py`
 (budget, persistence, group-shared re-seeding, counts; a check on a
 counter the program may lack is left out where the summary has no such
 key); and the collector's recorded log-probabilities of a seeded sample
 of the last rollout's decisions against the plain forward pass
-`reference/decima_np.py`, the mean gap and a high quantile of it, which
+`reference/decima_np.py` (`benchmarks/logprob_check.py`, the one copy
+of that comparison), the mean gap and a high quantile of it, which
 unlike the widest gap of a few dozen parts a sound run from one computed
 in bfloat16 (PERF.md, PR 30).
 """
@@ -42,8 +44,9 @@ import time
 
 import numpy as np
 
-from benchmarks import harness
-from benchmarks.reference import decima_np, stream_np
+from benchmarks import harness, logprob_check
+from benchmarks.drivers import collect_rollout
+from benchmarks.reference import stream_np
 
 HOST_SPANS = ("bench/collect",)
 UNATTRIBUTED = "rollout/host_gap"  # an idle gap under no host span
@@ -184,33 +187,15 @@ def measure(ctx: dict, seconds: float, tracer) -> dict:
 
 
 def verify(ctx: dict, window: dict) -> list[dict]:
-    import jax
-
-    conf = ctx["cell"]["config_data"]
     trainer = ctx["trainer"]
     params, ro = ctx["last"]
-    per_lane = np.asarray(jax.device_get(ro.valid)).sum(axis=1)
-    finite = all(bool(np.isfinite(np.asarray(jax.device_get(a))).all())
-                 for a in (ro.reward, ro.wall_times, ro.lgprob))
-    checks = [
-        harness.check("collections", len(window["scalars"]), int(
-            ctx["cell"]["mix"]["min_collections"]), ">="),
-        harness.check("health_mask", max(
-            (t["health_mask"] for t in window["telemetry"]), default=None),
-            0, "=="),
-        harness.check("telemetry_decisions_gap", sum(
-            t["decisions"] for t in window["telemetry"])
-            - window["samples"]["decisions"], 0, "=="),
-        harness.check("idle_lanes", int((per_lane == 0).sum()), 0, "=="),
-        harness.check("rollout_finite", finite, True, "=="),
-    ]
     telemetry = window["telemetry"]
+    checks = collect_rollout.sentinel_checks(ctx, window)
     checks += stream_checks(
         ctx["prev"], ctx["tail"], ro, trainer,
         telemetry[-1] if telemetry else None)
-    gaps = logprob_gaps(trainer, params, ro, ctx["seed"], conf,
-                        int(conf["limits"]["logprob_sample"]))
-    return checks + gap_checks(gaps, conf["limits"])
+    return checks + logprob_check.checks(
+        trainer, params, ro, ctx["seed"], ctx["cell"]["config_data"])
 
 
 def stream_checks(prev: dict, cur: dict, ro, trainer,
@@ -235,70 +220,6 @@ def stream_checks(prev: dict, cur: dict, ro, trainer,
     return [harness.check(k, v, 0, "==") for k, v in found.items()] + [
         harness.check("stream_episodes_seen", seen,
                       trainer.num_sequences, ">=")]
-
-
-def logprob_gaps(trainer, params, ro, seed: int, conf: dict, sample: int
-                 ) -> dict:
-    """A seeded sample of the rollout's valid stored decisions against
-    the plain forward pass, as `collect_rollout.logprob_checks` takes
-    it: the gaps between the collector's recorded log-probability and
-    the reference's, the reference in plain float32 and at the stated
-    precision."""
-    import jax
-
-    valid = np.asarray(jax.device_get(ro.valid))
-    lanes_t = np.argwhere(valid)
-    rng = np.random.default_rng(seed)
-    n = min(sample, len(lanes_t))
-    pick = lanes_t[rng.choice(len(lanes_t), size=n, replace=False)]
-    bi, ti = pick[:, 0], pick[:, 1]
-    so, stage_idx, exec_k, lgprob = jax.device_get(jax.tree_util.tree_map(
-        lambda a: a[bi, ti],
-        (ro.obs, ro.stage_idx, ro.num_exec_k, ro.lgprob)))
-    weights = jax.tree_util.tree_map(np.asarray, jax.device_get(params))
-    adj_bank = np.asarray(trainer.bank.adj)
-    j, s = so.job_mask.shape[1], adj_bank.shape[-1]
-    gaps = {"float32": [], "bf16_operands": []}
-    for i in range(n):
-        obs = {
-            name: np.asarray(getattr(so, name)[i])[: j * s].reshape(j, s)
-            for name in ("remaining", "duration", "schedulable",
-                         "node_mask")}
-        obs |= {"job_mask": so.job_mask[i],
-                "exec_supplies": so.exec_supplies[i],
-                "num_committable": so.num_committable[i],
-                "source_job": so.source_job[i],
-                "adj": adj_bank[np.asarray(so.job_template[i])]}
-        for matmul, out in gaps.items():
-            ref = decima_np.score_action(
-                weights, obs, int(stage_idx[i]), int(exec_k[i]),
-                trainer.params_env.num_executors,
-                gnn_slope=conf["model"]["gnn_negative_slope"],
-                matmul=matmul)
-            out.append(abs(float(lgprob[i]) - ref["lgprob"]))
-    return {k: np.asarray(v) for k, v in gaps.items()}
-
-
-def gap_checks(gaps: dict, limits: dict) -> list[dict]:
-    """The sample's size; the mean gap against the plain float32
-    reference (a gross fault: a wrong action, row or weight); against
-    the reference at the stated precision the mean gap and the
-    `logprob_stated_gap_q` quantile of the gaps, each beside its limit."""
-    plain, stated = gaps["float32"], gaps["bf16_operands"]
-    nan = float("nan")
-    q = float(limits["logprob_stated_gap_q"])
-    return [
-        harness.check("logprob_sample", int(stated.size), 1, ">="),
-        harness.check("logprob_gap_mean",
-                      float(plain.mean()) if plain.size else nan,
-                      limits["logprob_gap_mean"], "<="),
-        harness.check("logprob_stated_gap_mean",
-                      float(stated.mean()) if stated.size else nan,
-                      limits["logprob_stated_gap_mean"], "<="),
-        harness.check("logprob_stated_gap_quantile",
-                      float(np.quantile(stated, q)) if stated.size else nan,
-                      limits["logprob_stated_gap_quantile"], "<="),
-    ]
 
 
 def close(ctx: dict) -> None:

@@ -194,11 +194,13 @@ class Tracer:
     them."""
 
     def __init__(self, cell_name: str, chips: int,
-                 host_spans: tuple[str, ...], unattributed: str) -> None:
+                 host_spans: tuple[str, ...], unattributed: str,
+                 scopes: tuple[str, ...] = ()) -> None:
         self.dir = osp.join(OUT_DIR, cell_name, "trace")
         self.chips = chips
         self.host_spans = host_spans
         self.unattributed = unattributed
+        self.scopes = scopes  # looked for besides the reducer's own
         self.running = False
 
     def start(self) -> None:
@@ -231,7 +233,8 @@ class Tracer:
             raise RuntimeError(f"the profiler wrote no trace to {self.dir}")
         reduced = trace_reduce.reduce_file(
             paths[0], chips=self.chips, host_spans=self.host_spans,
-            unattributed=self.unattributed, window_span=WINDOW_SPAN)
+            unattributed=self.unattributed, window_span=WINDOW_SPAN,
+            scopes=trace_reduce.scope_names(self.scopes))
         shutil.rmtree(self.dir, ignore_errors=True)  # hundreds of MB
         return reduced
 
@@ -268,6 +271,15 @@ def metrics_of_cell(bench: dict, cell_name: str, kind: str) -> list[dict]:
     reports: those listing it, and those with no `workloads` key."""
     return [m for m in bench[kind]
             if cell_name in m.get("workloads", [cell_name])]
+
+
+def metric_scopes(base: str = HERE) -> tuple[str, ...]:
+    """Every `scope` value of the per-layer metrics' data files, sorted:
+    the names a traced run's reducer looks for besides its own, so a
+    metric over a scope that no list has is its data file alone."""
+    specs = (load_json(path, base="") for path in glob.glob(
+        osp.join(base, "layer_metrics", "*.json")))
+    return tuple(sorted({s["scope"] for s in specs if s.get("scope")}))
 
 
 def _load_file(path: str):

@@ -1,6 +1,7 @@
 """One key of the reduced profiler trace (`trace_reduce.reduce_events`)
-over another: collective time a collection (`over="units"`), or its
-uncovered part as a share of the traced window. A reduced trace that
+over another: collective time or device time under no scope a
+collection (`over="units"`), or the collectives' uncovered part as a
+share of the traced window. A reduced trace that
 lacks the key saw no such time: 0."""
 
 

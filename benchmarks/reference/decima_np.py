@@ -20,7 +20,14 @@ the children's message sum) are rounded to bfloat16, products and sums
 are exact, and everything between the products stays float32. A program
 at the stated precision differs from that by the order of its sums
 alone; one that also keeps its activations in bfloat16, the next
-precision down, does not.
+precision down, does not. `"bf16_compute"` is that next precision down,
+the reference put in such a program's place: operands rounded as above,
+and every value a layer hands on (a Dense layer's output before and
+after its activation, the features, a message sum, a node's updated
+embedding, the per-job and global summaries) rounded to bfloat16 too;
+the scores' log-softmax stays exact. It is the yardstick a cell's
+`correct` measures a program's gaps with: how far the lower precision
+moves the SAME decisions under the SAME weights.
 
 Departures from upstream, as the program makes them: graphs are padded
 to [jobs, stages] and masked; the executor head is evaluated for every
@@ -50,19 +57,24 @@ def bf16(x: np.ndarray) -> np.ndarray:
 def _operand(x: np.ndarray, matmul: str) -> np.ndarray:
     if matmul == "float32":
         return np.asarray(x, np.float64)
-    if matmul == "bf16_operands":
+    if matmul in ("bf16_operands", "bf16_compute"):
         return bf16(x)
     raise ValueError(f"unknown matmul treatment {matmul!r}")
+
+
+def _stored(x: np.ndarray, matmul: str) -> np.ndarray:
+    """A value a layer hands on: bfloat16 at the lower precision."""
+    return bf16(x) if matmul == "bf16_compute" else x
 
 
 def _mlp(tree: dict, x: np.ndarray, act, matmul: str) -> np.ndarray:
     n = len(tree)
     for i in range(n):
         d = tree[f"dense_{i}"]
-        x = _operand(x, matmul) @ _operand(d["kernel"], matmul) \
-            + np.asarray(d["bias"], np.float64)
+        x = _stored(_operand(x, matmul) @ _operand(d["kernel"], matmul)
+                    + np.asarray(d["bias"], np.float64), matmul)
         if i < n - 1:
-            x = act(x)
+            x = _stored(act(x), matmul)
     return x
 
 
@@ -141,7 +153,7 @@ def forward(weights: dict, f: dict, num_executors: int,
     execs = np.zeros((j_cap, num_executors))
     if jobs.size == 0:
         return stage, execs
-    x = f["x"][jobs]
+    x = _stored(f["x"][jobs], matmul)
     mask = f["node_mask"][jobs]
     adj = f["adj"][jobs].astype(np.float64)  # [parent, child]
     has_child = f["adj"][jobs].any(axis=-1)
@@ -157,14 +169,15 @@ def forward(weights: dict, f: dict, num_executors: int,
             upd = (level == lvl) & has_child & mask
             if not upd.any():
                 continue
-            agg = adj @ _operand(mlp("mlp_msg", h, g_act), matmul)
-            h = np.where(upd[..., None],
-                         h_init + mlp("mlp_update", agg, g_act), h)
+            agg = _stored(
+                adj @ _operand(mlp("mlp_msg", h, g_act), matmul), matmul)
+            h = np.where(upd[..., None], _stored(
+                h_init + mlp("mlp_update", agg, g_act), matmul), h)
     h = np.where(mask[..., None], h, 0.0)
 
     z = mlp("mlp_dag", np.concatenate([x, h], axis=-1), g_act)
-    h_dag = np.where(mask[..., None], z, 0.0).sum(axis=-2)
-    h_glob = mlp("mlp_glob", h_dag, g_act).sum(axis=0)
+    h_dag = _stored(np.where(mask[..., None], z, 0.0).sum(axis=-2), matmul)
+    h_glob = _stored(mlp("mlp_glob", h_dag, g_act).sum(axis=0), matmul)
 
     d = h_dag.shape[-1]
     stage_in = np.concatenate([

@@ -10,8 +10,10 @@ measures for `--seconds`, checks what the window produced outside the
 window, and prints as its last line one JSON object: `correct`,
 `attempted`, `failed`, `metrics`, `device` and, traced, `breakdown`.
 With `--trace 0` the metrics are the cell's end-to-end metrics, with
-`--trace 1` its per-layer metrics; every number compared, with its
-limit, and every timing's sample count are on earlier lines.
+`--trace 1` its per-layer metrics; every number compared is beside its
+limit on an earlier line, under the result line's last key `checks`
+(`name: [value, limit]`) and on the last lines of standard error; every
+timing's sample count is on an earlier line.
 `--control <name>` (the builder's, never the driver's) runs the cell
 with the lower-precision overrides its configuration file lists, to
 show that `correct` then comes out false.
@@ -23,6 +25,7 @@ T0 = time.perf_counter()  # set-up counts from here: imports included
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os.path as osp  # noqa: E402
 import sys  # noqa: E402
 
@@ -48,7 +51,7 @@ def run_cell(bench: dict, cell: dict, *, seed: int, seconds: float,
         if trace:
             tracer = harness.Tracer(
                 cell["name"], cell["chips"], tuple(driver.HOST_SPANS),
-                driver.UNATTRIBUTED)
+                driver.UNATTRIBUTED, harness.metric_scopes())
         compiles.armed = True
         window = driver.measure(ctx, seconds, tracer)
         compiles.armed = False
@@ -94,7 +97,18 @@ def run_cell(bench: dict, cell: dict, *, seed: int, seconds: float,
             "checks_failed": [c["check"] for c in checks if not c["ok"]]}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    # every number compared beside its limit: the line's last key
+    line["checks"] = {
+        c["check"]: [_shown(c["value"]), c["limit"]] for c in checks}
     return line
+
+
+def _shown(value):
+    """A compared value as the result line shows it: a float that is no
+    number goes as text (`NaN` is not JSON)."""
+    if isinstance(value, float) and not math.isfinite(value):
+        return str(value)
+    return value
 
 
 def run(argv: list[str] | None = None, *, t0: float = T0) -> dict:
@@ -125,4 +139,7 @@ def run(argv: list[str] | None = None, *, t0: float = T0) -> dict:
 
 
 if __name__ == "__main__":
-    print(json.dumps(run(), default=harness.plain), flush=True)
+    result = run()
+    print(json.dumps(result, default=harness.plain), flush=True)
+    for name, (value, limit) in result["checks"].items():
+        print(f"{name} {value} limit {limit}", file=sys.stderr, flush=True)

@@ -11,9 +11,12 @@ text, control flow (`while`, `conditional`, a called computation) as an
 event that contains its body's events. An operation's
 `jax.named_scope` path is in the HLO `op_name`, a string statistic of
 the event's metadata entry (`xplane_meta.py` reads those), so a scope is
-matched on that text. Times of events that contain one another
-are never added twice: every sum here is over a union of intervals or
-over self times.
+matched on that text. Which scopes: `KNOWN_SCOPES`, the program's
+top-level ones, and whatever the caller hands in besides; the harness
+hands in every `scope` value of `layer_metrics/*.json`, so a metric
+over a scope the list lacks is a data file and no edit here. Times of
+events that contain one another are never added twice: every sum here
+is over a union of intervals or over self times.
 """
 
 from __future__ import annotations
@@ -26,11 +29,16 @@ COLLECTIVE = re.compile(
     r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all"
     r"|all_reduce|all_gather|reduce_scatter|collective_permute|all_to_all"
     r"|psum|ppermute")
-# the scopes the program labels (`obs/tracing.annotate`), for the
-# operations' names in the breakdown
+# the program's top-level scopes (`obs/tracing.annotate`): the default
+# where a caller names none, and what "under no scope" is measured
+# against. A sub-scope is ONE name that holds its parent's
+# (`env/micro_step/drain`), so its time is its parent's too, and a
+# metric over one comes as a data file (`scope_names`)
 KNOWN_SCOPES = (
     "decima/gnn", "env/micro_step", "collect/scatter", "train/ppo_update",
-    "serve/decide_batch", "serve/decide", "serve/dispatch", "serve/flush")
+    "serve/decide_batch", "serve/decide", "serve/dispatch", "serve/flush",
+    "collect/observe", "collect/freeze", "collect/health",
+    "decima/features", "decima/sample")
 
 
 def union(intervals: list[tuple[float, float]]) -> list[tuple[float, float]]:
@@ -107,25 +115,38 @@ def _clip(events: list[dict], window: tuple[float, float]) -> list[dict]:
     return out
 
 
-def scopes_in(text: str) -> tuple[str, ...]:
-    """The program's scopes in an operation's text, outermost first."""
-    found = [(text.rfind(s), s) for s in KNOWN_SCOPES if s in text]
-    return tuple(s for _, s in sorted(found))
+def scope_names(more=()) -> tuple[str, ...]:
+    """`KNOWN_SCOPES` and the names in `more`, each once."""
+    return tuple(dict.fromkeys(KNOWN_SCOPES + tuple(more)))
+
+
+def scopes_in(text: str, scopes: tuple[str, ...] = KNOWN_SCOPES
+              ) -> tuple[str, ...]:
+    """Those of `scopes` in an operation's text, outermost first. A
+    scope is matched as a substring, so a sub-scope's name is found
+    where its parent's is: the longer one is the inner one."""
+    found = [(text.rfind(s), len(s), s) for s in scopes if s in text]
+    return tuple(s for _, _, s in sorted(found))
 
 
 def reduce_events(device_ops: dict[int, list[dict]], host: list[dict], *,
                   window: tuple[float, float], chips: int,
                   host_spans: tuple[str, ...] = (),
-                  unattributed: str = "host/other") -> dict:
+                  unattributed: str = "host/other",
+                  scopes: tuple[str, ...] = KNOWN_SCOPES) -> dict:
     """`device_ops[n]` are the operation events of device n: dicts with
     `name`, `start`, `dur` (seconds) and `text` (name and statistics as
-    one string). `host` are host events (`name`, `start`, `dur`)."""
+    one string). `host` are host events (`name`, `start`, `dur`).
+    `scopes` are the names looked for in an operation's text.
+    `unscoped_s` is the busy time that no operation under any of them
+    covers (the loop's own operations and the copies the compiler makes
+    between scopes), averaged over the devices like a scope's time."""
     devices = sorted(device_ops)[:chips]
     if not devices:
         raise ValueError("the trace has no device plane")
     window_s = window[1] - window[0]
     device_ops = {d: _clip(device_ops[d], window) for d in devices}
-    busy, scopes, coll, coll_exposed = [], {}, 0.0, 0.0
+    busy, by_name, unscoped, coll, coll_exposed = [], {}, 0.0, 0.0, 0.0
     ops_time: dict[str, float] = {}
     gaps_by: dict[str, float] = {}
     spans = sorted((h for h in host if h["name"] in host_spans),
@@ -136,14 +157,18 @@ def reduce_events(device_ops: dict[int, list[dict]], host: list[dict], *,
         ivs = union([(e["start"], e["start"] + e["dur"]) for e in evs])
         busy.append(total(ivs))
         by_scope: dict[str, list] = {}
+        scoped: list[tuple[float, float]] = []
         for e in evs:
             if e["text"] not in known:
-                known[e["text"]] = scopes_in(e["text"])
+                known[e["text"]] = scopes_in(e["text"], scopes)
+            iv = (e["start"], e["start"] + e["dur"])
             for s in known[e["text"]]:
-                by_scope.setdefault(s, []).append(
-                    (e["start"], e["start"] + e["dur"]))
+                by_scope.setdefault(s, []).append(iv)
+            if known[e["text"]]:
+                scoped.append(iv)
         for s, iv in by_scope.items():
-            scopes[s] = scopes.get(s, 0.0) + total(union(iv)) / len(devices)
+            by_name[s] = by_name.get(s, 0.0) + total(union(iv)) / len(devices)
+        unscoped += total(subtract(ivs, union(scoped))) / len(devices)
         if d != devices[0]:
             continue
         c_iv = union([(e["start"], e["start"] + e["dur"]) for e in evs
@@ -179,7 +204,8 @@ def reduce_events(device_ops: dict[int, list[dict]], host: list[dict], *,
         "window_s": window_s,
         "busy_s": sum(busy) / len(busy),
         "busy_s_per_device": busy,
-        "scopes": scopes,
+        "scopes": by_name,
+        "unscoped_s": unscoped,
         "collective_s": coll,
         "collective_exposed_s": coll_exposed,
         "top_ops": [[k, v] for k, v in top[:10]],
@@ -235,7 +261,9 @@ def load(path: str, window_span: str | None = None
 
 def reduce_file(path: str, *, chips: int, host_spans: tuple[str, ...] = (),
                 unattributed: str = "host/other",
-                window_span: str | None = None) -> dict:
+                window_span: str | None = None,
+                scopes: tuple[str, ...] = KNOWN_SCOPES) -> dict:
     device_ops, host, window = load(path, window_span)
     return reduce_events(device_ops, host, window=window, chips=chips,
-                         host_spans=host_spans, unattributed=unattributed)
+                         host_spans=host_spans, unattributed=unattributed,
+                         scopes=scopes)

@@ -162,6 +162,12 @@ def test_checks_fail_on_a_missing_number():
     assert not harness.check("a", None, 2.0)["ok"]
     assert harness.check("a", 0, 0, "==")["ok"]
     assert harness.check("a", 5, 3, ">=")["ok"]
+    # and the result line shows it as JSON that any parser reads
+    from benchmarks import run
+
+    shown = [run._shown(v) for v in (float("nan"), float("inf"), 0.5, None)]
+    assert shown == ["nan", "inf", 0.5, None]
+    assert "NaN" not in json.dumps(shown)
 
 
 def test_seeds_past_31_bits_make_keys_and_config_seeds():
@@ -174,7 +180,13 @@ def test_seeds_past_31_bits_make_keys_and_config_seeds():
 
 
 def test_benchmark_json_keeps_to_the_contract():
-    bench = harness.load_benchmark()
+    keeps_to_the_contract(harness.load_benchmark())
+
+
+def keeps_to_the_contract(bench: dict, *, base: str = harness.HERE,
+                          root: str = harness.ROOT) -> None:
+    """The contract's limits on `BENCHMARK.json` (`bench`, at `root`,
+    its files under `base`)."""
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
     assert 1 <= bench["run_seconds"] <= 51
@@ -194,7 +206,7 @@ def test_benchmark_json_keeps_to_the_contract():
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert NAME.match(c["name"]) and 1 <= len(c["source"]) <= 200
         assert any(c["file"].startswith(p + "/") for p in paths)
-        assert os.path.exists(os.path.join(harness.ROOT, c["file"]))
+        assert os.path.exists(os.path.join(root, c["file"]))
         assert c["file"] not in files
         files.add(c["file"])
         assert len(c["reduced"]) <= 16
@@ -209,7 +221,7 @@ def test_benchmark_json_keeps_to_the_contract():
         assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"]
         assert (w["config"], w["traffic"]) not in pairs
         pairs.add((w["config"], w["traffic"]))
-        cell = harness.load_cell(w["name"], bench)  # both files exist
+        cell = harness.load_cell(w["name"], bench, base=base)  # both exist
         assert harness.load_driver(cell["mix"]["driver"])
         assert cell["config_data"]["lower_precision"]
     four = sum(w["chips"] == 4 for w in bench["workloads"])
@@ -234,7 +246,7 @@ def test_benchmark_json_keeps_to_the_contract():
         layers.add(m["layer"])
         reports = set(e2e[m["moves"]].get("workloads", cells))
         assert set(m.get("workloads", cells)) <= reports, m["name"]
-        spec = os.path.join(harness.HERE, "layer_metrics", m["name"])
+        spec = os.path.join(base, "layer_metrics", m["name"])
         assert os.path.exists(spec + ".json") or os.path.exists(spec + ".py")
     names = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]]
     assert len(names) == len(set(names))

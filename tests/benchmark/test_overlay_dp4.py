@@ -4,27 +4,39 @@ the driver `collect_rollout_dp`, the reader `trace_value` and seventeen
 `dp4.*` metrics (a twin for each of `decima_rollout`'s fourteen, and
 three of the mesh's own). The parent's program runs the new cell (it has
 `config/decima_tpch_multichip.yaml` and the mesh) and is traced in
-every cell with these files laid over it."""
+every cell with these files laid over it. What is pinned is about what
+PR 34 added; what later PRs add to the cell follows it and is held by
+the rules of `test_overlay.py`."""
 
 import math
 
 import pytest
 
 from benchmarks import harness, trace_reduce
-from tests.benchmark.test_overlay import PARENT_SUMMARY, PARENT_WINDOW
+from tests.benchmark.test_overlay import (
+    FIRST_METRICS,
+    PARENT_SUMMARY,
+    PARENT_WINDOW,
+    may_read_nothing,
+    metric_spec,
+)
 
 BENCH = harness.load_benchmark()
 CELL = "decima_rollout_dp4"
 DP4 = [m for m in BENCH["per_layer"] if m["name"].startswith("dp4.")]
+PR34 = [m for m in DP4 if m["name"] in FIRST_METRICS[CELL]]
 # what a four-chip traced window adds to the parent's: the reducer's
 # collective keys (it is the benchmark's own file, the same on both
 # sides of a comparison)
 MESH_TRACE = dict(
     PARENT_WINDOW["trace"], collective_s=0.02, collective_exposed_s=0.015)
+# and a window of a program that has every scope a data file names
+FULL_TRACE = dict(MESH_TRACE, unscoped_s=0.04, scopes={
+    s: 0.01 for s in trace_reduce.scope_names(harness.metric_scopes())})
 
 
 def test_every_entry_added_lists_the_new_cell_and_nothing_else():
-    assert len(DP4) == 17
+    assert len(PR34) == 17 and DP4[:17] == PR34
     for m in DP4:
         assert m["workloads"] == [CELL], m["name"]
         assert m["moves"] == "rollout_decisions_per_s"
@@ -34,7 +46,7 @@ def test_every_entry_added_lists_the_new_cell_and_nothing_else():
         CELL]
     rate = {m["name"]: m for m in BENCH["end_to_end"]}[
         "rollout_decisions_per_s"]
-    assert rate["workloads"] == ["decima_rollout", "decima_stream", CELL]
+    assert rate["workloads"][:3] == ["decima_rollout", "decima_stream", CELL]
     assert [m["name"] for m in harness.metrics_of_cell(
         BENCH, CELL, "per_layer")] == [m["name"] for m in DP4]
 
@@ -42,16 +54,20 @@ def test_every_entry_added_lists_the_new_cell_and_nothing_else():
 @pytest.mark.parametrize("name", [m["name"] for m in DP4])
 def test_a_dp4_reader_reads_the_parents_window(name):
     """On the parent's window a reader gives a number, or None for the
-    one metric over the counter the parent lacks; on a window with the
-    mesh's trace keys and the counter, every one gives a number."""
+    one metric over the counter the parent lacks and for a metric over
+    a scope the window does not hold; on a window with the mesh's trace
+    keys, every scope and the counter, every one gives a number."""
     value = harness.read_layer_metric(name, PARENT_WINDOW)
     if name == "dp4.lane_syncs_per_row":
         assert value is None
+    elif value is None:
+        assert "scope" in metric_spec(name)
+        assert may_read_nothing(name, PARENT_WINDOW)
     else:
         assert isinstance(value, float) and math.isfinite(value)
     summary = dict(PARENT_SUMMARY, row=dict(
         PARENT_SUMMARY["row"], lane_syncs=52000))
-    window = dict(PARENT_WINDOW, telemetry=[summary] * 3, trace=MESH_TRACE)
+    window = dict(PARENT_WINDOW, telemetry=[summary] * 3, trace=FULL_TRACE)
     assert math.isfinite(harness.read_layer_metric(name, window))
     assert harness.read_layer_metric(name, {}) is None
 
@@ -80,18 +96,18 @@ def test_the_mesh_metrics_read_the_reducers_keys():
 
 def test_the_summary_gains_one_row_key_where_rows_were_counted():
     """`summarize` gives `row.lane_syncs` where the collector counted
-    rows and keeps the parent's keys where it counted none (the
-    fixture test of `test_overlay.py` summarizes zeros)."""
+    rows, beside every key the parent's `row` block had; over a
+    telemetry of zeros the key reads 0 or is absent."""
     import jax.numpy as jnp
 
     from sparksched_tpu.obs.telemetry import summarize, telemetry_zeros_like
 
     zeros = telemetry_zeros_like((2,))
-    assert "lane_syncs" not in summarize(zeros)["row"]
+    assert not summarize(zeros)["row"].get("lane_syncs")
     counted = summarize(zeros.replace(
         rows=jnp.full((2,), 3), lane_syncs=jnp.full((2,), 40)))
     assert counted["row"]["lane_syncs"] == 40
-    assert set(counted["row"]) - set(PARENT_SUMMARY["row"]) == {
+    assert set(counted["row"]) >= set(PARENT_SUMMARY["row"]) | {
         "lane_rows_frozen", "lane_syncs"}
 
 

@@ -100,6 +100,9 @@ def test_reduce_a_four_device_trace(four_chip_trace):
         (20 + 3 * 150) / 4 * us)
     assert r["scopes"]["env/micro_step"] == pytest.approx(20 / 4 * us)
     assert r["scopes"]["train/ppo_update"] == pytest.approx(32 / 4 * us)
+    # busy under no scope: device 0's loop less what its scoped
+    # operations cover (132 - 20 - 20 - 32), none on the other devices
+    assert r["unscoped_s"] == pytest.approx(60 / 4 * us)
     # collectives: device 0's, and the part no compute covers
     assert r["collective_s"] == pytest.approx(30 * us)
     assert r["collective_exposed_s"] == pytest.approx(20 * us)
@@ -167,3 +170,98 @@ def test_reduce_a_trace_recorded_on_a_tpu(tmp_path):
             r["window_s"] - r["busy_s"], rel=1e-6)
         assert set(gaps) <= {"bench/collect", "bench/update",
                              "train/host_gap"}
+
+
+DRAIN = "jit(c)/while/body/vmap(env/micro_step/drain)/while/body/select_n"
+DECIDE = "jit(c)/while/body/vmap(env/micro_step/decide)/scatter"
+RESET = "jit(c)/while/body/env/micro_step/reset/cond/branch_1_fun/select_n"
+FREEZE = "jit(c)/while/body/collect/freeze/select_n"
+
+
+def test_a_sub_scope_is_matched_with_its_parent_and_is_the_inner_one():
+    scopes = tr.scope_names(harness.metric_scopes())
+    assert set(tr.KNOWN_SCOPES) <= set(scopes)
+    assert len(scopes) == len(set(scopes))
+    for name in ("env/micro_step/drain", "env/micro_step/decide",
+                 "env/micro_step/reset", "collect/freeze"):
+        assert name in scopes
+    assert tr.scopes_in(DRAIN) == ("env/micro_step",)  # bare: the tuple
+    assert tr.scopes_in(DRAIN, scopes) == (
+        "env/micro_step", "env/micro_step/drain")
+    assert tr.scopes_in(RESET, scopes) == (
+        "env/micro_step", "env/micro_step/reset")
+    # a scope inside another scope: outermost first, whatever the lengths
+    nested = "jit(c)/env/micro_step/drain/while/body/decima/gnn/levels/dot"
+    assert tr.scopes_in(nested, scopes + ("decima/gnn/levels",)) == (
+        "env/micro_step", "env/micro_step/drain", "decima/gnn",
+        "decima/gnn/levels")
+    assert tr.scopes_in("jit(c)/while/body/copy", scopes) == ()
+
+
+@pytest.fixture()
+def row_trace():
+    """One device, one second: a scan `while` under no scope holding a
+    decide step 0.1..0.3, a drain loop 0.3..0.7 (a `while` of its own
+    with a fusion inside), a re-seed 0.7..0.75, a freeze 0.75..0.8 and
+    a copy under no scope 0.8..0.9."""
+    events = [("while.1", 0.0, 0.9, "jit(c)/while"),
+              ("fusion.1", 0.1, 0.3, DECIDE),
+              ("while.2", 0.3, 0.7, DRAIN.rsplit("/body", 1)[0]),
+              ("fusion.2", 0.35, 0.65, DRAIN),
+              ("fusion.3", 0.7, 0.75, RESET),
+              ("fusion.4", 0.75, 0.8, FREEZE),
+              ("copy.1", 0.8, 0.9, "copy.1")]
+    ops = {0: [{"name": n, "start": a, "dur": b - a, "text": f"{n} {t}"}
+               for n, a, b, t in events]}
+    reduced = tr.reduce_events(
+        ops, [], window=(0.0, 1.0), chips=1,
+        scopes=tr.scope_names(harness.metric_scopes()))
+    return dict(reduced, units=0.5)
+
+
+def test_the_engines_sub_scopes_add_up_to_the_engine(row_trace):
+    scopes = row_trace["scopes"]
+    assert scopes["env/micro_step/decide"] == pytest.approx(0.2)
+    assert scopes["env/micro_step/drain"] == pytest.approx(0.4)
+    assert scopes["env/micro_step/reset"] == pytest.approx(0.05)
+    assert scopes["collect/freeze"] == pytest.approx(0.05)
+    assert scopes["env/micro_step"] == pytest.approx(0.65)
+    # busy 0.9, of which 0.7 under a scope: the scan's own 0.2
+    assert row_trace["unscoped_s"] == pytest.approx(0.2)
+    top = dict(row_trace["top_ops"])
+    assert top["env/micro_step/drain:fusion.2"] == pytest.approx(0.3)
+    assert top["env/micro_step/drain:while.2"] == pytest.approx(0.1)
+    assert top["env/micro_step/decide:fusion.1"] == pytest.approx(0.2)
+    assert top["while.1"] == pytest.approx(0.1)
+    assert top["copy.1"] == pytest.approx(0.1)
+    # bare, the reducer reads what it read: the parent scope alone
+    bare = tr.reduce_events(
+        {0: [{"name": "fusion.2", "start": 0.35, "dur": 0.3,
+              "text": DRAIN}]}, [], window=(0.0, 1.0), chips=1)
+    assert set(bare["scopes"]) == {"env/micro_step"}
+    assert dict(bare["top_ops"]) == {
+        "env/micro_step:fusion.2": pytest.approx(0.3)}
+
+
+@pytest.mark.parametrize("name, want", [
+    ("rollout.drain_device_s", 0.8), ("rollout.decide_device_s", 0.4),
+    ("rollout.unscoped_device_s", 0.4), ("rollout.engine_device_s", 1.3),
+    ("stream.drain_device_s", 0.8), ("stream.decide_device_s", 0.4),
+    ("stream.reset_device_s", 0.1), ("stream.freeze_device_s", 0.1),
+    ("stream.unscoped_device_s", 0.4), ("stream.engine_device_s", 1.3),
+    ("dp4.drain_device_s", 0.8), ("dp4.decide_device_s", 0.4),
+    ("dp4.unscoped_device_s", 0.4), ("dp4.engine_device_s", 1.3),
+])
+def test_a_scope_metric_reads_its_scope_a_collection(row_trace, name, want):
+    """Seconds a collection: the traced window holds half a unit."""
+    window = {"trace": row_trace}
+    assert harness.read_layer_metric(name, window) == pytest.approx(want)
+    bench = harness.load_benchmark()
+    entry = {m["name"]: m for m in bench["per_layer"]}[name]
+    assert entry["source"] == "device_trace" and entry["unit"] == "s"
+    assert entry["moves"] == "rollout_decisions_per_s"
+    assert len(entry["workloads"]) == 1
+    # a trace with no operation under the scope: nothing to read
+    empty = dict(row_trace, scopes={})
+    if "unscoped" not in name:
+        assert harness.read_layer_metric(name, {"trace": empty}) is None
