@@ -223,6 +223,8 @@ def check_telemetry(tm, where: str = "Telemetry",
     found: list[Violation] = []
     for name in tm.__dataclass_fields__:
         leaf = getattr(tm, name)
+        if leaf is None:  # a counter that was not asked for
+            continue
         if str(leaf.dtype) != TELEMETRY_SCHEMA_DTYPE:
             found.append(Violation(
                 "contracts", "telemetry-schema", f"{where}.{name}",

@@ -54,6 +54,14 @@ class EnvParams:
     # (reference data_samplers/tpch.py:29-32)
     job_arrival_rate: float = 4.0e-5
 
+    # jobs that arrive together at t=0 (the Decima paper's batched
+    # arrivals, section 7.2; decima-sim's `--num_init_dags`): the first
+    # `num_init_jobs` jobs of a sequence, every later gap still
+    # Exponential(1/rate). 1 is the streaming sequence (one job at t=0).
+    # A Python-level branch of `workload.sample_job_sequence`, so at 1
+    # every program traces as it did.
+    num_init_jobs: int = 1
+
     # mean of the exponential per-episode time limit (ms). None => no time
     # limit (episode ends when all `max_jobs` jobs complete).
     # (reference wrappers/stochastic_time_limit.py)
@@ -85,6 +93,14 @@ class EnvParams:
                 "float32/f32/bfloat16/bf16"
             )
         object.__setattr__(self, "obs_dtype", canon)
+        n0 = self.num_init_jobs
+        if (isinstance(n0, bool) or int(n0) != n0
+                or not 1 <= n0 <= self.max_jobs):
+            raise ValueError(
+                f"num_init_jobs {n0!r} is not a whole number from 1 to "
+                f"max_jobs ({self.max_jobs})"
+            )
+        object.__setattr__(self, "num_init_jobs", int(n0))
 
     @property
     def num_nodes(self) -> int:
@@ -104,8 +120,15 @@ def env_params_from_cfg(env_cfg: dict[str, Any]) -> EnvParams:
     kw: dict[str, Any] = {}
     for k, v in env_cfg.items():
         if k not in types:
+            # skipped on purpose: an upstream `env:` block also holds
+            # its sampler's and renderer's keys. So a key this program
+            # does not know runs as if absent, and a benchmark cell's
+            # driver checks for the field it needs itself
+            # (benchmarks/drivers/collect_batched.py)
             continue
         if v is not None and types[k] != "str":
+            if types[k] == "int" and float(v) != int(float(v)):
+                raise ValueError(f"env.{k}: {v!r} is not a whole number")
             v = int(float(v)) if types[k] == "int" else float(v)
         kw[k] = v
     if "max_jobs" not in kw and "job_arrival_cap" in env_cfg:
@@ -220,6 +243,8 @@ OBS_KEYS = frozenset({
     # running blind is the quiet failure this subsystem removes)
     "runlog",  # true|false|path — the JSONL event-stream sink
     "telemetry",  # thread on-device engine counters per iteration
+    "episode_counters",  # with telemetry: the counters of episodes
+    #   that end inside the scan (obs/telemetry.py)
     "memory",  # per-iteration device-allocator sample (default True)
     "trace_iteration",  # capture a labeled device trace of iteration N
     "trace_dir",  # where that trace lands

@@ -1008,8 +1008,15 @@ def drain_to_decision(
         c0 = c0 + (telemetry,)
     with annotate("env/micro_step/drain"):
         c = lax.while_loop(cond, body, c0)
-    ls, rw, dt, rs = c[0], c[2], c[3], c[4]
-    tm = c[5] if track else None
+        ls, rw, dt, rs = c[0], c[2], c[3], c[4]
+        tm = c[5] if track else None
+        if track and tm.counts_episodes:
+            # an episode that ended in this drain with every job
+            # complete ended by completion, not on a limit (read here,
+            # before a re-seed replaces the state)
+            tm = _tm_add(
+                tm, episodes_terminated=rs & ls.env.all_jobs_complete
+            )
     if auto_reset:
         # one whole name (obs/tracing.py), beside `env/micro_step/drain`
         with annotate("env/micro_step/reset"):

@@ -73,6 +73,22 @@ Counter semantics per engine:
   budget was spent (`over`); a frozen lane's other counters, `reseeds`
   included, stand still for the row. All three stay 0 in sync mode
   (`auto_reset=False`, no budget).
+- episodes that end inside the scan (the batched-arrivals deployment,
+  where every lane's does): three counters carried only where asked
+  for (`telemetry_zeros_like(..., episodes=True)`; the trainer's
+  `obs.episode_counters`). They are None otherwise, no leaf of the
+  carry, and an engine adds to them only where they are there, so a
+  program that does not ask traces as if they did not exist.
+  `rows_ended` (the batch collectors, once a row) counts the decision
+  rows a lane sat out because its OWN episode was over at the row's
+  start, which only a sync lane can be (a streaming lane is re-seeded
+  in the row its episode ends in); `episodes_terminated`
+  (`flat_loop.drain_to_decision`) the lane's episodes that ended in a
+  drain with every job complete, so by completion and not on a time
+  limit (`reseeds` counts both kinds, in streaming mode only);
+  `jobs_present_sum` (the batch collectors) the jobs in the lane's
+  observation (`Observation.job_mask`), summed over its stored
+  decisions: the backlog a decision sees.
 
 Cross-engine invariant (the parity test): on a deterministic workload
 the two engines process the same trajectory, so `decide_steps`, the
@@ -142,18 +158,38 @@ class Telemetry(struct.PyTreeNode):
     # summarize window math still works because bits only ever get set
     # (so a - prev == the window's newly-set bits).
     health_mask: jnp.ndarray
+    # --- episodes that end inside the scan: None unless asked for
+    # (module docstring), and then no leaf of the carry ---
+    rows_ended: jnp.ndarray | None = None  # rows sat out, episode over
+    episodes_terminated: jnp.ndarray | None = None  # ended by completion
+    jobs_present_sum: jnp.ndarray | None = None  # jobs seen, over decisions
+
+    @property
+    def counts_episodes(self) -> bool:
+        return self.rows_ended is not None
 
 
-def telemetry_zeros() -> Telemetry:
-    z = jnp.zeros((), _i32)
-    return Telemetry(*([z] * len(Telemetry.__dataclass_fields__)))
+_EPISODE_COUNTERS = ("rows_ended", "episodes_terminated", "jobs_present_sum")
 
 
-def telemetry_zeros_like(batch_shape: tuple[int, ...]) -> Telemetry:
+def _zeros(z, episodes: bool) -> Telemetry:
+    return Telemetry(**{
+        k: z for k in Telemetry.__dataclass_fields__
+        if episodes or k not in _EPISODE_COUNTERS
+    })
+
+
+def telemetry_zeros(episodes: bool = False) -> Telemetry:
+    return _zeros(jnp.zeros((), _i32), episodes)
+
+
+def telemetry_zeros_like(
+    batch_shape: tuple[int, ...], episodes: bool = False
+) -> Telemetry:
     """Zeros with a leading batch shape on every counter — the starting
-    value for vmapped engines (one counter set per lane)."""
-    z = jnp.zeros(batch_shape, _i32)
-    return Telemetry(*([z] * len(Telemetry.__dataclass_fields__)))
+    value for vmapped engines (one counter set per lane). `episodes`:
+    with the three counters of episodes that end inside the scan."""
+    return _zeros(jnp.zeros(batch_shape, _i32), episodes)
 
 
 def _count(x) -> bool:
@@ -248,6 +284,18 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
     # all-reduces on the critical path); in the summary where rows were
     # counted (PERF.md section 7 says what keeps it from every summary)
     lane_syncs = {"lane_syncs": batch(t.lane_syncs)} if rows else {}
+    # where the episode counters were carried: episodes that ended with
+    # every job complete (not on a limit), the backlog a decision sees
+    # (the mean number of jobs in its observation), and the lane-rows a
+    # lane sat out with its own episode over
+    episodes, rows_ended = {}, {}
+    if t.counts_episodes:
+        episodes = {
+            "episodes_terminated_total": tot(t.episodes_terminated),
+            "jobs_present_total": tot(t.jobs_present_sum),
+            "jobs_present_per_decision": per_dec(tot(t.jobs_present_sum)),
+        }
+        rows_ended = {"lane_rows_ended": tot(t.rows_ended)}
     scan_steps = tot(t.bulk_scan_steps)
     bulk_passes = tot(t.bulk_passes)
     drain_batch = batch(t.drain_batch_iters)
@@ -298,6 +346,7 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
         # lane while the reset is unconditional); 0 in sync mode
         "reseeds_total": tot(t.reseeds),
         "reset_evals_total": tot(t.reset_evals),
+        **episodes,
         "drain_iters_mean": round(mean_di, 2),
         "drain_iters_max": int(di.max()) if lanes else 0,
         "drain_straggler_ratio": round(drain_straggler, 3),
@@ -316,6 +365,7 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
             "drain_iters_total": tot(t.drain_iters),
             # lane-rows a lane sat out with its budget spent
             "lane_rows_frozen": tot(t.rows_frozen),
+            **rows_ended,
         },
         # health sentinels (ISSUE 9): the pooled violation bitmask, its
         # decoded bit names, and how many lanes tripped anything —

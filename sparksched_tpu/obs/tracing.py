@@ -26,9 +26,12 @@ only: `drain_to_decision(auto_reset=True)` re-seeds a lane whose
 episode ended once, after its loop and beside `drain`, not inside it,
 under one predicate for the batch: the reset program and the select of
 the state against it), `collect/health`, `collect/freeze`,
-`collect/scatter`. Elsewhere: `env/micro_step` (the mode switch and tail
-of `flat_loop.micro_step`; with `auto_reset` the tail of the loops whose
-unit is the micro-step holds `env/micro_step/reset` in every step),
+`collect/scatter`. Before the scan, once a collection: `collect/reset`
+(`Trainer._collect`: the reset program of every lane; in streaming mode
+only where the lanes do not persist yet). Elsewhere: `env/micro_step`
+(the mode switch and tail of `flat_loop.micro_step`; with `auto_reset`
+the tail of the loops whose unit is the micro-step holds
+`env/micro_step/reset` in every step),
 `train/ppo_update`, `serve/decide`, `serve/decide_batch`,
 `serve/dispatch`, `serve/flush`.
 

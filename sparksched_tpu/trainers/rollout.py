@@ -37,6 +37,7 @@ from ..env import core
 from ..env.flat_loop import (
     M_DECIDE,
     LoopState,
+    _lane_done,
     aux_action_fields,
     decide_micro_step,
     drain_to_decision,
@@ -69,6 +70,46 @@ _ROW = 128
 def _flat_grid(a: jnp.ndarray) -> jnp.ndarray:
     a = a.reshape(-1)
     return jnp.pad(a, (0, -a.shape[0] % _ROW))
+
+
+def _row_scatter(scatter, buf, slot, rows):
+    """`buf[b, slot[b]] <- rows[b]` for every lane b by ONE scatter over
+    (lane, slot) pairs (`scatter`: `lax.scatter`, `_add` or `_max`); a
+    slot out of bounds is dropped. The pairs ARE sorted and unique, and
+    the scatter is not told so: `vmap` of a one-lane `.at[slot].set`
+    tells it (one index is sorted), the TPU compiler then stores all
+    lanes by one row scatter under a linear index with -1 for a
+    dropped row, which is sorted no longer, and on the v5e that store
+    LOST rows of the widest leaves at 1024 lanes x 640 rows, more of
+    them the more lanes sat a row out (PERF.md, PR 42)."""
+    pairs = jnp.stack(
+        [lax.iota(_i32, buf.shape[0]), slot.astype(_i32)], axis=-1
+    )
+    return scatter(
+        buf, pairs, rows,
+        lax.ScatterDimensionNumbers(
+            update_window_dims=tuple(range(1, rows.ndim)),
+            inserted_window_dims=(0, 1),
+            scatter_dims_to_operand_dims=(0, 1),
+        ),
+        indices_are_sorted=False, unique_indices=False,
+        mode=lax.GatherScatterMode.FILL_OR_DROP,
+    )
+
+
+def _on_own_lanes(fn, lane_shard):
+    """`fn` as it is or, on a dp mesh, run once a device over the lanes
+    that device holds (`fn` takes and returns trees whose leaves lead
+    with the lane axis, and works lane by lane). The partitioner does
+    not see that the (lane, slot) pairs of `_row_scatter` stay within a
+    lane, and would gather every update to every device; told so, the
+    mesh adds nothing to the store."""
+    if lane_shard is None:
+        return fn
+    return jax.shard_map(
+        fn, mesh=lane_shard.mesh, in_specs=lane_shard.spec,
+        out_specs=lane_shard.spec,
+    )
 
 
 class StoredObs(struct.PyTreeNode):
@@ -700,6 +741,17 @@ def _flat_collect_single_eval(
                     lane_syncs=pass_syncs + drained + 1 + row_syncs
                     + ("full_width" in aux),
                 )
+                if tm.counts_episodes:
+                    # a lane whose own episode was over at the row's
+                    # start sits the row out (sync: nothing re-seeds
+                    # it); the jobs a stored decision saw
+                    tm = _tm_add(
+                        tm,
+                        rows_ended=jax.vmap(_lane_done)(env0) & ~over,
+                        jobs_present_sum=jnp.where(
+                            dec, obs.job_mask.sum(-1, dtype=_i32), 0
+                        ),
+                    )
                 if auto_reset:
                     # the drain's re-seed ran iff some lane ended its
                     # episode there (a frozen lane sits the drain out,
@@ -722,34 +774,38 @@ def _flat_collect_single_eval(
             lgprob = jnp.broadcast_to(
                 jnp.asarray(lgprob, jnp.float32), stage_idx.shape
             )
-            slot = jnp.where(dec & (ndec < T), ndec, T)
             stored = jax.vmap(store_obs)(obs, env0)
-            set_at = lambda b, s, v: b.at[s].set(v, mode="drop")  # noqa: E731
-            buf = buf.replace(
-                obs=jax.tree_util.tree_map(
-                    lambda b, v: jax.vmap(set_at)(b, slot, v),
-                    buf.obs, stored,
-                ),
-                stage_idx=jax.vmap(set_at)(buf.stage_idx, slot, stage_idx),
-                job_idx=jax.vmap(set_at)(buf.job_idx, slot, job),
-                num_exec_k=jax.vmap(set_at)(buf.num_exec_k, slot, kk),
-                lgprob=jax.vmap(set_at)(buf.lgprob, slot, lgprob),
-                walls=jax.vmap(set_at)(
-                    buf.walls, slot, elapsed if use_elapsed else wall0
-                ),
-            )
+            slot = jnp.where(dec & (ndec < T), ndec, T)
             ndec2 = ndec + dec.astype(_i32)
             # span rewards belong to the most recent decision's slot;
             # spans before a resumed lane's first decision drop
             rslot = jnp.where((ndec2 > 0) & (ndec2 <= T), ndec2 - 1, T)
-            buf = buf.replace(
-                reward=jax.vmap(
-                    lambda b, s, v: b.at[s].add(v, mode="drop")
-                )(buf.reward, rslot, reward),
-                resets=jax.vmap(
-                    lambda b, s, v: b.at[s].max(v, mode="drop")
-                )(buf.resets, rslot, reset.astype(_i32)),
-            )
+
+            def store(a):
+                buf, row, slot, rslot, reward, reset = a
+                row = jax.tree_util.tree_map(
+                    lambda b, v: _row_scatter(lax.scatter, b, slot, v),
+                    {k: getattr(buf, k) for k in row}, row,
+                )
+                return buf.replace(
+                    reward=_row_scatter(
+                        lax.scatter_add, buf.reward, rslot, reward
+                    ),
+                    resets=_row_scatter(
+                        lax.scatter_max, buf.resets, rslot, reset
+                    ),
+                    **row,
+                )
+
+            buf = _on_own_lanes(store, lane_shard)((
+                buf,
+                dict(
+                    obs=stored, stage_idx=stage_idx, job_idx=job,
+                    num_exec_k=kk, lgprob=lgprob,
+                    walls=elapsed if use_elapsed else wall0,
+                ),
+                slot, rslot, reward, reset.astype(_i32),
+            ))
         carry = (ls3, k, t_ref2, elapsed2, ndec2, buf)
         return (carry + (tm,) if track else carry), None
 

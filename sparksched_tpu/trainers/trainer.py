@@ -66,6 +66,7 @@ from ..env.health import (
 from ..obs import RunLog, emit
 from ..obs.memory import device_memory_stats
 from ..obs.telemetry import summarize, telemetry_zeros_like
+from ..obs.tracing import annotate
 from ..schedulers import TrainableScheduler, make_scheduler
 from ..workload import make_workload_bank
 from .baselines import group_baselines
@@ -213,6 +214,11 @@ class Trainer(abc.ABC):
         #     (the default sink; TensorBoard stays a mirror)
         #   telemetry: true — thread engine counters through the rollout
         #     collectors and summarize once per iteration
+        #   episode_counters: true — with `telemetry`, also carry the
+        #     three counters of episodes that END inside the scan
+        #     (obs/telemetry.py: rows a lane sat out after its end,
+        #     episodes ended by completion, jobs a decision saw); off,
+        #     they are no part of the collector's program
         #   memory: true (default) — sample the device allocator
         #     (`obs.memory.device_memory_stats`) once per iteration and
         #     emit a `memory` runlog record + mem_* scalars; a no-op on
@@ -235,6 +241,9 @@ class Trainer(abc.ABC):
         rmb = oc.get("runlog_max_bytes")
         self.obs_runlog_max_bytes = int(rmb) if rmb else None
         self.obs_telemetry: bool = bool(oc.get("telemetry", False))
+        self.obs_episode_counters: bool = bool(
+            oc.get("episode_counters", False)
+        )
         self.obs_memory: bool = bool(oc.get("memory", True))
         ti = oc.get("trace_iteration")
         self.obs_trace_iteration = None if ti is None else int(ti)
@@ -449,7 +458,9 @@ class Trainer(abc.ABC):
         G, R = self.num_sequences, self.num_rollouts
         master = jax.random.PRNGKey(self.seed)
         telem0 = (
-            telemetry_zeros_like((G * R,)) if self.obs_telemetry else None
+            telemetry_zeros_like(
+                (G * R,), episodes=self.obs_episode_counters
+            ) if self.obs_telemetry else None
         )
         if self.fixed_sequences:
             iteration = jnp.zeros_like(iteration)
@@ -463,13 +474,16 @@ class Trainer(abc.ABC):
         r_ids = jnp.tile(jnp.arange(R), G)
 
         def fresh_states():
-            seq_rngs = jax.vmap(lambda g: seq_key(g, iteration))(g_ids)
-            lane_rngs = jax.vmap(
-                lambda s, r: jax.random.fold_in(s, 1000 + r)
-            )(seq_rngs, r_ids)
-            return jax.vmap(
-                lambda s, l: core.reset_pair(p, bank, s, l)
-            )(seq_rngs, lane_rngs)
+            # a scope of its own (obs/tracing.py): every lane's reset
+            # program, once a collection in sync mode
+            with annotate("collect/reset"):
+                seq_rngs = jax.vmap(lambda g: seq_key(g, iteration))(g_ids)
+                lane_rngs = jax.vmap(
+                    lambda s, r: jax.random.fold_in(s, 1000 + r)
+                )(seq_rngs, r_ids)
+                return jax.vmap(
+                    lambda s, l: core.reset_pair(p, bank, s, l)
+                )(seq_rngs, lane_rngs)
 
         def batch_policy_fn(k, obs):
             return self.scheduler.batch_policy(k, obs, model_params)

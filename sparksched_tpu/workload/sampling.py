@@ -1,4 +1,10 @@
-"""On-device workload sampling: Poisson job sequences and task durations.
+"""On-device workload sampling: job sequences and task durations.
+
+A job sequence is `EnvParams.num_init_jobs` jobs at t=0 and Poisson
+arrivals after them (gaps Exponential(1/`job_arrival_rate`)), up to the
+cap `max_jobs` and the episode's time limit: at 1 (the default) the
+streaming sequence of upstream's `TPCHDataSampler`, at the cap the
+Decima paper's batched arrivals (every job at t=0, none later).
 
 Replaces reference tpch.py:54-106 (host-side Python sampling of job arrivals
 and per-task durations). Everything here is shape-static and traced into the
@@ -17,21 +23,25 @@ def sample_job_sequence(
     params: EnvParams, bank: WorkloadBank, rng: jax.Array,
     time_limit: jnp.ndarray
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Sample up to `max_jobs` Poisson arrivals (reference tpch.py:54-73):
-    the first job arrives at t=0, subsequent inter-arrival gaps are
-    Exponential(1/rate); arrivals stop at the time limit or the cap.
+    """Sample up to `max_jobs` arrivals (reference tpch.py:54-73): the
+    first `params.num_init_jobs` jobs arrive at t=0 (1: upstream's
+    sampler; more: the Decima paper's batched arrivals, decima-sim's
+    `--num_init_dags`), subsequent inter-arrival gaps are
+    Exponential(1/rate); arrivals stop at the time limit or the cap. The
+    jobs at t=0 are loaded at reset in index order (`job_arrival_seq`).
 
     Returns (arrival_times[J] with inf padding, templates[J], arrived_cap
     num_jobs scalar, mask[J])."""
-    j_cap = params.max_jobs
+    j_cap, n0 = params.max_jobs, params.num_init_jobs
     k_gap, k_tpl = jax.random.split(rng)
     mean_gap = 1.0 / params.job_arrival_rate
     gaps = jax.random.exponential(k_gap, (j_cap,)) * mean_gap
     arrivals = jnp.concatenate(
-        [jnp.zeros(1), jnp.cumsum(gaps)[: j_cap - 1]]
+        [jnp.zeros(n0), jnp.cumsum(gaps)[: j_cap - n0]]
     ).astype(jnp.float32)
     mask = arrivals < time_limit
-    mask = mask.at[0].set(True)  # first job must arrive at t=0
+    # the jobs at t=0 must arrive, whatever the limit drawn
+    mask = mask | (jnp.arange(j_cap) < n0)
     # arrivals must be a prefix: a job only exists if all earlier ones do
     mask = jnp.cumprod(mask.astype(jnp.int32)).astype(bool)
     templates = jax.random.randint(
