@@ -929,9 +929,17 @@ def drain_to_decision(
     the span's reward/dt/reset with `t_ref` as the discount reference.
 
     The batch collectors vmap this; under vmap the while-loop costs the
-    batch-max drain length per decision row — but every iteration is
+    longest drain among the lanes the `vmap` spans per decision row —
+    but every iteration is
     pure env machinery (bulk passes + single pops), and the GNN runs
-    exactly once per decision outside this loop. Which slice is the
+    exactly once per decision outside this loop. That is why the
+    collectors span no more than a block of 128 lanes with one `vmap`
+    of this function (`trainers/rollout.py`, `_DRAIN_BLOCK`): a batch of
+    several blocks is drained block by block, so a lane waits for the
+    slowest lane of its own block and a block whose lanes are all at a
+    decision, or ended, pays one predicate (at 1024 lanes the whole
+    batch's `while` ran 24 bodies for each one a lane needed; PERF.md
+    section 6, PR 43). Which slice is the
     cheap one depends on the device: on the TPU v5e this loop is
     nearly three fifths of a decision row of 128 lanes and the GNN a
     sixth (PERF.md section 5, PR 39). The device time is under the
@@ -946,7 +954,8 @@ def drain_to_decision(
     refresh of the saturation caches counts on `EnvState.parent_sets`
     (until PR 39 a contraction over the adjacency, 181 us a body).
     `lane_axis`, the name the caller's `vmap` gave its lane axis, lets
-    that loop end on one predicate for the whole batch; a caller
+    that loop end on one predicate for all the lanes of that `vmap`
+    (the whole batch, or one block of it); a caller
     without one (a single lane) leaves it None.
     The ISSUE-7 restructure keeps that slice cheap two ways: the cond
     reduces to the existence bit of the next event (`_has_pending_event`
@@ -965,7 +974,8 @@ def drain_to_decision(
     writes (the adjacency, the templates, the task counts) stay
     loop-invariant. With `auto_reset` the lane is re-seeded ONCE, after
     the loop (`_reseed_ended`): the reset program runs only in a row in
-    which some lane of the batch ended (one predicate over `lane_axis`),
+    which some lane under the caller's `vmap` ended (one predicate over
+    `lane_axis`: the batch's, or the block's),
     at the ordinal the tail would have used. The state, the row's
     `(reward, dt, reset)` and the episode count are those of
     `drain_micro_step(auto_reset=True)` repeated until the lane is

@@ -41,7 +41,16 @@ Counter semantics per engine:
   the row's `drain_iters` increment: the bodies the vmapped `while`
   really ran, which every lane pays for). They are facts of the batch,
   not of a lane, so every lane holds the same value; no other engine or
-  collector touches them. A fifth, `lane_syncs`, counts the REDUCTIONS
+  collector touches them. The one exception: a collection of more than
+  one block of lanes (`rollout._DRAIN_BLOCK`; whole blocks a device)
+  drains block by block, each block under its own `while`, and a lane
+  waits for the slowest lane of its OWN block only: `drain_batch_iters`
+  is then the maximum over the lane's block, the same in every lane of
+  a block and another from block to block. `summarize` sums it over
+  the lanes (`row.drain_lane_iters_executed`: the bodies the device
+  ran, a lane at a time) and gives the mean over the lanes as
+  `row.drain_batch_iters`; with one block both are what they always
+  were. A fifth, `lane_syncs`, counts the REDUCTIONS
   OVER THE LANE AXIS the row executed: every evaluation of the fused
   bulk pass's loop predicate (`core._steps_while_active` with a named
   lane axis: its iterations and the one that ended it, in every body
@@ -54,7 +63,12 @@ Counter semantics per engine:
   Inside the drain's loop the lane that runs longest accumulates the
   passes' predicates in its own `lane_syncs` (one scalar of the carry);
   the row takes the maximum over lanes with `drain_batch_iters`', in
-  the one reduction.
+  the one reduction. `lane_syncs` counts reductions over ALL the lanes
+  of the batch. A blocked row's loops end on predicates of a block,
+  which cross no chip (on a mesh the blocked drain runs a device at a
+  time, `rollout._on_own_lanes`) and are not counted: such a row
+  counts `rows_live`'s `any`, the full-width predicate and, streaming,
+  `reset_evals`' `any`, and nothing else.
 - streaming (`auto_reset`): `reseeds` counts the lane's episodes that
   ended in the scan and were re-seeded, `reset_evals` the evaluations
   of the reset program (`reset_fn` / `core.reset` and the select of the
@@ -143,7 +157,9 @@ class Telemetry(struct.PyTreeNode):
     rows: jnp.ndarray  # scan iterations (decision rows)
     rows_live: jnp.ndarray  # rows in which some lane decided
     rows_full_width: jnp.ndarray  # rows scored at the full job width
-    drain_batch_iters: jnp.ndarray  # sum over rows of max-lane drain iters
+    # sum over rows of the most drain iters a lane of the batch needed
+    # (of the lane's own block where the drain runs block by block)
+    drain_batch_iters: jnp.ndarray
     lane_syncs: jnp.ndarray  # reductions over the lane axis executed
     # --- streaming (auto_reset) collection: 0 in sync mode ---
     reseeds: jnp.ndarray  # episodes that ended in the scan, re-seeded
@@ -298,7 +314,14 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
         rows_ended = {"lane_rows_ended": tot(t.rows_ended)}
     scan_steps = tot(t.bulk_scan_steps)
     bulk_passes = tot(t.bulk_passes)
-    drain_batch = batch(t.drain_batch_iters)
+    # the bodies the device ran, a lane at a time: every lane runs what
+    # the slowest lane of its `while` needs (the whole batch's, or its
+    # own block's in a blocked collection: module docstring), so the
+    # sum over the lanes; `drain_batch_iters` is a lane's mean
+    drain_executed = tot(t.drain_batch_iters)
+    drain_batch = drain_executed / lanes if lanes else 0.0
+    if drain_batch == int(drain_batch):
+        drain_batch = int(drain_batch)  # one block: the count itself
     hm = np.asarray(t.health_mask).ravel()
     health_mask = (
         int(np.bitwise_or.reduce(hm)) if hm.size else 0
@@ -361,7 +384,7 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
             "drain_batch_iters": drain_batch,
             **lane_syncs,
             "lane_rows": rows * lanes,
-            "drain_lane_iters_executed": drain_batch * lanes,
+            "drain_lane_iters_executed": drain_executed,
             "drain_iters_total": tot(t.drain_iters),
             # lane-rows a lane sat out with its budget spent
             "lane_rows_frozen": tot(t.rows_frozen),

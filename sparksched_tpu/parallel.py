@@ -15,10 +15,20 @@ The TPU-native equivalent has two layers:
 
 Rollout collection moves no lane's data to another chip, but it is NOT
 free of cross-lane operations: the collector is one program under `jit`
-with sharding constraints (no `shard_map`), so every reduction over the
-lane axis is an all-reduce of a scalar across the chips, on the critical
-path. A decision row makes these (`Telemetry.lane_syncs` counts them,
-`collector_collectives` below holds the compiled program to them):
+with sharding constraints, so every reduction over the lane axis that
+it makes outside a `shard_map` is an all-reduce of a scalar across the
+chips, on the critical path. Two parts of a row run inside `shard_map`,
+a device at a time over its own lanes (`rollout._on_own_lanes`): the
+row store, and the drain where a device's share of the lanes is whole
+blocks of `rollout._DRAIN_BLOCK` lanes (512 lanes on four chips: one
+block of 128 a chip). The drain's loops then end on predicates of the
+device's own blocks and hold NO collective; what such a row still
+reduces over all the lanes is the last group below less the counters'
+maximum and the re-seed's predicate. Where a device's share is not
+whole blocks (16 lanes a device in the tests) the whole batch drains
+under one `while`, and a decision row makes all of these
+(`Telemetry.lane_syncs` counts them, `collector_collectives` below
+holds the compiled program to them):
 
 - the predicate of the fused bulk pass's early-exit loop, `lax.pmax`
   over the lanes, once an iteration of that loop in every body of the
@@ -36,7 +46,13 @@ from the FIRST lane's key, so each such draw (two in a drain body, one in
 a decide step) is also a broadcast of that key from the chip that holds
 lane 0: the partitioner lowers it to an all-reduce of the key's four
 words. `lane_syncs` does not count those (they reduce nothing); the
-trace does (PERF.md, PR 34).
+trace does (PERF.md, PR 34). A drain that runs block by block draws a
+block's bits from the BLOCK's first lane, on the mesh and on one chip
+alike: under these keys the blocks are part of what a collection
+stores, and a mesh's collection equals the one-chip collection of the
+same lanes because both run the same blocks
+(`tests/test_parallel.py`); the drain's two broadcasts a body are gone
+with its collectives, the decide step's stays.
 """
 
 from __future__ import annotations

@@ -103,13 +103,66 @@ def _on_own_lanes(fn, lane_shard):
     with the lane axis, and works lane by lane). The partitioner does
     not see that the (lane, slot) pairs of `_row_scatter` stay within a
     lane, and would gather every update to every device; told so, the
-    mesh adds nothing to the store."""
+    mesh adds nothing to the store. The drain runs so too where a
+    device's share is whole blocks (`_DRAIN_BLOCK`): its loops then end
+    on the device's own lanes and hold no collective. (`check_vma` is
+    off because jax's check of varying axes trips over a reduction
+    along a `vmap` axis name, the drain's `lax.pmax(..., "lanes")`;
+    every leaf in and out is sharded over every mesh axis, so there is
+    nothing for it to infer.)"""
     if lane_shard is None:
         return fn
     return jax.shard_map(
         fn, mesh=lane_shard.mesh, in_specs=lane_shard.spec,
-        out_specs=lane_shard.spec,
+        out_specs=lane_shard.spec, check_vma=False,
     )
+
+
+# The lanes one drain `while` waits for. A vmapped `lax.while_loop` runs
+# until the slowest of ITS lanes is done, so the collectors run the
+# drain over blocks of this many lanes, one `while` a block
+# (`_flat_collect_single_eval`). 128 is the TPU's lane tile: a block is
+# a whole tile of the lane-minor layout the scan's carry has, a
+# 128-lane batch is ONE block and compiles the program it always has,
+# and so is a chip's share of 512 lanes on four. A constant, not a
+# setting: which path a collection takes follows from its lane count
+# and its mesh and from nothing else.
+_DRAIN_BLOCK = 128
+
+
+def _by_blocks(fn, block: int):
+    """`fn(state, args) -> state`, over trees whose leaves lead with a
+    lane axis of `block`, for a lane axis of any whole number of blocks:
+    one block after another (a loop: ONE copy of `fn` in the program,
+    whatever the number of blocks; never `vmap`, which would make the
+    blocks one batch again). A block's lanes are sliced out of the
+    full-width `state` and `args` and its new state written back in
+    place, so every array keeps the shape, and with it the layout, it
+    has outside the loop: stacked blocks (`lax.map`) came back in
+    another layout at 512 lanes x 200 jobs, the sampler then summed
+    its softmax in another order, and the mesh's log-probs no longer
+    matched the one-chip program's to the last bit (PERF.md, PR 43)."""
+
+    def over_blocks(state_and_args):
+        state, args = state_and_args
+        lanes = jax.tree_util.tree_leaves(state)[0].shape[0]
+        if lanes == block:
+            return fn(state, args)
+
+        def one_block(i, state):
+            at = i * block
+            new = fn(*jax.tree_util.tree_map(
+                lambda a: lax.dynamic_slice_in_dim(a, at, block),
+                (state, args),
+            ))
+            return jax.tree_util.tree_map(
+                lambda a, b: lax.dynamic_update_slice_in_dim(a, b, at, 0),
+                state, new,
+            )
+
+        return lax.fori_loop(0, lanes // block, one_block, state)
+
+    return over_blocks
 
 
 class StoredObs(struct.PyTreeNode):
@@ -558,18 +611,40 @@ def _flat_collect_single_eval(
     bulk_events: int,
     fulfill_bulk: bool,
     bulk_cycles: int,
-    reset_fns,  # None, or a per-lane factory: lane_idx -> reset_fn
+    reset_fns,  # None, or a per-lane factory: a lane's reset_args -> reset_fn
     rollout_duration,
     use_elapsed: bool,
     telemetry=None,
     lane_shard=None,
     bulk_fused: bool = True,
     health: bool = False,
+    reset_args=None,  # [B]-leading tree for `reset_fns`; None: the lane index
 ):
     """Shared single-eval collection scan over the WHOLE lane batch
     (`ls` carries a leading [B] axis; no outer vmap). Exactly
     `num_steps` scan iterations, each producing at most one decision
     per lane; see the section comment above for the shape.
+
+    The drain is the one part of a row that does NOT run over the whole
+    batch where the batch is more than one block of `_DRAIN_BLOCK`
+    lanes: lanes are independent, a vmapped `while` waits for the
+    slowest of its lanes, so a device's lanes are drained a block at a
+    time (`_by_blocks`), each block under its own `while` and its own
+    named lane axis: the drain `while`'s predicate, the fused bulk
+    pass's and the streaming re-seed's are then the BLOCK's, and a
+    block whose lanes are all at a decision (or all ended) pays one
+    predicate. The rule is the shape's: `B` a whole number of blocks
+    and more than one or, on a dp mesh, a device's share whole blocks,
+    where the drain then runs once a device over its own lanes
+    (`_on_own_lanes`) and holds no collective. Any other lane count (a
+    single block, fewer lanes, no multiple) drains the whole batch
+    under one `while`, as every collection did before. Under threefry
+    keys no stored bit depends on which lanes a lane waits for; under
+    rbg keys a vmapped draw takes the FIRST lane's key (`parallel.py`),
+    so a block draws from its own first lane, on a mesh as on one chip.
+    What a blocked row still reduces over ALL lanes: `rows_live`'s
+    `any`, the policy's full-width predicate and, streaming,
+    `reset_evals`' `any`.
 
     `lane_shard` (a `NamedSharding` over the lane axis, parallel.py:
     `lane_sharding`) pins the scan's carry — the [B] `LoopState`, the
@@ -585,6 +660,9 @@ def _flat_collect_single_eval(
     row and outside the drain's `while`; `rows_full_width` reads the
     policy's
     `aux["full_width"]` and stays 0 for a policy that gives none.
+    In a blocked row `drain_batch_iters` takes the bodies the lane's
+    OWN block ran, and `lane_syncs` the row's reductions over all the
+    lanes, not the blocks' own predicates.
     The per-lane `rows_frozen` counts the rows a lane sat out with its
     `rollout_duration` spent (0 without a budget).
 
@@ -618,13 +696,15 @@ def _flat_collect_single_eval(
         buf0 = constrain_lanes(buf0, lane_shard)
         if track:
             telemetry = constrain_lanes(telemetry, lane_shard)
-    lane_idx = jnp.arange(B)
-    # the reductions over the lane axis a row makes outside its loops:
-    # the maximum that gives `drain_batch_iters` and `rows_live`'s
-    # `any`; streaming adds `reset_evals`' `any` and the re-seed's
-    # predicate (`flat_loop._reseed_ended`); a policy with two widths
-    # adds its predicate (`aux["full_width"]`), counted in the body
-    row_syncs = 4 if auto_reset else 2
+    if reset_args is None:
+        reset_args = jnp.arange(B)
+    dp = 1 if lane_shard is None else lane_shard.mesh.size
+    blocked = B % (dp * _DRAIN_BLOCK) == 0 and B > _DRAIN_BLOCK
+    # the reductions over ALL the lanes a row makes outside its drain:
+    # `rows_live`'s `any`, streaming `reset_evals`' too; a policy with
+    # two widths adds its predicate (`aux["full_width"]`), counted in
+    # the body
+    row_syncs = 2 if auto_reset else 1
 
     def v_decide(ls, si, ne, tm):
         def one(l, s_, n_, t_):
@@ -634,9 +714,9 @@ def _flat_collect_single_eval(
 
         return jax.vmap(one)(ls, si, ne, tm)
 
-    def v_drain(ls, keys, li, t_ref, tm):
-        def one(l, k_, i_, tr, t_):
-            rf = None if reset_fns is None else reset_fns(i_)
+    def drain_lanes(a):  # (ls, keys, reset_args, t_ref, tm) of some lanes
+        def one(l, k_, ra, tr, t_):
+            rf = None if reset_fns is None else reset_fns(ra)
             return drain_to_decision(
                 params, bank, l, k_, auto_reset, event_bulk,
                 bulk_events, bulk_cycles, reset_fn=rf, t_ref=tr,
@@ -645,8 +725,38 @@ def _flat_collect_single_eval(
 
         # the lane axis has a name so that the fused bulk pass can end
         # its loop, and the re-seed after the drain be skipped, on one
-        # predicate for the whole batch
-        return jax.vmap(one, axis_name="lanes")(ls, keys, li, t_ref, tm)
+        # predicate for all these lanes
+        return jax.vmap(one, axis_name="lanes")(*a)
+
+    def drain_block(state, args):
+        """One block's drain, on its lanes of the state `v_drain`
+        threads through the blocks: `LoopState` and telemetry go on,
+        the span's `(reward, dt, reset)` is written and, with
+        telemetry, the bodies the block's `while` ran (the most any
+        lane of it needed), in every lane."""
+        ls, _, tm, _ = state
+        out = drain_lanes((ls,) + args + (tm,))
+        if not track:
+            return out + (None, None)
+        ran = out[2].drain_iters - tm.drain_iters
+        return out + (jnp.broadcast_to(ran.max(), ran.shape),)
+
+    v_drain = drain_lanes
+    if blocked:
+        drain_blocks = _on_own_lanes(
+            _by_blocks(drain_block, _DRAIN_BLOCK), lane_shard
+        )
+
+        def v_drain(a):
+            ls, keys, ra, t_ref, tm = a
+            zero = jnp.zeros((B,), jnp.float32)
+            ran = jnp.zeros((B,), _i32) if track else None
+            # the blocks' slices and the write-back are the drain's too
+            with annotate("env/micro_step/drain"):
+                return drain_blocks((
+                    (ls, (zero, zero, zero > 0), tm, ran),
+                    (keys, ra, t_ref),
+                ))
 
     def body(carry, _):
         if track:
@@ -691,12 +801,11 @@ def _flat_collect_single_eval(
                 )
 
         out = v_drain(
-            ls2, jax.random.split(k_drain, B), lane_idx, t_ref2, tm
+            (ls2, jax.random.split(k_drain, B), reset_args, t_ref2, tm)
         )
+        ls3, (rw2, dt2, rs2) = out[:2]
         if track:
-            ls3, (rw2, dt2, rs2), tm = out
-        else:
-            ls3, (rw2, dt2, rs2) = out
+            tm = out[2]
         with annotate("collect/freeze"):
             reward = rw1 + rw2
             dt = dt1 + dt2
@@ -718,15 +827,28 @@ def _flat_collect_single_eval(
             )
             dec = decided & ~over
             if track:
-                # the bodies the vmapped drain `while` ran this row
-                # (every lane, frozen ones too, waits for the slowest)
-                # and the predicates of the fused passes in them: the
-                # lane that ran longest counted every one. One
-                # reduction over the lanes for both.
-                drained, pass_syncs = jnp.stack(
-                    [tm.drain_iters - tm_frozen.drain_iters,
-                     tm.lane_syncs - tm_frozen.lane_syncs], -1
-                ).max(0)
+                if blocked:
+                    # the bodies the lane's own block ran; the
+                    # predicates of its loops cross no block and are
+                    # not counted
+                    drained, drain_syncs = out[3], 0
+                else:
+                    # the bodies the vmapped drain `while` ran this row
+                    # (every lane, frozen ones too, waits for the
+                    # slowest) and the predicates of the fused passes
+                    # in them: the lane that ran longest counted every
+                    # one. One reduction over the lanes for both.
+                    drained, pass_syncs = jnp.stack(
+                        [tm.drain_iters - tm_frozen.drain_iters,
+                         tm.lane_syncs - tm_frozen.lane_syncs], -1
+                    ).max(0)
+                    # the passes' predicates, the drain `while`'s (its
+                    # bodies and the one that ended it), this maximum
+                    # and, streaming, the re-seed's
+                    # (`flat_loop._reseed_ended`)
+                    drain_syncs = pass_syncs + drained + (
+                        3 if auto_reset else 2
+                    )
                 tm = jax.tree_util.tree_map(
                     lambda a, b: jnp.where(over, a, b), tm_frozen, tm
                 ).replace(lane_syncs=tm_frozen.lane_syncs)
@@ -736,9 +858,7 @@ def _flat_collect_single_eval(
                     tm, rows=1, rows_live=dec.any(),
                     rows_full_width=aux.get("full_width", False),
                     drain_batch_iters=drained, rows_frozen=over,
-                    # the passes' predicates, the drain `while`'s (its
-                    # bodies and the one that ended it) and the row's
-                    lane_syncs=pass_syncs + drained + 1 + row_syncs
+                    lane_syncs=drain_syncs + row_syncs
                     + ("full_width" in aux),
                 )
                 if tm.counts_episodes:
@@ -883,22 +1003,25 @@ def collect_flat_sync_batch(
     return (out[0], out[2]) if telemetry is not None else out[0]
 
 
-def _group_reset_fns(params, bank, seq_bases, reset_counts, lane_salts):
+def _group_reset_fns(params, bank):
     """The streaming batch collector's per-lane factory of reset
-    programs: `reset_fns(lane)(key, episodes)` is the lane's episode at
-    the group-shared ordinal `reset_counts[lane] + episodes`. It ignores
-    `key`: the fresh state is a function of the lane's sequence base,
-    its ordinal and its salt alone, so it is the same wherever in a row
-    it is evaluated."""
+    programs: `reset_fns((seq_base, reset_count, lane_salt))(key,
+    episodes)` is that lane's episode at the group-shared ordinal
+    `reset_count + episodes`. It ignores `key`: the fresh state is a
+    function of the lane's sequence base, its ordinal and its salt
+    alone, so it is the same wherever in a row it is evaluated. The
+    three come a lane at a time (the collector maps them with the
+    lanes), not as whole arrays under a lane index: a drain that runs
+    over a block or a device's share of the lanes holds only its own."""
 
-    def reset_fns(lane_idx):
+    def reset_fns(lane):
+        seq_base, reset_count, lane_salt = lane
+
         def reset_fn(key, episodes):
-            seq_rng = jax.random.fold_in(
-                seq_bases[lane_idx], reset_counts[lane_idx] + episodes
-            )
+            seq_rng = jax.random.fold_in(seq_base, reset_count + episodes)
             return core.reset_pair(
                 params, bank, seq_rng,
-                jax.random.fold_in(seq_rng, lane_salts[lane_idx]),
+                jax.random.fold_in(seq_rng, lane_salt),
             )
 
         return reset_fn
@@ -957,16 +1080,15 @@ def collect_flat_async_batch(
         jnp.asarray(reset_counts, _i32), (B,)
     )
     loop_states = loop_states.replace(episodes=jnp.zeros((B,), _i32))
-    reset_fns = _group_reset_fns(
-        params, bank, seq_bases, reset_counts, lane_salts
-    )
     out = _flat_collect_single_eval(
         params, bank, batch_policy_fn, rng, num_steps, loop_states,
         auto_reset=True, event_bulk=event_bulk, bulk_events=bulk_events,
         fulfill_bulk=fulfill_bulk, bulk_cycles=bulk_cycles,
-        reset_fns=reset_fns, rollout_duration=rollout_duration,
+        reset_fns=_group_reset_fns(params, bank),
+        rollout_duration=rollout_duration,
         use_elapsed=True, telemetry=telemetry, lane_shard=lane_shard,
         bulk_fused=bulk_fused, health=health,
+        reset_args=(seq_bases, reset_counts, lane_salts),
     )
     ro, ls = out[0], out[1]
     ro = ro.replace(final_reset_count=reset_counts + ls.episodes)

@@ -825,7 +825,9 @@ def test_row_counters_of_the_async_batch_collector_with_frozen_lanes():
 
 def test_summarize_reads_batch_counters_as_the_lane_maximum():
     """Every lane holds the same row counts; where a window's lanes
-    differ, the maximum is the batch's."""
+    differ, the maximum is the batch's. But the drain's bodies: a lane
+    holds its own block's (PR 43), so the device ran their sum and
+    `drain_batch_iters` is a lane's mean."""
     from sparksched_tpu.obs.telemetry import summarize, telemetry_zeros_like
 
     tm = telemetry_zeros_like((4,))
@@ -840,8 +842,8 @@ def test_summarize_reads_batch_counters_as_the_lane_maximum():
     )
     assert summarize(tm)["row"] == {
         "rows": 7, "rows_live": 5, "rows_full_width": 2,
-        "drain_batch_iters": 31, "lane_syncs": 260, "lane_rows": 28,
-        "drain_lane_iters_executed": 124, "drain_iters_total": 60,
+        "drain_batch_iters": 28, "lane_syncs": 260, "lane_rows": 28,
+        "drain_lane_iters_executed": 112, "drain_iters_total": 60,
         "lane_rows_frozen": 0,
     }
 
@@ -897,7 +899,14 @@ def _collector_scan_body(monkeypatch, mode: str):
 
     jaxpr = jax.make_jaxpr(collect)(
         jax.random.PRNGKey(1), states, telemetry_zeros_like((3,)))
+    body = _collection_scan_body(jaxpr, steps)
+    _SCAN_BODIES[mode] = jaxpr, body
+    return jaxpr, body
 
+
+def _collection_scan_body(jaxpr, steps: int):
+    """The body of a collector's scan over its `steps` decision rows:
+    the one scan of that length under no scope."""
     def scans(jp):
         for eqn in jp.eqns:
             if eqn.primitive.name == "scan":
@@ -908,8 +917,7 @@ def _collector_scan_body(monkeypatch, mode: str):
     (body,) = [e.params["jaxpr"].jaxpr for e in scans(jaxpr.jaxpr)
                if e.params["length"] == steps
                and not str(e.source_info.name_stack)]
-    _SCAN_BODIES[mode] = jaxpr, body
-    return jaxpr, body
+    return body
 
 
 def _inner_jaxprs(eqn):

@@ -491,6 +491,96 @@ def test_mesh_cell_collector_holds_scalar_all_reduces_only(mesh_cell_pair):
         ("all-gather", 64), ("all-reduce", 769)]
 
 
+# ---------------------------------------------------------------------------
+# PR 43: the drain over blocks of lanes on the mesh. Where a device's
+# share is whole blocks (`rollout._DRAIN_BLOCK`; at the benchmark's 512
+# lanes on four chips it is one block of 128) the drain runs once a
+# device over the device's own lanes, inside `shard_map`.
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def blocked_mesh_pair():
+    """dp=1 and dp=4 collections of 64 lanes x 12 rows under RBG keys
+    (the keys `decima_rollout_dp4` runs under), with the block at a
+    device's share of the lanes, 16: four blocks one after another on
+    one device, one block a device on four."""
+    from .test_drain_blocks import drain_block
+
+    prng = jax.config.jax_default_prng_impl
+    jax.config.update("jax_default_prng_impl", "rbg")
+    try:
+        with drain_block(MESH_LANES // 4):
+            return {dp: _collect_compiled(_make_mesh_cell_trainer(dp))
+                    for dp in (1, 4)}
+    finally:
+        jax.config.update("jax_default_prng_impl", prng)
+
+
+def test_blocked_mesh_collection_equals_the_one_device_one_under_rbg_keys(
+    blocked_mesh_pair, mesh_cell_pair
+):
+    """What `decima_rollout_dp4`'s `correct` rests on since PR 43: under
+    rbg keys a vmapped draw takes the FIRST lane's key, so a block draws
+    from its own first lane, and the mesh's collection equals the
+    one-device collection of the same lanes, leaf for leaf and counter
+    for counter, only because both run the same four blocks. (Against
+    the whole-batch drain the draws differ: under these keys the blocks
+    are part of the result.)"""
+    from sparksched_tpu.obs.telemetry import summarize
+
+    one, four = blocked_mesh_pair[1], blocked_mesh_pair[4]
+    for name in ("ro", "telem"):
+        a, b = jax.device_get((one[name], four[name]))
+        for (path, x), y in zip(jax.tree_util.tree_leaves_with_path(a),
+                                jax.tree_util.tree_leaves(b)):
+            np.testing.assert_array_equal(
+                x, y, err_msg=f"{name}{jax.tree_util.keystr(path)}")
+    s = summarize(four["telem"])
+    assert s == summarize(one["telem"]) and s["health_mask"] == 0
+    assert s["decisions"] > MESH_LANES
+    # the row's own reductions and nothing of the blocks': `rows_live`
+    # and the full-width predicate
+    assert s["row"]["lane_syncs"] == 2 * MESH_ROWS
+    bodies = np.asarray(four["telem"].drain_batch_iters).reshape(4, -1)
+    assert (bodies == bodies[:, :1]).all() and len(set(bodies[:, 0])) > 1
+    assert len({d.device.id
+                for d in four["telem"].lane_syncs.addressable_shards}) == 4
+    whole = summarize(mesh_cell_pair[4]["telem"])["row"]
+    assert s["row"]["drain_batch_iters"] < whole["drain_batch_iters"]
+
+
+def test_blocked_mesh_collector_holds_no_collective_under_the_drain(
+    blocked_mesh_pair,
+):
+    """The twin of
+    `test_mesh_cell_collector_holds_scalar_all_reduces_only` for the
+    blocked program: all-reduces of a few words and nothing else, and
+    NONE of them under `env/micro_step/drain` (a device's loops end on
+    its own lanes). What is left in the scan body: the one the compiler
+    combines from the full-width predicate and `rows_live`'s `any`, and
+    the decide step's broadcast of lane 0's rbg key; before the scan,
+    the reset's."""
+    from sparksched_tpu.parallel import (
+        EXPECTED_COLLECT_COLLECTIVES,
+        collector_collectives,
+        collector_violations,
+    )
+
+    hlo = blocked_mesh_pair[4]["compiled"].as_text()
+    found = collector_collectives(hlo)
+    assert found and not collector_violations(hlo), found
+    assert {c["family"] for c in found} == EXPECTED_COLLECT_COLLECTIVES
+    assert not [c for c in found if "env/micro_step/drain" in c["op_name"]]
+    in_body = sorted(
+        next(s for s in ("decima/gnn", "env/micro_step/decide")
+             if s in c["op_name"])
+        for c in found if "/while/body/" in c["op_name"])
+    assert in_body == ["decima/gnn", "env/micro_step/decide"]
+    assert not collector_collectives(
+        blocked_mesh_pair[1]["compiled"].as_text())
+
+
 def test_lane_syncs_against_a_count_made_by_hand(monkeypatch):
     """One lane, the fused pass's loop stepping one step at a time: the
     loop's predicate is evaluated once for each step the lane needed

@@ -246,22 +246,39 @@ def test_drain_loop_writes_nothing_of_the_adjacency_s_size(
     Until PR 39 the refresh selected and reduced the whole of it, as
     stored (a job's 20 x 20 block padded to a tile: 105 MB at 128
     lanes), in every body."""
+    p = flagship.params_env
+    _holds_no_adjacency_sized_result(
+        collector.as_text(),
+        [flagship.num_envs, p.max_jobs, p.max_stages, p.max_stages],
+    )
+
+
+def _loops(text: str, op_name_end: str) -> list[str]:
+    """The `while` instructions whose `op_name` ends so."""
+    return [line for line in text.split("\n")
+            if " while(" in line and op_name_end + '"' in line]
+
+
+def _inside(text: str, loop: str) -> dict[str, str]:
+    """A `while` instruction's body and predicate and what they call."""
     import re
 
-    p = flagship.params_env
-    dims = sorted(
-        [flagship.num_envs, p.max_jobs, p.max_stages, p.max_stages]
-    )
-    text = collector.as_text()
-    loops = [
-        line for line in text.split("\n")
-        if " while(" in line and 'env/micro_step/drain)/while"' in line
-    ]
-    assert len(loops) == 1, len(loops)
     inside = {}
     for key in ("body", "condition"):
-        root = re.search(rf"{key}=%([\w.\-]+)", loops[0]).group(1)
+        root = re.search(rf"{key}=%([\w.\-]+)", loop).group(1)
         inside |= _called(text, root)
+    return inside
+
+
+def _holds_no_adjacency_sized_result(text: str, dims: list[int]) -> None:
+    """PR 39's rule on the one drain `while` of a compiled collector,
+    whose lanes hold adjacencies of `dims` (in any order)."""
+    import re
+
+    dims = sorted(dims)
+    loops = _loops(text, "env/micro_step/drain)/while")
+    assert len(loops) == 1, len(loops)
+    inside = _inside(text, loops[0])
     assert len(inside) > 100, len(inside)  # the loop, not a stub of it
 
     hands_on = {"parameter", "get-tuple-element", "tuple", "bitcast"}
@@ -282,6 +299,87 @@ def test_drain_loop_writes_nothing_of_the_adjacency_s_size(
                     found.append((name, line.strip()[:160]))
     assert carried >= 2, carried  # the adjacency IS in the loop
     assert not found, found
+
+
+def test_blocked_drain_is_one_while_inside_one_loop_over_blocks(
+    flagship, one_chip, tmp_path
+):
+    """What takes a counter's place for the drain over blocks of lanes
+    (PR 43): how often it engages is a fact of the compiled collector.
+    At the batched-arrivals cell's 1024 lanes (its cluster, job axis
+    and keys; a short scan) the collector compiled for the v5e holds
+    ONE drain `while`, over 128 lanes, inside ONE loop over the eight
+    blocks, under the drain's scope: one copy of the drain's program,
+    not eight. PR 39's rule still holds inside a block: no instruction
+    of that `while` yields an array of the adjacency's size."""
+    import jax
+
+    import chip_smoke
+    from sparksched_tpu.trainers import make_trainer
+    from sparksched_tpu.trainers.rollout import _DRAIN_BLOCK
+
+    cfg = chip_smoke.load_cfg(
+        "config/decima_tpch_batched.yaml", str(tmp_path))
+    cfg["trainer"] |= {
+        "num_sequences": 128, "num_rollouts": 8, "rollout_steps": 16}
+    jax.config.update("jax_default_prng_impl", "threefry2x32")
+    try:  # the flagship fixture's rbg keys, and back (it restores)
+        trainer = make_trainer(cfg)
+        state = _train_state(trainer, one_chip)
+        compiled = trainer._collect_jit.lower(
+            state.params, state.iteration, state.rng, None).compile()
+    finally:
+        jax.config.update("jax_default_prng_impl", "rbg")
+    p = trainer.params_env
+    assert (trainer.num_envs, p.max_jobs, _DRAIN_BLOCK) == (1024, 20, 128)
+    _fits(compiled, temp_gib=1.0)
+    text = compiled.as_text()
+    (over_blocks,) = _loops(text, "env/micro_step/drain/while")
+    (drain,) = _loops(text, "env/micro_step/drain)/while")
+    name = drain.split(" = ")[0].strip()
+    assert any(name + " = " in body
+               for body in _inside(text, over_blocks).values())
+    # the outer loop carries the arrays at full width, the drain a
+    # block of them
+    assert "[1024," in over_blocks.split(" while(")[0]
+    carried = drain.split(" while(")[0]
+    assert "[128," in carried and "[1024," not in carried
+    _holds_no_adjacency_sized_result(
+        text, [_DRAIN_BLOCK, p.max_jobs, p.max_stages, p.max_stages])
+
+
+def test_blocked_drain_leaves_the_sampler_its_layout(
+    flagship, one_chip, tmp_path
+):
+    """What `decima_rollout_dp4`'s mesh check rests on besides the
+    blocks (PR 43): the one-chip collector at the cell's 512 lanes x
+    200 jobs drains four blocks, and the arrays around the drain keep
+    the layout they have without it. With the blocks stacked and
+    unstacked (`lax.map`) the carry's `[512,200,20]` grids came back
+    job-major, `{2,0,1}`, the layout reached the sampler, its softmax
+    over a lane's stage scores summed in another order, and a tenth of
+    the log-probs parted from the mesh's by a last bit. Sliced out and
+    written back in place they stay `{2,1,0}`, lanes outermost."""
+    import re
+
+    import chip_smoke
+    from sparksched_tpu.trainers import make_trainer
+
+    cfg = chip_smoke.load_cfg(
+        "config/decima_tpch_multichip.yaml", str(tmp_path))
+    cfg["parallel"] = {"dp": 1}
+    cfg["trainer"] |= {
+        "num_sequences": 64, "num_rollouts": 8, "rollout_steps": 16}
+    trainer = make_trainer(cfg)
+    p = trainer.params_env
+    assert (trainer.num_envs, p.max_jobs, p.max_stages) == (512, 200, 20)
+    state = _train_state(trainer, one_chip)
+    text = trainer._collect_jit.lower(
+        state.params, state.iteration, state.rng, None).compile().as_text()
+    assert len(_loops(text, "env/micro_step/drain/while")) == 1  # blocked
+    grids = re.findall(
+        r"= f32\[512,200,20\]\{([\d,]+)[^\n]*decima/sample", text)
+    assert len(grids) >= 4 and set(grids) == {"2,1,0"}, grids
 
 
 @pytest.mark.parametrize("batched", [False, True])

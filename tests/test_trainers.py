@@ -729,8 +729,8 @@ def test_reseed_after_the_drain_equals_the_reset_in_every_micro_step():
         lambda salt: core.reset_pair(
             params, bank, seq0, jax.random.fold_in(seq0, salt))
     )(salts))
-    reset_fns = _group_reset_fns(params, bank, bases, counts, salts)
-    lane_idx = jnp.arange(lanes)
+    reset_fns = _group_reset_fns(params, bank)
+    lane_args = (bases, counts, salts)
 
     @jax.jit
     def decide(ls, over):
@@ -749,7 +749,7 @@ def test_reseed_after_the_drain_equals_the_reset_in_every_micro_step():
             lambda l, k, i, t: drain_micro_step(
                 params, bank, l, k, True, reset_fn=reset_fns(i), t_ref=t,
                 masked=True)
-        )(ls, keys, lane_idx, t_ref)
+        )(ls, keys, lane_args, t_ref)
 
     @jax.jit
     def drain_row(ls, keys, t_ref):
@@ -758,7 +758,7 @@ def test_reseed_after_the_drain_equals_the_reset_in_every_micro_step():
                 params, bank, l, k, True, reset_fn=reset_fns(i), t_ref=t,
                 lane_axis="lanes"),
             axis_name="lanes",
-        )(ls, keys, lane_idx, t_ref)
+        )(ls, keys, lane_args, t_ref)
 
     def freeze(over, old, new):
         return jax.tree_util.tree_map(
