@@ -138,8 +138,7 @@ def compiles_in(recs: list[dict]) -> list[dict]:
     flagship config, eager `jax.random.fold_in` (the store's per-call
     key) re-traces its threefry helper on the host at every call and
     compiles nothing; `retraced` reports those by name."""
-    return [r for r in recs if r["ev"] == "jit_compile_detail"
-            or r["ev"] == "jit_compile"
+    return [r for r in recs if r["ev"] == "jit_compile"
             and not r["event"].endswith("jaxpr_trace_duration")]
 
 

@@ -18,7 +18,11 @@ Three parts (ISSUE 2 tentpole), each usable on its own:
 - `tracing`: named `annotate(...)` scopes (jax.named_scope +
   jax.profiler.TraceAnnotation) around the GNN eval, the env
   micro-step, the collection scatter and the PPO update, so a captured
-  Perfetto trace carries those phase labels.
+  Perfetto trace carries those phase labels; and `span(...)`, the
+  process's one host timer (PR 44): the trainer's start-up and every
+  call of its compiled programs, with jax's own trace, lower, compile
+  and cache events beside them, in one bounded record that the runlog
+  and the benchmark's `setup.*` metrics read.
 - `memory`: HBM byte accounting (ISSUE 5 tentpole) — compile-time
   `memory_analysis()` extraction, trace-time buffer sizing under the
   TPU tiled-layout model, the lane-fit advisor (max vmap lanes under
@@ -58,7 +62,7 @@ from .slo import (  # noqa: F401
     slo_from_config,
 )
 from .telemetry import Telemetry, summarize, telemetry_zeros  # noqa: F401
-from .tracing import RequestTrace, annotate  # noqa: F401
+from .tracing import RequestTrace, annotate, span  # noqa: F401
 
 # PEP 562 lazy imports for the submodules that double as CLIs
 # (`python -m sparksched_tpu.obs.{fleet,ledger}`) or that only the

@@ -6,8 +6,16 @@ the print-based profiler reports. Every record carries `ev` (the event
 kind) and `t` (unix seconds); the kinds the trainer/bench write:
 
 - `run_start` / `run_end`: run metadata (config summary, totals)
-- `span`: a timed host-side phase (`name`, `secs`, e.g. per-iteration
-  collect/update)
+- `span`: a timed host-side phase (`name`, `secs`). The trainer's
+  runlog holds the process's host spans (`obs/tracing.py` lists them):
+  after `run_start` the start-up split (`setup/mesh`,
+  `setup/trainer_init` with `setup/workload_bank` and
+  `setup/scheduler_init`, `setup/init_state`), then every iteration a
+  `collect/call` and a `train/update_call` (the host's part of the
+  compiled call: seconds of it after the first iteration are a
+  re-trace) inside `iter <n> collect` and `iter <n> update` (the call
+  and the device's run); those from the record carry `ordinal`,
+  `parent` and `started` (unix seconds)
 - `scalars`: per-iteration training stats (the TensorBoard mirror —
   identical keys/values to what `add_scalar` receives)
 - `telemetry`: an engine-telemetry summary (`obs.telemetry.summarize`)
@@ -43,9 +51,12 @@ kind) and `t` (unix seconds); the kinds the trainer/bench write:
   `SessionStore.set_params`/`rollback_params` so every served
   decision's staleness stamp (`params_version` on `trace` records)
   can be aligned with the swap history
-- `jit_compile` / `jit_compile_detail`: JIT (re)compilation events via
-  `jax.monitoring` duration hooks plus the dispatch logger (the latter
-  names WHICH function was traced/compiled)
+- `jit_compile`: a JIT (re)compilation event of `JIT_MIN_SECS` or more,
+  from the `jax.monitoring` listeners of `obs/tracing.py`'s record: the
+  phase (`event`: trace, lower, backend compile or cache load, compile
+  time saved), `secs`, `fun_name`, WHICH function it was, and `span`,
+  the `ordinal` of the `span` record it fell in (which `collect/call`
+  re-traced; null outside any)
 - `fleet`: a fleet-collector scoreboard snapshot (ISSUE 17) — per-
   replica windowed rps/p99/occupancy/page-churn/quarantine-rate/
   params-version(+lag) rows and the fleet-aggregate window, written
@@ -78,7 +89,6 @@ from __future__ import annotations
 
 import atexit
 import json
-import logging
 import os
 import os.path as osp
 import signal
@@ -87,6 +97,8 @@ import threading
 import time
 import weakref
 from typing import Any
+
+from . import tracing
 
 # sanctioned console sink: the lint tier forbids bare `print(` inside
 # sparksched_tpu/ outside renderer.py, so host-loop progress lines go
@@ -369,11 +381,39 @@ class RunLog:
     # -- JIT recompile hooks ----------------------------------------------
 
     def install_jit_hooks(self) -> None:
-        """Record JIT (re)compilations into this runlog — see
-        `_install_global_jit_listener`. Idempotent per process; multiple
+        """Record JIT (re)compilations into this runlog: a `jit_compile`
+        record for each of jax's compile events of `JIT_MIN_SECS` or
+        longer, with the phase (`event`), `secs`, the function
+        (`fun_name`) and the host span it fell in (`span`). Multiple
         runlogs each receive the events while open."""
-        _install_global_jit_listener()
-        _ACTIVE_RUNLOGS.add(self)
+        tracing.listen_to_jax()
+        tracing.RECORD.event_sinks.add(self)
+
+    def jax_event(self, rec: dict) -> None:
+        """The record's event sink (see `install_jit_hooks`)."""
+        if "compile" in rec["event"] and rec["secs"] >= JIT_MIN_SECS:
+            self.write("jit_compile", event=rec["event"],
+                       secs=round(rec["secs"], 4),
+                       fun_name=rec["fun_name"], span=rec["span"])
+
+    def follow_spans(self, since: int = 0) -> None:
+        """Write the host spans the process's record holds from ordinal
+        `since` on (`obs/tracing.py`; a trainer passes where its own
+        set-up began, or where its last run ended, so another trainer's
+        and an earlier run's spans stay out) as `span` records, and
+        every announced span from now on as it ends: the start-up split
+        and one `collect/call` an iteration."""
+        for rec in tracing.RECORD.spans():
+            if rec["ordinal"] >= since:
+                self.span_ended(rec)
+        tracing.RECORD.span_sinks.add(self)
+
+    def span_ended(self, rec: dict) -> None:
+        """The record's span sink (see `follow_spans`)."""
+        fields = {k: rec[k] for k in ("ordinal", "parent", "error")
+                  if rec.get(k) is not None}
+        self.span_event(rec["name"], rec["end"] - rec["start"],
+                        started=round(rec["wall"], 3), **fields)
 
     def close(self, **fields: Any) -> None:
         # double-checked fast path (idempotent close): the
@@ -384,7 +424,12 @@ class RunLog:
         with self._lock:
             self._closed = True
             self._fp.close()
-        _ACTIVE_RUNLOGS.discard(self)
+        self._forget()
+
+    def _forget(self) -> None:
+        """A closed runlog receives nothing more."""
+        tracing.RECORD.event_sinks.discard(self)
+        tracing.RECORD.span_sinks.discard(self)
         _OPEN_RUNLOGS.discard(self)
 
     def _teardown(self, reason: str) -> None:
@@ -411,8 +456,7 @@ class RunLog:
                 self._fp.close()
         finally:
             self._lock.release()
-        _ACTIVE_RUNLOGS.discard(self)
-        _OPEN_RUNLOGS.discard(self)
+        self._forget()
 
     def __enter__(self) -> "RunLog":
         return self
@@ -422,22 +466,26 @@ class RunLog:
 
 
 class _Span:
+    """`RunLog.span`: timed by `tracing.span` (so it is in the process's
+    record and on a running profiler's host track), written here."""
+
     def __init__(self, log: RunLog, name: str, fields: dict) -> None:
         self._log = log
-        self._name = name
         self._fields = fields
+        self._span = tracing.span(name, announce=False)
         self.elapsed = 0.0
 
     def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter()
+        self._span.__enter__()
         return self
 
     def __exit__(self, exc_type, exc_val, exc_tb) -> None:
-        self.elapsed = time.perf_counter() - self._t0
+        self._span.__exit__(exc_type, exc_val, exc_tb)
+        self.elapsed = self._span.elapsed
         fields = dict(self._fields)
         if exc_type is not None:
             fields["error"] = exc_type.__name__
-        self._log.span_event(self._name, self.elapsed, **fields)
+        self._log.span_event(self._span.name, self.elapsed, **fields)
 
 
 # ---------------------------------------------------------------------------
@@ -507,84 +555,17 @@ def _install_teardown_hooks() -> None:
 
 
 # ---------------------------------------------------------------------------
-# process-global JIT compile listener
+# JIT compile records
 #
-# jax.monitoring listeners cannot be individually unregistered, so ONE
-# listener is installed per process and fans out to the currently-open
-# runlogs (a WeakSet: a garbage-collected runlog stops receiving without
-# explicit teardown). The duration events name the compile PHASE
-# (/jax/core/compile/...) but not the function; the dispatch logger's
-# "Finished tracing + transforming <fun> ..." lines carry the name, so a
-# DEBUG handler on that logger records WHICH function recompiled.
+# The process has ONE pair of `jax.monitoring` listeners, those of
+# `obs/tracing.py`'s record (jax's listeners cannot be told apart once
+# registered); a runlog that asked for them (`install_jit_hooks`) is one
+# of the record's event sinks while it is open (held weakly: a
+# garbage-collected runlog stops receiving without explicit teardown).
 # ---------------------------------------------------------------------------
 
-_ACTIVE_RUNLOGS: "weakref.WeakSet[RunLog]" = weakref.WeakSet()
-_HOOKS_INSTALLED = False
-# compiles shorter than this are not recorded: the hundreds of trivial
-# broadcast/convert compiles at process start would bloat every runlog,
-# while any recompile worth investigating (a shape leak, a cache miss
-# mid-run) is orders of magnitude above it
+# compiles shorter than this are not WRITTEN (the record keeps them):
+# the hundreds of trivial broadcast/convert compiles at process start
+# would bloat every runlog, while any recompile worth investigating (a
+# shape leak, a cache miss mid-run) is orders of magnitude above it
 JIT_MIN_SECS = float(os.environ.get("RUNLOG_JIT_MIN_SECS", "0.05"))
-
-
-def _fanout(ev: str, **fields: Any) -> None:
-    for rl in list(_ACTIVE_RUNLOGS):
-        try:
-            rl.write(ev, **fields)
-        except Exception:
-            pass  # a closed/broken sink must never break compilation
-
-
-class _DispatchLogHandler(logging.Handler):
-    def emit(self, record: logging.LogRecord) -> None:  # noqa: A003
-        try:
-            msg = record.getMessage()
-        except Exception:
-            return
-        # "Finished XLA compilation of <fun> in <secs> sec" — the only
-        # record that names WHICH function compiled; tracing/MLIR lines
-        # are redundant with the duration events
-        if not msg.startswith("Finished XLA compilation"):
-            return
-        try:
-            secs = float(msg.rsplit(" in ", 1)[1].split()[0])
-        except (IndexError, ValueError):
-            secs = None
-        if secs is not None and secs < JIT_MIN_SECS:
-            return
-        _fanout("jit_compile_detail", msg=msg, secs=secs)
-
-
-def _install_global_jit_listener() -> None:
-    global _HOOKS_INSTALLED
-    if _HOOKS_INSTALLED:
-        return
-    import jax
-
-    def _on_duration(event: str, duration: float, **kw: Any) -> None:
-        if "compile" in event and float(duration) >= JIT_MIN_SECS:
-            _fanout("jit_compile", event=event,
-                    secs=round(float(duration), 4),
-                    **{k: _json_safe(v) for k, v in kw.items()})
-
-    jax.monitoring.record_event_duration_secs  # attr check before hook
-    jax.monitoring.register_event_duration_secs_listener(_on_duration)
-
-    # jax's per-compile "Finished ..." lines (the only place the
-    # compiled FUNCTION is named) log at DEBUG; lowering the logger to
-    # DEBUG would also spill every line to a basicConfig'd root logger,
-    # so propagation is cut and records at the logger's previous
-    # effective level (warnings) are re-emitted to root by hand.
-    logger = logging.getLogger("jax._src.dispatch")
-    prev_effective = logger.getEffectiveLevel()
-
-    class _Forward(logging.Handler):
-        def emit(self, record: logging.LogRecord) -> None:  # noqa: A003
-            if record.levelno >= max(prev_effective, logging.WARNING):
-                logging.getLogger().handle(record)
-
-    logger.addHandler(_DispatchLogHandler(level=logging.DEBUG))
-    logger.addHandler(_Forward(level=logging.DEBUG))
-    logger.setLevel(logging.DEBUG)
-    logger.propagate = False
-    _HOOKS_INSTALLED = True

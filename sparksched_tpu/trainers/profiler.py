@@ -3,7 +3,9 @@
 The reference wraps every rollout in a cProfile context manager printing
 top-N cumulative stats. Host-side Python profiling is meaningless for a
 jitted program, so `Profiler` keeps the same context-manager interface but
-reports wall time and, when a trace directory is given, captures a
+reports wall time (through `obs.tracing.span`, the process's one host
+timer: the block is in its record under `label`, and on the host track
+of a running profiler) and, when a trace directory is given, captures a
 `jax.profiler` device trace viewable in TensorBoard / Perfetto (phases
 are labeled via `obs.tracing.annotate` scopes; the module docstring of
 `obs/tracing.py` lists them, and `benchmarks/trace_reduce.py` turns
@@ -11,9 +13,8 @@ such a trace into device seconds per scope)."""
 
 from __future__ import annotations
 
-import time
-
 from ..obs.runlog import emit
+from ..obs.tracing import span
 
 
 class Profiler:
@@ -46,12 +47,15 @@ class Profiler:
 
             jax.profiler.start_trace(self.trace_dir)
             self._tracing = True
-        self._t0 = time.perf_counter()
+        # a sink reports the span itself: not announced a second time
+        self._span = span(self.label, announce=self.sink is None)
+        self._span.__enter__()
         return self
 
     def __exit__(self, exc_type, exc_val, exc_tb) -> None:
         try:
-            self.elapsed = time.perf_counter() - self._t0
+            self._span.__exit__(exc_type, exc_val, exc_tb)
+            self.elapsed = self._span.elapsed
             # the sink (runlog) always receives the span; `quiet` only
             # silences the console echo
             if self.sink is not None:

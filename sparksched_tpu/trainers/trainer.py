@@ -66,7 +66,7 @@ from ..env.health import (
 from ..obs import RunLog, emit
 from ..obs.memory import device_memory_stats
 from ..obs.telemetry import summarize, telemetry_zeros_like
-from ..obs.tracing import annotate
+from ..obs.tracing import annotate, mark, open_span, span, spanned
 from ..schedulers import TrainableScheduler, make_scheduler
 from ..workload import make_workload_bank
 from .baselines import group_baselines
@@ -135,11 +135,16 @@ def make_optimizer(train_cfg: CfgType) -> optax.GradientTransformation:
 class Trainer(abc.ABC):
     """Base trainer; subclasses implement the jitted `_update`."""
 
+    @span("setup/trainer_init")
     def __init__(self, agent_cfg: CfgType, env_cfg: CfgType,
                  train_cfg: CfgType, mesh=None,
                  obs_cfg: CfgType | None = None,
                  health_cfg: CfgType | None = None,
                  chaos_cfg: CfgType | None = None) -> None:
+        # where this trainer's host spans begin in the process's record
+        # (`make_trainer` moves it to its `setup/mesh`, the end of a
+        # run past the run's spans): a runlog's backlog starts there
+        self._spans_from: int = open_span().ordinal
         for key in REMOVED_TRAINER_KEYS:
             if key in train_cfg:
                 raise ValueError(
@@ -323,12 +328,13 @@ class Trainer(abc.ABC):
             env_cfg = env_cfg | {"beta": self.beta}
 
         self.params_env: EnvParams = env_params_from_cfg(env_cfg)
-        self.bank = make_workload_bank(
-            self.params_env.num_executors, self.params_env.max_stages,
-            **{k: v for k, v in env_cfg.items()
-               if k in ("data_dir", "bucket_size", "data_sampler_cls",
-                        "bank_dtype")},
-        )
+        with span("setup/workload_bank"):
+            self.bank = make_workload_bank(
+                self.params_env.num_executors, self.params_env.max_stages,
+                **{k: v for k, v in env_cfg.items()
+                   if k in ("data_dir", "bucket_size", "data_sampler_cls",
+                            "bank_dtype")},
+            )
         if self.bank.max_stages != self.params_env.max_stages:
             self.params_env = self.params_env.replace(
                 max_stages=self.bank.max_stages,
@@ -355,11 +361,12 @@ class Trainer(abc.ABC):
                 )
             )
         ) + 1
-        scheduler = make_scheduler(
-            {"num_levels": bank_depth}
-            | agent_cfg
-            | {"num_executors": self.params_env.num_executors}
-        )
+        with span("setup/scheduler_init"):
+            scheduler = make_scheduler(
+                {"num_levels": bank_depth}
+                | agent_cfg
+                | {"num_executors": self.params_env.num_executors}
+            )
         if not isinstance(scheduler, TrainableScheduler):
             raise ValueError(
                 f"{type(scheduler).__name__} is not a TrainableScheduler"
@@ -407,24 +414,28 @@ class Trainer(abc.ABC):
             # async (LoopState, reset_counts) carry, and the per-lane
             # Telemetry — shard them all, or the carry round-trips
             # through a replicated layout every iteration
-            self._collect_jit = jax.jit(
+            collect_jit = jax.jit(
                 self._collect, out_shardings=(lanes, lanes, lanes),
                 donate_argnums=donate,
             )
-            self._update_jit = jax.jit(
+            update_jit = jax.jit(
                 self._update, in_shardings=(None, lanes),
                 out_shardings=None,
             )
         else:
-            self._collect_jit = jax.jit(
-                self._collect, donate_argnums=donate
-            )
-            self._update_jit = jax.jit(self._update)
+            collect_jit = jax.jit(self._collect, donate_argnums=donate)
+            update_jit = jax.jit(self._update)
+        # the `jit` objects under a host span a call (obs/tracing.py):
+        # seconds of `collect/call` past an iteration's first are a
+        # re-trace, named and timed
+        self._collect_jit = spanned("collect/call", collect_jit)
+        self._update_jit = spanned("train/update_call", update_jit)
 
     # ------------------------------------------------------------------
     # device-side pieces
     # ------------------------------------------------------------------
 
+    @span("setup/init_state")
     def init_state(self) -> TrainState:
         params = self.scheduler.params
         return TrainState(
@@ -859,6 +870,7 @@ class Trainer(abc.ABC):
                 memory=self.obs_memory,
                 seed=self.seed,
             )
+            self._runlog.follow_spans(self._spans_from)
         self._tb = None
         if self.use_tensorboard:
             # a heavy torch dependency in a JAX repo: degrade to the
@@ -893,6 +905,7 @@ class Trainer(abc.ABC):
         if self._runlog is not None:
             self._runlog.close(iteration=int(state.iteration))
             self._runlog = None
+            self._spans_from = mark()
         emit("\nTraining complete.")
 
     def _checkpoint(self, i: int, best: dict[str, Any],
@@ -1072,10 +1085,13 @@ def make_trainer(cfg: CfgType) -> Trainer:
     name = cfg["trainer"]["trainer_cls"]
     if name not in registry:
         raise ValueError(f"'{name}' is not a valid trainer.")
-    return registry[name](
-        cfg["agent"], cfg["env"], cfg["trainer"],
-        mesh=mesh_from_config(cfg.get("parallel")),
+    with span("setup/mesh") as first:
+        mesh = mesh_from_config(cfg.get("parallel"))
+    trainer = registry[name](
+        cfg["agent"], cfg["env"], cfg["trainer"], mesh=mesh,
         obs_cfg=cfg.get("obs"),
         health_cfg=cfg.get("health"),
         chaos_cfg=cfg.get("chaos"),
     )
+    trainer._spans_from = first.ordinal
+    return trainer

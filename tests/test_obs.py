@@ -9,6 +9,7 @@ run by tests/test_static_analysis.py.)"""
 from __future__ import annotations
 
 import json
+import logging
 import os
 import signal
 import subprocess
@@ -298,10 +299,15 @@ def test_runlog_records_jit_compiles(tmp_path, monkeypatch):
     compiles = [r for r in recs if r["ev"] == "jit_compile"]
     assert compiles, "no jit_compile events recorded"
     assert all("event" in r and "secs" in r for r in compiles)
-    details = [r for r in recs if r["ev"] == "jit_compile_detail"]
-    assert any("f" in r["msg"] for r in details), (
-        "the compile detail records must name the compiled function"
+    backend = [r for r in compiles
+               if r["event"].endswith("backend_compile_duration")]
+    assert any(r["fun_name"] == "jit(f)" for r in backend), (
+        "the compile records must name the compiled function"
     )
+    # the scraper of jax's DEBUG log is gone with its record kind, and
+    # the dispatch logger is as jax left it
+    assert {r["ev"] for r in recs} == {"jit_compile", "run_end"}
+    assert logging.getLogger("jax._src.dispatch").propagate
 
 
 def test_runlog_span_and_json_safety(tmp_path):
