@@ -183,6 +183,28 @@ class Budget:
 # the net's and the update's as they were. New with it: the
 # `while_whole_read_elems` pin on micro_step, drain_to_decision and
 # serve_decide. The chip rows are PERF.md, PR 39.
+#
+# Re-pinned 2026-10-02 (PR 45: the three bulk passes read the frontier
+# bits of their executors' destinations from the frontier packed over
+# its stage axis, `core._frontier_at`, a pack, a one-hot select-reduce
+# over [N,J] and a bit test where each had one gather of N elements
+# out of [J,S]; the fused pass draws `[max_events + N, 2]` uniforms,
+# a pair a step, and its step no longer selects its pair out of a row
+# of N). Eqns / gathers before -> after: micro_step 4380/29 ->
+# 4438/27, decide_micro_step 2474/24 -> 2510/23, drain_to_decision
+# 3015/5 -> 3037/4, serve_decide 6666/33 -> 6724/31 (its record and
+# ring variants the same +58 and -2), serve_decide_batch 15378/268 ->
+# 15442/266 (its variants alike), flat_collect_batch 15050/206 ->
+# 15114/204, flat_collect_batch_health 15319/206 -> 15383/204;
+# scatters, observe, the net's and the update's as they were. Every
+# count inside its band; the gather caps of the programs whose rule
+# (measured x 1.35, at least measured + 2) now gives less than their
+# cap are lowered to it: micro_step 40 -> 37, decide_micro_step 33 ->
+# 32, drain_to_decision 8 -> 6, serve_decide and its record and ring
+# variants 45 -> 42 (the batch programs' caps, pinned at a lower count
+# than today's, are already under the rule's). What the change is for
+# is no count: `row_gathers` over the pass's own jaxpr
+# (tests/test_static_analysis.py). The chip rows are PERF.md, PR 45.
 # ---------------------------------------------------------------------------
 
 BUDGETS: dict[str, Budget] = {
@@ -197,12 +219,12 @@ BUDGETS: dict[str, Budget] = {
     # of work (the while is the fused event run's early-exit loop, not
     # a decision loop)
     "micro_step": Budget(
-        eqn_lo=2000, eqn_hi=5500, gather_hi=40, scatter_hi=3,
+        eqn_lo=2000, eqn_hi=5500, gather_hi=37, scatter_hi=3,
         while_whole_read_elems=AUDIT_ADJ_ELEMS,
     ),
     # the single-eval collectors' policy-bearing micro-step
     "decide_micro_step": Budget(
-        eqn_lo=1000, eqn_hi=3350, gather_hi=33, scatter_hi=2,
+        eqn_lo=1000, eqn_hi=3350, gather_hi=32, scatter_hi=2,
         loop_free=True,
     ),
     # the single-eval collectors' non-policy drain (while-loop by
@@ -210,7 +232,7 @@ BUDGETS: dict[str, Budget] = {
     # ISSUE-7 restructure keeps its cond to the event existence bit
     # and drops the per-iteration full-pytree rollback select)
     "drain_to_decision": Budget(
-        eqn_lo=1200, eqn_hi=3450, gather_hi=8, scatter_hi=3,
+        eqn_lo=1200, eqn_hi=3450, gather_hi=6, scatter_hi=3,
         while_whole_read_elems=AUDIT_ADJ_ELEMS,
     ),
     # Decima stage/exec scores over a [B]-stacked feature set, both
@@ -260,7 +282,7 @@ BUDGETS: dict[str, Budget] = {
     # `drain_to_decision` (the inter-decision drain, by design); the
     # scan is the GNN level pass + the bulk event kernel.
     "serve_decide": Budget(
-        eqn_lo=3000, eqn_hi=8800, gather_hi=45, scatter_hi=88,
+        eqn_lo=3000, eqn_hi=8800, gather_hi=42, scatter_hi=88,
         while_whole_read_elems=AUDIT_ADJ_ELEMS,
     ),
     "serve_decide_batch": Budget(
@@ -292,7 +314,7 @@ BUDGETS: dict[str, Budget] = {
     # NO count on any serve program — params enter as invars, the
     # traced computation is the same.
     "serve_decide_record": Budget(
-        eqn_lo=3000, eqn_hi=8810, gather_hi=45, scatter_hi=88,
+        eqn_lo=3000, eqn_hi=8810, gather_hi=42, scatter_hi=88,
     ),
     "serve_decide_batch_record": Budget(
         eqn_lo=6000, eqn_hi=17410, gather_hi=339, scatter_hi=88,
@@ -325,7 +347,7 @@ BUDGETS: dict[str, Budget] = {
     # re-measured BYTE-IDENTICAL in the same PR — the zero-cost-off
     # acceptance bar.
     "serve_decide_record_ring": Budget(
-        eqn_lo=3000, eqn_hi=8980, gather_hi=45, scatter_hi=117,
+        eqn_lo=3000, eqn_hi=8980, gather_hi=42, scatter_hi=117,
     ),
     "serve_decide_batch_record_ring": Budget(
         eqn_lo=6000, eqn_hi=17550, gather_hi=341, scatter_hi=117,
@@ -382,6 +404,26 @@ def whole_reads_in_while(jaxpr, elems: int, _in_loop: bool = False
                 for v in eqn.invars
                 if getattr(getattr(v, "aval", None), "size", 0) >= elems
             ]
+    return found
+
+
+def row_gathers(jaxpr, elems: int, rows: int) -> list[str]:
+    """The `gather` equations, at any depth, that read an operand of
+    at least `elems` elements at `rows` or more index rows (the
+    indices' shape less its last axis), as `gather(operand <- rows)`:
+    a grid of the state's size read once for every executor."""
+    found = []
+    for eqn in iter_eqns(jaxpr):
+        if eqn.primitive.name != "gather":
+            continue
+        operand, indices = (v.aval for v in eqn.invars[:2])
+        n_rows = 1
+        for d in indices.shape[:-1]:
+            n_rows *= d
+        if operand.size >= elems and n_rows >= rows:
+            found.append(
+                f"gather{tuple(operand.shape)} <- {tuple(indices.shape)}"
+            )
     return found
 
 

@@ -524,7 +524,7 @@ def _bulk_fulfill(
     valid = pos < num_idle
     common_dst = dj < 0
     send0 = ~common_dst & (ejob != dj)
-    frontier_k = state.frontier[djc, dsc]
+    frontier_k = _frontier_at(state, dj, ds0)
     start0 = ~common_dst & ~send0 & frontier_k
     park0 = ~common_dst & ~send0 & ~frontier_k
 
@@ -1296,7 +1296,7 @@ def _bulk_ready(
     djc = jnp.clip(dj, 0, j_cap - 1)
     dsc = jnp.clip(ds0, 0, s_cap - 1)
 
-    frontier_k = state.frontier[djc, dsc]
+    frontier_k = _frontier_at(state, dj, ds0)
     flat = djc * s_cap + dsc
     earlier = pos[None, :] < pos[:, None]
     stage_pair = flat[None, :] == flat[:, None]
@@ -1573,6 +1573,33 @@ def _flipped_parents(state: EnvState, delta: jnp.ndarray) -> jnp.ndarray:
     return hits(delta > 0) - hits(delta < 0)
 
 
+def _frontier_at(state: EnvState, dj: jnp.ndarray, ds: jnp.ndarray
+                 ) -> jnp.ndarray:
+    """bool[N]: `state.frontier[dj, ds]` for per-executor destinations
+    (both clipped into range, as an index is), read by no dynamic
+    index. The frontier is packed over its stage axis
+    (`_pack_stage_sets`: uint32[J,W], one elementwise pass over the
+    three [J,S] caches it is made of), each executor's job word picked
+    by a one-hot over the job axis ([N,J]; a row holds one match, so
+    the sum IS the word), and bit `ds % 32` of word `ds // 32` tested.
+    Bits and integers, so equal to the gather for every input and any
+    S. Why not the gather: on the TPU v5e it is serialised, 12 ns an
+    element, 77 us a drain body of 128 lanes x 50 executors at the
+    flagship cluster (PERF.md, PRs 39 and 45)."""
+    j_cap, s_cap = state.stage_exists.shape
+    djc = jnp.clip(dj, 0, j_cap - 1)
+    dsc = jnp.clip(ds, 0, s_cap - 1)
+    sets = _pack_stage_sets(state.frontier)  # [J,W]
+    of_job = djc[:, None] == jnp.arange(j_cap, dtype=_i32)[None, :]
+    words = jnp.where(of_job[:, :, None], sets[None], 0).sum(
+        1, dtype=jnp.uint32)  # [N,W]
+    at_word = lax.div(dsc, _i32(STAGE_SET_BITS))[:, None] == jnp.arange(
+        sets.shape[1], dtype=_i32)[None, :]
+    word = jnp.where(at_word, words, 0).sum(1, dtype=jnp.uint32)
+    bit = lax.rem(dsc, _i32(STAGE_SET_BITS)).astype(jnp.uint32)
+    return ((word >> bit) & 1).astype(bool)
+
+
 def _bulk_events_fused(
     params: EnvParams, bank: WorkloadBank, state: EnvState,
     enabled: jnp.ndarray, stop_at_limit: bool = False,
@@ -1652,10 +1679,12 @@ def _bulk_events_fused(
     of the flagship cluster, PERF.md section 5), not the budget;
     `lane_axis` names the caller's lane axis, if it has one.
 
-    Matches the sequential path bit-exactly except the rng stream
-    (one batched uniform table, as in the unfused passes; drawn whole
-    before the loop at `[max_events + N, N, 2]`, whatever the loop
-    reads of it)."""
+    Matches the sequential path bit-exactly except the rng stream:
+    one uniform table a pass, drawn before the loop at
+    `[max_events + N, 2]`, the pair a step can consume (a step
+    launches at most one task; a pair for every step AND executor is
+    N times what a pass can read, and cost 62 us a drain body of 128
+    lanes under threefry keys: PERF.md, PR 45)."""
     n = state.exec_job.shape[0]
     j_cap, s_cap = state.stage_remaining.shape
     pos = jnp.arange(n, dtype=_i32)
@@ -1673,7 +1702,7 @@ def _bulk_events_fused(
     ds0 = state.exec_dst_stage
     djc = jnp.clip(dj, 0, j_cap - 1)
     dsc = jnp.clip(ds0, 0, s_cap - 1)
-    frontier_a = state.frontier[djc, dsc]
+    frontier_a = _frontier_at(state, dj, ds0)
     tv_a = state.exec_task_valid
     ss_a = state.exec_task_stage == ds0
     sq_a = state.exec_arrive_seq
@@ -1687,10 +1716,11 @@ def _bulk_events_fused(
     )
 
     rng_next, sub = jax.random.split(state.rng)
-    # one batched draw for the whole pass; us[i, e] is consumed iff the
-    # i-th processed event belongs to executor e (selection at step i
-    # depends only on earlier draws, so consumed draws are i.i.d.)
-    us = jax.random.uniform(sub, (length, n, 2))
+    # one draw for the whole pass, a pair a step: step i takes ONE
+    # event and launches at most one task, which reads us[i] (which
+    # event it is depends only on earlier rows, so the pairs consumed
+    # are i.i.d.)
+    us = jax.random.uniform(sub, (length, 2))
 
     jcnt0 = (
         state.exec_job[None, :] == jnp.arange(j_cap, dtype=_i32)[:, None]
@@ -1699,7 +1729,7 @@ def _bulk_events_fused(
     def pick_i(oh, x):
         return jnp.where(oh, x, 0).sum().astype(x.dtype)
 
-    def step_fn(carry, u_row, in_budget):
+    def step_fn(carry, u2, in_budget):
         (t_f, sq_f, t_a, fj, fs, rem, jcnt, launch_t, dur_js, relc,
          arr_done, started, counter, wall, crossed, steps, active) = carry
         active = active & in_budget
@@ -1740,7 +1770,6 @@ def _bulk_events_fused(
 
         # duration for the launched task (relaunch: same-stage
         # continuation; arrival: the sequential wave inputs)
-        u2 = jnp.where(e_oh[:, None], u_row, 0.0).sum(0)
         nl = jcnt[tj] + is_arr.astype(_i32)  # arrival counts itself
         tv = jnp.where(is_fin, True, (e_oh & tv_a).any())
         ss = jnp.where(is_fin, True, (e_oh & ss_a).any())

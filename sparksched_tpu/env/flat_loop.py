@@ -943,13 +943,14 @@ def drain_to_decision(
     cheap one depends on the device: on the TPU v5e this loop is
     nearly three fifths of a decision row of 128 lanes and the GNN a
     sixth (PERF.md section 5, PR 39). The device time is under the
-    scope `env/micro_step/drain`. A body of 128 lanes costs 1.05 ms
-    there: 0.59 ms whatever the lanes hold (the pass's set-up, a
-    gather of the arrivals' frontier bits 77 us of it, and its merged
-    state update; the pop; the shared tail; sixty-odd relayouts of
-    [lanes,J,S] arrays at 8 to 10 us) and 24 us for each step of the
+    scope `env/micro_step/drain`. A body of 128 lanes costs 1.0 ms
+    there: 0.50 ms whatever the lanes hold (the pass's set-up and its
+    merged state update, the one-hot counters of the consumed arrivals
+    42 us of it; the pop; the shared tail; sixty-odd relayouts of
+    [lanes,J,S] arrays at 8 to 10 us) and 26 us for each step of the
     fused bulk pass's early-exit loop, which runs as many steps as the
-    longest run among the lanes (19 on average, of a budget of 58).
+    longest run among the lanes (19 on average, of a budget of 58;
+    PERF.md section 5, PR 45's count).
     Nothing in the body reads the [J,S,S] adjacency whole: the pass's
     refresh of the saturation caches counts on `EnvState.parent_sets`
     (until PR 39 a contraction over the adjacency, 181 us a body).

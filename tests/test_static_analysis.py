@@ -300,6 +300,101 @@ def test_drain_loop_reads_the_adjacency_by_rows_only(monkeypatch):
     }, vs
 
 
+def test_row_gathers_finds_a_grid_read_once_an_executor():
+    """`jaxpr_audit.row_gathers`: a gather of a grid-sized operand at
+    a row of indices an executor is found, at any depth; one element
+    of the grid, a row for each executor out of a small operand, and
+    a one-hot select-reduce over the same grid are not."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from sparksched_tpu.analysis import jaxpr_audit
+
+    grid = jnp.zeros((6, 5), bool)
+    dj, ds = jnp.zeros(4, jnp.int32), jnp.zeros(4, jnp.int32)
+
+    def found(fn, *args):
+        return jaxpr_audit.row_gathers(
+            jax.make_jaxpr(fn)(*args).jaxpr, elems=30, rows=4
+        )
+
+    def per_executor(g, j, s):
+        return g[j, s]
+
+    def in_a_loop(g, j, s):
+        return lax.fori_loop(
+            0, 3, lambda i, acc: acc | g[j, s], jnp.zeros(4, bool)
+        )
+
+    def one_element(g, j, s):
+        return g[j[0], s[0]]
+
+    def small_operand(g, j, s):
+        return g[0][s]
+
+    def one_hot(g, j, s):
+        pick = (j[:, None, None] == jnp.arange(6)[None, :, None]) & (
+            s[:, None, None] == jnp.arange(5)[None, None, :]
+        )
+        return (pick & g[None]).any((1, 2))
+
+    assert found(per_executor, grid, dj, ds) == [
+        "gather(6, 5) <- (4, 2)"
+    ]
+    assert len(found(in_a_loop, grid, dj, ds)) == 1
+    for fn in (one_element, small_operand, one_hot):
+        assert not found(fn, grid, dj, ds), fn.__name__
+    # under the thresholds, nobody's business
+    jx = jax.make_jaxpr(per_executor)(grid, dj, ds).jaxpr
+    assert not jaxpr_audit.row_gathers(jx, elems=31, rows=4)
+    assert not jaxpr_audit.row_gathers(jx, elems=30, rows=5)
+
+
+def test_fused_pass_sets_up_what_its_loop_can_read(monkeypatch):
+    """What takes a counter's place for PR 45: the jaxpr of the fused
+    bulk pass holds no gather that reads a [J,S]-sized operand once
+    for every executor (the arrivals' frontier bits come from the
+    packed frontier, `core._frontier_at`), and its one uniform draw is
+    `[max_events + N, 2]`, a pair a step. With the lookup as it was,
+    an element gather at the executors' destinations, the rule names
+    it."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparksched_tpu.analysis import jaxpr_audit
+    from sparksched_tpu.env import core
+
+    params, bank, state = jaxpr_audit.audit_setup()
+    n = params.num_executors
+    j_cap, s_cap = state.stage_remaining.shape
+    max_events = 8
+
+    def traced():
+        return jax.make_jaxpr(lambda st: core._bulk_events_fused(
+            params, bank, st, True, stop_at_limit=True,
+            max_events=max_events,
+        ))(state).jaxpr
+
+    jaxpr = traced()
+    assert not jaxpr_audit.row_gathers(jaxpr, j_cap * s_cap, n)
+    draws = [
+        e.params["shape"] for e in jaxpr_audit.iter_eqns(jaxpr)
+        if e.primitive.name == "random_bits"
+    ]
+    assert draws == [(max_events + n, 2)], draws
+
+    def gathered(state, dj, ds):
+        return state.frontier[
+            jnp.clip(dj, 0, j_cap - 1), jnp.clip(ds, 0, s_cap - 1)
+        ]
+
+    monkeypatch.setattr(core, "_frontier_at", gathered)
+    assert jaxpr_audit.row_gathers(traced(), j_cap * s_cap, n) == [
+        f"gather({j_cap}, {s_cap}) <- ({n}, 2)"
+    ]
+
+
 def test_unknown_program_name_is_an_error():
     from sparksched_tpu.analysis import jaxpr_audit
 

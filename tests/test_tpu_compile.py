@@ -204,17 +204,24 @@ def test_level_scan_body_has_one_layout(flagship, one_chip):
     assert not {op for _, op in node_sized} & {"copy", "transpose"}, node_sized
 
 
-def _called(text: str, root: str) -> dict[str, str]:
-    """The computations of an HLO module reachable from `root`, by
-    name: their bodies."""
+def _computations(text: str) -> dict[str, str]:
+    """The computations of an HLO module, by name: their bodies."""
     import re
 
-    comps = {
+    return {
         m.group(1): m.group(2)
         for m in re.finditer(
             r"\n(?:ENTRY )?%([\w.\-]+) \([^\n]*\{\n(.*?)\n\}\n", text, re.S
         )
     }
+
+
+def _called(text: str, root: str) -> dict[str, str]:
+    """The computations of an HLO module reachable from `root`, by
+    name: their bodies."""
+    import re
+
+    comps = _computations(text)
     calls = re.compile(
         r"(?:calls|body|condition|to_apply|true_computation"
         r"|false_computation)=%([\w.\-]+)|branch_computations=\{([^}]*)\}"
@@ -299,6 +306,52 @@ def _holds_no_adjacency_sized_result(text: str, dims: list[int]) -> None:
                     found.append((name, line.strip()[:160]))
     assert carried >= 2, carried  # the adjacency IS in the loop
     assert not found, found
+
+
+def _gathers(text: str, scope: str) -> list[tuple[list[int], list[int]]]:
+    """(result dims, operand dims) of every `gather` instruction whose
+    `op_name` holds `scope`, the operand looked up by name in the
+    instruction's own computation."""
+    import re
+
+    def dims(shape: str) -> list[int]:
+        return [int(d) for d in shape.split(",") if d]
+
+    found = []
+    for body in _computations(text).values():
+        shapes = dict(re.findall(r"%([\w.\-]+) = \w+\[([\d,]*)\]", body))
+        for m in re.finditer(
+            r"= \w+\[([\d,]*)\][^ ]* gather\(%([\w.\-]+), [^\n]*", body
+        ):
+            if scope in m.group(0):
+                found.append((dims(m.group(1)), dims(shapes[m.group(2)])))
+    return found
+
+
+def test_drain_reads_no_grid_once_an_executor(flagship, collector):
+    """What takes a counter's place for the fused pass's lookup of the
+    arrivals' frontier bits (PR 45): in the collector compiled for the
+    v5e, no gather under the drain's scope yields `[lanes, N]` from a
+    `[lanes, J, S]` operand, a grid of the state read once for every
+    executor. On this chip such a gather is serialised, 12 ns an
+    element: 77 us of a drain body of 128 lanes at the flagship
+    cluster, in every body, until the bits came from the packed
+    frontier (`core._frontier_at`). The decide step, once a row, still
+    reads other grids that way (`_bulk_fulfill`'s remaining-task
+    counts), which shows that the pattern is found where it is."""
+    p = flagship.params_env
+    lanes, n = flagship.num_envs, p.num_executors
+    grid = sorted([lanes, p.max_jobs, p.max_stages])
+
+    text = collector.as_text()
+
+    def per_executor(gathers):
+        return [(res, op) for res, op in gathers
+                if res == [lanes, n] and sorted(op) == grid]
+
+    drain = _gathers(text, "env/micro_step/drain")
+    assert len(drain) > 50 and not per_executor(drain)
+    assert per_executor(_gathers(text, "env/micro_step/decide"))
 
 
 def test_blocked_drain_is_one_while_inside_one_loop_over_blocks(
