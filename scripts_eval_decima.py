@@ -18,11 +18,10 @@ import sys
 import jax
 import numpy as np
 
-from sparksched_tpu import metrics
+from sparksched_tpu import sweep
 from sparksched_tpu.config import EnvParams
 from sparksched_tpu.env import core
 from sparksched_tpu.schedulers import DecimaScheduler, RoundRobinScheduler
-from sparksched_tpu.trainers.rollout import collect_sync
 from sparksched_tpu.workload import make_workload_bank
 
 import os
@@ -50,27 +49,22 @@ def episode_states(params, bank, seeds):
     )(seeds)
 
 
-def run_policy(params, bank, policy_fn, seeds):
-    states = episode_states(params, bank, seeds)
-    rngs = jax.vmap(
-        lambda s: jax.random.PRNGKey(s + 1)
-    )(seeds)
-
-    @jax.jit
-    def run(states, rngs):
-        return jax.vmap(
-            lambda r, s: collect_sync(params, bank, policy_fn, r, STEPS, s)
-        )(rngs, states)
-
+def run_policy(params, bank, scheduler, seeds):
+    """Every seed's episode to its end through the sweep loop
+    (`sparksched_tpu/sweep.py`, the evaluation path of every
+    `Scheduler`; until PR 46 `collect_sync`, the per-decision
+    `core.step` loop, under a padded cap of `STEPS` rows): the episodes'
+    average job durations in seed order, and whether every job of each
+    completed. `STEPS` bounds the rows a run may take."""
     import time
 
+    states = episode_states(params, bank, seeds)
     t0 = time.perf_counter()
-    ro = run(states, rngs)
-    fs = ro.final_state
-    done = np.asarray(jax.vmap(lambda s: s.all_jobs_complete)(fs))
-    ajd = np.asarray(jax.vmap(metrics.avg_job_duration)(fs))
+    out = sweep.run(
+        params, bank, scheduler, states=states, episodes=len(seeds),
+        seed=HELD_OUT_BASE, rows=64, max_chunks=-(-4 * STEPS // 64))
     print(f"  ({time.perf_counter() - t0:.0f}s)", flush=True)
-    return ajd, done
+    return out["avg_jct"], out["jobs_completed"] == params.max_jobs
 
 
 def make_decima(params, ckpt):
@@ -150,19 +144,14 @@ def main():
         params.num_executors, dynamic_partition=True
     )
     print("evaluating fair...", flush=True)
-    ajd_fair, done_fair = run_policy(
-        params, bank, lambda r, o: fair.policy(r, o), seeds
-    )
+    ajd_fair, done_fair = run_policy(params, bank, fair, seeds)
     assert done_fair.all(), "unfinished fair episodes"
 
     results = {}
     for name, ckpt in ckpts.items():
         print(f"evaluating {name}...", flush=True)
         dec = make_decima(params, ckpt)
-        ajd, done = run_policy(
-            params, bank,
-            lambda r, o: dec.policy(r, o, dec.params), seeds,
-        )
+        ajd, done = run_policy(params, bank, dec, seeds)
         assert done.all(), f"unfinished {name} episodes"
         results[name] = ajd
 

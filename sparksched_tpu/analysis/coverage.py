@@ -101,9 +101,15 @@ COVERAGE: dict[tuple[str, str], tuple[str, Any]] = {
     ("trainers/rollout.py", "collect_flat_async_batch"): ("program", (
         "flat_collect_batch",
     )),
-    # the reference collectors over core.step: what the tests,
-    # chip_smoke.py and scripts_eval_decima.py hold the production
-    # collectors to; the trainer does not reach them
+    # the reference collectors over core.step: what the tests and
+    # chip_smoke.py hold the production collectors to; the trainer
+    # does not reach them
+    # the sweep loop (PR 46): the chunk is audited under its own name;
+    # `init`'s reset of every lane is the reset program the env/core.py
+    # entries above waive
+    ("sweep.py", "<module>"): ("program", ("sweep_chunk",)),
+    ("sweep.py", "_reset_lanes"): ("waiver",
+        "core.reset over the lanes, once a sweep, before the first chunk"),
     ("trainers/rollout.py", "collect_sync"): ("waiver",
         "reference collector over core.step, parity-test path"),
     ("trainers/rollout.py", "collect_async"): ("waiver",

@@ -273,6 +273,16 @@ BUDGETS: dict[str, Budget] = {
     "flat_collect_batch_health": Budget(
         eqn_lo=9000, eqn_hi=17200, gather_hi=257, scatter_hi=27,
     ),
+    # PR 46: the sweep loop's chunk under the fair heuristic, health
+    # on (sweep.py): the collectors' decide and drain with the re-seed
+    # and an episode's result in the drain, no net and no stored
+    # observation in the row. Pinned 2026-10-02 at 14816/199/2 (4
+    # lanes x 3 rows): as many equations as the batch collector's,
+    # whose net and stores it lacks, because the reset program runs
+    # inside the scan (the bank's gathers are its)
+    "sweep_chunk": Budget(
+        eqn_lo=9000, eqn_hi=16600, gather_hi=269, scatter_hi=4,
+    ),
     # ISSUE 10: the AOT decision-serving programs (serve/aot.py),
     # pinned 2026-08-04 — serve_decide 6514/33/65, serve_decide_batch
     # 12853/251/65 (store capacity 8 / batch 4 at audit scale). The
@@ -678,6 +688,38 @@ def flat_collect_batch_callable(
     return fn, (states_b, key)
 
 
+def sweep_chunk_callable() -> tuple[Callable, tuple]:
+    """The sweep loop's chunk (`sweep.py: sweep_chunk`) under the fair
+    heuristic over a native lane axis, health sentinels on, as the cell
+    `sweep_fair` runs it: observe, one policy evaluation, decide, the
+    drain with its re-seed and the episode's result, the row's record.
+    As (callable, abstract args), at the batch collector's audit
+    widths."""
+    import jax
+    import jax.numpy as jnp
+
+    from .. import sweep
+    from ..env.flat_loop import init_loop_state
+    from ..schedulers.heuristics import RoundRobinScheduler
+
+    params, bank, state = audit_setup()
+    sched = RoundRobinScheduler(params.num_executors)
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    lanes = AUDIT_COLLECT_BATCH
+    carry = jax.eval_shape(
+        lambda s, k: sweep.SweepCarry(
+            ls=jax.vmap(init_loop_state)(s), key=k,
+            lane=jnp.zeros((lanes,), jnp.int32),
+            decisions=jnp.zeros((lanes,), jnp.int32)),
+        _batched(state, lanes), _batched(key, lanes))
+
+    def fn(c, r):
+        return sweep._chunk(
+            params, bank, sched.batch_policy, c, r, AUDIT_COLLECT_STEPS)
+
+    return fn, (carry, key)
+
+
 def lane_callables() -> dict[str, tuple[Callable, tuple]]:
     """The per-lane registry programs as (callable, UNBATCHED abstract
     args) — shared by the unbatched jaxpr trace below and the memory
@@ -808,6 +850,8 @@ def program_callables(names: tuple[str, ...] | None = None
         out["flat_collect_batch_health"] = flat_collect_batch_callable(
             health=True
         )
+    if want is None or "sweep_chunk" in want:
+        out["sweep_chunk"] = sweep_chunk_callable()
     return out
 
 

@@ -128,6 +128,9 @@ HOST_SYNC_EXEMPT_FUNCS: dict[str, tuple[str, ...]] = {
     "_checkpoint": ("trainers/trainer.py",),
     "_cleanup": ("trainers/trainer.py",),
     "schedule": ("schedulers/",),
+    # the sweep's host loop reads the episodes' results between chunks
+    "run": ("sweep.py",),
+    "results_of": ("sweep.py",),
 }
 
 # serve-host-sync (ISSUE 15) scoping: the serve pump hot path, and the

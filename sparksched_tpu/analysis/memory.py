@@ -211,6 +211,11 @@ MEM_BUDGETS: dict[str, MemBudget] = {
     # record-on serve deploy ever pages it.
     "serve_decide_record_ring": MemBudget(temp_hi=151 * MB),
     "serve_decide_batch_record_ring": MemBudget(temp_hi=773 * MB),
+    # PR 46: the sweep loop's chunk (4 lanes x 3 rows, fair policy,
+    # health on), pinned 2026-10-02 at 268.3 MB: under half the batch
+    # collector's (628.4 with health), whose net's width-K evaluation
+    # and stored observations it lacks
+    "sweep_chunk": MemBudget(temp_hi=362 * MB),
 }
 
 # lane counts the advisor sweeps (the bench's production range; 1024

@@ -922,6 +922,7 @@ def drain_to_decision(
     telemetry=None,
     bulk_fused: bool = True,
     lane_axis: str | None = None,
+    result_fn: Callable | None = None,
 ) -> tuple:
     """Drain one lane's non-decision work — FULFILL leftovers and the
     whole inter-decision event run — until it is ready to DECIDE again
@@ -982,7 +983,16 @@ def drain_to_decision(
     `drain_micro_step(auto_reset=True)` repeated until the lane is
     ready to decide (tests/test_trainers.py holds the two against each
     other a row at a time); with a `reset_fn` that ignores its key, as
-    the streaming collector's does, bit for bit."""
+    the streaming collector's does, bit for bit.
+
+    `result_fn`, where a caller gives one, is read on the state the
+    loop left, BEFORE the re-seed replaces an ended episode's: the one
+    place an episode's result (`metrics.episode_result`: its average
+    job completion time, the jobs it completed, its makespan) can be
+    taken, beside `episodes_terminated`. `result_fn(ls.env)` then rides
+    the span as its fourth element, `(reward, dt, reset, result)`, for
+    every lane; it is an ended episode's where `reset` is set. Without
+    one nothing is read and the program is what it was."""
     track = telemetry is not None
     zero = jnp.float32(0.0)
 
@@ -1028,6 +1038,9 @@ def drain_to_decision(
             tm = _tm_add(
                 tm, episodes_terminated=rs & ls.env.all_jobs_complete
             )
+        span = (rw, dt, rs)
+        if result_fn is not None:
+            span = span + (result_fn(ls.env),)
     if auto_reset:
         # one whole name (obs/tracing.py), beside `env/micro_step/drain`
         with annotate("env/micro_step/reset"):
@@ -1035,7 +1048,7 @@ def drain_to_decision(
                 params, bank, ls, rs, c[1], reset_fn, lane_axis
             )
             tm = _tm_add(tm, reseeds=rs)
-    return (ls, (rw, dt, rs), tm) if track else (ls, (rw, dt, rs))
+    return (ls, span, tm) if track else (ls, span)
 
 
 def _fresh_episode(

@@ -38,6 +38,19 @@ def num_job_arrivals(state: EnvState) -> jnp.ndarray:
     return state.job_arrived.sum()
 
 
+def episode_result(state: EnvState) -> dict[str, jnp.ndarray]:
+    """What a sweep keeps of an episode, read on the state it ended in
+    (`flat_loop.drain_to_decision`'s `result_fn`, before the re-seed):
+    its average job completion time over the jobs that arrived (with
+    every job complete, the mean of completion less arrival), the jobs
+    it completed and its makespan."""
+    return {
+        "avg_jct": avg_job_duration(state),
+        "jobs_completed": num_completed_jobs(state).astype(jnp.int32),
+        "makespan": state.wall_time,
+    }
+
+
 PERCENTILE_QS = (25, 50, 75, 100)
 
 

@@ -179,6 +179,8 @@ class Telemetry(struct.PyTreeNode):
     rows_ended: jnp.ndarray | None = None  # rows sat out, episode over
     episodes_terminated: jnp.ndarray | None = None  # ended by completion
     jobs_present_sum: jnp.ndarray | None = None  # jobs seen, over decisions
+    # --- the sweep loop's: None unless asked for (module docstring) ---
+    episode_decisions_sum: jnp.ndarray | None = None  # of ended episodes
 
     @property
     def counts_episodes(self) -> bool:
@@ -186,12 +188,14 @@ class Telemetry(struct.PyTreeNode):
 
 
 _EPISODE_COUNTERS = ("rows_ended", "episodes_terminated", "jobs_present_sum")
+_RESULT_COUNTERS = ("episode_decisions_sum",)
 
 
-def _zeros(z, episodes: bool) -> Telemetry:
+def _zeros(z, episodes: bool, results: bool = False) -> Telemetry:
     return Telemetry(**{
         k: z for k in Telemetry.__dataclass_fields__
-        if episodes or k not in _EPISODE_COUNTERS
+        if (episodes or k not in _EPISODE_COUNTERS)
+        and (results or k not in _RESULT_COUNTERS)
     })
 
 
@@ -200,12 +204,14 @@ def telemetry_zeros(episodes: bool = False) -> Telemetry:
 
 
 def telemetry_zeros_like(
-    batch_shape: tuple[int, ...], episodes: bool = False
+    batch_shape: tuple[int, ...], episodes: bool = False,
+    results: bool = False,
 ) -> Telemetry:
     """Zeros with a leading batch shape on every counter — the starting
     value for vmapped engines (one counter set per lane). `episodes`:
-    with the three counters of episodes that end inside the scan."""
-    return _zeros(jnp.zeros(batch_shape, _i32), episodes)
+    with the three counters of episodes that end inside the scan;
+    `results`: with the sweep loop's `episode_decisions_sum`."""
+    return _zeros(jnp.zeros(batch_shape, _i32), episodes, results)
 
 
 def _count(x) -> bool:
@@ -312,6 +318,9 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
             "jobs_present_per_decision": per_dec(tot(t.jobs_present_sum)),
         }
         rows_ended = {"lane_rows_ended": tot(t.rows_ended)}
+    if t.episode_decisions_sum is not None:
+        # the sweep loop: the decisions of the episodes that ended
+        episodes["episode_decisions_total"] = tot(t.episode_decisions_sum)
     scan_steps = tot(t.bulk_scan_steps)
     bulk_passes = tot(t.bulk_passes)
     # the bodies the device ran, a lane at a time: every lane runs what

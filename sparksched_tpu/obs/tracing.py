@@ -33,7 +33,13 @@ only where the lanes do not persist yet). Elsewhere: `env/micro_step`
 the tail of the loops whose unit is the micro-step holds
 `env/micro_step/reset` in every step),
 `train/ppo_update`, `serve/decide`, `serve/decide_batch`,
-`serve/dispatch`, `serve/flush`.
+`serve/dispatch`, `serve/flush`. A decision row of the sweep loop
+(`sparksched_tpu/sweep.py`) runs under `collect/observe`, `sweep/policy`
+(the scheduler's evaluation over the batch; a Decima scheduler's own
+scopes sit inside it), `env/micro_step/decide`, `env/micro_step/drain`,
+`env/micro_step/reset` (in every row in which a lane of the drain's
+block ended its episode), `collect/health` and `sweep/record` (the
+row's store, the episode's result and the row counters).
 
 A nested phase is ONE scope whose name holds its parent's
 (`annotate("env/micro_step/drain")`, not two nested `annotate`s): a
@@ -64,7 +70,10 @@ compiles) inside it, `setup/init_state` (`Trainer.init_state`),
 `collect/call` and `train/update_call` (every call of the trainer's
 compiled collector and update: trace, lower and compile or load on a
 first call, dispatch alone afterwards; the caller blocks on the result
-itself), and whatever `RunLog.span` and `trainers.Profiler` are given
+itself), `sweep/chunk_call` (the same around every call of the sweep's
+compiled chunk) with `setup/sweep_init` (`sweep.init`: the reset
+program of every lane) and, in `sweep.from_config`, the trainer's
+`setup/workload_bank` and `setup/scheduler_init`, and whatever `RunLog.span` and `trainers.Profiler` are given
 (`iter <n> collect`, `iter <n> update`). jax's own compile events land
 in the same record, each under the span it fell in (`JAX_TIMED`,
 `JAX_DURATIONS`, `JAX_COUNTED` below). A host span's name must not

@@ -32,6 +32,15 @@ class Scheduler(abc.ABC):
     def policy(self, rng: jax.Array, obs: Any):
         """Pure jittable single-decision function; vmap/scan-safe."""
 
+    def batch_policy(self, rng: jax.Array, obs: Any):
+        """`policy` over a [B]-stacked Observation, a lane at a time
+        under keys split from `rng`: `(stage_idx[B], num_exec[B], aux)`,
+        the form the sweep loop (`sparksched_tpu/sweep.py`) and the
+        trainer's collectors call once a decision row. The heuristics'
+        batch form is this `vmap` of what they have."""
+        lanes = jax.tree_util.tree_leaves(obs)[0].shape[0]
+        return jax.vmap(self.policy)(jax.random.split(rng, lanes), obs)
+
 
 class TrainableScheduler(Scheduler):
     """Interface for trainable schedulers (reference scheduler.py:21-55).

@@ -18,8 +18,11 @@ Both reference modes exist:
 One collector per mode is what the trainer runs, both over the flat
 engine (env/flat_loop.py): `collect_flat_sync_batch` and
 `collect_flat_async_batch`. `collect_sync` and `collect_async`, over
-`core.step`, are the reference collectors the tests, `chip_smoke.py` and
-`scripts_eval_decima.py` hold them to; the trainer does not reach them.
+`core.step`, are the reference collectors the tests and `chip_smoke.py`
+hold them to; the trainer does not reach them. A third caller of the
+engine's two primitives, with any `Scheduler` in the row and no stored
+observation, is the sweep loop (`sparksched_tpu/sweep.py`), which
+shares `_by_blocks` and `_DRAIN_BLOCK` below.
 """
 
 from __future__ import annotations

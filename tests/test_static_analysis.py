@@ -68,6 +68,10 @@ def test_shipped_tree_is_analysis_clean():
         # RingRec leaf while the record-off programs above pin that
         # ring off changes nothing
         "serve_decide_record_ring", "serve_decide_batch_record_ring",
+        # PR 46: the sweep loop's chunk (sweep.py), the third caller
+        # of the engine's decide and drain, with the re-seed and an
+        # episode's result inside the scan
+        "sweep_chunk",
     }
     assert set(report["passes"]["jaxpr"]["measured"]) == all_programs
     mem = report["passes"]["memory"]["measured"]
