@@ -205,6 +205,29 @@ class Budget:
 # than today's, are already under the rule's). What the change is for
 # is no count: `row_gathers` over the pass's own jaxpr
 # (tests/test_static_analysis.py). The chip rows are PERF.md, PR 45.
+#
+# Re-pinned 2026-10-03 (PR 47: the sampler computes its executor-level
+# interval from `params.num_executors`, one chain of selects over the
+# table's runs and three shifts and masks, `sampling.executor_interval`,
+# where it gathered a row out of each of four `i32[N+1]` bank leaves;
+# the bank has those four leaves fewer). Eqns / gathers before ->
+# after, four gathers fewer for every sampler call a program traces:
+# micro_step 4438/27 -> 4413/23, decide_micro_step 2510/23 -> 2497/19,
+# drain_to_decision 3037/4 -> 3019/4 (one lane: its reads were dynamic
+# slices), serve_decide 6724/31 -> 6693/27 (its record and ring
+# variants the same -31 and -4), serve_decide_batch 15442/266 ->
+# 15410/246 (its group, record, ring and sharded variants alike),
+# flat_collect_batch 15114/204 -> 15082/184, flat_collect_batch_health
+# 15383/204 -> 15351/184, sweep_chunk 14816/199 -> 14784/179; scatters,
+# observe, the net's and the update's as they were. Every count inside
+# its band; the gather caps follow the rule (measured x 1.35, at least
+# measured + 2) down: micro_step 37 -> 32, decide_micro_step 32 -> 26,
+# serve_decide and its record and ring variants 42 -> 37, the batch
+# serve programs 339 -> 333 (the ring one 341 -> 334), the two
+# collectors 257 -> 249, sweep_chunk 269 -> 242. What the change is for
+# is no count: `reads_of_shape` over the pass's and the sampler's
+# jaxprs (tests/test_static_analysis.py). The chip rows are PERF.md,
+# PR 47.
 # ---------------------------------------------------------------------------
 
 BUDGETS: dict[str, Budget] = {
@@ -219,12 +242,12 @@ BUDGETS: dict[str, Budget] = {
     # of work (the while is the fused event run's early-exit loop, not
     # a decision loop)
     "micro_step": Budget(
-        eqn_lo=2000, eqn_hi=5500, gather_hi=37, scatter_hi=3,
+        eqn_lo=2000, eqn_hi=5500, gather_hi=32, scatter_hi=3,
         while_whole_read_elems=AUDIT_ADJ_ELEMS,
     ),
     # the single-eval collectors' policy-bearing micro-step
     "decide_micro_step": Budget(
-        eqn_lo=1000, eqn_hi=3350, gather_hi=32, scatter_hi=2,
+        eqn_lo=1000, eqn_hi=3350, gather_hi=26, scatter_hi=2,
         loop_free=True,
     ),
     # the single-eval collectors' non-policy drain (while-loop by
@@ -257,7 +280,7 @@ BUDGETS: dict[str, Budget] = {
     # what makes this CPU audit valid for the sharded configuration;
     # the HLO-level collective census lives in tests/test_parallel.py.
     "flat_collect_batch": Budget(
-        eqn_lo=9000, eqn_hi=16900, gather_hi=257, scatter_hi=25,
+        eqn_lo=9000, eqn_hi=16900, gather_hi=249, scatter_hi=25,
     ),
     # ISSUE 9: the `health:`-on variants of the two production
     # programs. Pinned 2026-08-03 — ppo_update_health 3209/43/3 (the
@@ -271,7 +294,7 @@ BUDGETS: dict[str, Budget] = {
         eqn_lo=1000, eqn_hi=4350, gather_hi=60, scatter_hi=5,
     ),
     "flat_collect_batch_health": Budget(
-        eqn_lo=9000, eqn_hi=17200, gather_hi=257, scatter_hi=27,
+        eqn_lo=9000, eqn_hi=17200, gather_hi=249, scatter_hi=27,
     ),
     # PR 46: the sweep loop's chunk under the fair heuristic, health
     # on (sweep.py): the collectors' decide and drain with the re-seed
@@ -281,7 +304,7 @@ BUDGETS: dict[str, Budget] = {
     # whose net and stores it lacks, because the reset program runs
     # inside the scan (the bank's gathers are its)
     "sweep_chunk": Budget(
-        eqn_lo=9000, eqn_hi=16600, gather_hi=269, scatter_hi=4,
+        eqn_lo=9000, eqn_hi=16600, gather_hi=242, scatter_hi=4,
     ),
     # ISSUE 10: the AOT decision-serving programs (serve/aot.py),
     # pinned 2026-08-04 — serve_decide 6514/33/65, serve_decide_batch
@@ -292,11 +315,11 @@ BUDGETS: dict[str, Budget] = {
     # `drain_to_decision` (the inter-decision drain, by design); the
     # scan is the GNN level pass + the bulk event kernel.
     "serve_decide": Budget(
-        eqn_lo=3000, eqn_hi=8800, gather_hi=42, scatter_hi=88,
+        eqn_lo=3000, eqn_hi=8800, gather_hi=37, scatter_hi=88,
         while_whole_read_elems=AUDIT_ADJ_ELEMS,
     ),
     "serve_decide_batch": Budget(
-        eqn_lo=6000, eqn_hi=17400, gather_hi=339, scatter_hi=88,
+        eqn_lo=6000, eqn_hi=17400, gather_hi=333, scatter_hi=88,
     ),
     # ISSUE 13: the dp-sharded store variant (serve/aot.py
     # `serve_decide_batch_fn(..., shard=...)`), pinned 2026-08-04 —
@@ -309,7 +332,7 @@ BUDGETS: dict[str, Budget] = {
     # re-measured byte-identical, which is the acceptance bar (shard
     # off must change nothing).
     "serve_decide_batch_sharded": Budget(
-        eqn_lo=6000, eqn_hi=17500, gather_hi=339, scatter_hi=88,
+        eqn_lo=6000, eqn_hi=17500, gather_hi=333, scatter_hi=88,
     ),
     # ISSUE 14: the record-on serve variants (serve/aot.py
     # `record=True` — the online trajectory path's programs), pinned
@@ -324,10 +347,10 @@ BUDGETS: dict[str, Budget] = {
     # NO count on any serve program — params enter as invars, the
     # traced computation is the same.
     "serve_decide_record": Budget(
-        eqn_lo=3000, eqn_hi=8810, gather_hi=42, scatter_hi=88,
+        eqn_lo=3000, eqn_hi=8810, gather_hi=37, scatter_hi=88,
     ),
     "serve_decide_batch_record": Budget(
-        eqn_lo=6000, eqn_hi=17410, gather_hi=339, scatter_hi=88,
+        eqn_lo=6000, eqn_hi=17410, gather_hi=333, scatter_hi=88,
     ),
     # ISSUE 15: the GROUP-shaped serve program (the pipelined store's
     # [hot_capacity/groups] lowering — serve/aot.py
@@ -340,7 +363,7 @@ BUDGETS: dict[str, Budget] = {
     # byte-identical in the same PR (the take_slot/write_slot
     # refactor moved code, not equations).
     "serve_decide_batch_group": Budget(
-        eqn_lo=6000, eqn_hi=17400, gather_hi=339, scatter_hi=88,
+        eqn_lo=6000, eqn_hi=17400, gather_hi=333, scatter_hi=88,
     ),
     # ISSUE 18: the ring-recording serve programs (serve/aot.py
     # `serve_decide_ring_fn` / `serve_decide_batch_ring_fn` — the
@@ -357,10 +380,10 @@ BUDGETS: dict[str, Budget] = {
     # re-measured BYTE-IDENTICAL in the same PR — the zero-cost-off
     # acceptance bar.
     "serve_decide_record_ring": Budget(
-        eqn_lo=3000, eqn_hi=8980, gather_hi=42, scatter_hi=117,
+        eqn_lo=3000, eqn_hi=8980, gather_hi=37, scatter_hi=117,
     ),
     "serve_decide_batch_record_ring": Budget(
-        eqn_lo=6000, eqn_hi=17550, gather_hi=341, scatter_hi=117,
+        eqn_lo=6000, eqn_hi=17550, gather_hi=334, scatter_hi=117,
     ),
 }
 
@@ -435,6 +458,22 @@ def row_gathers(jaxpr, elems: int, rows: int) -> list[str]:
                 f"gather{tuple(operand.shape)} <- {tuple(indices.shape)}"
             )
     return found
+
+
+def reads_of_shape(jaxpr, shape: tuple[int, ...]) -> list[str]:
+    """The equations, at any depth, with an operand of exactly `shape`,
+    as `primitive(shape)`: a gather from a table of that shape, a loop
+    that carries it, a call that is handed it. With `(N + 1,)` it says
+    whether a program reads a table indexed by an executor count
+    (PR 47: the sampler's executor-level intervals are computed from
+    `EnvParams.num_executors`, and the bank holds no such leaf)."""
+    shape = tuple(shape)
+    return [
+        f"{eqn.primitive.name}{shape}"
+        for eqn in iter_eqns(jaxpr)
+        for v in eqn.invars
+        if getattr(getattr(v, "aval", None), "shape", None) == shape
+    ]
 
 
 def count_eqns(jaxpr) -> int:

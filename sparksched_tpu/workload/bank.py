@@ -25,6 +25,7 @@ Sampling a duration on-device is then two integer gathers and one
 
 from __future__ import annotations
 
+import functools
 import os.path as osp
 from typing import Any
 
@@ -61,15 +62,6 @@ class WorkloadBank(struct.PyTreeNode):
     cnt: jnp.ndarray  # i32[T,S,3,L]
     level_present: jnp.ndarray  # bool[T,S,L]; key present in first_wave
     max_present: jnp.ndarray  # i32[T,S]; index of max present level
-
-    # --- executor-count interpolation (depends on num_executors) ---
-    # For each possible num_local_executors in [0, N]: the left/right level
-    # VALUES bracketing it and their indices into EXEC_LEVEL_VALUES
-    # (reference tpch.py:216-262).
-    itv_left_val: jnp.ndarray  # i32[N+1]
-    itv_right_val: jnp.ndarray  # i32[N+1]
-    itv_left_idx: jnp.ndarray  # i32[N+1]
-    itv_right_idx: jnp.ndarray  # i32[N+1]
 
     # --- low-precision layout (ISSUE 7) ---
     # When `dur` carries an integer dtype (int8/int16 via
@@ -135,6 +127,39 @@ def _executor_intervals(num_executors: int) -> np.ndarray:
     return intervals
 
 
+def _to_idx(vals: np.ndarray) -> np.ndarray:
+    """Level values -> indices into `EXEC_LEVEL_VALUES`; unknown values
+    (e.g. the zeroed tail entry of the reference table) map to index 0:
+    the presence fallback replaces them anyway."""
+    idx = np.zeros_like(vals)
+    for i, v in enumerate(EXEC_LEVEL_VALUES):
+        idx[vals == v] = i
+    return idx
+
+
+@functools.lru_cache(maxsize=None)
+def executor_interval_runs(
+    num_executors: int,
+) -> tuple[tuple[int, ...], tuple[tuple[int, int, int, int], ...]]:
+    """`_executor_intervals(num_executors)` by its runs: the table is
+    piecewise constant in num_local_executors (3 runs at 10 executors,
+    9 at 50, at most 17 for any count), so it is the first
+    num_local of each run (`starts`, starts[0] == 0) and the run's
+    (left value, right value, left index, right index). A function of
+    the executor count alone, so the sampler computes the lookup from
+    these literals and no program reads an `[N+1]` table
+    (`sampling.sample_executor_key`)."""
+    itv = _executor_intervals(num_executors)
+    table = np.concatenate([itv, _to_idx(itv)], axis=1)  # [N+1, 4]
+    starts = np.flatnonzero(
+        np.r_[True, (table[1:] != table[:-1]).any(axis=1)]
+    )
+    return (
+        tuple(int(s) for s in starts),
+        tuple(tuple(int(v) for v in table[s]) for s in starts),
+    )
+
+
 def _value_to_index() -> dict[int, int]:
     return {v: i for i, v in enumerate(EXEC_LEVEL_VALUES)}
 
@@ -155,7 +180,13 @@ def pack_bank(
         with wave_name in ('fresh_durations', 'first_wave', 'rest_wave').
         Levels present in 'first_wave' define the presence mask
         (reference tpch.py:228-231).
+
+    The bank holds nothing that depends on `num_executors`: the sampler
+    computes the executor-level interval from `EnvParams.num_executors`
+    (`executor_interval_runs`). The argument stays so that a positional
+    call means what it meant.
     """
+    del num_executors
     rng = np.random.default_rng(seed)
     t_n = len(templates)
     s_cap = max_stages
@@ -205,18 +236,6 @@ def pack_bank(
             max_present[t, s] = pres_idx.max() if pres_idx.size else 0
             rough[t, s] = float(np.mean(all_durs)) if all_durs else 1.0
 
-    itv = _executor_intervals(num_executors)
-    lv_arr = np.array(EXEC_LEVEL_VALUES, dtype=np.int64)
-
-    def to_idx(vals: np.ndarray) -> np.ndarray:
-        # map values to level indices; unknown values (e.g. the zeroed tail
-        # entry of the reference table) map to index 0 — the presence
-        # fallback replaces them anyway
-        idx = np.zeros_like(vals)
-        for i, v in enumerate(lv_arr):
-            idx[vals == v] = i
-        return idx
-
     return WorkloadBank(
         num_stages=jnp.asarray(num_stages),
         num_tasks=jnp.asarray(num_tasks),
@@ -227,10 +246,6 @@ def pack_bank(
         cnt=jnp.asarray(cnt),
         level_present=jnp.asarray(present),
         max_present=jnp.asarray(max_present),
-        itv_left_val=jnp.asarray(itv[:, 0], dtype=jnp.int32),
-        itv_right_val=jnp.asarray(itv[:, 1], dtype=jnp.int32),
-        itv_left_idx=jnp.asarray(to_idx(itv[:, 0]), dtype=jnp.int32),
-        itv_right_idx=jnp.asarray(to_idx(itv[:, 1]), dtype=jnp.int32),
     )
 
 

@@ -16,7 +16,13 @@ import jax
 import jax.numpy as jnp
 
 from ..config import EnvParams
-from .bank import WAVE_FIRST, WAVE_FRESH, WAVE_REST, WorkloadBank
+from .bank import (
+    WAVE_FIRST,
+    WAVE_FRESH,
+    WAVE_REST,
+    WorkloadBank,
+    executor_interval_runs,
+)
 
 
 def sample_job_sequence(
@@ -52,24 +58,69 @@ def sample_job_sequence(
     return arrivals, templates, num_jobs, mask
 
 
+# bit offsets of a run's (left value, right value, left index, right
+# index) in one packed int32: values are at most 100 (7 bits), indices
+# at most 7 (3 bits)
+_ITV_SHIFTS = (0, 7, 14, 17)
+_ITV_MASKS = (0x7F, 0x7F, 0x7, 0x7)
+
+
+def executor_interval(
+    num_executors: int, num_local: jnp.ndarray
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The executor-level interval of `num_local` executors on a job
+    (reference tpch.py:237-262): the bracketing level values (left,
+    right) and their indices into `EXEC_LEVEL_VALUES`, row `num_local`
+    of `bank._executor_intervals(num_executors)` and `_to_idx` of it
+    (a `num_local` outside [0, N] reads the nearest row).
+
+    The table is piecewise constant (`bank.executor_interval_runs`: 3
+    runs at 10 executors, 9 at 50, never more than 17) and a function
+    of the executor count alone, so it is no array: one chain of
+    selects over the runs' literal starts picks the run's four numbers
+    packed in one word, and shifts and masks take them apart. No
+    gather and no bank operand: four `i32[N+1]` bank leaves cost every
+    loop that samples a duration four serialised gathers a step, or,
+    where the bank is a jit argument and N small enough for the
+    compiler to unroll them, 4 x (N+1) scalar operands carried through
+    the loop (a fifth of `sweep_fair`'s device time: PERF.md, PR 47)."""
+    starts, rows = executor_interval_runs(num_executors)
+    words = [
+        sum(v << sh for v, sh in zip(row, _ITV_SHIFTS)) for row in rows
+    ]
+    assert all(
+        0 <= v <= m for row in rows for v, m in zip(row, _ITV_MASKS)
+    ), rows
+    word = jnp.full_like(num_local, words[0], dtype=jnp.int32)
+    for start, w in zip(starts[1:], words[1:]):
+        word = jnp.where(num_local >= start, jnp.int32(w), word)
+    left_v, right_v, left_i, right_i = (
+        (word >> sh) & m for sh, m in zip(_ITV_SHIFTS, _ITV_MASKS)
+    )
+    return left_v, right_v, left_i, right_i
+
+
 def sample_executor_key(
-    bank: WorkloadBank, u: jnp.ndarray, template: jnp.ndarray,
-    stage: jnp.ndarray, num_local: jnp.ndarray
+    params: EnvParams, bank: WorkloadBank, u: jnp.ndarray,
+    template: jnp.ndarray, stage: jnp.ndarray, num_local: jnp.ndarray
 ) -> jnp.ndarray:
     """Map the executor count to a trace executor-level index, randomly
     interpolating between the two bracketing levels and falling back to the
-    max level present for this stage (reference tpch.py:216-235).
+    max level present for this stage (reference tpch.py:216-235). The
+    bracketing levels are a static function of `params.num_executors`
+    (`executor_interval`); the bank is read for the stage's present
+    levels alone.
 
     `u` is a pre-drawn Uniform[0,1) scalar, NOT a PRNG key: the round-5
     CPU decomposition measured the per-call rng plumbing (fold_in +
     split + uniform + randint per sampled task) at ~31% of the whole
-    flat micro-step, while the bank-table gathers were free. Callers
+    flat micro-step, while the bank-table gathers were free (on the
+    CPU: on the chip the four interval tables were not, PR 47). Callers
     draw ONE batched uniform array per bulk pass and hand each row's
     slice down (see `sample_task_duration`)."""
-    left_v = bank.itv_left_val[num_local]
-    right_v = bank.itv_right_val[num_local]
-    left_i = bank.itv_left_idx[num_local]
-    right_i = bank.itv_right_idx[num_local]
+    left_v, right_v, left_i, right_i = executor_interval(
+        params.num_executors, num_local
+    )
     rand_pt = 1 + (u * (right_v - left_v)).astype(jnp.int32)
     use_left = (left_v == right_v) | (rand_pt <= num_local - left_v)
     key_idx = jnp.where(use_left, left_i, right_i)
@@ -106,7 +157,9 @@ def sample_task_duration(
     on the CPU backend (round-5 ablation), with identical per-row
     distributions (rows were independently keyed before, independent
     uniforms now; `pick = floor(u*n)` matches randint's law)."""
-    li = sample_executor_key(bank, u2[0], template, stage, num_local)
+    li = sample_executor_key(
+        params, bank, u2[0], template, stage, num_local
+    )
 
     cnt = bank.cnt[template, stage, :, li]  # i32[3]
     has = cnt > 0

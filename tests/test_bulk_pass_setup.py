@@ -302,3 +302,152 @@ def test_pass_hands_a_step_its_own_pair_and_durations_keep_their_law(
     assert abs(np.corrcoef(pairs.T)[0, 1]) < 0.05
     for q in (0.25, 0.5, 0.75):
         assert np.abs(np.quantile(pairs, q, axis=0) - q).max() < 0.03
+
+
+# ---------------------------------------------------------------------
+# PR 47: the sampler's executor-level interval, computed and not read
+# ---------------------------------------------------------------------
+
+
+def _table_executor_key(itv, bank, u, template, stage, num_local):
+    """The reference: `sample_executor_key` as it was until PR 47,
+    reading row `num_local` of the four `i32[N+1]` interval tables the
+    bank then held (`itv`: left value, right value, left index, right
+    index)."""
+    import jax.numpy as jnp
+
+    left_v, right_v, left_i, right_i = (t[num_local] for t in itv)
+    rand_pt = 1 + (u * (right_v - left_v)).astype(jnp.int32)
+    use_left = (left_v == right_v) | (rand_pt <= num_local - left_v)
+    key_idx = jnp.where(use_left, left_i, right_i)
+    key_val = jnp.where(use_left, left_v, right_v)
+    present = bank.level_present[template, stage, key_idx] & (key_val > 0)
+    return jnp.where(present, key_idx, bank.max_present[template, stage])
+
+
+def _interval_tables(num_executors: int) -> np.ndarray:
+    """The four tables as `pack_bank` built them: `i32[4, N+1]`."""
+    from sparksched_tpu.workload.bank import _executor_intervals, _to_idx
+
+    itv = _executor_intervals(num_executors)
+    return np.concatenate([itv, _to_idx(itv)], axis=1).T.astype(np.int32)
+
+
+@pytest.mark.parametrize("n", [1, 4, 5, 6, 10, 50, 100, 101, 120])
+def test_executor_interval_is_the_reference_table_row_for_row(n):
+    """`sampling.executor_interval(N, num_local)` is row `num_local` of
+    `_executor_intervals(N)` and `_to_idx` of it, for every num_local
+    in 0..N (the zeroed last row above 100 executors included) and,
+    like the clamped gather it replaced, the nearest row outside; the
+    runs it is computed from tile 0..N, differ from one to the next,
+    and are few."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparksched_tpu.workload.bank import executor_interval_runs
+    from sparksched_tpu.workload.sampling import executor_interval
+
+    want = _interval_tables(n)
+    nl = np.arange(-2, n + 3, dtype=np.int32)
+    got = np.stack([np.asarray(x) for x in jax.jit(
+        lambda a: executor_interval(n, a)
+    )(jnp.asarray(nl))])
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, want[:, np.clip(nl, 0, n)])
+    # a scalar, as the unbatched callers ask
+    for k in (0, n // 2, n):
+        one = executor_interval(n, jnp.int32(k))
+        assert [int(x) for x in one] == list(want[:, k])
+
+    starts, rows = executor_interval_runs(n)
+    assert starts[0] == 0 and list(starts) == sorted(set(starts))
+    assert starts[-1] <= n and len(starts) == len(rows) <= 17
+    assert all(a != b for a, b in zip(rows, rows[1:]))
+    for start, end, row in zip(starts, starts[1:] + (n + 1,), rows):
+        assert (want[:, start:end] == np.array(row)[:, None]).all()
+    if n > 100:
+        assert rows[-1] == (0, 0, 0, 0) and starts[-1] == n
+    assert len(starts) == {10: 3, 50: 9, 100: 15, 120: 16}.get(
+        n, len(starts))
+
+
+def test_a_wrong_run_list_fails_the_law(monkeypatch):
+    """The law test above can fail: with one run's start moved by one
+    (the (5, 10) run of a 10-executor cluster starting at 7), the
+    lookup parts from the table at num_local 6."""
+    import jax.numpy as jnp
+
+    from sparksched_tpu.workload import sampling
+
+    starts, rows = sampling.executor_interval_runs(10)
+    assert starts == (0, 6, 10)
+    monkeypatch.setattr(
+        sampling, "executor_interval_runs", lambda n: ((0, 7, 10), rows)
+    )
+    got = np.stack([np.asarray(x) for x in sampling.executor_interval(
+        10, jnp.arange(11, dtype=jnp.int32)
+    )])
+    unequal = (got != _interval_tables(10)).any(axis=0)
+    assert list(np.flatnonzero(unequal)) == [6]
+
+
+@pytest.mark.parametrize("n", [10, 50])
+def test_sampled_durations_equal_the_table_reading_sampler_s(
+    monkeypatch, n
+):
+    """`sample_task_duration` over a grid of (template, stage,
+    num_local, task valid, same stage, u2) on the synthetic bank
+    equals, bit for bit, the sampler that read the bank's interval
+    tables (`_table_executor_key`, the old `sample_executor_key`):
+    every num_local in 0..N, every wave chain, stages with and without
+    the level asked for, interpolation draws on both sides of every
+    interval."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparksched_tpu.config import EnvParams
+    from sparksched_tpu.workload import make_workload_bank, sampling
+
+    params = EnvParams(num_executors=n, max_jobs=4)
+    bank = make_workload_bank(n)
+    itv = jnp.asarray(_interval_tables(n))
+
+    rs = np.random.RandomState(47 + n)
+    u0 = np.concatenate([[0.0, 0.999999], rs.rand(6)]).astype(np.float32)
+    tpl, nl, tv, ss, ui = (g.ravel() for g in np.meshgrid(
+        np.arange(0, bank.num_templates, 5), np.arange(n + 1),
+        [False, True], [False, True], np.arange(u0.size), indexing="ij",
+    ))
+    stage = rs.randint(0, bank.max_stages, tpl.size)
+    stage = stage % np.asarray(bank.num_stages)[tpl]
+    u2 = np.stack([u0[ui], rs.rand(tpl.size).astype(np.float32)], -1)
+    args = tuple(jnp.asarray(x) for x in (
+        u2, tpl.astype(np.int32), stage.astype(np.int32),
+        nl.astype(np.int32), tv, ss,
+    ))
+
+    def durations():
+        return np.asarray(jax.jit(jax.vmap(
+            lambda *a: sampling.sample_task_duration(params, bank, *a)
+        ))(*args))
+
+    got = durations()
+    keys = np.asarray(jax.jit(jax.vmap(
+        lambda u, t, s, k: sampling.sample_executor_key(
+            params, bank, u, t, s, k)
+    ))(args[0][:, 0], *args[1:4]))
+    read = []
+
+    def from_tables(params_, *a):
+        read.append(params_.num_executors)
+        return _table_executor_key(itv, *a)
+
+    monkeypatch.setattr(sampling, "sample_executor_key", from_tables)
+    want = durations()
+    assert read == [n]
+    np.testing.assert_array_equal(got, want)
+    assert got.size > 2000 and (got > 0).all()
+    # the grid reaches what it says: several levels, both sides of an
+    # interval, the warm-up branch and plain ones
+    assert len(np.unique(keys)) >= (2 if n == 10 else 4), np.unique(keys)
+    assert len(np.unique(got)) > got.size // 20

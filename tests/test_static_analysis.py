@@ -399,6 +399,73 @@ def test_fused_pass_sets_up_what_its_loop_can_read(monkeypatch):
     ]
 
 
+def test_no_program_reads_a_table_an_executor_count_long(monkeypatch):
+    """What takes a counter's place for PR 47: the sampler's
+    executor-level interval is computed from `params.num_executors`
+    (`sampling.executor_interval`), so the jaxpr of the fused bulk
+    pass, and of `sample_task_duration` alone, holds no equation with
+    an operand of `num_executors + 1` elements: no gather from such a
+    table, no loop that carries one, whether the bank is a constant of
+    the program or an argument of it (the sweep's chunk). With the
+    sampler that read the bank's four `i32[N+1]` tables
+    (`tests/test_bulk_pass_setup.py` keeps it as the reference) the
+    rule names four gathers a sampled duration."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparksched_tpu.analysis import jaxpr_audit
+    from sparksched_tpu.env import core
+    from sparksched_tpu.workload import sampling
+
+    from .test_bulk_pass_setup import _interval_tables, _table_executor_key
+
+    params, bank, state = jaxpr_audit.audit_setup()
+    n = params.num_executors
+    assert not any(
+        n + 1 in jnp.shape(leaf) for leaf in jax.tree_util.tree_leaves(bank)
+    )
+
+    def traced():
+        # functions of its own for each call: `make_jaxpr` keeps what
+        # it traced for a function it has seen
+        def fused_pass(bank_, st):
+            return core._bulk_events_fused(
+                params, bank_, st, True, stop_at_limit=True, max_events=8
+            )
+
+        def durations(bank_, u2, nl):
+            return jax.vmap(lambda k: sampling.sample_task_duration(
+                params, bank_, u2, jnp.int32(3), jnp.int32(1), k,
+                jnp.bool_(True), jnp.bool_(False),
+            ))(nl)
+
+        u2, nl = jnp.zeros(2), jnp.arange(7, dtype=jnp.int32)
+        return [
+            jax.make_jaxpr(lambda st: fused_pass(bank, st))(state).jaxpr,
+            jax.make_jaxpr(fused_pass)(bank, state).jaxpr,
+            jax.make_jaxpr(lambda *a: durations(bank, *a))(u2, nl).jaxpr,
+            jax.make_jaxpr(durations)(bank, u2, nl).jaxpr,
+        ]
+
+    for jaxpr in traced():
+        assert not jaxpr_audit.reads_of_shape(jaxpr, (n + 1,))
+        assert jaxpr_audit.count_eqns(jaxpr) > 60
+
+    itv = jnp.asarray(_interval_tables(n))
+    monkeypatch.setattr(
+        sampling, "sample_executor_key",
+        lambda params_, *a: _table_executor_key(itv, *a),
+    )
+    with_tables = [
+        jaxpr_audit.reads_of_shape(j, (n + 1,)) for j in traced()
+    ]
+    assert with_tables[2] == with_tables[3] == [f"gather({n + 1},)"] * 4
+    # the pass samples in each of its loop's two unrolled steps (one
+    # lane: a scalar index, so the four reads are dynamic slices)
+    assert with_tables[0] == with_tables[1] == [
+        f"dynamic_slice({n + 1},)"] * 8
+
+
 def test_unknown_program_name_is_an_error():
     from sparksched_tpu.analysis import jaxpr_audit
 

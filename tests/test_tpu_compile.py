@@ -354,25 +354,19 @@ def test_drain_reads_no_grid_once_an_executor(flagship, collector):
     assert per_executor(_gathers(text, "env/micro_step/decide"))
 
 
-def test_blocked_drain_is_one_while_inside_one_loop_over_blocks(
-    flagship, one_chip, tmp_path
-):
-    """What takes a counter's place for the drain over blocks of lanes
-    (PR 43): how often it engages is a fact of the compiled collector.
-    At the batched-arrivals cell's 1024 lanes (its cluster, job axis
-    and keys; a short scan) the collector compiled for the v5e holds
-    ONE drain `while`, over 128 lanes, inside ONE loop over the eight
-    blocks, under the drain's scope: one copy of the drain's program,
-    not eight. PR 39's rule still holds inside a block: no instruction
-    of that `while` yields an array of the adjacency's size."""
+@pytest.fixture(scope="module")
+def batched(flagship, one_chip, tmp_path_factory):
+    """The batched-arrivals cell's collector (its cluster, job axis,
+    1024 lanes and threefry keys; a short scan) compiled for the chip,
+    once for the tests that read it: `(trainer, HLO text)`."""
     import jax
 
     import chip_smoke
     from sparksched_tpu.trainers import make_trainer
-    from sparksched_tpu.trainers.rollout import _DRAIN_BLOCK
 
     cfg = chip_smoke.load_cfg(
-        "config/decima_tpch_batched.yaml", str(tmp_path))
+        "config/decima_tpch_batched.yaml",
+        str(tmp_path_factory.mktemp("tpu_compile_batched")))
     cfg["trainer"] |= {
         "num_sequences": 128, "num_rollouts": 8, "rollout_steps": 16}
     jax.config.update("jax_default_prng_impl", "threefry2x32")
@@ -383,10 +377,24 @@ def test_blocked_drain_is_one_while_inside_one_loop_over_blocks(
             state.params, state.iteration, state.rng, None).compile()
     finally:
         jax.config.update("jax_default_prng_impl", "rbg")
+    _fits(compiled, temp_gib=1.0)
+    return trainer, compiled.as_text()
+
+
+def test_blocked_drain_is_one_while_inside_one_loop_over_blocks(batched):
+    """What takes a counter's place for the drain over blocks of lanes
+    (PR 43): how often it engages is a fact of the compiled collector.
+    At the batched-arrivals cell's 1024 lanes (its cluster, job axis
+    and keys; a short scan) the collector compiled for the v5e holds
+    ONE drain `while`, over 128 lanes, inside ONE loop over the eight
+    blocks, under the drain's scope: one copy of the drain's program,
+    not eight. PR 39's rule still holds inside a block: no instruction
+    of that `while` yields an array of the adjacency's size."""
+    from sparksched_tpu.trainers.rollout import _DRAIN_BLOCK
+
+    trainer, text = batched
     p = trainer.params_env
     assert (trainer.num_envs, p.max_jobs, _DRAIN_BLOCK) == (1024, 20, 128)
-    _fits(compiled, temp_gib=1.0)
-    text = compiled.as_text()
     (over_blocks,) = _loops(text, "env/micro_step/drain/while")
     (drain,) = _loops(text, "env/micro_step/drain)/while")
     name = drain.split(" = ")[0].strip()
@@ -399,6 +407,91 @@ def test_blocked_drain_is_one_while_inside_one_loop_over_blocks(
     assert "[128," in carried and "[1024," not in carried
     _holds_no_adjacency_sized_result(
         text, [_DRAIN_BLOCK, p.max_jobs, p.max_stages, p.max_stages])
+
+
+# the fused bulk pass's early-exit loop, inside the vmapped drain
+EARLY_EXIT = "vmap(env/micro_step/drain)/while/body/while/body"
+
+
+def test_early_exit_loop_gathers_from_no_executor_count_table(batched):
+    """What takes a counter's place for PR 47 in the collectors, whose
+    bank is a constant of the program: the early-exit loop of the
+    batched collector compiled for the v5e holds no gather from an
+    operand of `num_executors + 1` elements. Until the sampler
+    computed its executor-level interval from the executor count
+    (`sampling.executor_interval`) each of the loop's two steps
+    gathered from four loop-carried `s32[51]` tables and joined the
+    rows: six of a step's 58 operations, the heaviest of
+    `decima_batch20`'s `breakdown` among them. The loop still gathers
+    (a job's template, the bank's counts and durations), which shows
+    that the pattern is found where it is."""
+    trainer, text = batched
+    n = trainer.params_env.num_executors
+    in_loop = _gathers(text, EARLY_EXIT)
+    assert len(in_loop) >= 12, in_loop
+    assert not [op for _, op in in_loop if op == [n + 1]], in_loop
+
+
+def _scalar_operands(text: str, scope: str) -> dict[str, int]:
+    """The rank-0 operands of every fusion whose `op_name` holds
+    `scope`, by the fusion's name (operands looked up by name in the
+    fusion's own computation)."""
+    import re
+
+    found = {}
+    for body in _computations(text).values():
+        shapes = dict(re.findall(r"%([\w.\-]+) = (\w+\[[\d,]*\])", body))
+        for m in re.finditer(
+            r"%([\w.\-]+) = [^\n]*? fusion\(([^)]*)\), kind=[^\n]*", body
+        ):
+            if scope in m.group(0):
+                found[m.group(1)] = sum(
+                    shapes.get(o, "").endswith("[]")
+                    for o in re.findall(r"%([\w.\-]+)", m.group(2))
+                )
+    return found
+
+
+def test_sweep_chunk_carries_no_table_as_scalars(flagship, one_chip):
+    """What takes a counter's place for PR 47 in the sweep, whose bank
+    is an ARGUMENT of the chunk program (the benchmark runs the timed
+    executable over a second bank): `sweep_chunk` under
+    `config/sweep_fair_demo.yaml` (10 executors, 50 jobs, the fair
+    policy) at 2,048 lanes, compiled for the v5e with the bank as
+    shapes, has under the drain's scope no fusion with more than 8
+    rank-0 operands. With the interval tables in the bank, `i32[11]`
+    and so short that the compiler unrolled each gather into a chain
+    of selects, three fusions took the 4 x 11 entries as 44 scalars
+    carried through the drain's loops: two of them in every iteration
+    of the early-exit loop, a fifth of `sweep_fair`'s device time."""
+    import jax
+
+    from sparksched_tpu import config, sweep
+
+    jax.config.update("jax_default_prng_impl", "threefry2x32")
+    try:  # the sweep's own keys; the flagship fixture's back after
+        params, bank, scheduler = sweep.from_config(
+            config.load("config/sweep_fair_demo.yaml"))
+        key = jax.random.PRNGKey(0)
+        carry = jax.eval_shape(
+            lambda k: sweep.init(params, bank, k, 2048), key)
+        compiled = sweep.sweep_chunk.lower(
+            params, _on(one_chip, bank), scheduler.batch_policy,
+            _on(one_chip, carry), _on(one_chip, key), 16,
+        ).compile()
+    finally:
+        jax.config.update("jax_default_prng_impl", "rbg")
+    assert (params.num_executors, params.max_jobs) == (10, 50)
+    _fits(compiled, temp_gib=2.0)
+    text = compiled.as_text()
+    assert len(_loops(text, "env/micro_step/drain/while")) == 1  # blocked
+    scalars = _scalar_operands(text, "env/micro_step/drain")
+    assert len(scalars) > 200, len(scalars)
+    assert max(scalars.values()) <= 8, {
+        k: v for k, v in scalars.items() if v > 8}
+    # the early-exit loop is among what was read
+    assert any(EARLY_EXIT in line and " fusion(" in line
+               for line in text.split("\n"))
 
 
 def test_blocked_drain_leaves_the_sampler_its_layout(
