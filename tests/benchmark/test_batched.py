@@ -197,36 +197,47 @@ def test_the_driver_ends_at_once_on_a_program_without_the_field(
                    for m in set(sys.modules) - before)
 
 
-def test_the_cells_entries_are_what_the_issue_names():
-    config = {c["name"]: c for c in BENCH["configs"]}[CONF]
-    assert BENCH["configs"][-1] is config
+def entries_hold(bench: dict, base: str = harness.HERE) -> None:
+    """What PR 42 added, where it was put: the fourth configuration,
+    the fourth cell, the fourth place in the rate's `workloads`, and at
+    the head of the cell's per-layer metrics the sixteen twins and the
+    three over the new counters. What later PRs add follows."""
+    config = {c["name"]: c for c in bench["configs"]}[CONF]
+    assert bench["configs"][3] is config
     assert set(config["reduced"]) == {
         "num_sequences", "num_rollouts", "rollout_steps"}
     assert "7.2" in config["source"] and "20" in config["source"]
-    cell = BENCH["workloads"][-1]
+    cell = bench["workloads"][3]
     assert (cell["name"], cell["config"], cell["chips"]) == (CELL, CONF, 1)
-    rate = {m["name"]: m for m in BENCH["end_to_end"]}[
+    rate = {m["name"]: m for m in bench["end_to_end"]}[
         "rollout_decisions_per_s"]
-    assert rate["workloads"][-1] == CELL
+    assert rate["workloads"][3] == CELL
     read = [m["name"] for m in harness.metrics_of_cell(
-        BENCH, CELL, "per_layer")]
-    twins = [m["name"].replace("rollout.", "batch20.", 1)
-             for m in BENCH["per_layer"] if m["name"].startswith("rollout.")
-             and m["name"] != "rollout.gnn_full_width_share"]
+        bench, CELL, "per_layer")]
+    names = [m["name"] for m in bench["per_layer"]]
+    came = min(i for i, n in enumerate(names) if n.startswith("batch20."))
+    # a twin of each `rollout.*` metric that was there when the cell came
+    twins = [n.replace("rollout.", "batch20.", 1) for n in names[:came]
+             if n.startswith("rollout.")
+             and n != "rollout.gnn_full_width_share"]
     assert len(twins) == 16 and read[:16] == twins
-    assert read[16:] == [
+    assert read[16:19] == [
         "batch20.ended_lane_row_share", "batch20.jobs_present_per_decision",
         "batch20.episodes_terminated_share"]
-    for m in BENCH["per_layer"]:
+    for m in bench["per_layer"]:
         if m["name"].startswith("batch20."):
             assert m["workloads"] == [CELL]
-    loaded = harness.load_cell(CELL, BENCH)
+    loaded = harness.load_cell(CELL, bench, base=base)
     mix, conf = loaded["mix"], loaded["config_data"]
     assert mix["driver"] == "collect_batched"
     assert mix["lanes"] == (mix["overrides"]["trainer"]["num_sequences"]
                             * mix["overrides"]["trainer"]["num_rollouts"])
     assert conf["env"]["num_init_jobs"] == conf["env"]["job_arrival_cap"] == 20
     assert conf["limits"]["episodes_terminated_share"] == 0.9
+
+
+def test_the_cells_entries_are_what_the_issue_names():
+    entries_hold(harness.load_benchmark())
 
 
 def test_the_new_counter_metrics_read_the_programs_summary(collected):

@@ -183,10 +183,39 @@ def test_benchmark_json_keeps_to_the_contract():
     keeps_to_the_contract(harness.load_benchmark())
 
 
+# what the names a test makes up start with (a cell, a configuration, a
+# mix: `probe_`; a metric: `probe.`), so that no test's made-up name can
+# be one a later PR wants: no entry of a real benchmark starts so
+PROBE = ("probe_", "probe.")
+
+
+def only_adds_to(bench: dict, parent: dict) -> None:
+    """What the contract lets a PR that is no `benchmark` PR do to
+    `BENCHMARK.json`: add entries at the end of their lists. Every
+    entry of `parent` stands where it stood and as it stood, but that a
+    metric's `workloads` may have grown at its end."""
+    for key in ("command", "paths", "run_seconds"):
+        assert bench[key] == parent[key], key
+    for key in ("configs", "workloads"):
+        assert bench[key][:len(parent[key])] == parent[key], key
+    for key in ("end_to_end", "per_layer"):
+        assert len(bench[key]) >= len(parent[key]), key
+        for was, now in zip(parent[key], bench[key]):
+            assert set(now) == set(was), was["name"]
+            for field, value in was.items():
+                kept = (now[field][:len(value)] if field == "workloads"
+                        else now[field])
+                assert kept == value, (was["name"], field)
+
+
 def keeps_to_the_contract(bench: dict, *, base: str = harness.HERE,
-                          root: str = harness.ROOT) -> None:
+                          root: str = harness.ROOT, probe: bool = False,
+                          parent: dict | None = None) -> None:
     """The contract's limits on `BENCHMARK.json` (`bench`, at `root`,
-    its files under `base`)."""
+    its files under `base`). `probe` says that it is a test's copy with
+    made-up entries; any other holds no name that starts as theirs do.
+    With the benchmark it was made from as `parent`, it only adds to
+    it."""
     assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
     assert 1 <= bench["run_seconds"] <= 51
@@ -250,6 +279,13 @@ def keeps_to_the_contract(bench: dict, *, base: str = harness.HERE,
         assert os.path.exists(spec + ".json") or os.path.exists(spec + ".py")
     names = [m["name"] for m in bench["end_to_end"] + bench["per_layer"]]
     assert len(names) == len(set(names))
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        assert m.get("workloads", cells), m["name"]  # some cell reports it
     for w in cells:  # set-up, another end-to-end metric, a per-layer one
         assert len(harness.metrics_of_cell(bench, w, "end_to_end")) >= 2
         assert harness.metrics_of_cell(bench, w, "per_layer")
+    made_up = [n for n in names + list(configs) + list(cells) + [
+        w["traffic"] for w in bench["workloads"]] if n.startswith(PROBE)]
+    assert probe or not made_up, made_up
+    if parent is not None:
+        only_adds_to(bench, parent)

@@ -12,9 +12,11 @@ added since lists its cells.
 
 Each rule is a function of a benchmark (`BENCHMARK.json` as a dict) and
 its directory, run here on the repo's own and, in the last test, on a
-temporary copy to which a fourth cell, a counter metric and a scope
-metric were added as files and entries alone: a later PR's new cell,
-counter or scope needs no edit of this file."""
+temporary copy to which one more cell, a counter metric and a scope
+metric were added as files and entries alone, under names no real
+entry may take (`test_harness.PROBE`): a later PR's new cell, counter
+or scope needs no edit of this file (`test_next_cell.py` holds every
+module's rules on the entries on such a copy)."""
 
 import json
 import math
@@ -239,16 +241,18 @@ def test_the_streaming_driver_ends_at_once_without_its_configuration():
                    for m in set(sys.modules) - before)
 
 
-def test_a_fourth_cell_a_counter_and_a_scope_come_as_files_alone(tmp_path):
-    """What the next `model_config` PR does, in a temporary copy of the
-    benchmark's directory: a configuration, a traffic mix and a fourth
-    one-chip cell, a metric over a counter the parent lacks and a metric
-    over a scope no list names, as new files and new entries. Every
-    overlay rule and the contract hold on the copy, the reducer finds
-    the new scope through the data file, and no file that was there
-    changed."""
-    from tests.benchmark.test_harness import keeps_to_the_contract
+PROBE_CELL, PROBE_CONFIG, PROBE_MIX = "probe_cell", "probe_config", "probe_mix"
 
+
+def a_copy_with_a_cell_appended(tmp_path, metric_files: dict):
+    """What the next `model_config` PR does, in a temporary copy of the
+    benchmark's directory under `tmp_path`: a configuration, a traffic
+    mix and one more one-chip cell, its name at the end of the rate's
+    `workloads`, and a per-layer metric for each data file of
+    `metric_files` at the end of `per_layer`, as new files and new
+    entries, every one under a made-up name (`test_harness.PROBE`).
+    Gives the benchmark, its directory and the bytes of every file that
+    was there."""
     base = tmp_path / "benchmarks"
     shutil.copytree(harness.HERE, base, ignore=shutil.ignore_patterns(
         "__pycache__", "tests"))
@@ -256,51 +260,63 @@ def test_a_fourth_cell_a_counter_and_a_scope_come_as_files_alone(tmp_path):
     bench = harness.load_benchmark()
     config = json.loads((base / "configs" / (
         bench["configs"][0]["name"] + ".json")).read_text())
-    (base / "configs" / "decima_tpch_50x200_batched.json").write_text(
-        json.dumps(dict(config, deployment="batched arrivals")))
+    (base / "configs" / (PROBE_CONFIG + ".json")).write_text(
+        json.dumps(dict(config, deployment="made up by a test")))
     mix = json.loads((base / "traffic" / "decima_128x800.json").read_text())
-    (base / "traffic" / "batched_128x800.json").write_text(json.dumps(mix))
-    files = {
-        "batched.collect_s": {
-            "reader": "scalar_stat", "key": "collect_seconds",
-            "stat": "median"},
-        "batched.wave_jobs_per_row": {
-            "reader": "telemetry_ratio", "num": "row.wave_jobs",
-            "den": "row.rows", "may_lack": True},
-        "batched.wave_device_s": {
-            "reader": "trace_scope", "scope": "env/micro_step/wave"},
-    }
-    for name, spec in files.items():
+    (base / "traffic" / (PROBE_MIX + ".json")).write_text(json.dumps(mix))
+    for name, spec in metric_files.items():
         (base / "layer_metrics" / (name + ".json")).write_text(
             json.dumps(spec))
     bench["configs"].append(dict(
-        bench["configs"][0], name="decima_tpch_50x200_batched",
-        file="benchmarks/configs/decima_tpch_50x200_batched.json"))
+        bench["configs"][0], name=PROBE_CONFIG,
+        file=f"benchmarks/configs/{PROBE_CONFIG}.json"))
     bench["workloads"].append({
-        "name": "decima_batched", "config": "decima_tpch_50x200_batched",
-        "traffic": "batched_128x800", "chips": 1,
-        "why": "added as files and entries alone"})
+        "name": PROBE_CELL, "config": PROBE_CONFIG, "traffic": PROBE_MIX,
+        "chips": 1, "why": "added as files and entries alone"})
     for m in bench["end_to_end"]:
         if m["name"] == "rollout_decisions_per_s":
-            m["workloads"] = m["workloads"] + ["decima_batched"]
+            m["workloads"] = m["workloads"] + [PROBE_CELL]
     twin = {m["name"]: m for m in bench["per_layer"]}["rollout.collect_s"]
-    for name in files:
+    for name in metric_files:
         bench["per_layer"].append(dict(
-            twin, name=name, workloads=["decima_batched"]))
+            twin, name=name, workloads=[PROBE_CELL]))
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    return bench, base, before
+
+
+def test_a_fourth_cell_a_counter_and_a_scope_come_as_files_alone(tmp_path):
+    """A later PR's cell, a metric over a counter the parent lacks and a
+    metric over a scope no list names, on a copy
+    (`a_copy_with_a_cell_appended`). Every overlay rule and the contract
+    hold on the copy, the reducer finds the new scope through the data
+    file, and no file that was there changed."""
+    from tests.benchmark.test_harness import keeps_to_the_contract
+
+    files = {
+        "probe.collect_s": {
+            "reader": "scalar_stat", "key": "collect_seconds",
+            "stat": "median"},
+        "probe.wave_jobs_per_row": {
+            "reader": "telemetry_ratio", "num": "row.wave_jobs",
+            "den": "row.rows", "may_lack": True},
+        "probe.wave_device_s": {
+            "reader": "trace_scope", "scope": "env/micro_step/wave"},
+    }
+    bench, base, before = a_copy_with_a_cell_appended(tmp_path, files)
 
     here = str(base)
-    keeps_to_the_contract(bench, base=here, root=str(tmp_path))
+    keeps_to_the_contract(bench, base=here, root=str(tmp_path), probe=True,
+                          parent=harness.load_benchmark())
     old_cells_read_what_they_read(bench)
     values = {m["name"]: reads_the_parents_window(m["name"], here)
               for m in bench["per_layer"]}
-    assert values["batched.collect_s"] == 15.2
-    assert values["batched.wave_jobs_per_row"] is None
-    assert values["batched.wave_device_s"] is None
+    assert values["probe.collect_s"] == 15.2
+    assert values["probe.wave_jobs_per_row"] is None
+    assert values["probe.wave_device_s"] is None
     for m in bench["per_layer"]:  # an empty window: nothing to read
         assert harness.read_layer_metric(m["name"], {}, base=here) is None
     assert [m["name"] for m in harness.metrics_of_cell(
-        bench, "decima_batched", "per_layer")] == list(files)
+        bench, PROBE_CELL, "per_layer")] == list(files)
     # the program that HAS the counter and the scope: both read
     assert "env/micro_step/wave" in harness.metric_scopes(here)
     assert "env/micro_step/wave" not in harness.metric_scopes()
@@ -317,8 +333,8 @@ def test_a_fourth_cell_a_counter_and_a_scope_come_as_files_alone(tmp_path):
     window = dict(PARENT_WINDOW, telemetry=[summary],
                   trace=dict(trace, units=0.5))
     read = harness.read_layer_metric
-    assert read("batched.wave_jobs_per_row", window, base=here) == 2.0
-    assert read("batched.wave_device_s", window, base=here) == (
+    assert read("probe.wave_jobs_per_row", window, base=here) == 2.0
+    assert read("probe.wave_device_s", window, base=here) == (
         pytest.approx(0.4))
     assert read("rollout.engine_device_s", window, base=here) == (
         pytest.approx(0.4))  # the parent scope still holds its child

@@ -277,11 +277,13 @@ UNLISTED = {"sweep.decide_device_s", "sweep.policy_device_s",
             "sweep.record_device_s", "sweep.observe_device_s"}
 
 
+LISTED = [n for n in WANT if n not in UNLISTED]
+
+
 def test_the_cell_reads_the_metrics_its_traced_line_can_hold():
-    assert SWEEP_METRICS == [n for n in WANT if n not in UNLISTED]
-    assert len(SWEEP_METRICS) == 16
-    assert [m["name"] for m in harness.metrics_of_cell(
-        BENCH, CELL, "per_layer")] == SWEEP_METRICS
+    """Sixteen of the issue's twenty; `entries_hold` has them at the
+    head of the cell's list."""
+    assert len(LISTED) == 16 and SWEEP_METRICS[:16] == LISTED
 
 
 @pytest.mark.parametrize("name", list(WANT))
@@ -289,12 +291,7 @@ def test_each_sweep_metric_reads_its_own_source(name):
     assert harness.read_layer_metric(name, WINDOW) == pytest.approx(
         WANT[name])
     assert harness.read_layer_metric(name, {}) is None
-    entry = {m["name"]: m for m in BENCH["per_layer"]}.get(name)
-    if name in UNLISTED:
-        assert entry is None
-        return
-    assert entry["workloads"] == [CELL]
-    assert entry["moves"] == "rollout_decisions_per_s"
+    assert (name in SWEEP_METRICS) == (name not in UNLISTED)
 
 
 def test_the_new_scopes_reach_the_reducer_through_their_data_files():
@@ -326,18 +323,30 @@ def test_the_summary_holds_the_new_counter_only_where_asked_for():
 # -- the cell's entries -----------------------------------------------------
 
 
-def test_the_sweep_cells_entries_are_what_the_issue_names():
-    config = {c["name"]: c for c in BENCH["configs"]}[CONF]
+def entries_hold(bench: dict, base: str = harness.HERE) -> None:
+    """What PR 46 added, where it was put: the fifth cell, under a
+    configuration no older cell uses, the fifth in the rate's
+    `workloads`; the sixteen listed `sweep.*` metrics lead their family
+    and the cell's per-layer metrics, and every `sweep.*` metric lists
+    the cell alone. What later PRs add follows."""
+    config = {c["name"]: c for c in bench["configs"]}[CONF]
     assert set(config["reduced"]) == {"lanes", "rows_per_chunk"}
     assert "examples.py:15-23" in config["source"]
-    cell = {w["name"]: w for w in BENCH["workloads"]}[CELL]
-    assert (cell["config"], cell["traffic"], cell["chips"]) == (
-        CONF, "fair_steady", 1)
-    rate = {m["name"]: m for m in BENCH["end_to_end"]}[
+    cell = bench["workloads"][4]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        CELL, CONF, "fair_steady", 1)
+    assert not any(w["config"] == CONF for w in bench["workloads"][:4])
+    rate = {m["name"]: m for m in bench["end_to_end"]}[
         "rollout_decisions_per_s"]
-    assert CELL in rate["workloads"]
-    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) == 1
-    loaded = harness.load_cell(CELL, BENCH)
+    assert rate["workloads"][4] == CELL
+    family = [m for m in bench["per_layer"] if m["name"].startswith("sweep.")]
+    assert [m["name"] for m in family[:16]] == LISTED
+    for m in family:
+        assert m["workloads"] == [CELL], m["name"]
+        assert m["moves"] == "rollout_decisions_per_s"
+    assert [m["name"] for m in harness.metrics_of_cell(
+        bench, CELL, "per_layer")][:16] == LISTED
+    loaded = harness.load_cell(CELL, bench, base=base)
     mix, conf = loaded["mix"], loaded["config_data"]
     assert mix["driver"] == "sweep_chunks"
     assert mix["lanes"] % 2048 == 0 and mix["lanes"] <= 32768
@@ -353,6 +362,11 @@ def test_the_sweep_cells_entries_are_what_the_issue_names():
     assert conf["lower_precision"] == {
         "bank_int8": {"env": {"bank_dtype": "int8"}}}
     assert len(conf["guarantees"]) == 7
+
+
+def test_the_sweep_cells_entries_are_what_the_issue_names():
+    entries_hold(BENCH)
+    conf = harness.load_cell(CELL, BENCH)["config_data"]
     # the program's own YAML states the same cluster and scheduler
     from sparksched_tpu import config as program_config
 
@@ -383,28 +397,13 @@ def test_every_line_of_the_benchmark_fits_200_printable_characters():
 
 
 def test_no_name_of_the_cell_is_one_the_overlay_test_makes_up():
-    names = [CELL, CONF] + SWEEP_METRICS
-    assert not any(n == "decima_batched" or n.startswith("batched.")
-                   for n in names)
+    """The tests' made-up entries start with `probe_` or `probe.`
+    (until PR 48 `test_overlay.py` made up `decima_batched` and
+    `batched.*`, which cost PR 42 its cell's name)."""
+    from tests.benchmark.test_harness import PROBE
 
-
-def test_the_benchmark_as_an_older_cell_knew_it():
-    """`conftest.as_it_stood_with`: what `test_batched.py`'s pinned
-    test reads."""
-    from tests.benchmark.conftest import as_it_stood_with
-
-    then = as_it_stood_with(BENCH, "decima_batch20")
-    assert [w["name"] for w in then["workloads"]][-1] == "decima_batch20"
-    assert CONF not in [c["name"] for c in then["configs"]]
-    assert not any(m["name"].startswith("sweep.") for m in then["per_layer"])
-    rate = {m["name"]: m for m in then["end_to_end"]}[
-        "rollout_decisions_per_s"]
-    assert rate["workloads"][-1] == "decima_batch20"
-    assert {m["name"] for m in then["end_to_end"]} == {
-        m["name"] for m in BENCH["end_to_end"]}
-    now = as_it_stood_with(BENCH, CELL)
-    assert now["workloads"] == BENCH["workloads"]
-    assert now["per_layer"] == BENCH["per_layer"]
+    names = [CELL, CONF, "fair_steady"] + SWEEP_METRICS
+    assert not any(n.startswith(PROBE) for n in names)
 
 
 def test_the_driver_ends_at_once_without_the_programs_sweep(monkeypatch):
