@@ -102,7 +102,12 @@ Counter semantics per engine:
   limit (`reseeds` counts both kinds, in streaming mode only);
   `jobs_present_sum` (the batch collectors) the jobs in the lane's
   observation (`Observation.job_mask`), summed over its stored
-  decisions: the backlog a decision sees.
+  decisions: the backlog a decision sees. The sweep loop carries two
+  more the same way: `episode_decisions_sum` (`results=True`), and
+  under a policy that states a log-probability `nodes_present_sum`
+  (`nodes=True`): the active nodes (`Observation.node_mask`) in the
+  observation of each stored decision, the part of the net's padded
+  job-by-stage grid that is real.
 
 Cross-engine invariant (the parity test): on a deterministic workload
 the two engines process the same trajectory, so `decide_steps`, the
@@ -181,6 +186,10 @@ class Telemetry(struct.PyTreeNode):
     jobs_present_sum: jnp.ndarray | None = None  # jobs seen, over decisions
     # --- the sweep loop's: None unless asked for (module docstring) ---
     episode_decisions_sum: jnp.ndarray | None = None  # of ended episodes
+    # the active nodes (`Observation.node_mask`) in the observation of
+    # each stored decision: the share of a net's padded [J, S] grid
+    # that is real (the sweep loop, under a policy that scores nodes)
+    nodes_present_sum: jnp.ndarray | None = None
 
     @property
     def counts_episodes(self) -> bool:
@@ -189,13 +198,16 @@ class Telemetry(struct.PyTreeNode):
 
 _EPISODE_COUNTERS = ("rows_ended", "episodes_terminated", "jobs_present_sum")
 _RESULT_COUNTERS = ("episode_decisions_sum",)
+_NODE_COUNTERS = ("nodes_present_sum",)
 
 
-def _zeros(z, episodes: bool, results: bool = False) -> Telemetry:
+def _zeros(z, episodes: bool, results: bool = False,
+           nodes: bool = False) -> Telemetry:
     return Telemetry(**{
         k: z for k in Telemetry.__dataclass_fields__
         if (episodes or k not in _EPISODE_COUNTERS)
         and (results or k not in _RESULT_COUNTERS)
+        and (nodes or k not in _NODE_COUNTERS)
     })
 
 
@@ -205,13 +217,14 @@ def telemetry_zeros(episodes: bool = False) -> Telemetry:
 
 def telemetry_zeros_like(
     batch_shape: tuple[int, ...], episodes: bool = False,
-    results: bool = False,
+    results: bool = False, nodes: bool = False,
 ) -> Telemetry:
     """Zeros with a leading batch shape on every counter — the starting
     value for vmapped engines (one counter set per lane). `episodes`:
     with the three counters of episodes that end inside the scan;
-    `results`: with the sweep loop's `episode_decisions_sum`."""
-    return _zeros(jnp.zeros(batch_shape, _i32), episodes, results)
+    `results`: with the sweep loop's `episode_decisions_sum`; `nodes`:
+    with its `nodes_present_sum`."""
+    return _zeros(jnp.zeros(batch_shape, _i32), episodes, results, nodes)
 
 
 def _count(x) -> bool:
@@ -321,6 +334,11 @@ def summarize(tm: Telemetry, prev=None) -> dict[str, Any]:
     if t.episode_decisions_sum is not None:
         # the sweep loop: the decisions of the episodes that ended
         episodes["episode_decisions_total"] = tot(t.episode_decisions_sum)
+    if t.nodes_present_sum is not None:
+        episodes |= {
+            "nodes_present_total": tot(t.nodes_present_sum),
+            "nodes_present_per_decision": per_dec(tot(t.nodes_present_sum)),
+        }
     scan_steps = tot(t.bulk_scan_steps)
     bulk_passes = tot(t.bulk_passes)
     # the bodies the device ran, a lane at a time: every lane runs what

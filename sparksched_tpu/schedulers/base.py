@@ -18,6 +18,18 @@ from typing import Any
 import jax
 
 
+def keys_by_lane(rng: jax.Array, lanes: int) -> jax.Array:
+    """One key a lane: `rng` split `lanes` ways where it is a single
+    key, `rng` itself where it already leads with the lane axis (the
+    sweep loop splits a row's key over all its lanes and hands a block
+    of lanes its own slice, so a lane's draw does not depend on which
+    lanes are evaluated beside it)."""
+    typed = jax.dtypes.issubdtype(rng.dtype, jax.dtypes.prng_key)
+    if rng.ndim == (0 if typed else 1):
+        return jax.random.split(rng, lanes)
+    return rng
+
+
 class Scheduler(abc.ABC):
     """Interface for all schedulers (reference scheduler.py:10-18)."""
 
@@ -34,12 +46,13 @@ class Scheduler(abc.ABC):
 
     def batch_policy(self, rng: jax.Array, obs: Any):
         """`policy` over a [B]-stacked Observation, a lane at a time
-        under keys split from `rng`: `(stage_idx[B], num_exec[B], aux)`,
-        the form the sweep loop (`sparksched_tpu/sweep.py`) and the
-        trainer's collectors call once a decision row. The heuristics'
-        batch form is this `vmap` of what they have."""
+        under keys split from `rng` (or under `rng` itself where it is
+        one key a lane: `keys_by_lane`): `(stage_idx[B], num_exec[B],
+        aux)`, the form the sweep loop (`sparksched_tpu/sweep.py`) and
+        the trainer's collectors call once a decision row. The
+        heuristics' batch form is this `vmap` of what they have."""
         lanes = jax.tree_util.tree_leaves(obs)[0].shape[0]
-        return jax.vmap(self.policy)(jax.random.split(rng, lanes), obs)
+        return jax.vmap(self.policy)(keys_by_lane(rng, lanes), obs)
 
 
 class TrainableScheduler(Scheduler):

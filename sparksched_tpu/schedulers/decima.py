@@ -31,7 +31,7 @@ from flax import struct
 
 from ..env.observe import Observation
 from ..obs.tracing import annotate
-from .base import TrainableScheduler
+from .base import TrainableScheduler, keys_by_lane
 
 NUM_NODE_FEATURES = 5  # reference env_wrapper.py:9
 NUM_DAG_FEATURES = 3  # reference scheduler.py:34
@@ -679,7 +679,8 @@ class DecimaScheduler(TrainableScheduler):
         """Policy over a [B]-leading Observation stack in ONE net
         evaluation, with the compaction cond at batch level (scalar
         predicate — one branch executes at runtime). `rng` is a single
-        key, split per lane internally. Returns per-lane
+        key, split per lane internally, or one key a lane
+        (`base.keys_by_lane`). Returns per-lane
         (stage_idx[B], num_exec_1based[B], aux-of-[B]); where the net
         has two widths, aux also holds the row's scalar `full_width`
         (see `full_width`)."""
@@ -690,7 +691,7 @@ class DecimaScheduler(TrainableScheduler):
             stage_scores, exec_scores = self.score(params, f)
             wide = self.full_width(f)
         with annotate("decima/sample"):
-            keys = jax.random.split(rng, f.job_mask.shape[0])
+            keys = keys_by_lane(rng, f.job_mask.shape[0])
             action, lgprob = jax.vmap(
                 lambda r, ss, es, ff: sample_action(
                     r, ss, es, ff, deterministic

@@ -28,13 +28,19 @@ drains in blocks of `rollout._DRAIN_BLOCK` lanes through the same
   on the row an episode ends in and, there, the episode's result: its
   average job completion time, the jobs it completed, its makespan
   (`metrics.episode_result`, read by `drain_to_decision` before the
-  re-seed) and its decisions.
+  re-seed) and its decisions; under a policy that states one, the
+  decision's log-probability (`lgprob`).
 - no engine switch: the bulk-pass constants below are the values the
   trainer passes at the flagship.
 
-One decision row: observe every lane, evaluate the policy once over
-the batch (`Scheduler.batch_policy`), apply `decide_micro_step`, drain
-to the next decision. Device scopes: `collect/observe`, `sweep/policy`,
+One decision row: observe the lanes, evaluate the policy over them
+(`Scheduler.batch_policy`), apply `decide_micro_step`, drain to the
+next decision; over more than one block of lanes the drain runs a block
+of 128 lanes at a time, and with a net in the row (`DecimaScheduler`;
+its weights an argument of the chunk, `sweep_chunk(..., weights)`) all
+four do, in ONE loop over the blocks, so that the net is one copy in
+the program and holds activations for a block only. Device
+scopes: `collect/observe`, `sweep/policy`,
 `env/micro_step/decide`, `env/micro_step/drain`, `env/micro_step/reset`,
 `collect/health`, `sweep/record`. Host span: `sweep/chunk_call` around
 every call of the compiled chunk (trace, lower and compile or load on a
@@ -70,6 +76,7 @@ from .obs.telemetry import add as _tm_add
 from .obs.telemetry import orr as _tm_orr
 from .obs.telemetry import summarize, telemetry_zeros_like
 from .obs.tracing import annotate, span, spanned
+from .schedulers.base import TrainableScheduler
 from .trainers.rollout import _DRAIN_BLOCK, _by_blocks
 from .workload.bank import WorkloadBank
 
@@ -111,6 +118,9 @@ class SweepRecord(struct.PyTreeNode):
     jobs_completed: jnp.ndarray  # i32
     makespan: jnp.ndarray  # f32; ms
     decisions: jnp.ndarray  # i32
+    # f32; the log-probability the policy states of its decision
+    # (`aux["lgprob"]`); None, and no leaf, where it states none
+    lgprob: jnp.ndarray | None = None
 
 
 def lane_keys(key: jax.Array, lanes: jnp.ndarray) -> jax.Array:
@@ -163,17 +173,63 @@ def concat(carries: list[SweepCarry]) -> SweepCarry:
 
 
 def _chunk(params: EnvParams, bank: WorkloadBank, policy: Callable,
-           carry: SweepCarry, rng: jax.Array, rows: int):
+           carry: SweepCarry, rng: jax.Array, rows: int,
+           weights: Any = None):
     """`rows` decision rows over every lane of `carry`: the new carry,
     the rows' `SweepRecord` and the chunk's `Telemetry` ([lanes]; the
     collectors' row counters, the episode counters and
     `episode_decisions_sum`, from zero; the in-JIT health sentinels of
     `env/health.py` ORed into its `health_mask` every row).
     `policy(rng, obs)` is a scheduler's `batch_policy`; only it reads
-    `rng`: an episode's draws come from its own state."""
+    `rng`: an episode's draws come from its own state.
+
+    Where the lanes are more than one block of `_DRAIN_BLOCK` and whole
+    blocks, the drain runs a block at a time. What runs beside it is
+    chosen by ONE static fact, whether the policy comes with `weights`
+    (a `TrainableScheduler`'s parameters, which `run` hands over):
+
+    - without (a heuristic, a `vmap` of scalar work): observe, policy
+      and decide once over all lanes, the drain alone block by block:
+      the program `sweep_fair` has been measured with since PR 46, to
+      the byte (`tests/test_sweep_decima.py` holds its lowered text).
+      With a block's whole row inside the loop the chunk no longer
+      loads beside that cell's 26,624 lanes (PERF.md, PR 49);
+    - with (a net: `policy(keys, obs, weights)`, one key a lane, split
+      from the row's key over ALL the lanes): ONE loop over the blocks
+      whose body is a block's whole row (observe, policy, decide,
+      drain), so that an observation and the net's activations exist
+      for 128 lanes at a time, one copy of the net is in the program
+      whatever the lane count, and no stored bit depends on the block
+      layout. The weights are an ARGUMENT of the compiled program:
+      another checkpoint of the same net sweeps through the program
+      that is there.
+
+    Any other lane count runs either row once over the whole batch. A
+    policy whose `aux` states a log-probability (`lgprob`: a net's
+    decision) has it recorded, and the nodes its decisions saw counted
+    (`nodes_present_sum`); any other policy's record and telemetry hold
+    no such leaf. Such a policy without `weights` is refused: as
+    closure constants a net's parameters make every checkpoint a
+    program of its own."""
     lanes = carry.lane.shape[0]
     s_cap = params.max_stages
     blocked = lanes % _DRAIN_BLOCK == 0 and lanes > _DRAIN_BLOCK
+    width = _DRAIN_BLOCK if blocked else lanes
+    net = weights is not None
+
+    def observe_lanes(env):
+        return jax.vmap(lambda e: observe(params, e))(env)
+
+    # what the policy states of a decision, from its shapes alone
+    scored = "lgprob" in jax.eval_shape(
+        lambda k, env: policy(
+            k, observe_lanes(env), *((weights,) if net else ()))[2],
+        *jax.tree_util.tree_map(
+            lambda a: a[:width], (carry.key, carry.ls.env)))
+    if scored and not net:
+        raise ValueError(
+            "a policy that states a log-probability is a net: hand its "
+            "parameters to the chunk as `weights`")
 
     def decide_lanes(ls, stage_idx, num_exec, tm):
         return jax.vmap(lambda l, s, n, t: decide_micro_step(
@@ -222,21 +278,70 @@ def _chunk(params: EnvParams, bank: WorkloadBank, policy: Callable,
     span0 = (zero, zero, zero > 0, {
         "avg_jct": zero, "jobs_completed": count0, "makespan": zero})
 
+    def net_row(state, args):
+        """A net's decision row of the lanes `state` leads with (a
+        block, or the whole batch): observe, policy, decide, then the
+        drain under the lanes' own `while`. The lanes' `LoopState` and
+        telemetry go on; the rest of `state` is written."""
+        ls, tm = state["ls"], state["tm"]
+        k_pol, k_drain, lane_key = args
+        with annotate("collect/observe"):
+            obs = observe_lanes(ls.env)
+        with annotate("sweep/policy"):
+            stage_idx, num_exec, aux = policy(k_pol, obs, weights)
+        ls, (decided, rw1, _, _), tm = decide_lanes(
+            ls, stage_idx, num_exec, tm)
+        with annotate("sweep/record"):
+            seen = {"jobs_present_sum": obs.job_mask}
+            if scored:
+                seen["nodes_present_sum"] = obs.node_mask.reshape(
+                    obs.node_mask.shape[0], -1)
+            tm = _tm_add(tm, **{
+                name: jnp.where(decided, mask.sum(-1, dtype=_i32), 0)
+                for name, mask in seen.items()})
+        with annotate("env/micro_step/drain"):
+            ls, span, tm, drained, paid = drain_block(
+                (ls, None, tm, None, None), (k_drain, lane_key))
+        return dict(
+            ls=ls, tm=tm, stage_idx=stage_idx, num_exec=num_exec,
+            decided=decided, rw1=rw1, span=span, drained=drained,
+            paid=paid, **({"lgprob": aux["lgprob"]} if scored else {}))
+
+    written = dict(
+        stage_idx=count0, num_exec=count0, decided=zero > 0, rw1=zero,
+        span=span0, drained=count0, paid=zero > 0,
+        **({"lgprob": zero} if scored else {}))
+
     def body(c, _):
         carry, k, tm = c
         ls = carry.ls
         k, k_pol, k_drain = jax.random.split(k, 3)
         env0 = ls.env
-        with annotate("collect/observe"):
-            obs = jax.vmap(lambda e: observe(params, e))(env0)
-        with annotate("sweep/policy"):
-            stage_idx, num_exec, _ = policy(k_pol, obs)
-        ls2, (decided, rw1, _, _), tm1 = decide_lanes(
-            ls, stage_idx, num_exec, tm)
-        ls3, (rw2, _, ended, result), tm2, drained, paid = drain((
-            (ls2, span0, tm1, count0, zero > 0),
-            (jax.random.split(k_drain, lanes), carry.key),
-        ))
+        if net:
+            # a block's whole row inside the loop over blocks, every
+            # lane under a key of its own
+            out = _by_blocks(net_row, width)((
+                dict(written, ls=ls, tm=tm),
+                (jax.random.split(k_pol, lanes),
+                 jax.random.split(k_drain, lanes), carry.key)))
+            ls3, tm2, stage_idx, num_exec, decided, rw1, drained, paid = (
+                out[name] for name in (
+                    "ls", "tm", "stage_idx", "num_exec", "decided", "rw1",
+                    "drained", "paid"))
+            rw2, _, ended, result = out["span"]
+        else:
+            # a heuristic is a `vmap` of scalar work: observe, policy
+            # and decide once over all lanes, the drain block by block
+            with annotate("collect/observe"):
+                obs = observe_lanes(env0)
+            with annotate("sweep/policy"):
+                stage_idx, num_exec, _ = policy(k_pol, obs)
+            ls2, (decided, rw1, _, _), tm1 = decide_lanes(
+                ls, stage_idx, num_exec, tm)
+            ls3, (rw2, _, ended, result), tm2, drained, paid = drain((
+                (ls2, span0, tm1, count0, zero > 0),
+                (jax.random.split(k_drain, lanes), carry.key),
+            ))
         if blocked:
             # a block's loops end on the block's own predicates
             drain_syncs = 0
@@ -259,34 +364,36 @@ def _chunk(params: EnvParams, bank: WorkloadBank, policy: Callable,
                 stage=jnp.where(chose, stage_idx % s_cap, -1),
                 num_exec=num_exec, reset=ended, ordinal=ls.episodes,
                 decisions=jnp.where(ended, taken, 0),
+                lgprob=out["lgprob"] if scored else None,
                 **{name: jnp.where(ended, v, jnp.zeros_like(v))
                    for name, v in result.items()},
             )
             # the row counters are facts of the batch (`rows_live`'s
             # `any` is the one reduction over all lanes a blocked row
             # makes); `reset_evals` of the lane's own `while`
+            counted = dict(
+                rows=1, rows_live=decided.any(), drain_batch_iters=drained,
+                lane_syncs=drain_syncs + 1, reset_evals=paid)
+            if not net:  # a net's row counts what its blocks saw
+                counted["jobs_present_sum"] = jnp.where(
+                    decided, obs.job_mask.sum(-1, dtype=_i32), 0)
             tm2 = _tm_add(
-                tm2, rows=1, rows_live=decided.any(),
-                drain_batch_iters=drained, lane_syncs=drain_syncs + 1,
-                reset_evals=paid,
-                jobs_present_sum=jnp.where(
-                    decided, obs.job_mask.sum(-1, dtype=_i32), 0),
-                episode_decisions_sum=row.decisions,
-            )
+                tm2, **counted, episode_decisions_sum=row.decisions)
         carry = carry.replace(
             ls=ls3, decisions=jnp.where(ended, 0, taken))
         return (carry, k, tm2), row
 
-    tm0 = telemetry_zeros_like((lanes,), episodes=True, results=True)
+    tm0 = telemetry_zeros_like(
+        (lanes,), episodes=True, results=True, nodes=scored)
     (carry, _, tm), record = lax.scan(
         body, (carry, rng, tm0), None, length=rows)
     return carry, record, tm
 
 
-# `sweep_chunk(params, bank, policy, carry, rng, rows)`: the one jitted
-# chunk program (`_chunk`), under the host span `sweep/chunk_call`.
-# `params`, `policy` and `rows` are static; the carry is not donated (a
-# caller may keep the one it handed in)
+# `sweep_chunk(params, bank, policy, carry, rng, rows, weights=None)`:
+# the one jitted chunk program (`_chunk`), under the host span
+# `sweep/chunk_call`. `params`, `policy` and `rows` are static; the
+# carry is not donated (a caller may keep the one it handed in)
 sweep_chunk = spanned("sweep/chunk_call", jax.jit(
     _chunk, static_argnums=(0, 2, 5)))
 
@@ -318,7 +425,9 @@ def run(params: EnvParams, bank: WorkloadBank, scheduler, *, episodes: int,
         states: EnvState | None = None, policy: Callable | None = None,
         max_chunks: int | None = None) -> dict:
     """Sweeps `scheduler` (its `batch_policy`, or `policy` where given:
-    a Decima scheduler's greedy `flat_batch_policy(deterministic=True)`)
+    a Decima scheduler's greedy `partial(batch_policy,
+    deterministic=True)`; a `TrainableScheduler`'s is called with the
+    scheduler's `params` third, an argument of the compiled chunk)
     over `episodes` episodes on `lanes` lanes: chunk after chunk of
     `rows` rows until the results of episodes 0 to `episodes` - 1 are
     in, episode e being ordinal `e // lanes` of lane `e % lanes` (a set
@@ -331,6 +440,9 @@ def run(params: EnvParams, bank: WorkloadBank, scheduler, *, episodes: int,
     carry = init(params, bank, key_law, lanes, states=states)
     lanes = int(carry.lane.shape[0])
     policy = scheduler.batch_policy if policy is None else policy
+    # a net's parameters go in as an argument of the chunk
+    weights = (scheduler.params
+               if isinstance(scheduler, TrainableScheduler) else None)
     per_lane = math.ceil(episodes / lanes)
     found: dict[tuple[int, int], dict] = {}
     chunks, decisions, telemetry = 0, 0, None
@@ -341,7 +453,7 @@ def run(params: EnvParams, bank: WorkloadBank, scheduler, *, episodes: int,
                 f"{chunks} chunks of {rows} rows")
         carry, record, tm = sweep_chunk(
             params, bank, policy, carry,
-            jax.random.fold_in(key_run, chunks), rows)
+            jax.random.fold_in(key_run, chunks), rows, weights)
         chunks += 1
         telemetry = add_telemetry(telemetry, tm)
         decisions += int(record.valid.sum())
@@ -360,9 +472,10 @@ def run(params: EnvParams, bank: WorkloadBank, scheduler, *, episodes: int,
 def from_config(cfg: dict):
     """`(params, bank, scheduler)` of a sweep's YAML (`env:` and
     `agent:` blocks as `train.py`'s), built as the trainer builds
-    them."""
+    them: a Decima net's level scan is bounded by the bank's depth
+    unless the `agent:` block states `num_levels`."""
     from .schedulers import make_scheduler
-    from .workload import make_workload_bank
+    from .workload import bank_depth, make_workload_bank
 
     env_cfg = cfg["env"]
     params = env_params_from_cfg(env_cfg)
@@ -378,5 +491,6 @@ def from_config(cfg: dict):
             max_levels=max(params.max_levels, bank.max_stages))
     with span("setup/scheduler_init"):
         scheduler = make_scheduler(
-            cfg["agent"] | {"num_executors": params.num_executors})
+            {"num_levels": bank_depth(bank)} | cfg["agent"]
+            | {"num_executors": params.num_executors})
     return params, bank, scheduler

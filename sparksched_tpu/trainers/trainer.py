@@ -45,7 +45,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import optax
 from flax import serialization, struct
 
@@ -68,7 +67,7 @@ from ..obs.memory import device_memory_stats
 from ..obs.telemetry import summarize, telemetry_zeros_like
 from ..obs.tracing import annotate, mark, open_span, span, spanned
 from ..schedulers import TrainableScheduler, make_scheduler
-from ..workload import make_workload_bank
+from ..workload import bank_depth, make_workload_bank
 from .baselines import group_baselines
 from .profiler import Profiler
 from .returns import (
@@ -351,19 +350,9 @@ class Trainer(abc.ABC):
         # (bit-identical — deeper levels are no-op updates — and the
         # dominant GNN cost scales with it; the synthetic bank is 6 deep
         # vs a 20-stage cap). An explicit agent num_levels wins.
-        bank_depth = int(
-            np.max(
-                np.where(
-                    np.asarray(self.bank.node_level)
-                    < self.bank.max_stages,
-                    np.asarray(self.bank.node_level),
-                    -1,
-                )
-            )
-        ) + 1
         with span("setup/scheduler_init"):
             scheduler = make_scheduler(
-                {"num_levels": bank_depth}
+                {"num_levels": bank_depth(self.bank)}
                 | agent_cfg
                 | {"num_executors": self.params_env.num_executors}
             )

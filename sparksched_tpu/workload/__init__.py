@@ -19,6 +19,7 @@ from .bank import (  # noqa: F401
     EXEC_LEVEL_VALUES,
     NUM_EXEC_LEVELS,
     WorkloadBank,
+    bank_depth,
     bank_dtype_label,
     load_tpch_templates,
     pack_bank,

@@ -108,6 +108,15 @@ def topological_levels(adj: np.ndarray, num_stages: int) -> np.ndarray:
     return level
 
 
+def bank_depth(bank: "WorkloadBank") -> int:
+    """The topological generations of the bank's deepest DAG: one more
+    than the largest `node_level` of a real node (padding slots hold
+    `max_stages`). What bounds the Decima net's level scan
+    (`DecimaNet.num_levels`): deeper levels update nothing."""
+    level = np.asarray(bank.node_level)
+    return int(np.max(np.where(level < bank.max_stages, level, -1))) + 1
+
+
 def _executor_intervals(num_executors: int) -> np.ndarray:
     """Map num_local_executors -> (left, right) executor-level VALUES,
     reproducing the reference table exactly (tpch.py:237-262), including its

@@ -12,6 +12,7 @@ names.
 import csv
 import os
 import time
+from functools import partial
 
 from sparksched_tpu import sweep
 from sparksched_tpu.config import enable_compilation_cache, load
@@ -24,7 +25,7 @@ def main(cfg: dict) -> dict:
     opts = cfg["sweep"]
     policy = None
     if opts.get("deterministic"):
-        policy = scheduler.flat_batch_policy(deterministic=True)
+        policy = partial(scheduler.batch_policy, deterministic=True)
     t0 = time.perf_counter()
     out = sweep.run(
         params, bank, scheduler, policy=policy,

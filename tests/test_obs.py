@@ -1042,3 +1042,72 @@ def test_the_streaming_drain_while_is_the_sync_one(monkeypatch):
     assert facts["sync"]["equations"] > 5000
     for what, n in facts["stream"].items():
         assert n <= facts["sync"][what], (what, facts)
+
+
+# ---------------------------------------------------------------------------
+# the sweep loop's decision row under a net (PR 49)
+# ---------------------------------------------------------------------------
+
+SWEEP_ROW_SCOPES = (
+    "collect/observe", "sweep/policy", "env/micro_step/decide",
+    "env/micro_step/drain", "env/micro_step/reset", "collect/health",
+    "sweep/record",
+)
+POLICY_SCOPES = ("decima/features", "decima/gnn", "decima/sample")
+
+
+def test_every_equation_of_the_sweeps_decima_row_is_under_a_scope(
+        monkeypatch):
+    """The sweep loop's row with the Decima net in it, 256 lanes in two
+    blocks: the rows' scan holds ONE loop over the blocks, inside it
+    every scope of a block's row (observe, the policy with the net's
+    three inside it, decide, the drain with the re-seed under it) and
+    after it, over all lanes, health and record; no equation of the
+    block's row but the handling of keys and the blocks' slices and
+    write-backs is under no scope."""
+    import jax
+
+    from sparksched_tpu import sweep
+
+    params, bank, sched, _ = _tiny_decima_rows(monkeypatch, job_bucket=0)
+    lanes, rows = 256, 3
+    carry = jax.eval_shape(
+        lambda: sweep.init(params, bank, jax.random.PRNGKey(0), lanes))
+    jaxpr = jax.make_jaxpr(lambda b, c, k, w: sweep._chunk(
+        params, b, sched.batch_policy, c, k, rows, w))(
+        bank, carry, jax.random.PRNGKey(1), sched.params)
+    body = _collection_scan_body(jaxpr, rows)
+    (blocks,) = [e for e in body.eqns if e.primitive.name == "scan"]
+    assert blocks.params["length"] == lanes // 128
+    assert not str(blocks.source_info.name_stack)
+    row = blocks.params["jaxpr"].jaxpr
+
+    def stacks(jp):
+        for e in jp.eqns:
+            yield e.primitive.name, str(e.source_info.name_stack)
+            for inner in _inner_jaxprs(e):
+                yield from stacks(inner)
+
+    inside = list(stacks(row))
+    assert len(inside) > 3000  # a block's whole row is in this body
+    in_block = SWEEP_ROW_SCOPES[:5] + ("sweep/record",) + POLICY_SCOPES
+    assert {s for s in SWEEP_ROW_SCOPES + POLICY_SCOPES
+            if any(s in st for _, st in inside)} == set(in_block)
+    for scope in POLICY_SCOPES:  # the net's scopes are the policy's
+        assert all("sweep/policy" in st for _, st in inside if scope in st)
+    # at the top of the block's row (an inner jaxpr's names are its
+    # own): the blocks' slices and write-backs and the keys' handling
+    bare = [e.primitive.name for e in row.eqns if not any(
+        s in str(e.source_info.name_stack) for s in SWEEP_ROW_SCOPES)]
+    moves = {"dynamic_slice", "dynamic_update_slice", "mul", "add", "lt",
+             "select_n", "random_wrap", "random_unwrap", "slice", "squeeze",
+             "convert_element_type", "broadcast_in_dim"}
+    assert set(bare) <= moves, sorted(set(bare) - moves)
+    assert len(bare) < len(row.eqns) // 4, (len(bare), len(row.eqns))
+    # after the loop, over all the lanes: health and the record
+    after = [str(e.source_info.name_stack) for e in body.eqns[
+        body.eqns.index(blocks) + 1:]]
+    assert any("collect/health" in st for st in after)
+    assert any("sweep/record" in st for st in after)
+    assert not any(s in st for st in after for s in (
+        "sweep/policy", "env/micro_step", "collect/observe"))

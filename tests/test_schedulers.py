@@ -1400,3 +1400,35 @@ def test_leaky_relu_is_the_select_form():
         leaky_relu(1.5)
     with pytest.raises(ValueError, match="negative_slope"):
         leaky_relu(-0.1)
+
+
+def test_the_trainers_level_scan_is_bounded_by_bank_depth(tmp_path):
+    """PR 49 moved the bank's depth out of `Trainer.__init__` into
+    `workload.bank_depth` (the sweep's `from_config` takes it too): the
+    trainer's scheduler still scans `depth - 1` levels, an explicit
+    `agent.num_levels` still wins, and the function gives what the
+    trainer computed in place (the deepest real node's level, plus
+    one)."""
+    import numpy as np_
+
+    from sparksched_tpu.schedulers.decima import _dummy_features
+    from sparksched_tpu.trainers import make_trainer
+    from sparksched_tpu.workload import bank_depth
+
+    from .test_trainers import _mini_cfg
+
+    cfg = _mini_cfg({"artifacts_dir": str(tmp_path)})
+    trainer = make_trainer(cfg)
+    nl = np_.asarray(trainer.bank.node_level)
+    depth = int(np_.max(np_.where(
+        nl < trainer.bank.max_stages, nl, -1))) + 1
+    assert bank_depth(trainer.bank) == depth
+    assert 1 < depth < trainer.bank.max_stages
+    assert trainer.scheduler.net.num_levels == depth
+    feats = _dummy_features(5)
+    assert _scan_lengths(
+        lambda p, f: trainer.scheduler.net.apply(p, f),
+        trainer.scheduler.params, feats,
+    ) == [min(depth, 3) - 1]  # the dummy grid is 3 stages deep at most
+    cfg["agent"]["num_levels"] = 2
+    assert make_trainer(cfg).scheduler.net.num_levels == 2

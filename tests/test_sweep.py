@@ -17,6 +17,8 @@ count; (e) the sweep's per-episode average JCT equals what
 `collect_sync` (the `core.step` engine) leaves in its final state; and
 every `Scheduler` runs through the loop."""
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -389,7 +391,7 @@ def test_every_scheduler_runs_through_the_loop(small, name):
         sched = DecimaScheduler(
             num_executors=EXECUTORS, num_levels=params.max_stages)
         if name == "greedy":
-            policy = sched.flat_batch_policy(deterministic=True)
+            policy = partial(sched.batch_policy, deterministic=True)
     out = sweep.run(params, bank, sched, policy=policy, episodes=3, lanes=3,
                     seed=5, rows=CHUNK, max_chunks=80)
     assert (out["jobs_completed"] == JOBS).all()

@@ -494,6 +494,54 @@ def test_sweep_chunk_carries_no_table_as_scalars(flagship, one_chip):
                for line in text.split("\n"))
 
 
+def test_sweep_chunk_under_decima_is_one_net_in_the_loop_over_blocks(
+    flagship, one_chip
+):
+    """PR 49: `sweep_chunk` under `config/sweep_decima_demo.yaml` (10
+    executors, 50 jobs, the Decima net sampled, its weights an
+    ARGUMENT) at 2,048 lanes compiles for the v5e and fits with room:
+    the compiled program holds the net's level loop ONCE, inside the
+    loop over blocks inside the rows' loop, beside the one drain
+    `while`; its temporaries stay under a tenth of what one evaluation
+    over all 2,048 lanes' [50, 20, 20] adjacencies and activations
+    would take; and nothing of the weights is a constant of the
+    program."""
+    import jax
+
+    from sparksched_tpu import config, sweep
+
+    lanes = 2048
+    jax.config.update("jax_default_prng_impl", "threefry2x32")
+    try:  # the sweep's own keys; the flagship fixture's back after
+        params, bank, scheduler = sweep.from_config(
+            config.load("config/sweep_decima_demo.yaml"))
+        key = jax.random.PRNGKey(0)
+        carry = jax.eval_shape(
+            lambda k: sweep.init(params, bank, k, lanes), key)
+        compiled = sweep.sweep_chunk.lower(
+            params, _on(one_chip, bank), scheduler.batch_policy,
+            _on(one_chip, carry), _on(one_chip, key), 16,
+            _on(one_chip, scheduler.params),
+        ).compile()
+    finally:
+        jax.config.update("jax_default_prng_impl", "rbg")
+    assert (params.num_executors, params.max_jobs) == (10, 50)
+    _fits(compiled, temp_gib=0.6)
+    text = compiled.as_text()
+    rows, blocks = "jit(_chunk)/while", "/body/closed_call/while"
+    levels = _loops(text, "decima/gnn/levels/while")
+    assert len(levels) == 1
+    assert f'op_name="{rows}{blocks}/body/closed_call/sweep/policy/' in (
+        levels[0])
+    assert len(_loops(text, rows)) == len(_loops(text, rows + blocks)) == 1
+    assert len(_loops(text, "env/micro_step/drain)/while")) == 1
+    # the weights come in as arguments: no constant of a Dense kernel's
+    # size ([64, 64] float32 is the largest)
+    assert "f32[64,64]" in text.split("ENTRY")[1].split("\n")[0]
+    assert not [line for line in text.split("\n")
+                if " constant(" in line and "f32[64,64]" in line]
+
+
 def test_blocked_drain_leaves_the_sampler_its_layout(
     flagship, one_chip, tmp_path
 ):
