@@ -385,7 +385,7 @@ def parity_phase(trainer) -> None:
     params, bank = trainer.params_env, trainer.bank
     T = PARITY_DECISIONS
 
-    def det_sampler(params, bank, rng, template, stage, num_local,
+    def det_sampler(params, bank, rng, facts, template, stage, num_local,
                     task_valid, same_stage):
         return (bank.rough_duration[template, stage]
                 + jnp.where(task_valid & same_stage, 7.0, 131.0)

@@ -6,7 +6,7 @@ from sparksched_tpu.config import EnvParams, enable_compilation_cache
 from sparksched_tpu.env import core
 
 # ablation: cheap deterministic sampler (one gather, no rng)
-def cheap_sampler(params, bank, rng, template, stage, num_local, task_valid, same_stage):
+def cheap_sampler(params, bank, rng, facts, template, stage, num_local, task_valid, same_stage):
     return bank.rough_duration[template, stage]
 
 import sys

@@ -61,7 +61,7 @@ LANES = 8
 SEED = 3
 
 
-def _det_sampler(params, bank, rng, template, stage, num_local,
+def _det_sampler(params, bank, rng, facts, template, stage, num_local,
                  task_valid, same_stage):
     """Deterministic stand-in for sample_task_duration (the fixture trick
     tests/test_flat_loop.py uses): distinct per continuation kind and

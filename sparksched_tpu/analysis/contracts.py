@@ -87,6 +87,7 @@ ENV_STATE_SCHEMA: dict[str, tuple[str, tuple]] = {
     "unsat_parent_count": ("int32", ("J", "S")),
     "incomplete_parent_count": ("int32", ("J", "S")),
     "parent_sets": ("uint32", ("J", "W", "S")),
+    "duration_facts": ("uint32", ("J", "S")),
     "node_level": ("int32", ("J", "S")),
     "commit_count": ("int32", ("J", "S")),
     "moving_count": ("int32", ("J", "S")),

@@ -228,6 +228,36 @@ class Budget:
 # is no count: `reads_of_shape` over the pass's and the sampler's
 # jaxprs (tests/test_static_analysis.py). The chip rows are PERF.md,
 # PR 47.
+#
+# Re-pinned 2026-10-04 (PR 50: the sampler takes a stage's word of
+# `EnvState.duration_facts`, a new u32[J,S] leaf packed at reset from
+# the bank, `sampling.pack_duration_facts`, where it gathered from
+# `bank.level_present` and `bank.max_present` and read three elements
+# of `bank.cnt` and picked one; the fused pass's step picks the word,
+# `rem[tj, ts]`, `jcnt[tj]` and `job_template[tj]` with the one-hots
+# it builds for its updates). Eqns / gathers before -> after, for
+# every sampler call a program traces four bank reads fewer and one
+# read of the word more (in the fused pass's two steps four indexed
+# reads of the lane's own state fewer besides), and in every program
+# that holds a reset the pack and one gather of its rows: micro_step
+# 4413/23 -> 4430/21, decide_micro_step 2497/19 -> 2502/16,
+# drain_to_decision 3019/4 -> 3041/5 (one lane: the sampler's and the
+# step's reads are dynamic slices, the reset's row gather is the one
+# more), serve_decide 6693/27/66 -> 6710/24/67 (its record and ring
+# variants the same +17, -3 and one scatter more: the leaf's write
+# back to the store), serve_decide_batch 15410/246/66 -> 15529/224/67
+# (its group, record, ring and sharded variants alike),
+# flat_collect_batch 15082/184 -> 15195/161, flat_collect_batch_health
+# 15351/184 -> 15464/161, sweep_chunk 14786/179 -> 14916/157; observe,
+# the net's and the update's as they were. Every count inside its
+# band; the gather caps follow the rule (measured x 1.35, at least
+# measured + 2): micro_step 32 -> 28, decide_micro_step 26 -> 22,
+# drain_to_decision 6 -> 7, serve_decide and its record and ring
+# variants 37 -> 33, the batch serve programs 333 -> 303 (the ring one
+# 334 -> 304), the two collectors 249 -> 218, sweep_chunk 242 -> 212.
+# What the change is for is no count: `loop_row_reads` over the pass's
+# jaxpr (tests/test_static_analysis.py). The chip rows are PERF.md,
+# PR 50.
 # ---------------------------------------------------------------------------
 
 BUDGETS: dict[str, Budget] = {
@@ -242,12 +272,12 @@ BUDGETS: dict[str, Budget] = {
     # of work (the while is the fused event run's early-exit loop, not
     # a decision loop)
     "micro_step": Budget(
-        eqn_lo=2000, eqn_hi=5500, gather_hi=32, scatter_hi=3,
+        eqn_lo=2000, eqn_hi=5500, gather_hi=28, scatter_hi=3,
         while_whole_read_elems=AUDIT_ADJ_ELEMS,
     ),
     # the single-eval collectors' policy-bearing micro-step
     "decide_micro_step": Budget(
-        eqn_lo=1000, eqn_hi=3350, gather_hi=26, scatter_hi=2,
+        eqn_lo=1000, eqn_hi=3350, gather_hi=22, scatter_hi=2,
         loop_free=True,
     ),
     # the single-eval collectors' non-policy drain (while-loop by
@@ -255,7 +285,7 @@ BUDGETS: dict[str, Budget] = {
     # ISSUE-7 restructure keeps its cond to the event existence bit
     # and drops the per-iteration full-pytree rollback select)
     "drain_to_decision": Budget(
-        eqn_lo=1200, eqn_hi=3450, gather_hi=6, scatter_hi=3,
+        eqn_lo=1200, eqn_hi=3450, gather_hi=7, scatter_hi=3,
         while_whole_read_elems=AUDIT_ADJ_ELEMS,
     ),
     # Decima stage/exec scores over a [B]-stacked feature set, both
@@ -280,7 +310,7 @@ BUDGETS: dict[str, Budget] = {
     # what makes this CPU audit valid for the sharded configuration;
     # the HLO-level collective census lives in tests/test_parallel.py.
     "flat_collect_batch": Budget(
-        eqn_lo=9000, eqn_hi=16900, gather_hi=249, scatter_hi=25,
+        eqn_lo=9000, eqn_hi=16900, gather_hi=218, scatter_hi=25,
     ),
     # ISSUE 9: the `health:`-on variants of the two production
     # programs. Pinned 2026-08-03 — ppo_update_health 3209/43/3 (the
@@ -294,7 +324,7 @@ BUDGETS: dict[str, Budget] = {
         eqn_lo=1000, eqn_hi=4350, gather_hi=60, scatter_hi=5,
     ),
     "flat_collect_batch_health": Budget(
-        eqn_lo=9000, eqn_hi=17200, gather_hi=249, scatter_hi=27,
+        eqn_lo=9000, eqn_hi=17200, gather_hi=218, scatter_hi=27,
     ),
     # PR 46: the sweep loop's chunk under the fair heuristic, health
     # on (sweep.py): the collectors' decide and drain with the re-seed
@@ -304,7 +334,7 @@ BUDGETS: dict[str, Budget] = {
     # whose net and stores it lacks, because the reset program runs
     # inside the scan (the bank's gathers are its)
     "sweep_chunk": Budget(
-        eqn_lo=9000, eqn_hi=16600, gather_hi=242, scatter_hi=4,
+        eqn_lo=9000, eqn_hi=16600, gather_hi=212, scatter_hi=4,
     ),
     # ISSUE 10: the AOT decision-serving programs (serve/aot.py),
     # pinned 2026-08-04 — serve_decide 6514/33/65, serve_decide_batch
@@ -315,11 +345,11 @@ BUDGETS: dict[str, Budget] = {
     # `drain_to_decision` (the inter-decision drain, by design); the
     # scan is the GNN level pass + the bulk event kernel.
     "serve_decide": Budget(
-        eqn_lo=3000, eqn_hi=8800, gather_hi=37, scatter_hi=88,
+        eqn_lo=3000, eqn_hi=8800, gather_hi=33, scatter_hi=88,
         while_whole_read_elems=AUDIT_ADJ_ELEMS,
     ),
     "serve_decide_batch": Budget(
-        eqn_lo=6000, eqn_hi=17400, gather_hi=333, scatter_hi=88,
+        eqn_lo=6000, eqn_hi=17400, gather_hi=303, scatter_hi=88,
     ),
     # ISSUE 13: the dp-sharded store variant (serve/aot.py
     # `serve_decide_batch_fn(..., shard=...)`), pinned 2026-08-04 —
@@ -332,7 +362,7 @@ BUDGETS: dict[str, Budget] = {
     # re-measured byte-identical, which is the acceptance bar (shard
     # off must change nothing).
     "serve_decide_batch_sharded": Budget(
-        eqn_lo=6000, eqn_hi=17500, gather_hi=333, scatter_hi=88,
+        eqn_lo=6000, eqn_hi=17500, gather_hi=303, scatter_hi=88,
     ),
     # ISSUE 14: the record-on serve variants (serve/aot.py
     # `record=True` — the online trajectory path's programs), pinned
@@ -347,10 +377,10 @@ BUDGETS: dict[str, Budget] = {
     # NO count on any serve program — params enter as invars, the
     # traced computation is the same.
     "serve_decide_record": Budget(
-        eqn_lo=3000, eqn_hi=8810, gather_hi=37, scatter_hi=88,
+        eqn_lo=3000, eqn_hi=8810, gather_hi=33, scatter_hi=88,
     ),
     "serve_decide_batch_record": Budget(
-        eqn_lo=6000, eqn_hi=17410, gather_hi=333, scatter_hi=88,
+        eqn_lo=6000, eqn_hi=17410, gather_hi=303, scatter_hi=88,
     ),
     # ISSUE 15: the GROUP-shaped serve program (the pipelined store's
     # [hot_capacity/groups] lowering — serve/aot.py
@@ -363,7 +393,7 @@ BUDGETS: dict[str, Budget] = {
     # byte-identical in the same PR (the take_slot/write_slot
     # refactor moved code, not equations).
     "serve_decide_batch_group": Budget(
-        eqn_lo=6000, eqn_hi=17400, gather_hi=333, scatter_hi=88,
+        eqn_lo=6000, eqn_hi=17400, gather_hi=303, scatter_hi=88,
     ),
     # ISSUE 18: the ring-recording serve programs (serve/aot.py
     # `serve_decide_ring_fn` / `serve_decide_batch_ring_fn` — the
@@ -380,10 +410,10 @@ BUDGETS: dict[str, Budget] = {
     # re-measured BYTE-IDENTICAL in the same PR — the zero-cost-off
     # acceptance bar.
     "serve_decide_record_ring": Budget(
-        eqn_lo=3000, eqn_hi=8980, gather_hi=37, scatter_hi=117,
+        eqn_lo=3000, eqn_hi=8980, gather_hi=33, scatter_hi=117,
     ),
     "serve_decide_batch_record_ring": Budget(
-        eqn_lo=6000, eqn_hi=17550, gather_hi=334, scatter_hi=117,
+        eqn_lo=6000, eqn_hi=17550, gather_hi=304, scatter_hi=117,
     ),
 }
 
@@ -474,6 +504,34 @@ def reads_of_shape(jaxpr, shape: tuple[int, ...]) -> list[str]:
         for v in eqn.invars
         if getattr(getattr(v, "aval", None), "shape", None) == shape
     ]
+
+
+def loop_row_reads(jaxpr, operands, _in_loop: bool = False
+                   ) -> list[str]:
+    """The row reads (`ROW_READ_PRIMS`: a gather under `vmap`, a
+    dynamic slice in one lane) inside a `while` (its body or its
+    predicate, at any depth) from an operand that is one of
+    `operands` by shape and dtype (arrays or shape structs: a bank's
+    leaves), as `primitive(dtype[shape])`. With a bank's leaves it
+    says what the loop reads of the bank, and how often (PR 50: the
+    fused bulk pass's early-exit loop reads `cnt`, `dur` and
+    `rough_duration` once a step and nothing of `level_present` or
+    `max_present`, which the stage's word of `EnvState.duration_facts`
+    holds)."""
+    want = {(tuple(o.shape), str(o.dtype)) for o in operands}
+    found = []
+    for eqn in jaxpr.eqns:
+        inside = _in_loop or eqn.primitive.name == "while"
+        for sub in _sub_jaxprs(eqn):
+            found += loop_row_reads(sub, operands, inside)
+        if _in_loop and eqn.primitive.name in ROW_READ_PRIMS:
+            aval = eqn.invars[0].aval
+            if (tuple(aval.shape), str(aval.dtype)) in want:
+                found.append(
+                    f"{eqn.primitive.name}"
+                    f"({aval.dtype}{list(aval.shape)})"
+                )
+    return found
 
 
 def count_eqns(jaxpr) -> int:

@@ -271,8 +271,13 @@ def use_fast_prng() -> None:
     per draw; the simulator's hot loop draws several keys per
     micro-step (reset keys, task-duration samples), so on an op-count
     bound engine the RNG alone is a measurable slice of every step
-    (jaxpr census: sample_task_duration is ~200 eqns, ~180 of them
-    threefry). ``rbg`` lowers to a single XLA RngBitGenerator op.
+    (jaxpr census of the sampler while it drew its own keys:
+    sample_task_duration was ~200 eqns, ~180 of them threefry; since
+    its callers hand it pre-drawn uniforms it holds no draw, and since
+    PR 50 it is 140 equations and three row reads of the bank in one
+    lane, 167 and three gathers under `vmap`: the draws are the
+    callers', one table a bulk pass). ``rbg`` lowers to a single XLA
+    RngBitGenerator op.
 
     Trade-off: rbg's split/fold_in are statistically weaker than
     threefry's, which is irrelevant for workload sampling. Keys from

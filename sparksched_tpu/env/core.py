@@ -30,7 +30,11 @@ from jax import lax
 from ..config import EnvParams
 from ..obs.telemetry import add as _tm_add
 from ..workload.bank import WorkloadBank
-from ..workload.sampling import sample_job_sequence, sample_task_duration
+from ..workload.sampling import (
+    pack_duration_facts,
+    sample_job_sequence,
+    sample_task_duration,
+)
 from .state import (
     BIG_SEQ,
     EV_EXECUTOR_READY,
@@ -262,7 +266,8 @@ def _apply_action(
     tpl = state.job_template[tj]
     num_local = (state.exec_job == tj).sum()
     dur = sample_task_duration(
-        params, bank, jax.random.uniform(sub, (2,)), tpl, ts, num_local,
+        params, bank, jax.random.uniform(sub, (2,)),
+        state.duration_facts[tj, ts], tpl, ts, num_local,
         state.exec_task_valid[e], state.exec_task_stage[e] == ts,
     )
 
@@ -582,10 +587,10 @@ def _bulk_fulfill(
     tv = state.exec_task_valid[e]
     ss_same = state.exec_task_stage[e] == ds0
     durs = jax.vmap(
-        lambda u2, tp, s_, nl_, tv_, sm_: sample_task_duration(
-            params, bank, u2, tp, s_, nl_, tv_, sm_,
+        lambda u2, f_, tp, s_, nl_, tv_, sm_: sample_task_duration(
+            params, bank, u2, f_, tp, s_, nl_, tv_, sm_,
         )
-    )(us, tpl, dsc, nl, tv, ss_same)
+    )(us, state.duration_facts[djc, dsc], tpl, dsc, nl, tv, ss_same)
 
     inc = (start | send).astype(_i32)
     seq_k = state.seq_counter + (earlier & (inc[None, :] > 0)).sum(-1)
@@ -1123,9 +1128,10 @@ def _bulk_relaunch(
     # before; independent uniforms now — sample_task_duration docstring)
     us = jax.random.uniform(sub, (max_events * n, 2))
     e_rep = jnp.tile(pos, max_events)
+    facts = state.duration_facts[jc, sc]
     dur_table = jax.vmap(
         lambda u2, e: sample_task_duration(
-            params, bank, u2, tpl[e], sc[e], num_local[e],
+            params, bank, u2, facts[e], tpl[e], sc[e], num_local[e],
             jnp.bool_(True), jnp.bool_(True),
         )
     )(us, e_rep).reshape(max_events, n)
@@ -1325,10 +1331,10 @@ def _bulk_ready(
     tv = state.exec_task_valid[jnp.clip(e, 0, n - 1)]
     ss_same = state.exec_task_stage[jnp.clip(e, 0, n - 1)] == ds0
     durs = jax.vmap(
-        lambda u2, tp, s_, nl_, tv_, sm_: sample_task_duration(
-            params, bank, u2, tp, s_, nl_, tv_, sm_,
+        lambda u2, f_, tp, s_, nl_, tv_, sm_: sample_task_duration(
+            params, bank, u2, f_, tp, s_, nl_, tv_, sm_,
         )
-    )(us, tpl, dsc, nl, tv, ss_same)
+    )(us, state.duration_facts[djc, dsc], tpl, dsc, nl, tv, ss_same)
     fin_k = to + durs
 
     before_star = (to < t_star) | ((to == t_star) & (so < seq_star))
@@ -1623,7 +1629,10 @@ def _bulk_events_fused(
     op chains per micro-step — every scan step picks the lexicographic
     (time, seq) minimum over ALL pending finishes and arrivals,
     classifies it against the live remaining-task view, and applies it.
-    One rng split, one duration-sampling chain per consumed event, and
+    One rng split, one duration-sampling chain per consumed event (of
+    the bank it reads three elements, the chosen bucket's count, the
+    picked sample and the stage's rough duration; what no draw decides
+    is the stage's word of `state.duration_facts`), and
     after the loop one merged state update: the [J,S] counters of the
     consumed arrivals as one-hot sums over the executors, and the
     saturation caches (`stage_sat`, `unsat_parent_count`) refreshed at
@@ -1751,10 +1760,19 @@ def _bulk_events_fused(
             is_fin, fcand & (sq_f == fsmin), acand & (sq_a == asmin)
         )
 
-        # the winner's target stage on the LIVE views
+        # the winner's target stage on the LIVE views. What the step
+        # reads of its target in the lane's own arrays (the stage's
+        # remaining tasks and word of duration facts, the job's
+        # executor count and template) it picks with the one-hots its
+        # updates need anyway: under `vmap` an indexed read is a
+        # gather, serialised on the TPU (1.5 to 2.2 us for 128 lanes
+        # where a select-reduce fuses with its neighbours), and each
+        # brought a relayout of its index column with it
         tj = jnp.where(is_fin, pick_i(e_oh, fj), pick_i(e_oh, djc))
         ts = jnp.where(is_fin, pick_i(e_oh, fs), pick_i(e_oh, dsc))
-        rem_t = rem[tj, ts]
+        oh_j = _onehot(j_cap, tj)
+        oh2 = _onehot2(j_cap, s_cap, tj, ts)
+        rem_t = pick_i(oh2, rem)
         ok = active & has & before_job & (rem_t > 0)
         if stop_at_limit:
             ok = ok & ~crossed
@@ -1770,21 +1788,22 @@ def _bulk_events_fused(
 
         # duration for the launched task (relaunch: same-stage
         # continuation; arrival: the sequential wave inputs)
-        nl = jcnt[tj] + is_arr.astype(_i32)  # arrival counts itself
+        # (an arrival counts itself among the job's executors)
+        nl = pick_i(oh_j, jcnt) + is_arr.astype(_i32)
         tv = jnp.where(is_fin, True, (e_oh & tv_a).any())
         ss = jnp.where(is_fin, True, (e_oh & ss_a).any())
         dur = sample_task_duration(
-            params, bank, u2, state.job_template[tj], ts, nl, tv, ss
+            params, bank, u2, pick_i(oh2, state.duration_facts),
+            pick_i(oh_j, state.job_template), ts, nl, tv, ss,
         )
 
-        oh2 = _onehot2(j_cap, s_cap, tj, ts)
         t_f = jnp.where(launch & e_oh, tmin + dur, t_f)
         sq_f = jnp.where(launch & e_oh, counter, sq_f)
         t_a = jnp.where(is_arr & e_oh, INF, t_a)
         fj = jnp.where(is_arr & start_a & e_oh, tj, fj)
         fs = jnp.where(is_arr & start_a & e_oh, ts, fs)
         rem = rem - (launch & oh2).astype(_i32)
-        jcnt = jcnt + (is_arr & _onehot(j_cap, tj)).astype(_i32)
+        jcnt = jcnt + (is_arr & oh_j).astype(_i32)
         launch_t = launch_t | (launch & oh2)
         dur_js = jnp.where(launch & oh2, dur, dur_js)
         relc = relc + (is_rel & oh2).astype(_i32)
@@ -2117,6 +2136,7 @@ def reset_from_sequence(
         unsat_parent_count=unsat0,
         incomplete_parent_count=ipc0,
         parent_sets=pack_parents(adj),
+        duration_facts=pack_duration_facts(bank)[templates],
         node_level=topo_levels(exists, adj),
         time_limit=time_limit,
         seq_counter=num_jobs,

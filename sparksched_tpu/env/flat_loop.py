@@ -951,7 +951,14 @@ def drain_to_decision(
     [lanes,J,S] arrays at 8 to 10 us) and 26 us for each step of the
     fused bulk pass's early-exit loop, which runs as many steps as the
     longest run among the lanes (19 on average, of a budget of 58;
-    PERF.md section 5, PR 45's count).
+    PERF.md section 5, PR 45's count). Since PR 50 a step costs 8 us
+    (an iteration of two 15.7 us at a job axis of 50 and 16.7 at 20,
+    from 39: the step reads the bank for three elements and the lane's
+    own state by the one-hots it builds, where it made nine gathers),
+    so the loop is a quarter to a half of a body and the part spent
+    whatever the lanes hold the larger one (276 of 384 us in
+    `sweep_fair`, 168 of 318 in `decima_batch20`; PERF.md section 5,
+    PR 50).
     Nothing in the body reads the [J,S,S] adjacency whole: the pass's
     refresh of the saturation caches counts on `EnvState.parent_sets`
     (until PR 39 a contraction over the adjacency, 181 us a body).

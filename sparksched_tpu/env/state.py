@@ -135,6 +135,16 @@ class EnvState(struct.PyTreeNode):
     # of `unsat_parent_count` counts flipped parents on it instead of
     # contracting the whole [J,S,S] adjacency in every drain body
     parent_sets: jnp.ndarray  # u32[J,W,S]; bit p%32 of [j,p//32,c] = adj[j,p,c]
+    # what the duration sampler reads of a (job, stage) that no draw
+    # decides, one word each (`sampling.pack_duration_facts` of the
+    # bank, the rows of the episode's templates): bits 0 to 7 the
+    # executor levels the stage has first-wave samples at, bits 8 to
+    # 31 which of its 3 x 8 (wave, level) buckets hold a sample.
+    # Written where `job_template` is, at reset; a fact of the
+    # episode, so loop-invariant in every drain, where the fused bulk
+    # pass's step picks its stage's word with the one-hot over [J,S]
+    # it builds for its updates and reads no bank table for it
+    duration_facts: jnp.ndarray  # u32[J,S]
 
     # --- incremental node-level cache [J,S] ---
     # per-job topological generations over the job's existing, incomplete
@@ -364,6 +374,7 @@ def empty_state(params: EnvParams, rng: jax.Array) -> EnvState:
         parent_sets=jnp.zeros(
             (j, -(-s // STAGE_SET_BITS), s), jnp.uint32
         ),
+        duration_facts=jnp.zeros((j, s), jnp.uint32),
         node_level=jnp.full((j, s), s, i32),
         commit_count=jnp.zeros((j, s), i32),
         moving_count=jnp.zeros((j, s), i32),

@@ -19,8 +19,10 @@ all environments:
   masks driving the same fallback chain as the reference's
   try/except sampling (tpch.py:75-106).
 
-Sampling a duration on-device is then two integer gathers and one
-`jax.random.randint` — no host round trip.
+Sampling a duration on-device is then three element reads of the bank
+(a bucket's count, the picked sample, the stage's rough duration) beside
+one word of the lane's own state and two pre-drawn uniforms
+(`sampling.sample_task_duration`) — no host round trip.
 """
 
 from __future__ import annotations
@@ -60,6 +62,13 @@ class WorkloadBank(struct.PyTreeNode):
     # --- durations ---
     dur: jnp.ndarray  # f32[T,S,3,L,K]
     cnt: jnp.ndarray  # i32[T,S,3,L]
+    # `level_present` and `cnt > 0` are read at RESET only, packed one
+    # word a (template, stage) into `EnvState.duration_facts`
+    # (`sampling.pack_duration_facts`): no loop of the engine reads
+    # either, nor `max_present`, which is the highest set bit of
+    # `level_present` (0 where none) and is derived there from the word.
+    # A bank rebuilt with `bank.replace(cnt=..., level_present=...)`
+    # needs no other leaf brought in line
     level_present: jnp.ndarray  # bool[T,S,L]; key present in first_wave
     max_present: jnp.ndarray  # i32[T,S]; index of max present level
 

@@ -14,9 +14,11 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..config import EnvParams
 from .bank import (
+    NUM_EXEC_LEVELS,
     WAVE_FIRST,
     WAVE_FRESH,
     WAVE_REST,
@@ -100,22 +102,59 @@ def executor_interval(
     return left_v, right_v, left_i, right_i
 
 
+# a stage's duration facts in one word (`EnvState.duration_facts`):
+# bit l is `bank.level_present[t, s, l]`, bit 8 + 8 w + l is
+# `bank.cnt[t, s, w, l] > 0` (three waves, eight levels)
+_FACTS_BUCKET_SHIFT = NUM_EXEC_LEVELS
+assert _FACTS_BUCKET_SHIFT + 3 * NUM_EXEC_LEVELS <= 32
+
+
+def pack_duration_facts(bank: WorkloadBank) -> jnp.ndarray:
+    """u32[T,S]: what the duration sampler reads of a (template, stage)
+    that no draw decides, one word each: which executor levels the
+    stage has first-wave samples at (`bank.level_present`, bits 0 to
+    7) and which of its 3 x 8 (wave, level) buckets hold a sample
+    (`bank.cnt > 0`, bit 8 + 8 w + l). `bank.max_present` is the
+    highest set bit of the first byte, 0 where none (`pack_bank`).
+
+    Computed IN the program from the bank it is handed, at reset,
+    where a job's template is written (`core.reset_from_sequence`
+    gathers a job's row into `EnvState.duration_facts`), and no leaf
+    of the bank: a caller that rebuilds `cnt` or `level_present`
+    (`bank.replace(...)`, as the benchmark's sweep drivers do for
+    their comparison) hands the program a bank whose facts follow."""
+    t, s = bank.level_present.shape[:2]
+    # the bit axis leading and (template, stage) flat and minor-most:
+    # every intermediate is whole tiles (with the 3 x 8 buckets minor
+    # a [T,S,3,8] boolean pads to 12.6 MB of tiles)
+    bits = jnp.concatenate([
+        jnp.moveaxis(bank.level_present, -1, 0).reshape(-1, t * s),
+        jnp.moveaxis(bank.cnt, (2, 3), (0, 1)).reshape(-1, t * s) > 0,
+    ])  # bool[32, T*S]: the levels, then the buckets wave-major
+    place = jnp.arange(bits.shape[0], dtype=jnp.uint32)[:, None]
+    return (bits.astype(jnp.uint32) << place).sum(
+        0, dtype=jnp.uint32
+    ).reshape(t, s)
+
+
 def sample_executor_key(
-    params: EnvParams, bank: WorkloadBank, u: jnp.ndarray,
-    template: jnp.ndarray, stage: jnp.ndarray, num_local: jnp.ndarray
+    params: EnvParams, facts: jnp.ndarray, u: jnp.ndarray,
+    num_local: jnp.ndarray
 ) -> jnp.ndarray:
     """Map the executor count to a trace executor-level index, randomly
     interpolating between the two bracketing levels and falling back to the
     max level present for this stage (reference tpch.py:216-235). The
     bracketing levels are a static function of `params.num_executors`
-    (`executor_interval`); the bank is read for the stage's present
-    levels alone.
+    (`executor_interval`); the stage's present levels are the first
+    byte of its word of duration facts (`pack_duration_facts`), the
+    highest of them its highest set bit: no bank table is read.
 
     `u` is a pre-drawn Uniform[0,1) scalar, NOT a PRNG key: the round-5
     CPU decomposition measured the per-call rng plumbing (fold_in +
     split + uniform + randint per sampled task) at ~31% of the whole
     flat micro-step, while the bank-table gathers were free (on the
-    CPU: on the chip the four interval tables were not, PR 47). Callers
+    CPU: on the chip the four interval tables were not, PR 47, nor the
+    reads of `level_present` and `max_present`, PR 50). Callers
     draw ONE batched uniform array per bulk pass and hand each row's
     slice down (see `sample_task_duration`)."""
     left_v, right_v, left_i, right_i = executor_interval(
@@ -128,14 +167,19 @@ def sample_executor_key(
     # the reference's interval table leaves index num_executors zeroed when
     # num_executors > 100 (tpch.py:258-260 excludes it); a 0 "level" is not
     # a first_wave key there, so it falls through to the max present level
-    present = bank.level_present[template, stage, key_idx] & (key_val > 0)
-    return jnp.where(present, key_idx, bank.max_present[template, stage])
+    levels = facts & jnp.uint32((1 << NUM_EXEC_LEVELS) - 1)
+    present = ((levels >> key_idx.astype(jnp.uint32)) & 1).astype(bool) & (
+        key_val > 0
+    )
+    max_present = jnp.maximum(31 - lax.clz(levels).astype(jnp.int32), 0)
+    return jnp.where(present, key_idx, max_present)
 
 
 def sample_task_duration(
     params: EnvParams, bank: WorkloadBank, u2: jnp.ndarray,
-    template: jnp.ndarray, stage: jnp.ndarray, num_local: jnp.ndarray,
-    task_valid: jnp.ndarray, same_stage: jnp.ndarray
+    facts: jnp.ndarray, template: jnp.ndarray, stage: jnp.ndarray,
+    num_local: jnp.ndarray, task_valid: jnp.ndarray,
+    same_stage: jnp.ndarray
 ) -> jnp.ndarray:
     """Sample one task duration, reproducing the reference's wave logic and
     try/except fallback chains (tpch.py:75-106):
@@ -149,6 +193,17 @@ def sample_task_duration(
     A final fallback to the stage's rough mean duration replaces the
     reference's uncaught exception when a bucket is entirely empty.
 
+    `facts` is the stage's word of `EnvState.duration_facts`
+    (`pack_duration_facts` of the bank, row `template`, `stage`): the
+    executor level and the wave are chosen from it alone, and the bank
+    is read for what a draw decides: ONE element of `cnt` (the chosen
+    bucket's size), ONE of `dur` (the pick) and the stage's rough
+    duration. Until PR 50 the level read `level_present` and
+    `max_present` and the wave three elements of `cnt`: on the chip a
+    gather is serialised (12 ns an element, 1.5 to 2.2 us for 128
+    lanes), and those reads were the heaviest operations of the fused
+    bulk pass's early-exit loop (PERF.md, PR 50).
+
     `u2` is f32[2] of pre-drawn Uniform[0,1) variates (NOT a key):
     u2[0] drives the executor-level interpolation, u2[1] the
     within-bucket pick. Hot callers (`_apply_action` and the three bulk
@@ -157,12 +212,14 @@ def sample_task_duration(
     on the CPU backend (round-5 ablation), with identical per-row
     distributions (rows were independently keyed before, independent
     uniforms now; `pick = floor(u*n)` matches randint's law)."""
-    li = sample_executor_key(
-        params, bank, u2[0], template, stage, num_local
-    )
+    li = sample_executor_key(params, facts, u2[0], num_local)
 
-    cnt = bank.cnt[template, stage, :, li]  # i32[3]
-    has = cnt > 0
+    # does the level's bucket of each wave hold a sample (`cnt > 0`)
+    at_level = facts >> (_FACTS_BUCKET_SHIFT + li).astype(jnp.uint32)
+    has = [
+        ((at_level >> (NUM_EXEC_LEVELS * w)) & 1).astype(bool)
+        for w in range(3)
+    ]
     fresh_i, first_i, rest_i = WAVE_FRESH, WAVE_FIRST, WAVE_REST
 
     # wave choice + warmup flag per the chains above
@@ -178,7 +235,8 @@ def sample_task_duration(
     )
     warm = jnp.where(~task_valid, idle_warm, False)
 
-    n = jnp.maximum(cnt[wave], 1)
+    cnt = bank.cnt[template, stage, wave, li]
+    n = jnp.maximum(cnt, 1)
     pick = jnp.minimum((u2[1] * n).astype(jnp.int32), n - 1)
     dur = bank.dur[template, stage, wave, li, pick]
     if dur.dtype != jnp.float32:
@@ -190,7 +248,5 @@ def sample_task_duration(
         dur = dur.astype(jnp.float32)
         if bank.dur_scale is not None:
             dur = jnp.expm1(dur * bank.dur_scale[template])
-    dur = jnp.where(
-        cnt[wave] > 0, dur, bank.rough_duration[template, stage]
-    )
+    dur = jnp.where(cnt > 0, dur, bank.rough_duration[template, stage])
     return dur + jnp.where(warm, params.warmup_delay, 0.0)

@@ -3,7 +3,9 @@ before its loop: the frontier bits of the arrivals' destinations, read
 from the frontier packed over its stage axis (`core._frontier_at`)
 where a gather of one element an executor read them, and the pass's
 uniform table, one pair a step where it held a pair a step and
-executor."""
+executor. PR 47: the sampler's executor-level interval, computed.
+PR 50: a stage's duration facts, one word a (job, stage) of the state
+(`EnvState.duration_facts`), against the bank's tables it packs."""
 
 from __future__ import annotations
 
@@ -170,9 +172,9 @@ def _recorded_passes(params, bank, envs, on, max_events):
     run as the fixed scan over its own `step_fn`, with what each step
     did handed out beside the pass's result: whether it launched a
     task, the duration the launch stored, the pair the step was
-    handed, what `sample_task_duration` was asked beside it (template,
-    stage, executors on the job, task valid, same stage), and the
-    table as the pass drew it."""
+    handed, what `sample_task_duration` was asked beside it (the
+    stage's word of duration facts, template, stage, executors on the
+    job, task valid, same stage), and the table as the pass drew it."""
     import jax
     import jax.numpy as jnp
 
@@ -247,8 +249,17 @@ def test_pass_hands_a_step_its_own_pair_and_durations_keep_their_law(
     (after, k_rel, k_rdy, _), steps = _recorded_passes(
         params, bank, envs, on, max_events
     )
-    launched, dur, u, tmpl, stage, nl, tv, ss, table = (
+    launched, dur, u, facts, tmpl, stage, nl, tv, ss, table = (
         np.asarray(x) for x in steps
+    )
+    # the word the step picked by its one-hot over [J,S] is the one of
+    # the (template, stage) it asked the bank's tables for
+    from sparksched_tpu.workload.sampling import pack_duration_facts
+
+    np.testing.assert_array_equal(
+        facts[launched],
+        np.asarray(pack_duration_facts(bank))[
+            tmpl[launched], stage[launched]],
     )
 
     # the table: the lane's own, [length, 2], row i to step i
@@ -280,7 +291,8 @@ def test_pass_hands_a_step_its_own_pair_and_durations_keep_their_law(
         return np.asarray(jax.jit(jax.vmap(
             lambda *a: core.sample_task_duration(params, bank, *a)
         ))(jnp.asarray(u2), *(
-            jnp.asarray(x[launched]) for x in (tmpl, stage, nl, tv, ss)
+            jnp.asarray(x[launched])
+            for x in (facts, tmpl, stage, nl, tv, ss)
         )))
 
     np.testing.assert_array_equal(dur[launched], direct(pairs))
@@ -331,6 +343,67 @@ def _interval_tables(num_executors: int) -> np.ndarray:
 
     itv = _executor_intervals(num_executors)
     return np.concatenate([itv, _to_idx(itv)], axis=1).T.astype(np.int32)
+
+
+def _table_task_duration(
+    itv, params, bank, u2, template, stage, num_local, task_valid,
+    same_stage,
+):
+    """The reference: `sample_task_duration` as it read the bank until
+    PR 50 (and, through `_table_executor_key`, until PR 47): the
+    level's presence from `bank.level_present`, the fallback from
+    `bank.max_present`, the wave from the three counts
+    `bank.cnt[t, s, :, li]`."""
+    import jax.numpy as jnp
+
+    from sparksched_tpu.workload.bank import (
+        WAVE_FIRST, WAVE_FRESH, WAVE_REST,
+    )
+
+    li = _table_executor_key(itv, bank, u2[0], template, stage, num_local)
+    cnt = bank.cnt[template, stage, :, li]  # i32[3]
+    has = cnt > 0
+    idle_wave = jnp.where(has[WAVE_FRESH], WAVE_FRESH, WAVE_FIRST)
+    idle_warm = ~has[WAVE_FRESH]
+    same_wave = jnp.where(
+        has[WAVE_REST], WAVE_REST,
+        jnp.where(has[WAVE_FIRST], WAVE_FIRST, WAVE_FRESH),
+    )
+    diff_wave = jnp.where(has[WAVE_FIRST], WAVE_FIRST, WAVE_FRESH)
+    wave = jnp.where(
+        ~task_valid, idle_wave, jnp.where(same_stage, same_wave, diff_wave)
+    )
+    warm = jnp.where(~task_valid, idle_warm, False)
+    n = jnp.maximum(cnt[wave], 1)
+    pick = jnp.minimum((u2[1] * n).astype(jnp.int32), n - 1)
+    dur = bank.dur[template, stage, wave, li, pick]
+    if dur.dtype != jnp.float32:
+        dur = dur.astype(jnp.float32)
+        if bank.dur_scale is not None:
+            dur = jnp.expm1(dur * bank.dur_scale[template])
+    dur = jnp.where(
+        cnt[wave] > 0, dur, bank.rough_duration[template, stage]
+    )
+    return dur + jnp.where(warm, params.warmup_delay, 0.0)
+
+
+def table_reading_sampler(num_executors: int):
+    """`_table_task_duration` under `sample_task_duration`'s signature
+    (the stage's word of duration facts is taken and not read): what a
+    test patches in for `core.sample_task_duration` /
+    `sampling.sample_task_duration` to run a program as its parent
+    sampled."""
+    import jax.numpy as jnp
+
+    itv = jnp.asarray(_interval_tables(num_executors))
+
+    def sampler(params, bank, u2, facts, *args):
+        assert params.num_executors == num_executors
+        sampler.traced += 1
+        return _table_task_duration(itv, params, bank, u2, *args)
+
+    sampler.traced = 0  # the calls a trace made: that it WAS patched in
+    return sampler
 
 
 @pytest.mark.parametrize("n", [1, 4, 5, 6, 10, 50, 100, 101, 120])
@@ -391,63 +464,278 @@ def test_a_wrong_run_list_fails_the_law(monkeypatch):
     assert list(np.flatnonzero(unequal)) == [6]
 
 
-@pytest.mark.parametrize("n", [10, 50])
-def test_sampled_durations_equal_the_table_reading_sampler_s(
-    monkeypatch, n
-):
-    """`sample_task_duration` over a grid of (template, stage,
-    num_local, task valid, same stage, u2) on the synthetic bank
-    equals, bit for bit, the sampler that read the bank's interval
-    tables (`_table_executor_key`, the old `sample_executor_key`):
-    every num_local in 0..N, every wave chain, stages with and without
-    the level asked for, interpolation draws on both sides of every
-    interval."""
+def _thinned(templates, seed: int):
+    """The templates with levels dropped from every stage's waves at
+    random: some first-wave levels (a stage in eight keeps none),
+    other fresh and rest levels, whole waves now and then. What the
+    sampler's fallback chains are for, and what the TPC-H-like bank
+    (every level present in every wave) never asks of them."""
+    import copy
+
+    rng = np.random.default_rng(seed)
+    out = copy.deepcopy(templates)
+    for tpl in out:
+        for waves in tpl["durations"].values():
+            for name in ("fresh_durations", "first_wave", "rest_wave"):
+                have = sorted(waves.get(name, {}))
+                keep = rng.random(len(have)) < rng.choice(
+                    [0.0, 0.3, 0.6, 1.0], p=[0.125, 0.375, 0.375, 0.125])
+                waves[name] = {
+                    lv: waves[name][lv]
+                    for lv, k in zip(have, keep) if k
+                }
+    return out
+
+
+def sparse_bank(num_executors: int, max_stages: int = 20):
+    """The TPC-H-like templates thinned (`_thinned`), packed: a bank
+    with executor levels and whole waves missing."""
+    from sparksched_tpu.workload import pack_bank
+    from sparksched_tpu.workload.synthetic import make_templates
+
+    return pack_bank(
+        _thinned(make_templates(seed=50, bucket_size=16), 50),
+        num_executors, max_stages, 16,
+    )
+
+
+@pytest.fixture(scope="module")
+def banks():
+    """The banks the word is held on: `tpch` (`make_workload_bank`'s
+    own: the TPC-H traces where they are on disk, else the TPC-H-like
+    bank, 154 templates), `sparse` (its templates thinned, so that
+    levels and whole waves are missing), and each rebuilt as the
+    benchmark's sweep drivers rebuild theirs for the engine comparison
+    (`sweep_chunks.fixed_durations`: `bank.replace(dur, cnt,
+    level_present, dur_scale=None)`, `max_present` left as it was)."""
+    from benchmarks.drivers.sweep_chunks import fixed_durations
+    from sparksched_tpu.workload import make_workload_bank
+
+    tpch = make_workload_bank(10)
+    sparse = sparse_bank(10, tpch.max_stages)
+    return {
+        "tpch": tpch, "sparse": sparse,
+        "tpch_fixed": fixed_durations(tpch)[0],
+        "sparse_fixed": fixed_durations(sparse)[0],
+    }
+
+
+@pytest.mark.parametrize(
+    "name", ["tpch", "sparse", "tpch_fixed", "sparse_fixed"])
+def test_duration_facts_are_the_tables_they_pack(banks, name):
+    """`sampling.pack_duration_facts(bank)` against the bank's tables,
+    for every (template, stage), padding stages too: bits 0 to 7 are
+    `level_present` bit for bit, bit 8 + 8 w + l is `cnt[t, s, w, l] >
+    0`, and the highest set bit of the first byte (`lax.clz`, as the
+    sampler takes it) is `max_present`, 0 where no level is present,
+    on a bank as `pack_bank` makes it. On a bank rebuilt by
+    `bank.replace(...)` the word follows the rebuilt tables (it is
+    computed in the program from the bank handed in), where
+    `bank.max_present` is the old bank's: there the derived one is the
+    rebuilt presence's highest bit, and every level of a bucket holds
+    one duration, so the level is not read (the durations' test
+    below)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from sparksched_tpu.workload.sampling import pack_duration_facts
+
+    bank = banks[name]
+    facts = np.asarray(jax.jit(pack_duration_facts)(bank))
+    present = np.asarray(bank.level_present)
+    cnt = np.asarray(bank.cnt)
+    assert facts.dtype == np.uint32 and facts.shape == present.shape[:2]
+    assert present.shape[2] == 8 and cnt.shape[2:] == (3, 8)
+    for lv in range(8):
+        np.testing.assert_array_equal(
+            (facts >> lv) & 1, present[:, :, lv], err_msg=str(lv))
+        for w in range(3):
+            np.testing.assert_array_equal(
+                (facts >> (8 + 8 * w + lv)) & 1, cnt[:, :, w, lv] > 0,
+                err_msg=str((w, lv)))
+    derived = np.asarray(jnp.maximum(
+        31 - lax.clz(jnp.asarray(facts & 0xFF)).astype(jnp.int32), 0))
+    by_hand = np.where(
+        present.any(-1), 7 - np.argmax(present[:, :, ::-1], -1), 0)
+    np.testing.assert_array_equal(derived, by_hand)
+    if not name.endswith("_fixed"):
+        np.testing.assert_array_equal(
+            derived, np.asarray(bank.max_present))
+    real = np.arange(facts.shape[1]) < np.asarray(bank.num_stages)[:, None]
+    if name == "sparse":
+        # the thinning reaches what it says: stages with no level,
+        # with some, with all; every bucket bit both ways
+        levels = facts[real] & 0xFF
+        assert (levels == 0).sum() > 50 and (levels == 0xFF).sum() > 50
+        assert len(np.unique(levels)) > 100
+        assert np.bitwise_or.reduce(facts[real]) == 0xFFFFFFFF
+        assert np.bitwise_and.reduce(facts[real]) == 0
+    if name == "sparse_fixed":
+        assert set(np.unique(facts[real] & 0xFF)) == {0xFF}
+        assert (np.asarray(bank.max_present)[real] != 7).sum() > 100
+
+
+@pytest.mark.parametrize("how", ["reset", "reseed"])
+def test_a_lane_holds_the_facts_of_its_own_templates(banks, how):
+    """`EnvState.duration_facts` is row `job_template[j]` of the pack,
+    for every job slot (a slot no job arrives in holds its template's
+    too: the tables were read there alike), wherever a job's template
+    is written: `core.reset`, and the streaming re-seed, which gives
+    the lanes that ended a fresh episode's templates and must give
+    them its words, and leave the others' alone."""
     import jax
     import jax.numpy as jnp
 
     from sparksched_tpu.config import EnvParams
-    from sparksched_tpu.workload import make_workload_bank, sampling
+    from sparksched_tpu.env import core
+    from sparksched_tpu.env.flat_loop import _reseed_ended, init_loop_state
+    from sparksched_tpu.workload.sampling import pack_duration_facts
 
-    params = EnvParams(num_executors=n, max_jobs=4)
-    bank = make_workload_bank(n)
-    itv = jnp.asarray(_interval_tables(n))
+    bank = banks["sparse"]
+    params = EnvParams(
+        num_executors=4, max_jobs=8, max_stages=bank.max_stages,
+        max_levels=bank.max_stages, moving_delay=700.0,
+        warmup_delay=1000.0, job_arrival_rate=4e-5, mean_time_limit=None,
+    )
+    pack = np.asarray(pack_duration_facts(bank))
 
-    rs = np.random.RandomState(47 + n)
-    u0 = np.concatenate([[0.0, 0.999999], rs.rand(6)]).astype(np.float32)
-    tpl, nl, tv, ss, ui = (g.ravel() for g in np.meshgrid(
-        np.arange(0, bank.num_templates, 5), np.arange(n + 1),
-        [False, True], [False, True], np.arange(u0.size), indexing="ij",
+    def holds(envs):
+        tpl = np.asarray(envs.job_template)
+        assert len(np.unique(tpl)) > 8
+        np.testing.assert_array_equal(
+            np.asarray(envs.duration_facts), pack[tpl])
+        return tpl
+
+    keys = jax.random.split(jax.random.PRNGKey(50), 4)
+    envs = jax.vmap(lambda k: core.reset(params, bank, k))(keys)
+    tpl0 = holds(envs)
+    assert np.asarray(envs.duration_facts).dtype == np.uint32
+    if how == "reset":
+        return
+    ended = jnp.asarray([True, False, True, False])
+    ls = jax.vmap(init_loop_state)(envs)
+    ls = ls.replace(episodes=ls.episodes + ended.astype(jnp.int32))
+    out = jax.jit(jax.vmap(
+        lambda l, e, k: _reseed_ended(
+            params, bank, l, e, k, None, "lanes"
+        ),
+        axis_name="lanes",
+    ))(ls, ended, jax.random.split(jax.random.PRNGKey(51), 4))
+    tpl1 = holds(out.env)
+    np.testing.assert_array_equal(
+        (tpl1 != tpl0).any(1), np.asarray(ended))
+
+
+def _duration_grid(bank, n: int, seed: int):
+    """(u2, facts, template, stage, num_local, task valid, same stage)
+    over EVERY (template, stage) of the bank (padding stages too),
+    every num_local in 0..N, both flags both ways and a grid of
+    interpolation draws (both ends of [0, 1) among them), a fresh
+    pick draw each."""
+    import jax.numpy as jnp
+
+    from sparksched_tpu.workload.sampling import pack_duration_facts
+
+    rs = np.random.RandomState(seed)
+    u0 = np.concatenate([[0.0, 0.999999], rs.rand(4)]).astype(np.float32)
+    tpl, stage, nl, tv, ss, ui = (g.ravel() for g in np.meshgrid(
+        np.arange(bank.num_templates), np.arange(bank.max_stages),
+        np.arange(n + 1), [False, True], [False, True],
+        np.arange(u0.size), indexing="ij",
     ))
-    stage = rs.randint(0, bank.max_stages, tpl.size)
-    stage = stage % np.asarray(bank.num_stages)[tpl]
     u2 = np.stack([u0[ui], rs.rand(tpl.size).astype(np.float32)], -1)
-    args = tuple(jnp.asarray(x) for x in (
-        u2, tpl.astype(np.int32), stage.astype(np.int32),
+    facts = np.asarray(pack_duration_facts(bank))[tpl, stage]
+    return tuple(jnp.asarray(x) for x in (
+        u2, facts, tpl.astype(np.int32), stage.astype(np.int32),
         nl.astype(np.int32), tv, ss,
     ))
 
-    def durations():
-        return np.asarray(jax.jit(jax.vmap(
-            lambda *a: sampling.sample_task_duration(params, bank, *a)
-        ))(*args))
 
-    got = durations()
-    keys = np.asarray(jax.jit(jax.vmap(
-        lambda u, t, s, k: sampling.sample_executor_key(
-            params, bank, u, t, s, k)
-    ))(args[0][:, 0], *args[1:4]))
-    read = []
+@pytest.mark.parametrize("n,name,dtype", [
+    (10, "tpch", "f32"), (50, "tpch", "f32"),
+    (10, "sparse", "f32"), (50, "sparse", "f32"),
+    (120, "sparse", "f32"), (10, "sparse", "int8"),
+    (50, "tpch", "int8"), (10, "sparse_fixed", "f32"),
+])
+def test_sampled_durations_equal_the_table_reading_sampler_s(
+    banks, n, name, dtype
+):
+    """`sample_task_duration`, handed the stage's word of duration
+    facts, equals bit for bit the sampler that read the bank's tables
+    (`_table_task_duration`: the four interval tables until PR 47,
+    `level_present`, `max_present` and the three counts until PR 50),
+    over EVERY (template, stage, num_local in 0..N, task valid, same
+    stage) on a grid of draws: on the TPC-H(-like) bank, on a bank
+    with levels and whole waves missing (every fallback chain, the
+    warm-up branch, the rough duration of an empty bucket, the zeroed
+    level above 100 executors), float32 and int8 (`quantize_bank`),
+    and on a bank rebuilt as the benchmark's engine comparison rebuilds
+    it, whose `max_present` is stale and whose levels all hold one
+    duration."""
+    import jax
 
-    def from_tables(params_, *a):
-        read.append(params_.num_executors)
-        return _table_executor_key(itv, *a)
+    from sparksched_tpu.config import EnvParams
+    from sparksched_tpu.workload import quantize_bank, sampling
 
-    monkeypatch.setattr(sampling, "sample_executor_key", from_tables)
-    want = durations()
-    assert read == [n]
+    params = EnvParams(num_executors=n, max_jobs=4)
+    bank = quantize_bank(banks[name], dtype)
+    assert (bank.dur_scale is not None) == (dtype == "int8")
+    args = _duration_grid(bank, n, 47 + n)
+
+    got = np.asarray(jax.jit(jax.vmap(
+        lambda *a: sampling.sample_task_duration(params, bank, *a)
+    ))(*args))
+    reference = table_reading_sampler(n)
+    want = np.asarray(jax.jit(jax.vmap(
+        lambda *a: reference(params, bank, *a)
+    ))(*args))
     np.testing.assert_array_equal(got, want)
-    assert got.size > 2000 and (got > 0).all()
+    assert got.size == bank.num_templates * bank.max_stages * (n + 1) * 24
+    real = np.asarray(args[3]) < np.asarray(bank.num_stages)[
+        np.asarray(args[2])]
+    assert (got[real] > 0).all() and not real.all()
+
+    keys = np.asarray(jax.jit(jax.vmap(
+        lambda u2, f, k: sampling.sample_executor_key(
+            params, f, u2[0], k)
+    ))(args[0], args[1], args[4]))
     # the grid reaches what it says: several levels, both sides of an
     # interval, the warm-up branch and plain ones
     assert len(np.unique(keys)) >= (2 if n == 10 else 4), np.unique(keys)
-    assert len(np.unique(got)) > got.size // 20
+    if not name.endswith("_fixed") and dtype == "f32":
+        assert len(np.unique(got)) > got.size // 200
+    if name == "sparse":
+        rough = np.asarray(bank.rough_duration)[
+            np.asarray(args[2]), np.asarray(args[3])]
+        warm = got == rough + params.warmup_delay
+        assert (real & (got == rough)).sum() > 1000  # an empty bucket
+        assert (real & warm).sum() > 1000  # ... on an idle executor
+        assert len(np.unique(keys)) == 8
+
+
+@pytest.mark.parametrize("how", ["another stage's word", "a bit off"])
+def test_a_wrong_fact_fails_the_durations_test(banks, monkeypatch, how):
+    """The test above can fail: handed the word of the NEXT stage, or
+    reading the bucket bits one place off (`_FACTS_BUCKET_SHIFT` 9),
+    the sampler parts from the table-reading one on the thinned
+    bank."""
+    import jax
+
+    from sparksched_tpu.config import EnvParams
+    from sparksched_tpu.workload import sampling
+
+    params = EnvParams(num_executors=10, max_jobs=4)
+    bank = banks["sparse"]
+    args = _duration_grid(bank, 10, 57)
+    reference = table_reading_sampler(10)
+    want = np.asarray(jax.jit(jax.vmap(
+        lambda *a: reference(params, bank, *a)))(*args))
+    if how == "a bit off":
+        monkeypatch.setattr(sampling, "_FACTS_BUCKET_SHIFT", 9)
+    else:
+        args = (args[0], np.roll(np.asarray(args[1]), 11 * 24)) + args[2:]
+    got = np.asarray(jax.jit(jax.vmap(
+        lambda *a: sampling.sample_task_duration(params, bank, *a)
+    ))(*args))
+    assert (got != want).mean() > 0.05

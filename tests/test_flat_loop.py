@@ -399,7 +399,7 @@ def _decima_parity_fixture(monkeypatch):
     from sparksched_tpu.schedulers import DecimaScheduler
     from sparksched_tpu.workload import make_workload_bank
 
-    def det_sampler(params, bank, rng, template, stage, num_local,
+    def det_sampler(params, bank, rng, facts, template, stage, num_local,
                     task_valid, same_stage):
         base = bank.rough_duration[template, stage]
         return (
@@ -683,7 +683,7 @@ def test_fused_bulk_pass_matches_unfused_plain(monkeypatch, moving_delay):
     from sparksched_tpu.schedulers import round_robin_policy
     from sparksched_tpu.workload import make_workload_bank
 
-    def det_sampler(params, bank, rng, template, stage, num_local,
+    def det_sampler(params, bank, rng, facts, template, stage, num_local,
                     task_valid, same_stage):
         base = bank.rough_duration[template, stage] * 0.05
         return (
@@ -822,7 +822,7 @@ def test_bulk_paths_match_sequential_on_synthetic_bank(
     from sparksched_tpu.schedulers import round_robin_policy
     from sparksched_tpu.workload import make_workload_bank
 
-    def det_sampler(params, bank, rng, template, stage, num_local,
+    def det_sampler(params, bank, rng, facts, template, stage, num_local,
                     task_valid, same_stage):
         base = bank.rough_duration[template, stage] * dur_scale
         # distinct per (stage-continuation kind) so wave logic still

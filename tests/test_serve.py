@@ -76,17 +76,26 @@ def _tiny_store_state(params, bank, capacity=2):
 def test_create_writes_the_parent_sets_of_its_adjacency(setup):
     """A session's slot holds the packed parent sets of ITS adjacency
     (`EnvState.parent_sets`, what the fused bulk pass of the served
-    drain reads since PR 39): after `create` over whatever the slot
-    held, and still after decisions served from it."""
+    drain reads since PR 39) and the duration facts of ITS templates
+    (`EnvState.duration_facts`, what the duration sampler reads since
+    PR 50): after `create` over whatever the slot held, and still
+    after decisions served from it."""
+    from sparksched_tpu.workload.sampling import pack_duration_facts
+
     params, bank, sched = setup
     store = SessionStore(params, bank, sched, capacity=3, max_batch=2,
                          seed=0)
+    facts = np.asarray(pack_duration_facts(bank))
 
     def check(sids):
         env = store._stores[0].env
         adj = np.asarray(env.adj)
         np.testing.assert_array_equal(
             np.asarray(env.parent_sets), parent_sets_by_hand(adj)
+        )
+        np.testing.assert_array_equal(
+            np.asarray(env.duration_facts),
+            facts[np.asarray(env.job_template)],
         )
         return [adj[s].copy() for s in sids]
 

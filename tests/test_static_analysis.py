@@ -417,7 +417,7 @@ def test_no_program_reads_a_table_an_executor_count_long(monkeypatch):
     from sparksched_tpu.env import core
     from sparksched_tpu.workload import sampling
 
-    from .test_bulk_pass_setup import _interval_tables, _table_executor_key
+    from .test_bulk_pass_setup import table_reading_sampler
 
     params, bank, state = jaxpr_audit.audit_setup()
     n = params.num_executors
@@ -435,8 +435,8 @@ def test_no_program_reads_a_table_an_executor_count_long(monkeypatch):
 
         def durations(bank_, u2, nl):
             return jax.vmap(lambda k: sampling.sample_task_duration(
-                params, bank_, u2, jnp.int32(3), jnp.int32(1), k,
-                jnp.bool_(True), jnp.bool_(False),
+                params, bank_, u2, jnp.uint32(0x01010103), jnp.int32(3),
+                jnp.int32(1), k, jnp.bool_(True), jnp.bool_(False),
             ))(nl)
 
         u2, nl = jnp.zeros(2), jnp.arange(7, dtype=jnp.int32)
@@ -451,11 +451,9 @@ def test_no_program_reads_a_table_an_executor_count_long(monkeypatch):
         assert not jaxpr_audit.reads_of_shape(jaxpr, (n + 1,))
         assert jaxpr_audit.count_eqns(jaxpr) > 60
 
-    itv = jnp.asarray(_interval_tables(n))
-    monkeypatch.setattr(
-        sampling, "sample_executor_key",
-        lambda params_, *a: _table_executor_key(itv, *a),
-    )
+    reference = table_reading_sampler(n)
+    monkeypatch.setattr(sampling, "sample_task_duration", reference)
+    monkeypatch.setattr(core, "sample_task_duration", reference)
     with_tables = [
         jaxpr_audit.reads_of_shape(j, (n + 1,)) for j in traced()
     ]
@@ -464,6 +462,108 @@ def test_no_program_reads_a_table_an_executor_count_long(monkeypatch):
     # lane: a scalar index, so the four reads are dynamic slices)
     assert with_tables[0] == with_tables[1] == [
         f"dynamic_slice({n + 1},)"] * 8
+
+
+def test_loop_row_reads_finds_a_table_read_inside_a_loop():
+    """`jaxpr_audit.loop_row_reads`: a gather (under `vmap`) or a
+    dynamic slice (one lane) from an operand of a named shape AND
+    dtype is found inside a `while`, through a call, and not outside
+    it; an operand of the same shape and another dtype is not named."""
+    import jax
+    import jax.numpy as jnp
+
+    from sparksched_tpu.analysis import jaxpr_audit
+
+    table = jnp.arange(12, dtype=jnp.int32).reshape(3, 4)
+    other = jnp.ones((3, 4), jnp.float32)
+
+    @jax.jit
+    def read(i):
+        return table[i % 3, i % 4] + other[i % 3, 0].astype(jnp.int32)
+
+    def prog(i0):
+        before = table[i0 % 3, 1]
+        return jax.lax.while_loop(
+            lambda c: c[0] < 5, lambda c: (c[0] + 1, c[1] + read(c[0])),
+            (i0, before),
+        )
+
+    one = jax.make_jaxpr(prog)(jnp.int32(0)).jaxpr
+    many = jax.make_jaxpr(jax.vmap(prog))(jnp.arange(4)).jaxpr
+    for jaxpr, prim in ((one, "dynamic_slice"), (many, "gather")):
+        assert jaxpr_audit.loop_row_reads(jaxpr, [table]) == [
+            f"{prim}(int32[3, 4])"]
+        assert jaxpr_audit.loop_row_reads(jaxpr, [other]) == [
+            f"{prim}(float32[3, 4])"]
+        assert len(jaxpr_audit.loop_row_reads(jaxpr, [table, other])) == 2
+        assert not jaxpr_audit.loop_row_reads(
+            jaxpr, [jnp.zeros((3, 4), jnp.uint32)])
+
+
+def test_early_exit_loop_reads_three_bank_tables_a_step(monkeypatch):
+    """What takes a counter's place for PR 50 (every sampled duration
+    goes through it): the jaxpr of the fused bulk pass, one lane and
+    under `vmap`, bank closed over and handed in, holds NO equation
+    anywhere with an operand of `bank.level_present`'s shape, and in
+    its early-exit loop no row read from an `int32[T,S]` operand
+    (`bank.max_present`) and three row reads a step from the bank:
+    `cnt`, `dur`, `rough_duration`, one element each (two steps an
+    iteration: `_BULK_STEP_GRANULE`). What the sampler needs of a
+    stage that no draw decides is the stage's word of
+    `EnvState.duration_facts`, picked by the step's one-hot. With the
+    table-reading sampler (`tests/test_bulk_pass_setup.py` keeps it)
+    the rule names five reads a step, the two tables among them."""
+    import jax
+
+    from sparksched_tpu.analysis import jaxpr_audit
+    from sparksched_tpu.env import core
+
+    from .test_bulk_pass_setup import table_reading_sampler
+
+    params, bank, state = jaxpr_audit.audit_setup()
+    steps = core._BULK_STEP_GRANULE
+    states = jaxpr_audit._batched(state, 3)
+    leaves = jax.tree_util.tree_leaves(bank)
+    present, highest = bank.level_present, bank.max_present
+    t, s_cap = highest.shape
+    assert present.shape == (t, s_cap, 8) and t != state.job_template.shape[0]
+
+    def traced():
+        def fused_pass(bank_, st):
+            return core._bulk_events_fused(
+                params, bank_, st, True, stop_at_limit=True, max_events=8
+            )
+
+        return {
+            "closed over": jax.make_jaxpr(
+                lambda st: fused_pass(bank, st))(state).jaxpr,
+            "handed in": jax.make_jaxpr(fused_pass)(bank, state).jaxpr,
+            "under vmap": jax.make_jaxpr(jax.vmap(
+                fused_pass, in_axes=(None, 0)))(bank, states).jaxpr,
+        }
+
+    def table(reads, leaf):
+        return [r for r in reads if r.endswith(
+            f"({leaf.dtype}{list(leaf.shape)})")]
+
+    for how, jaxpr in traced().items():
+        assert not jaxpr_audit.reads_of_shape(jaxpr, present.shape), how
+        reads = jaxpr_audit.loop_row_reads(jaxpr, leaves)
+        assert len(reads) == 3 * steps, (how, reads)
+        assert not table(reads, highest) and not table(reads, present)
+        for leaf in (bank.cnt, bank.dur, bank.rough_duration):
+            assert len(table(reads, leaf)) == steps, (how, reads)
+        prim = "gather" if how == "under vmap" else "dynamic_slice"
+        assert all(r.startswith(prim + "(") for r in reads), reads
+
+    reference = table_reading_sampler(params.num_executors)
+    monkeypatch.setattr(core, "sample_task_duration", reference)
+    for how, jaxpr in traced().items():
+        reads = jaxpr_audit.loop_row_reads(jaxpr, leaves)
+        assert len(reads) == 5 * steps, (how, reads)
+        assert len(table(reads, highest)) == steps
+        assert len(table(reads, present)) == steps
+        assert jaxpr_audit.reads_of_shape(jaxpr, present.shape)
 
 
 def test_unknown_program_name_is_an_error():

@@ -279,6 +279,57 @@ def test_a_concatenated_carry_goes_on_as_its_parts(small, swept):
         assert np.array_equal(a[:, 2:], want[:64, :2]), name
 
 
+def test_a_chunk_equals_the_one_sampled_from_the_banks_tables(monkeypatch):
+    """PR 50: a chunk of the sweep (whole episodes, re-seeds inside the
+    scan) over a bank with executor levels and whole waves missing is,
+    leaf for leaf, the chunk collected with the sampler that read the
+    bank's `level_present`, `max_present` and three counts a duration
+    (`tests/test_bulk_pass_setup.py` keeps it): carry, record and
+    telemetry, 0 unequal leaves. The carry holds the one leaf more,
+    each lane's words of its OWN templates, the re-seeded ones too."""
+    from sparksched_tpu.env import core
+    from sparksched_tpu.workload.sampling import pack_duration_facts
+
+    from .test_bulk_pass_setup import sparse_bank, table_reading_sampler
+
+    bank = sparse_bank(EXECUTORS)
+    params = EnvParams(
+        num_executors=EXECUTORS, max_jobs=JOBS, max_stages=bank.max_stages,
+        max_levels=bank.max_stages, moving_delay=MOVING, warmup_delay=WARMUP)
+    sched = RoundRobinScheduler(EXECUTORS)
+    carry0 = sweep.init(params, bank, KEY, LANES)
+
+    def chunk():
+        # a function and a jit of its own a side: the sampler is no
+        # key of a jit's cache
+        def program(*args):
+            return sweep._chunk(*args)
+
+        return jax.device_get(jax.jit(program, static_argnums=(0, 2, 5))(
+            params, bank, sched.batch_policy, carry0, jax.random.PRNGKey(1),
+            ROWS))
+
+    got = chunk()
+    reference = table_reading_sampler(EXECUTORS)
+    monkeypatch.setattr(core, "sample_task_duration", reference)
+    want = chunk()
+    assert reference.traced >= 3  # the passes sampled by it
+    unequal = [
+        jax.tree_util.keystr(path)
+        for (path, x), y in zip(
+            jax.tree_util.tree_flatten_with_path(got)[0],
+            jax.tree_util.tree_leaves(want))
+        if not np.array_equal(x, y)]
+    assert not unequal, unequal
+    carry, rec, _ = got
+    assert rec.reset.sum() >= LANES and rec.valid.all()
+    env = carry.ls.env
+    assert (env.job_template != carry0.ls.env.job_template).any()
+    np.testing.assert_array_equal(
+        env.duration_facts,
+        np.asarray(pack_duration_facts(bank))[env.job_template])
+
+
 def test_init_takes_states_the_caller_made(small):
     """`init(states=...)`: lanes that are not reset states go on from
     where they are, and their later episodes follow the seed law."""

@@ -403,12 +403,16 @@ def test_a_heuristics_blocked_row_equals_its_whole_batch_row(monkeypatch):
 
 
 # sha256 of the lowered text of `sweep_chunk` under
-# `config/sweep_fair_demo.yaml` at 1,024 lanes x 16 rows at the parent
-# commit of PR 49 (73a56a0), taken by this function's lines in a
-# checkout of it. A PR that changes the engine or a heuristic's row on
-# purpose takes it again from its own tree and says so.
+# `config/sweep_fair_demo.yaml` at 1,024 lanes x 16 rows, taken by this
+# function's lines. At the parent commit of PR 49 (73a56a0) it was
+# 214f6dd2... of 1,643,269 characters, and PR 49's own tree gave the
+# same: a heuristic's row is the one it had. A PR that changes the
+# engine or a heuristic's row on purpose takes it again from its own
+# tree and says so: PR 50 (the duration sampler reads the stage's word
+# of `EnvState.duration_facts`; the engine's change, the row's order
+# as it was) took this one.
 FAIR_CHUNK_AT_PARENT = (
-    "214f6dd282d751b200679678f1633c6cdc097448d5b3876b2802fa020834dd8e")
+    "1a2764b3f82faf5754e95f91b23c0ff2fcfbd3de957dd9d2a7a8cd3dab1097ce")
 
 
 def test_a_heuristics_chunk_lowers_to_the_parents_text():
@@ -422,7 +426,7 @@ def test_a_heuristics_chunk_lowers_to_the_parents_text():
     carry = jax.eval_shape(lambda: sweep.init(params, bank, KEY, 1024))
     text = sweep.sweep_chunk.lower(
         params, bank, sched.batch_policy, carry, KEY, 16).as_text()
-    assert len(text) == 1643269
+    assert len(text) == 1642076
     assert hashlib.sha256(text.encode()).hexdigest() == FAIR_CHUNK_AT_PARENT
 
 

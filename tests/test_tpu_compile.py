@@ -423,12 +423,12 @@ def test_early_exit_loop_gathers_from_no_executor_count_table(batched):
     gathered from four loop-carried `s32[51]` tables and joined the
     rows: six of a step's 58 operations, the heaviest of
     `decima_batch20`'s `breakdown` among them. The loop still gathers
-    (a job's template, the bank's counts and durations), which shows
-    that the pattern is found where it is."""
+    (the bank's counts and durations), which shows that the pattern is
+    found where it is."""
     trainer, text = batched
     n = trainer.params_env.num_executors
     in_loop = _gathers(text, EARLY_EXIT)
-    assert len(in_loop) >= 12, in_loop
+    assert len(in_loop) >= 6, in_loop
     assert not [op for _, op in in_loop if op == [n + 1]], in_loop
 
 
@@ -452,18 +452,13 @@ def _scalar_operands(text: str, scope: str) -> dict[str, int]:
     return found
 
 
-def test_sweep_chunk_carries_no_table_as_scalars(flagship, one_chip):
-    """What takes a counter's place for PR 47 in the sweep, whose bank
-    is an ARGUMENT of the chunk program (the benchmark runs the timed
-    executable over a second bank): `sweep_chunk` under
-    `config/sweep_fair_demo.yaml` (10 executors, 50 jobs, the fair
-    policy) at 2,048 lanes, compiled for the v5e with the bank as
-    shapes, has under the drain's scope no fusion with more than 8
-    rank-0 operands. With the interval tables in the bank, `i32[11]`
-    and so short that the compiler unrolled each gather into a chain
-    of selects, three fusions took the 4 x 11 entries as 44 scalars
-    carried through the drain's loops: two of them in every iteration
-    of the early-exit loop, a fifth of `sweep_fair`'s device time."""
+@pytest.fixture(scope="module")
+def fair_chunk(flagship, one_chip):
+    """`sweep_chunk` under `config/sweep_fair_demo.yaml` (10 executors,
+    50 jobs, the fair policy) at 2,048 lanes, compiled for the v5e
+    with the bank as shapes (it is an ARGUMENT of the chunk program:
+    the benchmark runs the timed executable over a second bank), once
+    for the tests that read it: `(params, bank, HLO text)`."""
     import jax
 
     from sparksched_tpu import config, sweep
@@ -483,7 +478,19 @@ def test_sweep_chunk_carries_no_table_as_scalars(flagship, one_chip):
         jax.config.update("jax_default_prng_impl", "rbg")
     assert (params.num_executors, params.max_jobs) == (10, 50)
     _fits(compiled, temp_gib=2.0)
-    text = compiled.as_text()
+    return params, bank, compiled.as_text()
+
+
+def test_sweep_chunk_carries_no_table_as_scalars(fair_chunk):
+    """What takes a counter's place for PR 47 in the sweep, whose bank
+    is an ARGUMENT of the chunk program: the chunk compiled for the
+    v5e has under the drain's scope no fusion with more than 8
+    rank-0 operands. With the interval tables in the bank, `i32[11]`
+    and so short that the compiler unrolled each gather into a chain
+    of selects, three fusions took the 4 x 11 entries as 44 scalars
+    carried through the drain's loops: two of them in every iteration
+    of the early-exit loop, a fifth of `sweep_fair`'s device time."""
+    _, _, text = fair_chunk
     assert len(_loops(text, "env/micro_step/drain/while")) == 1  # blocked
     scalars = _scalar_operands(text, "env/micro_step/drain")
     assert len(scalars) > 200, len(scalars)
@@ -492,6 +499,46 @@ def test_sweep_chunk_carries_no_table_as_scalars(flagship, one_chip):
     # the early-exit loop is among what was read
     assert any(EARLY_EXIT in line and " fusion(" in line
                for line in text.split("\n"))
+
+
+@pytest.mark.parametrize("program", ["sweep chunk", "batched collector"])
+def test_early_exit_loop_gathers_three_bank_elements_a_step(
+    request, program
+):
+    """What takes a counter's place for PR 50 (every sampled duration
+    goes through it), in the programs as compiled for the v5e: the
+    early-exit loop of the sweep chunk (bank an argument) and of the
+    batched collector (bank a constant) holds SIX gathers, three a
+    step of its two: one element each of the bank's `cnt`, `dur` and
+    `rough_duration`. The parent's held eighteen: besides these (the
+    `cnt` read three elements and a fourth gather picked one), the
+    bank's `level_present` and `max_present`, and the lane's own
+    `rem[tj, ts]`, `jcnt[tj]` and `job_template[tj]`, each a
+    serialised `kCustom` fusion of 1.5 to 2.2 us with a relayout of
+    its index column before it (the ten heaviest operations of
+    `sweep_fair`'s `breakdown` were ten of them). What a step reads
+    of the lane's own state it now picks with the one-hots it builds
+    for its updates, the stage's word of `EnvState.duration_facts`
+    among it, and nothing in the loop has `level_present`'s or the
+    state's `[lanes, J, S]` dimensions as a gather's operand."""
+    if program == "sweep chunk":
+        params, bank, text = request.getfixturevalue("fair_chunk")
+    else:
+        trainer, text = request.getfixturevalue("batched")
+        params, bank = trainer.params_env, trainer.bank
+    in_loop = sorted(op for _, op in _gathers(text, EARLY_EXIT))
+    tables = sorted(
+        list(leaf.shape)
+        for leaf in (bank.cnt, bank.dur, bank.rough_duration)
+        for _ in range(2)
+    )
+    assert in_loop == tables, in_loop
+    assert list(bank.level_present.shape) not in in_loop
+    # the drain's body outside that loop still gathers from the
+    # state's grids: the pattern is found where it is
+    grid = [128, params.max_jobs, params.max_stages]
+    outside = [op for _, op in _gathers(text, "env/micro_step/drain")]
+    assert outside.count(grid) >= 10, outside
 
 
 def test_sweep_chunk_under_decima_is_one_net_in_the_loop_over_blocks(

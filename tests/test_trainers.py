@@ -819,3 +819,48 @@ def test_sync_rollout_is_leaf_equal_under_telemetry_and_counts_no_stream():
     assert (s["reseeds_total"], s["reset_evals_total"],
             s["row"]["lane_rows_frozen"]) == (0, 0, 0)
     assert s["decisions"] == int(np.asarray(ro.valid).sum())
+
+
+def test_sync_collection_equals_the_one_sampled_from_the_banks_tables(
+    monkeypatch,
+):
+    """PR 50: a sync collection over a bank with executor levels and
+    whole waves missing is, leaf for leaf, the one collected with the
+    sampler that read the bank's `level_present`, `max_present` and
+    three counts a duration (`tests/test_bulk_pass_setup.py` keeps
+    it): every stored observation, action, reward and time, and the
+    final state, which holds each lane's words of its templates."""
+    import jax
+
+    from sparksched_tpu.env import core
+    from sparksched_tpu.trainers.rollout import collect_flat_sync_batch
+    from sparksched_tpu.workload.sampling import pack_duration_facts
+
+    from .test_bulk_pass_setup import sparse_bank, table_reading_sampler
+
+    params, _, bpol, _, _, salts = _stream_fixture()
+    bank = sparse_bank(params.num_executors, params.max_stages)
+    seq0 = jax.random.fold_in(jax.random.PRNGKey(7), 0)
+    states = jax.vmap(lambda salt: core.reset_pair(
+        params, bank, seq0, jax.random.fold_in(seq0, salt)))(salts)
+
+    def collect():
+        # a function and a jit of its own a side: the sampler is no
+        # key of a jit's cache
+        def collector(*args):
+            return collect_flat_sync_batch.__wrapped__(*args)
+
+        return jax.device_get(jax.jit(collector, static_argnums=(0, 2, 4))(
+            params, bank, bpol, jax.random.PRNGKey(100), 60, states))
+
+    got = collect()
+    reference = table_reading_sampler(params.num_executors)
+    monkeypatch.setattr(core, "sample_task_duration", reference)
+    want = collect()
+    assert reference.traced >= 3  # the passes sampled by it
+    _assert_leaf_equal(got, want)
+    assert got.valid.sum() > 40 and len(np.unique(got.wall_times)) > 40
+    final = got.final_state
+    np.testing.assert_array_equal(
+        final.duration_facts,
+        np.asarray(pack_duration_facts(bank))[final.job_template])
