@@ -258,6 +258,36 @@ class Budget:
 # What the change is for is no count: `loop_row_reads` over the pass's
 # jaxpr (tests/test_static_analysis.py). The chip rows are PERF.md,
 # PR 50.
+#
+# Re-pinned 2026-10-05 (PR 51: what the pop, the handlers,
+# `_resolve_action`, `_apply_action`, `_refresh_sat`, the decide step's
+# commit and the FULFILL branch read of a lane's own state at ONE
+# (job, stage), job, executor or slot they pick with the one-hot their
+# masked writes use, `core._pick`, a select and a reduce where each was
+# an indexed read; the adjacency's rows come off `parent_sets`). Under
+# a lane `vmap` each of those reads was a gather, in a one-lane program
+# a dynamic slice, so the gather counts fall in the batched programs
+# alone. Eqns / gathers before (re-measured on the parent's checkout)
+# -> after: micro_step 4378/21 -> 4147/21, decide_micro_step 2476/16 ->
+# 2359/16, drain_to_decision 3002/5 -> 2852/5, serve_decide 6645/24 ->
+# 6378/24 (its record and ring variants the same -267),
+# serve_decide_batch 15464/224 -> 15044/108 (its group, record and
+# sharded variants alike; the ring one 225 -> 109), flat_collect_batch
+# 15130/161 -> 14710/45, flat_collect_batch_health 15399/161 ->
+# 14979/45, sweep_chunk 14851/157 -> 14431/41; scatters,
+# observe, the net's and the update's as they were; every eqn count
+# inside its band. What is left in the collectors: `_bulk_fulfill`'s
+# reads at a vector of candidates (ROADMAP S10), the bank's three a
+# sampled duration, the net's, the reset's rows of the bank and the
+# stores. The gather caps of the BATCHED programs are pinned at the
+# measured count + 2, under this table's usual rule (x 1.35), so that
+# the next indexed read of the state in these paths fails here and is
+# looked at: the serve batch programs 303 -> 110 (the ring one 304 ->
+# 111), the two collectors 218 -> 47, sweep_chunk 212 -> 43; the
+# one-lane programs' caps stay where the rule puts them (28, 22, 7,
+# 33). What the change is for is a count of the COMPILED drain body's
+# gathers (tests/test_tpu_compile.py). The chip rows are PERF.md,
+# PR 51.
 # ---------------------------------------------------------------------------
 
 BUDGETS: dict[str, Budget] = {
@@ -310,7 +340,7 @@ BUDGETS: dict[str, Budget] = {
     # what makes this CPU audit valid for the sharded configuration;
     # the HLO-level collective census lives in tests/test_parallel.py.
     "flat_collect_batch": Budget(
-        eqn_lo=9000, eqn_hi=16900, gather_hi=218, scatter_hi=25,
+        eqn_lo=9000, eqn_hi=16900, gather_hi=47, scatter_hi=25,
     ),
     # ISSUE 9: the `health:`-on variants of the two production
     # programs. Pinned 2026-08-03 — ppo_update_health 3209/43/3 (the
@@ -324,7 +354,7 @@ BUDGETS: dict[str, Budget] = {
         eqn_lo=1000, eqn_hi=4350, gather_hi=60, scatter_hi=5,
     ),
     "flat_collect_batch_health": Budget(
-        eqn_lo=9000, eqn_hi=17200, gather_hi=218, scatter_hi=27,
+        eqn_lo=9000, eqn_hi=17200, gather_hi=47, scatter_hi=27,
     ),
     # PR 46: the sweep loop's chunk under the fair heuristic, health
     # on (sweep.py): the collectors' decide and drain with the re-seed
@@ -334,7 +364,7 @@ BUDGETS: dict[str, Budget] = {
     # whose net and stores it lacks, because the reset program runs
     # inside the scan (the bank's gathers are its)
     "sweep_chunk": Budget(
-        eqn_lo=9000, eqn_hi=16600, gather_hi=212, scatter_hi=4,
+        eqn_lo=9000, eqn_hi=16600, gather_hi=43, scatter_hi=4,
     ),
     # ISSUE 10: the AOT decision-serving programs (serve/aot.py),
     # pinned 2026-08-04 — serve_decide 6514/33/65, serve_decide_batch
@@ -349,7 +379,7 @@ BUDGETS: dict[str, Budget] = {
         while_whole_read_elems=AUDIT_ADJ_ELEMS,
     ),
     "serve_decide_batch": Budget(
-        eqn_lo=6000, eqn_hi=17400, gather_hi=303, scatter_hi=88,
+        eqn_lo=6000, eqn_hi=17400, gather_hi=110, scatter_hi=88,
     ),
     # ISSUE 13: the dp-sharded store variant (serve/aot.py
     # `serve_decide_batch_fn(..., shard=...)`), pinned 2026-08-04 —
@@ -362,7 +392,7 @@ BUDGETS: dict[str, Budget] = {
     # re-measured byte-identical, which is the acceptance bar (shard
     # off must change nothing).
     "serve_decide_batch_sharded": Budget(
-        eqn_lo=6000, eqn_hi=17500, gather_hi=303, scatter_hi=88,
+        eqn_lo=6000, eqn_hi=17500, gather_hi=110, scatter_hi=88,
     ),
     # ISSUE 14: the record-on serve variants (serve/aot.py
     # `record=True` — the online trajectory path's programs), pinned
@@ -380,7 +410,7 @@ BUDGETS: dict[str, Budget] = {
         eqn_lo=3000, eqn_hi=8810, gather_hi=33, scatter_hi=88,
     ),
     "serve_decide_batch_record": Budget(
-        eqn_lo=6000, eqn_hi=17410, gather_hi=303, scatter_hi=88,
+        eqn_lo=6000, eqn_hi=17410, gather_hi=110, scatter_hi=88,
     ),
     # ISSUE 15: the GROUP-shaped serve program (the pipelined store's
     # [hot_capacity/groups] lowering — serve/aot.py
@@ -393,7 +423,7 @@ BUDGETS: dict[str, Budget] = {
     # byte-identical in the same PR (the take_slot/write_slot
     # refactor moved code, not equations).
     "serve_decide_batch_group": Budget(
-        eqn_lo=6000, eqn_hi=17400, gather_hi=303, scatter_hi=88,
+        eqn_lo=6000, eqn_hi=17400, gather_hi=110, scatter_hi=88,
     ),
     # ISSUE 18: the ring-recording serve programs (serve/aot.py
     # `serve_decide_ring_fn` / `serve_decide_batch_ring_fn` — the
@@ -413,7 +443,7 @@ BUDGETS: dict[str, Budget] = {
         eqn_lo=3000, eqn_hi=8980, gather_hi=33, scatter_hi=117,
     ),
     "serve_decide_batch_record_ring": Budget(
-        eqn_lo=6000, eqn_hi=17550, gather_hi=304, scatter_hi=117,
+        eqn_lo=6000, eqn_hi=17550, gather_hi=111, scatter_hi=117,
     ),
 }
 

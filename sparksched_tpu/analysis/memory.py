@@ -100,7 +100,20 @@ inside their caps (serve_decide_batch 622.5 -> 627.7,
 flat_collect_batch 627.2 -> 632.5, sweep_chunk 268.0 -> 277.1),
 decide_micro_step 7.1 -> 7.0. The MODEL's growth again, not the
 chip's: compiled for the v5e the sweep chunk's temporaries at 26,624
-lanes FELL, 14.45 -> 14.04 GB (PERF.md, PR 50).
+lanes FELL, 14.45 -> 14.04 GB (PERF.md, PR 50). Re-pinned 2026-10-05
+(PR 51): the engine picks what it read of a lane's own state at one
+index with the one-hot its writes use (`core._pick`), and a jaxpr
+holds every select's [J,S] operand as a buffer of its own where an
+indexed read held a scalar; the compiler fuses each into its reduce.
+Measured MB before -> after: decide_micro_step 7.0 -> 9.2 (cap 8 ->
+12), micro_step 22.4 -> 26.6, drain_to_decision 16.0 -> 18.8,
+serve_decide 113.6 -> 118.5, flat_collect_batch 632.4 -> 651.7,
+sweep_chunk 277.1 -> 296.4, serve_decide_batch 627.6 -> 646.9, all
+inside their caps. The MODEL's growth once more: compiled for the v5e
+the sweep chunk's temporaries at 26,624 lanes fell from 14.04 to 2.95
+GB, the batched collector's from 323 to 160 MB and the flagship
+collector's from 337 to 184 MB (the gathers' operands were relaid
+whole; PERF.md, PR 51).
 """
 
 from __future__ import annotations
@@ -159,7 +172,7 @@ MB = 10**6
 MEM_BUDGETS: dict[str, MemBudget] = {
     "observe": MemBudget(temp_hi=4 * MB),
     "micro_step": MemBudget(temp_hi=30 * MB),
-    "decide_micro_step": MemBudget(temp_hi=8 * MB),
+    "decide_micro_step": MemBudget(temp_hi=12 * MB),
     "drain_to_decision": MemBudget(temp_hi=22 * MB),
     "decima_score": MemBudget(temp_hi=490 * MB),
     "decima_batch_policy": MemBudget(temp_hi=510 * MB),

@@ -62,6 +62,26 @@ def _onehot2(j_cap: int, s_cap: int, j: jnp.ndarray, s: jnp.ndarray
     return _onehot(j_cap, j)[:, None] & _onehot(s_cap, s)[None, :]
 
 
+def _pick(oh: jnp.ndarray, x: jnp.ndarray, axis=None) -> jnp.ndarray:
+    """`x` at the one position the mask `oh` marks, as a select-reduce:
+    what `x[i]` reads where `oh` is `_onehot(n, i)` (`x[j, s]` under
+    `_onehot2`; with `oh = oj[:, None]` and `axis=0`, the row `x[j]`),
+    for every in-range index, whatever `x` holds (an `inf` included:
+    nothing is multiplied). A flag is reduced by `any`, a number by a
+    sum over zeros. An all-false mask (the one-hot of a -1 sentinel or
+    of an index past the end) gives 0 / False where the indexed read
+    wraps or clamps: every caller says why that value is masked.
+
+    Why not the indexed read: under `jax.vmap` it is a gather, which
+    the TPU serialises (1.1 to 2.2 us for 128 lanes, and a relayout of
+    the index column beside it), while this fuses with the elementwise
+    work around it; the one-hot is the one the caller's masked writes
+    need anyway (PERF.md, PRs 50 and 51)."""
+    if x.dtype == jnp.bool_:
+        return (oh & x).any(axis)
+    return jnp.where(oh, x, 0).sum(axis).astype(x.dtype)
+
+
 # --------------------------------------------------------------------------
 # schedulable-stage computation (reference :505-555)
 # --------------------------------------------------------------------------
@@ -86,34 +106,35 @@ def find_schedulable(
     return job_ok[:, None] & ready & ~state.stage_selected
 
 
-def _refresh_sat(state: EnvState, j: jnp.ndarray, s: jnp.ndarray,
+def _refresh_sat(state: EnvState, oj: jnp.ndarray, os_: jnp.ndarray,
                  enable: jnp.ndarray = True) -> EnvState:
     """Recompute saturation of stage (j,s) after a demand mutation and
     propagate the flip to its children's unsaturated-parent counts.
+    The stage comes as the one-hots of its job and of its stage index
+    (`oj` bool[J], `os_` bool[S]) that the caller built for its own
+    writes; a caller whose job is the -1 sentinel hands an all-false
+    `oj` and `enable=False`, and nothing changes.
 
     Written as masked whole-array selects rather than `.at[j, s]`
-    scatters: under `jax.vmap` a batched scatter is a serialized kernel,
-    while broadcast+select fuses with the surrounding elementwise work."""
-    demand = (
-        state.stage_remaining[j, s]
-        - state.moving_count[j, s]
-        - state.commit_count[j, s]
-    )
-    new = demand <= 0
-    old = state.stage_sat[j, s]
+    scatters, and as picks (`_pick`) rather than `[j, s]` reads: under
+    `jax.vmap` a batched scatter or gather is a serialized kernel,
+    while broadcast+select fuses with the surrounding elementwise work.
+    The stage's children are read off the packed parent sets of its
+    job (`_children`), not off the [J,S,S] adjacency."""
+    m2 = oj[:, None] & os_[None, :]
+    new = _pick(m2, state.exec_demand) <= 0
+    old = _pick(m2, state.stage_sat)
     # only existing stages count as unsaturated parents
     delta = jnp.where(
-        enable & state.stage_exists[j, s],
+        enable & _pick(m2, state.stage_exists),
         new.astype(_i32) - old.astype(_i32),
         0,
     )
-    j_cap, s_cap = state.stage_sat.shape
-    oj = _onehot(j_cap, j)
-    m2 = oj[:, None] & _onehot(s_cap, s)[None, :]
+    children = _children(_job_parent_sets(state, oj), os_)
     return state.replace(
         stage_sat=jnp.where(m2 & enable, new, state.stage_sat),
         unsat_parent_count=state.unsat_parent_count
-        - delta * (oj[:, None] & state.adj[j, s][None, :]).astype(_i32),
+        - delta * (oj[:, None] & children[None, :]).astype(_i32),
     )
 
 
@@ -123,14 +144,18 @@ def _refresh_sat(state: EnvState, j: jnp.ndarray, s: jnp.ndarray,
 
 
 def _move_idle_from_pool(
-    state: EnvState, pj: jnp.ndarray, ps: jnp.ndarray, mask: jnp.ndarray
+    state: EnvState, pj: jnp.ndarray, ps: jnp.ndarray, mask: jnp.ndarray,
+    opj: jnp.ndarray,
 ) -> EnvState:
     """_move_idle_executors (reference :745-782): no-op for the common pool
     and for unsaturated job pools; otherwise masked executors move to the
     common pool (job saturated — detaching them) or to the job pool (task
     reference intentionally retained, matching the reference's
-    move_executor_to_pool which does not clear `executor.task`)."""
-    sat = state.job_saturated[jnp.maximum(pj, 0)]
+    move_executor_to_pool which does not clear `executor.task`).
+    `opj` is the caller's one-hot of `pj` over the jobs: all-false for
+    the common pool's -1, where `sat` then reads False and is masked by
+    `noop` (the indexed read took job 0's there, masked the same)."""
+    sat = _pick(opj, state.job_saturated)
     noop = (pj < 0) | ((ps < 0) & ~sat)
     m = mask & ~noop
     to_common = m & sat
@@ -144,10 +169,12 @@ def _move_idle_from_pool(
     )
 
 
-def _exec_location(state: EnvState, e: jnp.ndarray):
-    """Pool key of executor e: (-1,-1) for common; (job, stage|-1) else."""
-    pj = jnp.where(state.exec_at_common[e], -1, state.exec_job[e])
-    ps = jnp.where(state.exec_at_common[e], -1, state.exec_stage[e])
+def _exec_location(state: EnvState, one_e: jnp.ndarray):
+    """Pool key of the executor `one_e` marks (bool[N]): (-1,-1) for
+    common; (job, stage|-1) else."""
+    at_common = _pick(one_e, state.exec_at_common)
+    pj = jnp.where(at_common, -1, _pick(one_e, state.exec_job))
+    ps = jnp.where(at_common, -1, _pick(one_e, state.exec_stage))
     return pj, ps
 
 
@@ -177,16 +204,16 @@ A_NONE, A_START, A_SEND, A_IDLE, A_PARK = 0, 1, 2, 3, 4
 # --------------------------------------------------------------------------
 
 
-def _find_backup_stage(params: EnvParams, state: EnvState, e: jnp.ndarray,
-                       quirk_src: jnp.ndarray):
+def _find_backup_stage(params: EnvParams, state: EnvState,
+                       own: jnp.ndarray, quirk_src: jnp.ndarray):
     """Greedy local-then-global search for a stage to absorb an executor
+    (of job `own`, -1 for none)
     that arrived somewhere it is no longer needed. Reproduces the
     reference's `if not source_job_id` falsiness quirk (:521-522): when the
     executor's job id is 0, the saturation-filter exemption falls back to
     the tracker's source job *as it was when the reference would run this
     search* (`quirk_src` — phase-A handlers may update the tracked source
     before the search runs here)."""
-    own = state.exec_job[e]
     eff_src = jnp.where(own == 0, quirk_src, own)
     sched = find_schedulable(params, state, eff_src)
     j_cap, s_cap = sched.shape
@@ -223,14 +250,22 @@ def _resolve_action(
     _mets_inner send/start/park (:799-819)."""
     j = jnp.maximum(rj, 0)
     s = jnp.maximum(rs, 0)
-    saturated = state.stage_remaining[j, s] == 0
-    found, bj, bs = _find_backup_stage(params, state, e, quirk_src)
+    j_cap, s_cap = state.stage_remaining.shape
+    # `e` may be a job's index (a job arrival's argument, a request of
+    # RQ_NONE): clipped as `_apply_action` clips it, so that the pick
+    # is the indexed read's clamp
+    n = state.exec_job.shape[0]
+    own = _pick(_onehot(n, jnp.clip(e, 0, n - 1)), state.exec_job)
+    saturated = _pick(
+        _onehot2(j_cap, s_cap, j, s), state.stage_remaining
+    ) == 0
+    found, bj, bs = _find_backup_stage(params, state, own, quirk_src)
     use_backup = saturated & found
     tj = jnp.where(use_backup, bj, j)
     ts = jnp.where(use_backup, bs, s)
     dead = saturated & ~found
-    send = state.exec_job[e] != tj
-    start = state.frontier[tj, ts]
+    send = own != tj
+    start = _pick(_onehot2(j_cap, s_cap, tj, ts), state.frontier)
     ak_move = jnp.where(
         dead, A_IDLE,
         jnp.where(send, A_SEND, jnp.where(start, A_START, A_PARK)),
@@ -262,20 +297,23 @@ def _apply_action(
     fused into one straight-line pass of masked whole-array selects, at
     most one update per state field."""
     rng, sub = jax.random.split(state.rng)
-    e = jnp.clip(e, 0, state.exec_job.shape[0] - 1)
-    tpl = state.job_template[tj]
+    n = state.exec_job.shape[0]
+    j_cap, s_cap = state.stage_remaining.shape
+    # the one-hots of this call's writes, which its reads pick with
+    # too (`_pick`): (tj, ts) is in range, from `_resolve_action`
+    one_e = _onehot(n, jnp.clip(e, 0, n - 1))
+    oj = _onehot(j_cap, tj)
+    os_ = _onehot(s_cap, ts)
+    m2 = oj[:, None] & os_[None, :]
+
     num_local = (state.exec_job == tj).sum()
     dur = sample_task_duration(
         params, bank, jax.random.uniform(sub, (2,)),
-        state.duration_facts[tj, ts], tpl, ts, num_local,
-        state.exec_task_valid[e], state.exec_task_stage[e] == ts,
+        _pick(m2, state.duration_facts), _pick(oj, state.job_template),
+        ts, num_local,
+        _pick(one_e, state.exec_task_valid),
+        _pick(one_e, state.exec_task_stage) == ts,
     )
-
-    n = state.exec_job.shape[0]
-    j_cap, s_cap = state.stage_remaining.shape
-    one_e = _onehot(n, e)
-    oj = _onehot(j_cap, tj)
-    m2 = _onehot2(j_cap, s_cap, tj, ts)
 
     is_start = ak == A_START
     is_send = ak == A_SEND
@@ -284,15 +322,17 @@ def _apply_action(
 
     # IDLE = _move_idle_executors for the single executor e: no-op for the
     # common pool and unsaturated job pools; saturated job -> common pool
-    pj, ps = _exec_location(state, e)
-    pool_sat = state.job_saturated[jnp.maximum(pj, 0)]
+    # (at the common pool's -1 the pick reads False and `pj < 0` masks
+    # it, as it masked job 0's under the indexed read)
+    pj, ps = _exec_location(state, one_e)
+    pool_sat = _pick(_onehot(j_cap, pj), state.job_saturated)
     idle_eff = is_idle & ~((pj < 0) | ((ps < 0) & ~pool_sat))
     idle_common = idle_eff & pool_sat
 
     # START/SEND bookkeeping read before any mutation
     seq = state.seq_counter
-    old_job = state.exec_job[e]
-    newly_saturated = is_start & (state.stage_remaining[tj, ts] == 1)
+    old_job = _pick(one_e, state.exec_job)
+    newly_saturated = is_start & (_pick(m2, state.stage_remaining) == 1)
 
     i32_ = lambda b: b.astype(_i32)  # noqa: E731
     m2_start = m2 & is_start
@@ -358,7 +398,7 @@ def _apply_action(
         ),
         moving_count=state.moving_count + i32_(m2 & is_send),
     )
-    return _refresh_sat(state, tj, ts, enable=is_start | is_send)
+    return _refresh_sat(state, oj, os_, enable=is_start | is_send)
 
 
 # --------------------------------------------------------------------------
@@ -389,8 +429,9 @@ def _add_commitment(
 
     j_cap, s_cap = state.commit_count.shape
     oj = _onehot(j_cap, dj)  # all-false when dj == -1
+    os_ = _onehot(s_cap, ds)
     supply = state.job_supply + n * (oj & (dj != src_j)).astype(_i32)
-    cc = state.commit_count + n * _onehot2(j_cap, s_cap, dj, ds).astype(
+    cc = state.commit_count + n * (oj[:, None] & os_[None, :]).astype(
         _i32
     )
 
@@ -405,9 +446,7 @@ def _add_commitment(
         cm_dst_stage=jnp.where(take, ds, state.cm_dst_stage),
         cm_seq=jnp.where(take, seq, state.cm_seq),
     )
-    return _refresh_sat(
-        state, jnp.maximum(dj, 0), jnp.maximum(ds, 0), enable=dj >= 0
-    )
+    return _refresh_sat(state, oj, os_, enable=dj >= 0)
 
 
 def _commit_remaining(state: EnvState) -> EnvState:
@@ -441,26 +480,28 @@ def _fulfill_commitment_phase_a(
     Pure bookkeeping + move request; the actual move is resolved/applied by
     the caller (see structural note above). Returns
     (state, req_kind, rj, rs)."""
-    dj = state.cm_dst_job[slot]
-    ds = state.cm_dst_stage[slot]
-    sj = state.cm_src_job[slot]
+    n = state.cm_valid.shape[0]
     j_cap, s_cap = state.commit_count.shape
+    # `slot` is a slot's index (`_peek_commitment`'s argmin, an entry
+    # of `slot_order`), so the picks are the indexed reads
+    oslot = _onehot(n, slot)
+    dj = _pick(oslot, state.cm_dst_job)
+    ds = _pick(oslot, state.cm_dst_stage)
+    sj = _pick(oslot, state.cm_src_job)
     oj = _onehot(j_cap, dj)  # all-false when dj == -1
-    m2 = _onehot2(j_cap, s_cap, dj, ds)
+    os_ = _onehot(s_cap, ds)
+    m2 = oj[:, None] & os_[None, :]
     state = state.replace(
-        cm_valid=state.cm_valid
-        & ~_onehot(state.cm_valid.shape[0], slot),
+        cm_valid=state.cm_valid & ~oslot,
         job_supply=state.job_supply - (oj & (dj != sj)).astype(_i32),
         commit_count=state.commit_count - m2.astype(_i32),
     )
-    state = _refresh_sat(
-        state, jnp.maximum(dj, 0), jnp.maximum(ds, 0), enable=dj >= 0
-    )
+    state = _refresh_sat(state, oj, os_, enable=dj >= 0)
 
     def to_common(st: EnvState):
-        pj, ps = _exec_location(st, e)
-        n = st.exec_job.shape[0]
-        st = _move_idle_from_pool(st, pj, ps, _onehot(n, e))
+        one_e = _onehot(n, e)
+        pj, ps = _exec_location(st, one_e)
+        st = _move_idle_from_pool(st, pj, ps, one_e, _onehot(j_cap, pj))
         return st, _i32(RQ_NONE), _i32(-1), _i32(-1)
 
     def to_stage(st: EnvState):
@@ -770,10 +811,11 @@ def _fulfill_from_source(
             k, st, tm = carry
         else:
             k, st = carry
-        e = exec_order[k]
+        ok = _onehot(n, k)  # k < num_idle <= n
+        e = _pick(ok, exec_order)
         quirk_src = st.source_job_id()
         st, rk, rj, rs = _fulfill_commitment_phase_a(
-            st, e, slot_order[k]
+            st, e, _pick(ok, slot_order)
         )
         ak, tj, ts = _resolve_action(
             params, st, rk, e, rj, rs, quirk_src
@@ -850,12 +892,18 @@ def _handle_job_arrival(state: EnvState, j: jnp.ndarray):
 
 
 def _handle_executor_ready(state: EnvState, e: jnp.ndarray):
-    j = state.exec_dst_job[e]
-    s = state.exec_dst_stage[e]
     n = state.exec_job.shape[0]
     j_cap, s_cap = state.moving_count.shape
+    # under `vmap` every handler runs for every lane: where the event
+    # is a job's arrival `e` is a job's index, an `e` past the last
+    # executor picks 0 where the read clamped, and the switch discards
+    # the branch either way
     one_e = _onehot(n, e)
-    m2 = _onehot2(j_cap, s_cap, j, s)
+    j = _pick(one_e, state.exec_dst_job)
+    s = _pick(one_e, state.exec_dst_stage)
+    oj = _onehot(j_cap, j)
+    os_ = _onehot(s_cap, s)
+    m2 = oj[:, None] & os_[None, :]
     state = state.replace(
         moving_count=state.moving_count - m2.astype(_i32),
         exec_moving=state.exec_moving & ~one_e,
@@ -864,19 +912,24 @@ def _handle_executor_ready(state: EnvState, e: jnp.ndarray):
         exec_job=jnp.where(one_e, j, state.exec_job),
         exec_stage=jnp.where(one_e, -1, state.exec_stage),
     )
-    state = _refresh_sat(state, j, s)
+    state = _refresh_sat(state, oj, os_)
     return state, _i32(RQ_MOVE), j, s
 
 
 def _handle_task_finished(state: EnvState, e: jnp.ndarray):
-    j = state.exec_job[e]
-    s = state.exec_task_stage[e]
     n = state.exec_job.shape[0]
     j_cap, s_cap = state.stage_executing.shape
+    # as in `_handle_executor_ready`: where this is not the event's
+    # handler `e` may mark no executor, or one with no job (-1), the
+    # picks below then read 0 / False where the reads clamped or
+    # wrapped, and the switch discards the branch
     one_e = _onehot(n, e)
+    j = _pick(one_e, state.exec_job)
+    s = _pick(one_e, state.exec_task_stage)
     oj = _onehot(j_cap, j)
-    m2 = oj[:, None] & _onehot(s_cap, s)[None, :]
-    frontier_before = state.frontier[j]
+    os_ = _onehot(s_cap, s)
+    m2 = oj[:, None] & os_[None, :]
+    frontier_before = _pick(oj[:, None], state.frontier, axis=0)
 
     state = state.replace(
         stage_executing=state.stage_executing - m2.astype(_i32),
@@ -890,12 +943,17 @@ def _handle_task_finished(state: EnvState, e: jnp.ndarray):
         return st, _i32(RQ_START), j, s
 
     def released(st: EnvState):
-        stage_done = st.stage_completed[j, s]
+        stage_done = _pick(m2, st.stage_completed)
+        # job j's adjacency, from its packed parent sets (a pick over
+        # the jobs of a [J,S]-sized operand: nothing here reads the
+        # [J,S,S] adjacency)
+        adj_j = _unpack_parents(_job_parent_sets(st, oj), s_cap)
         # maintain the frontier cache: one fewer incomplete parent for
         # every child of a completed stage
+        children = _pick(os_[:, None], adj_j, axis=0)
         st = st.replace(
             incomplete_parent_count=st.incomplete_parent_count
-            - (stage_done & oj[:, None] & st.adj[j, s][None, :]).astype(
+            - (stage_done & oj[:, None] & children[None, :]).astype(
                 _i32
             )
         )
@@ -903,8 +961,10 @@ def _handle_task_finished(state: EnvState, e: jnp.ndarray):
         # j's active subgraph, so recompute THAT job's row only (stage
         # completion is the sole mutation point — the bulk passes only
         # launch tasks and can never complete a stage)
-        act_row = st.stage_exists[j] & ~st.stage_completed[j]
-        adj_row = st.adj[j] & act_row[:, None] & act_row[None, :]
+        act_row = _pick(
+            oj[:, None], st.stage_exists & ~st.stage_completed, axis=0
+        )
+        adj_row = adj_j & act_row[:, None] & act_row[None, :]
         lvl_row = _job_topo_levels(act_row, adj_row)
         st = st.replace(
             node_level=jnp.where(
@@ -912,13 +972,15 @@ def _handle_task_finished(state: EnvState, e: jnp.ndarray):
                 st.node_level,
             )
         )
-        new_frontier = st.frontier[j] & ~frontier_before
+        new_frontier = (
+            _pick(oj[:, None], st.frontier, axis=0) & ~frontier_before
+        )
         did_change = stage_done & new_frontier.any()
-        job_done = st.job_completed[j]
+        job_done = _pick(oj, st.job_completed)
 
         def complete_job(st: EnvState) -> EnvState:
             pool = st.pool_member_mask(j, _i32(-1)) & ~st.exec_executing
-            st = _move_idle_from_pool(st, j, _i32(-1), pool)
+            st = _move_idle_from_pool(st, j, _i32(-1), pool, oj)
             return st.replace(
                 job_t_completed=jnp.where(
                     oj, st.wall_time, st.job_t_completed
@@ -926,7 +988,7 @@ def _handle_task_finished(state: EnvState, e: jnp.ndarray):
             )
 
         st = lax.cond(
-            job_done & jnp.isinf(st.job_t_completed[j]),
+            job_done & jnp.isinf(_pick(oj, st.job_t_completed)),
             complete_job, lambda s2: s2, st,
         )
 
@@ -941,7 +1003,7 @@ def _handle_task_finished(state: EnvState, e: jnp.ndarray):
             )
             st = lax.cond(
                 did_change,
-                lambda s2: _move_idle_from_pool(s2, j, s, _onehot(n, e)),
+                lambda s2: _move_idle_from_pool(s2, j, s, one_e, oj),
                 lambda s2: s2,
                 st,
             )
@@ -964,7 +1026,7 @@ def _handle_task_finished(state: EnvState, e: jnp.ndarray):
         return st, rk, rj, rs
 
     return lax.cond(
-        state.stage_remaining[j, s] > 0, more_tasks, released, state
+        _pick(m2, state.stage_remaining) > 0, more_tasks, released, state
     )
 
 
@@ -1562,6 +1624,31 @@ def pack_parents(adj: jnp.ndarray) -> jnp.ndarray:
     return _pack_stage_sets(adj)
 
 
+def _job_parent_sets(state: EnvState, oj: jnp.ndarray) -> jnp.ndarray:
+    """uint32[W,S]: the packed parent sets of the job `oj` marks
+    (bool[J]; all zero where it marks none), picked over the job axis
+    of `state.parent_sets`. What the drain body's fixed part reads of
+    the adjacency it reads here: a [J,S]-sized operand, where
+    `adj[j, s]` and `adj[j]` gather from the [J,S,S] one."""
+    return _pick(oj[:, None, None], state.parent_sets, axis=0)
+
+
+def _children(sets_j: jnp.ndarray, os_: jnp.ndarray) -> jnp.ndarray:
+    """bool[S]: `adj[j, s, :]`, the children of the stage `os_` marks
+    (bool[S]) in the job whose packed parent sets are `sets_j`: c is a
+    child iff its parent set holds the stage's bit."""
+    of_stage = _pack_stage_sets(os_[None, :])[0]  # [W]: the set {s}
+    return ((sets_j & of_stage[:, None]) != 0).any(0)
+
+
+def _unpack_parents(sets_j: jnp.ndarray, s_cap: int) -> jnp.ndarray:
+    """bool[S,S]: `adj[j]` (`[p, c]`: edge p -> c) from the job's packed
+    parent sets, `pack_parents` undone for one job."""
+    place = jnp.arange(STAGE_SET_BITS, dtype=jnp.uint32)[None, :, None]
+    bits = (sets_j[:, None, :] >> place) & 1  # [W,32,S]
+    return bits.reshape(-1, sets_j.shape[-1])[:s_cap].astype(bool)
+
+
 def _flipped_parents(state: EnvState, delta: jnp.ndarray) -> jnp.ndarray:
     """`sum_p delta[j,p] * adj[j,p,c]` for `delta` in {-1, 0, +1}, from
     the state's packed parent sets: the parents of (j,c) that turned
@@ -1735,9 +1822,6 @@ def _bulk_events_fused(
         state.exec_job[None, :] == jnp.arange(j_cap, dtype=_i32)[:, None]
     ).sum(-1).astype(_i32)
 
-    def pick_i(oh, x):
-        return jnp.where(oh, x, 0).sum().astype(x.dtype)
-
     def step_fn(carry, u2, in_budget):
         (t_f, sq_f, t_a, fj, fs, rem, jcnt, launch_t, dur_js, relc,
          arr_done, started, counter, wall, crossed, steps, active) = carry
@@ -1768,33 +1852,33 @@ def _bulk_events_fused(
         # gather, serialised on the TPU (1.5 to 2.2 us for 128 lanes
         # where a select-reduce fuses with its neighbours), and each
         # brought a relayout of its index column with it
-        tj = jnp.where(is_fin, pick_i(e_oh, fj), pick_i(e_oh, djc))
-        ts = jnp.where(is_fin, pick_i(e_oh, fs), pick_i(e_oh, dsc))
+        tj = jnp.where(is_fin, _pick(e_oh, fj), _pick(e_oh, djc))
+        ts = jnp.where(is_fin, _pick(e_oh, fs), _pick(e_oh, dsc))
         oh_j = _onehot(j_cap, tj)
         oh2 = _onehot2(j_cap, s_cap, tj, ts)
-        rem_t = pick_i(oh2, rem)
+        rem_t = _pick(oh2, rem)
         ok = active & has & before_job & (rem_t > 0)
         if stop_at_limit:
             ok = ok & ~crossed
             crossed = crossed | (ok & (tmin >= state.time_limit))
-        start_a = (e_oh & frontier_a).any()  # arrival-start vs park
+        start_a = _pick(e_oh, frontier_a)  # arrival-start vs park
         is_rel = ok & is_fin
         is_arr = ok & ~is_fin
         launch = is_rel | (is_arr & start_a)
         # an arrival that joins the live source pool ends the run
         # AFTER being consumed (the caller's tail then runs exactly
         # where the sequential loop's would)
-        joins = is_arr & (e_oh & joins_a).any()
+        joins = is_arr & _pick(e_oh, joins_a)
 
         # duration for the launched task (relaunch: same-stage
         # continuation; arrival: the sequential wave inputs)
         # (an arrival counts itself among the job's executors)
-        nl = pick_i(oh_j, jcnt) + is_arr.astype(_i32)
-        tv = jnp.where(is_fin, True, (e_oh & tv_a).any())
-        ss = jnp.where(is_fin, True, (e_oh & ss_a).any())
+        nl = _pick(oh_j, jcnt) + is_arr.astype(_i32)
+        tv = jnp.where(is_fin, True, _pick(e_oh, tv_a))
+        ss = jnp.where(is_fin, True, _pick(e_oh, ss_a))
         dur = sample_task_duration(
-            params, bank, u2, pick_i(oh2, state.duration_facts),
-            pick_i(oh_j, state.job_template), ts, nl, tv, ss,
+            params, bank, u2, _pick(oh2, state.duration_facts),
+            _pick(oh_j, state.job_template), ts, nl, tv, ss,
         )
 
         t_f = jnp.where(launch & e_oh, tmin + dur, t_f)
@@ -2014,7 +2098,8 @@ def _resume_simulation(
             def move_and_clear(st: EnvState) -> EnvState:
                 idle = st.source_pool_mask() & ~st.exec_executing
                 st = _move_idle_from_pool(
-                    st, st.source_job, st.source_stage, idle
+                    st, st.source_job, st.source_stage, idle,
+                    _onehot(params.max_jobs, st.source_job),
                 )
                 return st.replace(
                     source_valid=jnp.bool_(False),
@@ -2190,10 +2275,12 @@ def step(
     s_cap = params.max_stages
     j = stage_idx // s_cap
     s = stage_idx % s_cap
+    # all-false for a `stage_idx` out of range, which `valid` rules out
+    sel = _onehot2(params.max_jobs, s_cap, j, s)
     valid = (
         (stage_idx >= 0)
         & (stage_idx < params.num_nodes)
-        & state.schedulable[j, s]
+        & _pick(sel, state.schedulable)
     )
     if track:
         live = ~(state.terminated | state.truncated)
@@ -2201,10 +2288,9 @@ def step(
     def do_commit(st: EnvState) -> EnvState:
         committable = st.num_committable()
         n = jnp.clip(num_exec, 1, committable)
-        n = jnp.minimum(n, st.exec_demand[j, s])  # _adjust_num_executors
+        # _adjust_num_executors
+        n = jnp.minimum(n, _pick(sel, st.exec_demand))
         st = _add_commitment(st, n, j, s)
-        j_cap, s_cap2 = st.stage_selected.shape
-        sel = _onehot2(j_cap, s_cap2, j, s)
         st = st.replace(stage_selected=st.stage_selected | sel)
         sched = find_schedulable(params, st, st.source_job_id())
         return st.replace(schedulable=sched)

@@ -48,7 +48,9 @@ from .core import (
     RQ_NONE,
     _compute_jobtime,
     _rank_order,
+    _onehot,
     _onehot2,
+    _pick,
     _add_commitment,
     _apply_action,
     _bulk_events_fused,
@@ -329,19 +331,19 @@ def _apply_decision(
     n = st.exec_job.shape[0]
     s_cap = params.max_stages
     j, s = stage_idx // s_cap, stage_idx % s_cap
+    # all-false for a `stage_idx` out of range, which `valid` rules out
+    sel = _onehot2(params.max_jobs, s_cap, j, s)
     valid = (
         (stage_idx >= 0)
         & (stage_idx < params.num_nodes)
-        & st.schedulable[j, s]
+        & _pick(sel, st.schedulable)
     )
 
     def do_commit(stt: EnvState) -> EnvState:
         committable = stt.num_committable()
         nn = jnp.clip(num_exec, 1, committable)
-        nn = jnp.minimum(nn, stt.exec_demand[j, s])
+        nn = jnp.minimum(nn, _pick(sel, stt.exec_demand))
         stt = _add_commitment(stt, nn, j, s)
-        j_cap, s_cap2 = stt.stage_selected.shape
-        sel = _onehot2(j_cap, s_cap2, j, s)
         stt = stt.replace(stage_selected=stt.stage_selected | sel)
         return stt.replace(
             schedulable=find_schedulable(
@@ -418,11 +420,15 @@ def _fulfill_branch(ls: LoopState):
     the shared-tail argument tuple."""
     st = ls.env
     k = ls.fulfill_k
-    e = ls.exec_order[k]
+    # a lane past its last idle executor (`skip` below; k may be N)
+    # picks executor 0 where the read clamped to the last entry, and
+    # requests nothing either way
+    ok = _onehot(ls.exec_order.shape[0], k)
+    e = _pick(ok, ls.exec_order)
     quirk = st.source_job_id()
 
     def do(st: EnvState):
-        return _fulfill_commitment_phase_a(st, e, ls.slot_order[k])
+        return _fulfill_commitment_phase_a(st, e, _pick(ok, ls.slot_order))
 
     def skip(st: EnvState):
         return st, _i32(RQ_NONE), _i32(-1), _i32(-1)
@@ -660,7 +666,8 @@ def _finish_micro_step(
         def move_and_clear(st: EnvState) -> EnvState:
             idle = st.source_pool_mask() & ~st.exec_executing
             st = _move_idle_from_pool(
-                st, st.source_job, st.source_stage, idle
+                st, st.source_job, st.source_stage, idle,
+                _onehot(params.max_jobs, st.source_job),
             )
             return st.replace(
                 source_valid=jnp.bool_(False),
@@ -955,13 +962,26 @@ def drain_to_decision(
     (an iteration of two 15.7 us at a job axis of 50 and 16.7 at 20,
     from 39: the step reads the bank for three elements and the lane's
     own state by the one-hots it builds, where it made nine gathers),
-    so the loop is a quarter to a half of a body and the part spent
+    so the loop was a quarter to a half of a body and the part spent
     whatever the lanes hold the larger one (276 of 384 us in
     `sweep_fair`, 168 of 318 in `decima_batch20`; PERF.md section 5,
-    PR 50).
-    Nothing in the body reads the [J,S,S] adjacency whole: the pass's
+    PR 50): compiled for the v5e, 71 of that part's 220 instructions
+    were gathers, every indexed read of a lane's own state at one
+    (job, stage), job, executor or slot, each with a relayout of its
+    index column. Since PR 51 those reads are picks by the one-hots
+    the masked writes use (`core._pick`) and the part is 120
+    instructions, its three gathers the bank's for `_apply_action`'s
+    duration: 45 us of a 130-us body in `sweep_fair` and 44 of 194 in
+    `decima_batch20` (most of what went were relayouts of whole
+    [lanes,J,S] grids into the layout the gathers' operands wanted,
+    7 us each; PERF.md sections 5 and 6, PR 51), so the early-exit
+    loop is two thirds to three quarters of a body now.
+    Nothing in the body reads the [J,S,S] adjacency at all: the pass's
     refresh of the saturation caches counts on `EnvState.parent_sets`
-    (until PR 39 a contraction over the adjacency, 181 us a body).
+    (until PR 39 a contraction over the adjacency, 181 us a body), and
+    `_refresh_sat` and the released-stage handler read a stage's
+    children and a job's adjacency off the same words (until PR 51 six
+    row gathers), so the compiled loop does not carry the adjacency.
     `lane_axis`, the name the caller's `vmap` gave its lane axis, lets
     that loop end on one predicate for all the lanes of that `vmap`
     (the whole batch, or one block of it); a caller

@@ -198,3 +198,196 @@ def test_rank_order_matches_stable_argsort():
         np.asarray(_rank_order(key)),
         np.asarray(jnp.argsort(key, stable=True)),
     )
+
+
+def indexed_pick(oh, x, axis=None):
+    """The read `core._pick` stands for, as the engine made it until
+    PR 51: `x` at the index the one-hot marks, by an indexed read
+    (under `jax.vmap` a gather). A mask that marks nothing reads index
+    0, as the reads that clamped a -1 sentinel did. The leaf-for-leaf
+    tests of a sweep chunk and of a sync collection put it in
+    `_pick`'s place (tests/test_sweep.py, tests/test_trainers.py)."""
+    import jax.numpy as jnp
+
+    if axis is None:
+        oh = jnp.broadcast_to(oh, x.shape)
+        return x.reshape(-1)[jnp.argmax(oh.reshape(-1))]
+    assert axis == 0 and oh.shape[1:] == (1,) * (x.ndim - 1), oh.shape
+    return x[jnp.argmax(oh.reshape(-1))]
+
+
+def swap_in_the_reads_replaced(monkeypatch, which, num_executors):
+    """Put back what a `perf_opt` took out of the engine, for a
+    leaf-for-leaf comparison: "tables", PR 50's sampler that read the
+    bank's `level_present`, `max_present` and three counts a duration
+    (`tests/test_bulk_pass_setup.py` keeps it); "indexed reads", the
+    indexed read of a lane's own state in place of PR 51's pick by
+    one-hot (`indexed_pick`, above; a sentinel's
+    all-false mask then reads element 0 and not 0 / False, which is
+    how this shows that every such value is masked). Returns a
+    function that says how often the stand-in was traced."""
+    from sparksched_tpu.env import core, flat_loop
+
+    from .test_bulk_pass_setup import table_reading_sampler
+
+    if which == "tables":
+        reference = table_reading_sampler(num_executors)
+        monkeypatch.setattr(core, "sample_task_duration", reference)
+        return lambda: reference.traced
+    assert which == "indexed reads"
+    traced = []
+
+    def pick(oh, x, axis=None):
+        traced.append(x.shape)
+        return indexed_pick(oh, x, axis)
+
+    monkeypatch.setattr(core, "_pick", pick)
+    monkeypatch.setattr(flat_loop, "_pick", pick)
+    return lambda: len(traced)
+
+
+def _pick_operand(shape, dtype):
+    rng = np.random.default_rng(51)
+    if dtype == "bool":
+        return rng.integers(0, 2, size=shape).astype(bool)
+    if dtype == "float32":
+        x = rng.normal(size=shape).astype(np.float32) * 1e4
+        flat = x.reshape(-1)
+        flat[::3] = np.inf  # the engine's pending times are INF-padded
+        flat[1] = -0.5
+        return x
+    hi = {"int32": 2**31 - 1, "uint32": 2**32 - 1}[dtype]
+    x = rng.integers(0, hi, size=shape, dtype=np.int64)
+    if dtype == "int32":
+        x.reshape(-1)[::2] *= -1  # -1 sentinels and below
+    return x.astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", ["int32", "uint32", "float32", "bool"])
+@pytest.mark.parametrize(
+    "shape", [(6, 20), (6,), (10,)], ids=["JS", "J", "N"])
+def test_pick_is_the_indexed_read_at_every_index(shape, dtype):
+    """`core._pick` against `x[i]` / `x[j, s]` over EVERY in-range
+    index of a `[J,S]`, `[J]` and `[N]` array of each kind the state
+    holds (int32 with negatives, the uint32 words, float32 with `inf`,
+    flags), under `jax.vmap` as the engine runs it, bit for bit."""
+    import jax.numpy as jnp
+
+    from sparksched_tpu.env.core import _onehot, _onehot2, _pick
+
+    x = jnp.asarray(_pick_operand(shape, dtype))
+    if len(shape) == 2:
+        j, s = (a.reshape(-1) for a in np.indices(shape))
+        got = jax.vmap(
+            lambda j, s: _pick(_onehot2(*shape, j, s), x))(j, s)
+        want = np.asarray(x)[j, s]
+    else:
+        i = np.arange(shape[0])
+        got = jax.vmap(lambda i: _pick(_onehot(shape[0], i), x))(i)
+        want = np.asarray(x)[i]
+    got = np.asarray(got)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(got, want)
+    ref = jax.vmap(
+        lambda oh: indexed_pick(oh, x))(jnp.eye(x.size, dtype=bool).reshape(
+            (x.size,) + shape))
+    np.testing.assert_array_equal(np.asarray(ref), want)
+
+
+@pytest.mark.parametrize("dtype", ["int32", "uint32", "float32", "bool"])
+def test_pick_of_a_row_is_the_indexed_row(dtype):
+    """The row form: `_pick(oj[:, None], x, axis=0)` is `x[j]` for every
+    job of a `[J,S]` array, and over the `[J,W,S]` packed parent sets
+    (`oj[:, None, None]`) a job's `[W,S]` words."""
+    import jax.numpy as jnp
+
+    from sparksched_tpu.env.core import _onehot, _pick
+
+    x = jnp.asarray(_pick_operand((6, 20), dtype))
+    rows = jax.vmap(
+        lambda j: _pick(_onehot(6, j)[:, None], x, axis=0))(np.arange(6))
+    np.testing.assert_array_equal(np.asarray(rows), np.asarray(x))
+    assert rows.dtype == x.dtype
+    ref = jax.vmap(lambda j: indexed_pick(
+        _onehot(6, j)[:, None], x, axis=0))(np.arange(6))
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(x))
+    if dtype == "uint32":
+        sets = jnp.asarray(_pick_operand((6, 2, 20), dtype))
+        words = jax.vmap(lambda j: _pick(
+            _onehot(6, j)[:, None, None], sets, axis=0))(np.arange(6))
+        np.testing.assert_array_equal(np.asarray(words), np.asarray(sets))
+
+
+@pytest.mark.parametrize("index", [-1, 6, 2**31 - 1])
+def test_pick_out_of_range_reads_zero_where_the_read_wraps_or_clamps(
+    index,
+):
+    """What an index outside the array gives: the one-hot marks
+    nothing, so a pick reads 0 / False (0.0 even beside an `inf`),
+    where the indexed read of a traced index wraps a negative one to
+    the end and clamps one past it. Every caller of `_pick` whose
+    index can be a sentinel masks the value (core.py says where)."""
+    import jax.numpy as jnp
+
+    from sparksched_tpu.env.core import _onehot, _onehot2, _pick
+
+    for dtype in ("int32", "uint32", "float32", "bool"):
+        x = jnp.asarray(_pick_operand((6,), dtype))
+        x = x.at[-1].set(np.asarray(True if dtype == "bool" else 7, dtype))
+        i = jnp.int32(index)
+        got = jax.jit(lambda i: _pick(_onehot(6, i), x))(i)
+        assert got.dtype == x.dtype and not got.any(), (dtype, got)
+        assert jax.jit(lambda i: x[i])(i) == x[-1]  # the read it replaced
+        g = jnp.asarray(_pick_operand((6, 20), dtype))
+        assert not _pick(_onehot2(6, 20, i, jnp.int32(3)), g).any()
+        past_s = jnp.int32(20 if index == 6 else index)
+        assert not _pick(_onehot2(6, 20, jnp.int32(3), past_s), g).any()
+        row = _pick(_onehot(6, i)[:, None], g, axis=0)
+        assert row.shape == (20,) and not row.any()
+
+
+def test_parent_set_reads_are_the_adjacency_s(small_setup):
+    """What the drain body's fixed part reads of the adjacency it reads
+    off the packed parent sets: `_children(_job_parent_sets(state, oj),
+    os_)` is `adj[j, s]` and `_unpack_parents` of a job's sets `adj[j]`,
+    for every (job, stage) of a reset state, and at a stage axis over
+    one word (S = 40: two words a set)."""
+    import jax.numpy as jnp
+
+    from sparksched_tpu.env.core import (
+        _children,
+        _job_parent_sets,
+        _onehot,
+        _unpack_parents,
+        pack_parents,
+    )
+
+    params, bank = small_setup
+    state = reset(params, bank, jax.random.PRNGKey(3))
+    adj = np.asarray(state.adj)
+    assert adj.any()
+    j_cap, s_cap = adj.shape[:2]
+    j, s = (a.reshape(-1) for a in np.indices((j_cap, s_cap)))
+
+    def reads(state, s_cap):
+        return jax.vmap(lambda j, s: (
+            _children(_job_parent_sets(state, _onehot(j_cap, j)),
+                      _onehot(s_cap, s)),
+            _unpack_parents(
+                _job_parent_sets(state, _onehot(j_cap, j)), s_cap),
+        ))
+
+    rows, blocks = reads(state, s_cap)(j, s)
+    np.testing.assert_array_equal(np.asarray(rows), adj[j, s])
+    np.testing.assert_array_equal(np.asarray(blocks), adj[j])
+
+    wide = np.zeros((j_cap, 40, 40), bool)
+    wide[:, :s_cap, :s_cap] = adj
+    wide[:, 33, 39] = wide[:, 2, 35] = wide[:, 31, 32] = True
+    wide_state = state.replace(
+        adj=jnp.asarray(wide), parent_sets=pack_parents(jnp.asarray(wide)))
+    assert wide_state.parent_sets.shape == (j_cap, 2, 40)
+    j, s = (a.reshape(-1) for a in np.indices((j_cap, 40)))
+    rows, blocks = reads(wide_state, 40)(j, s)
+    np.testing.assert_array_equal(np.asarray(rows), wide[j, s])
+    np.testing.assert_array_equal(np.asarray(blocks), wide[j])

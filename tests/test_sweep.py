@@ -279,18 +279,14 @@ def test_a_concatenated_carry_goes_on_as_its_parts(small, swept):
         assert np.array_equal(a[:, 2:], want[:64, :2]), name
 
 
-def test_a_chunk_equals_the_one_sampled_from_the_banks_tables(monkeypatch):
-    """PR 50: a chunk of the sweep (whole episodes, re-seeds inside the
-    scan) over a bank with executor levels and whole waves missing is,
-    leaf for leaf, the chunk collected with the sampler that read the
-    bank's `level_present`, `max_present` and three counts a duration
-    (`tests/test_bulk_pass_setup.py` keeps it): carry, record and
-    telemetry, 0 unequal leaves. The carry holds the one leaf more,
-    each lane's words of its OWN templates, the re-seeded ones too."""
-    from sparksched_tpu.env import core
-    from sparksched_tpu.workload.sampling import pack_duration_facts
-
-    from .test_bulk_pass_setup import sparse_bank, table_reading_sampler
+@pytest.fixture(scope="module")
+def sparse_chunk():
+    """A chunk of the sweep (whole episodes, re-seeds inside the scan)
+    over a bank with executor levels and whole waves missing:
+    `(bank, carry0, chunk, got)`, `chunk()` the program traced anew
+    (whatever the engine's helpers are at that moment) and run, `got`
+    its result with the helpers as they are."""
+    from .test_bulk_pass_setup import sparse_bank
 
     bank = sparse_bank(EXECUTORS)
     params = EnvParams(
@@ -300,8 +296,8 @@ def test_a_chunk_equals_the_one_sampled_from_the_banks_tables(monkeypatch):
     carry0 = sweep.init(params, bank, KEY, LANES)
 
     def chunk():
-        # a function and a jit of its own a side: the sampler is no
-        # key of a jit's cache
+        # a function and a jit of its own a side: the sampler and the
+        # pick are no key of a jit's cache
         def program(*args):
             return sweep._chunk(*args)
 
@@ -309,11 +305,30 @@ def test_a_chunk_equals_the_one_sampled_from_the_banks_tables(monkeypatch):
             params, bank, sched.batch_policy, carry0, jax.random.PRNGKey(1),
             ROWS))
 
-    got = chunk()
-    reference = table_reading_sampler(EXECUTORS)
-    monkeypatch.setattr(core, "sample_task_duration", reference)
+    return bank, carry0, chunk, chunk()
+
+
+@pytest.mark.parametrize("replaced", ["tables", "indexed reads"])
+def test_a_chunk_equals_the_one_collected_by_the_reads_replaced(
+    sparse_chunk, monkeypatch, replaced,
+):
+    """A chunk of the sweep over a bank with executor levels and whole
+    waves missing is, leaf for leaf (carry, record and telemetry, 0
+    unequal), the chunk collected (PR 50, "tables") with the sampler
+    that read the bank's tables, and (PR 51, "indexed reads") with
+    every pick by one-hot of the drain's fixed part, of the decide
+    step and of the early-exit loop made as the indexed read it
+    replaced. The carry holds each lane's words of its OWN templates,
+    the re-seeded ones too."""
+    from sparksched_tpu.workload.sampling import pack_duration_facts
+
+    from .test_env_core import swap_in_the_reads_replaced
+
+    bank, carry0, chunk, got = sparse_chunk
+    traced = swap_in_the_reads_replaced(monkeypatch, replaced, EXECUTORS)
     want = chunk()
-    assert reference.traced >= 3  # the passes sampled by it
+    # the passes sampled by it; the forty-odd reads of a body and a row
+    assert traced() >= (3 if replaced == "tables" else 40), traced()
     unequal = [
         jax.tree_util.keystr(path)
         for (path, x), y in zip(

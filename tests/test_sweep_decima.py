@@ -409,10 +409,12 @@ def test_a_heuristics_blocked_row_equals_its_whole_batch_row(monkeypatch):
 # same: a heuristic's row is the one it had. A PR that changes the
 # engine or a heuristic's row on purpose takes it again from its own
 # tree and says so: PR 50 (the duration sampler reads the stage's word
-# of `EnvState.duration_facts`; the engine's change, the row's order
-# as it was) took this one.
+# of `EnvState.duration_facts`) took 1a2764b3... of 1,642,076, and
+# PR 51 (the engine picks what it read of a lane's state at one index
+# with the one-hot its writes use, `core._pick`; the engine's change,
+# the row's order as it was) took this one.
 FAIR_CHUNK_AT_PARENT = (
-    "1a2764b3f82faf5754e95f91b23c0ff2fcfbd3de957dd9d2a7a8cd3dab1097ce")
+    "0fcdf0e295e11877a528885d475b7cba91f378985f5f6400fb52586c7013460e")
 
 
 def test_a_heuristics_chunk_lowers_to_the_parents_text():
@@ -426,7 +428,7 @@ def test_a_heuristics_chunk_lowers_to_the_parents_text():
     carry = jax.eval_shape(lambda: sweep.init(params, bank, KEY, 1024))
     text = sweep.sweep_chunk.lower(
         params, bank, sched.batch_policy, carry, KEY, 16).as_text()
-    assert len(text) == 1642076
+    assert len(text) == 1535622
     assert hashlib.sha256(text.encode()).hexdigest() == FAIR_CHUNK_AT_PARENT
 
 

@@ -248,8 +248,11 @@ def test_drain_loop_writes_nothing_of_the_adjacency_s_size(
     and every fusion, conditional and inner loop they call) yields an
     array with the adjacency's dimensions, lanes x 200 x 20 x 20 in
     any order: no copy of it into another layout, no select or
-    product over it. The loop hands the adjacency on (parameters and
-    tuples) and reads rows of it (gathers, whose results are rows).
+    product over it. Until PR 51 the loop handed the adjacency on
+    (parameters and tuples) and read rows of it (gathers, whose
+    results are rows); since then the drain reads a stage's children
+    and a job's adjacency off the packed parent sets, and the loop
+    does not hold the adjacency at all.
     Until PR 39 the refresh selected and reduced the whole of it, as
     stored (a job's 20 x 20 block padded to a tile: 105 MB at 128
     lanes), in every body."""
@@ -277,11 +280,15 @@ def _inside(text: str, loop: str) -> dict[str, str]:
     return inside
 
 
-def _holds_no_adjacency_sized_result(text: str, dims: list[int]) -> None:
+def _holds_no_adjacency_sized_result(
+    text: str, dims: list[int], batch: int | None = None
+) -> None:
     """PR 39's rule on the one drain `while` of a compiled collector,
-    whose lanes hold adjacencies of `dims` (in any order)."""
+    whose lanes hold adjacencies of `dims` (in any order; `batch`: the
+    lanes of the whole batch where the drain runs a block of them)."""
     import re
 
+    whole = sorted([batch or dims[0]] + dims[1:])  # dims[0]: the lanes
     dims = sorted(dims)
     loops = _loops(text, "env/micro_step/drain)/while")
     assert len(loops) == 1, len(loops)
@@ -289,22 +296,34 @@ def _holds_no_adjacency_sized_result(text: str, dims: list[int]) -> None:
     assert len(inside) > 100, len(inside)  # the loop, not a stub of it
 
     hands_on = {"parameter", "get-tuple-element", "tuple", "bitcast"}
-    found, carried = [], 0
-    for name, body in inside.items():
-        for line in body.split("\n"):
-            m = re.match(
-                r"\s*(?:ROOT )?%[\w.\-]+ = (\(?)(\w+)\[([\d,]*)\]"
-                r"[^ ]* ([\w\-]+)\(", line
-            )
-            if not m or m.group(1):
-                continue  # a tuple is handed on, whatever it holds
-            shape = sorted(int(d) for d in m.group(3).split(",") if d)
-            if shape == dims:
-                if m.group(4) in hands_on:
-                    carried += 1
-                else:
-                    found.append((name, line.strip()[:160]))
-    assert carried >= 2, carried  # the adjacency IS in the loop
+
+    def adjacency_sized(comps, dims=dims):
+        """(handed on, yielded) among the instructions of `comps`."""
+        found, carried = [], 0
+        for name, body in comps.items():
+            for line in body.split("\n"):
+                m = re.match(
+                    r"\s*(?:ROOT )?%[\w.\-]+ = (\(?)(\w+)\[([\d,]*)\]"
+                    r"[^ ]* ([\w\-]+)\(", line
+                )
+                if not m or m.group(1):
+                    continue  # a tuple is handed on, whatever it holds
+                shape = sorted(int(d) for d in m.group(3).split(",") if d)
+                if shape == dims:
+                    if m.group(4) in hands_on:
+                        carried += 1
+                    else:
+                        found.append((name, line.strip()[:160]))
+        return carried, found
+
+    # the adjacency IS in the program and the pattern finds it. Until
+    # PR 51 that was shown on the loop itself, which handed it on and
+    # gathered rows of it; since then nothing in the drain reads the
+    # adjacency (its rows come off `parent_sets`) and the compiler
+    # keeps it out of the loop's carry altogether
+    carried, _ = adjacency_sized(_computations(text), whole)
+    assert carried >= 2, carried
+    _, found = adjacency_sized(inside)
     assert not found, found
 
 
@@ -338,7 +357,10 @@ def test_drain_reads_no_grid_once_an_executor(flagship, collector):
     cluster, in every body, until the bits came from the packed
     frontier (`core._frontier_at`). The decide step, once a row, still
     reads other grids that way (`_bulk_fulfill`'s remaining-task
-    counts), which shows that the pattern is found where it is."""
+    counts), which shows that the pattern is found where it is: since
+    PR 51 the drain gathers from the bank alone, nine times, and the
+    decide step's `_bulk_fulfill` is where the lanes' own arrays are
+    still gathered from."""
     p = flagship.params_env
     lanes, n = flagship.num_envs, p.num_executors
     grid = sorted([lanes, p.max_jobs, p.max_stages])
@@ -350,8 +372,9 @@ def test_drain_reads_no_grid_once_an_executor(flagship, collector):
                 if res == [lanes, n] and sorted(op) == grid]
 
     drain = _gathers(text, "env/micro_step/drain")
-    assert len(drain) > 50 and not per_executor(drain)
-    assert per_executor(_gathers(text, "env/micro_step/decide"))
+    assert drain and not per_executor(drain)
+    decide = _gathers(text, "env/micro_step/decide")
+    assert len(decide) > 10 and per_executor(decide)
 
 
 @pytest.fixture(scope="module")
@@ -406,7 +429,8 @@ def test_blocked_drain_is_one_while_inside_one_loop_over_blocks(batched):
     carried = drain.split(" while(")[0]
     assert "[128," in carried and "[1024," not in carried
     _holds_no_adjacency_sized_result(
-        text, [_DRAIN_BLOCK, p.max_jobs, p.max_stages, p.max_stages])
+        text, [_DRAIN_BLOCK, p.max_jobs, p.max_stages, p.max_stages],
+        batch=trainer.num_envs)
 
 
 # the fused bulk pass's early-exit loop, inside the vmapped drain
@@ -534,11 +558,65 @@ def test_early_exit_loop_gathers_three_bank_elements_a_step(
     )
     assert in_loop == tables, in_loop
     assert list(bank.level_present.shape) not in in_loop
-    # the drain's body outside that loop still gathers from the
-    # state's grids: the pattern is found where it is
-    grid = [128, params.max_jobs, params.max_stages]
-    outside = [op for _, op in _gathers(text, "env/micro_step/drain")]
-    assert outside.count(grid) >= 10, outside
+    # the decide step (`_bulk_fulfill`) still gathers from the state's
+    # grids, at the batch's full width: the pattern is found where it
+    # is (until PR 51 the drain's body outside that loop did too)
+    grid = [params.max_jobs, params.max_stages]
+    decide = [op[1:] for _, op in _gathers(text, "env/micro_step/decide")]
+    assert decide.count(grid) >= 4, decide
+
+
+# the vmapped drain's `while` body, which holds the early-exit loop
+DRAIN_BODY = "vmap(env/micro_step/drain)/while/body"
+
+
+@pytest.mark.parametrize(
+    "program", ["sweep chunk", "batched collector", "flagship collector"])
+def test_drain_body_gathers_from_no_array_of_the_lane_s_own(
+    request, program
+):
+    """What takes a counter's place for PR 51 (the mechanism engages in
+    every drain body by construction), in the programs as compiled for
+    the v5e: the drain body OUTSIDE the early-exit loop (the pop, the
+    handlers, `_resolve_action`, `_apply_action`, `_refresh_sat`, the
+    shared tail) holds THREE gathers, one element each of the bank's
+    `cnt`, `dur` and `rough_duration` for `_apply_action`'s sampled
+    duration, and none whose operand is a lane's own `[128,J,S]`,
+    `[128,J]`, `[128,N]` or `[128,J,S,S]` array. The parent's held
+    SEVENTY-ONE there (`s32[128,J,S]` fifteen times, `s32[128,N]`
+    twenty, `pred[128,J,S]` fourteen, six rows of the adjacency): each
+    a serialised `kCustom` fusion with a relayout of its index column
+    before it. What the body reads of a lane's state at one (job,
+    stage), job, executor or slot it picks with the one-hot its
+    masked writes use (`core._pick`), and the adjacency's rows it
+    reads off the packed parent sets (`core._job_parent_sets`): no
+    adjacency gather stays. Under the drain's whole scope (the
+    early-exit loop's six, and in the sweep the re-seed inside the
+    loop over blocks) every gather's operand is a table of the bank."""
+    if program == "sweep chunk":
+        params, bank, text = request.getfixturevalue("fair_chunk")
+    elif program == "batched collector":
+        trainer, text = request.getfixturevalue("batched")
+        params, bank = trainer.params_env, trainer.bank
+    else:
+        trainer = request.getfixturevalue("flagship")
+        text = request.getfixturevalue("collector").as_text()
+        params, bank = trainer.params_env, trainer.bank
+    lanes = 128  # a block of the drain; the flagship's whole batch
+    body = [op for _, op in _gathers(text, DRAIN_BODY)]
+    in_loop = [op for _, op in _gathers(text, EARLY_EXIT)]
+    tables = sorted(
+        list(leaf.shape)
+        for leaf in (bank.cnt, bank.dur, bank.rough_duration))
+    assert len(in_loop) == 6 and len(body) == 9, (in_loop, body)
+    fixed = sorted(body)
+    for op in in_loop:
+        fixed.remove(op)
+    assert fixed == tables, fixed
+    templates = bank.num_stages.shape[0]
+    drain = [op for _, op in _gathers(text, "env/micro_step/drain")]
+    assert len(drain) >= 9 and all(
+        op[0] == templates and op[0] != lanes for op in drain), drain
 
 
 def test_sweep_chunk_under_decima_is_one_net_in_the_loop_over_blocks(
@@ -600,7 +678,12 @@ def test_blocked_drain_leaves_the_sampler_its_layout(
     job-major, `{2,0,1}`, the layout reached the sampler, its softmax
     over a lane's stage scores summed in another order, and a tenth of
     the log-probs parted from the mesh's by a last bit. Sliced out and
-    written back in place they stay `{2,1,0}`, lanes outermost."""
+    written back in place they keep ONE layout, the one the mesh's
+    program gives a chip's 128 lanes: `{2,1,0}`, lanes outermost,
+    while the drain's gathers wanted a stage-minor carry; `{0,1,2}`,
+    lane-minor, since PR 51 took them out (the mesh's program and this
+    one compiled for the described v5e:2x2 agree on it, on both sides
+    of that PR: PERF.md section 6, PR 51)."""
     import re
 
     import chip_smoke
@@ -620,7 +703,7 @@ def test_blocked_drain_leaves_the_sampler_its_layout(
     assert len(_loops(text, "env/micro_step/drain/while")) == 1  # blocked
     grids = re.findall(
         r"= f32\[512,200,20\]\{([\d,]+)[^\n]*decima/sample", text)
-    assert len(grids) >= 4 and set(grids) == {"2,1,0"}, grids
+    assert len(grids) >= 4 and set(grids) == {"0,1,2"}, grids
 
 
 @pytest.mark.parametrize("batched", [False, True])

@@ -821,22 +821,18 @@ def test_sync_rollout_is_leaf_equal_under_telemetry_and_counts_no_stream():
     assert s["decisions"] == int(np.asarray(ro.valid).sum())
 
 
-def test_sync_collection_equals_the_one_sampled_from_the_banks_tables(
-    monkeypatch,
-):
-    """PR 50: a sync collection over a bank with executor levels and
-    whole waves missing is, leaf for leaf, the one collected with the
-    sampler that read the bank's `level_present`, `max_present` and
-    three counts a duration (`tests/test_bulk_pass_setup.py` keeps
-    it): every stored observation, action, reward and time, and the
-    final state, which holds each lane's words of its templates."""
+@pytest.fixture(scope="module")
+def sparse_collection():
+    """A sync collection over a bank with executor levels and whole
+    waves missing: `(params, bank, collect, got)`, `collect()` the
+    collector traced anew (whatever the engine's helpers are at that
+    moment) and run, `got` its result with the helpers as they are."""
     import jax
 
     from sparksched_tpu.env import core
     from sparksched_tpu.trainers.rollout import collect_flat_sync_batch
-    from sparksched_tpu.workload.sampling import pack_duration_facts
 
-    from .test_bulk_pass_setup import sparse_bank, table_reading_sampler
+    from .test_bulk_pass_setup import sparse_bank
 
     params, _, bpol, _, _, salts = _stream_fixture()
     bank = sparse_bank(params.num_executors, params.max_stages)
@@ -845,19 +841,40 @@ def test_sync_collection_equals_the_one_sampled_from_the_banks_tables(
         params, bank, seq0, jax.random.fold_in(seq0, salt)))(salts)
 
     def collect():
-        # a function and a jit of its own a side: the sampler is no
-        # key of a jit's cache
+        # a function and a jit of its own a side: the sampler and the
+        # pick are no key of a jit's cache
         def collector(*args):
             return collect_flat_sync_batch.__wrapped__(*args)
 
         return jax.device_get(jax.jit(collector, static_argnums=(0, 2, 4))(
             params, bank, bpol, jax.random.PRNGKey(100), 60, states))
 
-    got = collect()
-    reference = table_reading_sampler(params.num_executors)
-    monkeypatch.setattr(core, "sample_task_duration", reference)
+    return params, bank, collect, collect()
+
+
+@pytest.mark.parametrize("replaced", ["tables", "indexed reads"])
+def test_sync_collection_equals_the_one_collected_by_the_reads_replaced(
+    sparse_collection, monkeypatch, replaced,
+):
+    """A sync collection over a bank with executor levels and whole
+    waves missing is, leaf for leaf (every stored observation, action,
+    reward and time, and the final state, which holds each lane's
+    words of its templates), the one collected (PR 50, "tables") with
+    the sampler that read the bank's `level_present`, `max_present`
+    and three counts a duration, and (PR 51, "indexed reads") with
+    every pick by one-hot of the decide step, the drain body and the
+    early-exit loop made as the indexed read it replaced
+    (`tests/test_env_core.py` keeps both stand-ins)."""
+    from sparksched_tpu.workload.sampling import pack_duration_facts
+
+    from .test_env_core import swap_in_the_reads_replaced
+
+    params, bank, collect, got = sparse_collection
+    traced = swap_in_the_reads_replaced(
+        monkeypatch, replaced, params.num_executors)
     want = collect()
-    assert reference.traced >= 3  # the passes sampled by it
+    # the passes sampled by it; the forty-odd reads of a body and a row
+    assert traced() >= (3 if replaced == "tables" else 40), traced()
     _assert_leaf_equal(got, want)
     assert got.valid.sum() > 40 and len(np.unique(got.wall_times)) > 40
     final = got.final_state
